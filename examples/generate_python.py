@@ -62,8 +62,8 @@ def main(argv=None) -> int:
         args.out = os.path.join(
             args.work_dir if SMOKE else ARTIFACTS, "pycorpus_samples.txt")
 
-    # The decode-scan program is expensive to compile through remote-
-    # compile transports (~minutes); persist it so reruns are instant.
+    # The decode-scan program takes a while to compile; persist it so
+    # reruns start at once.
     from pddl_tpu.utils.compile_cache import enable_persistent_compile_cache
 
     enable_persistent_compile_cache()

@@ -101,28 +101,11 @@ def axis_index(axis_name: str) -> jnp.ndarray:
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named axis (``hvd.size()`` analogue in traced code).
-
-    Version-gated like :func:`pddl_tpu.core.mesh.shard_map`: newer jax
-    spells it ``lax.axis_size``; older releases expose the frame via
-    ``jax.core.axis_frame`` (which, depending on the release, returns
-    either the size itself or a frame object carrying ``.size``)."""
-    sz = getattr(lax, "axis_size", None)
-    if sz is not None:
-        return sz(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
+    """Static size of a named axis (``hvd.size()`` analogue in traced code)."""
+    return lax.axis_size(axis_name)
 
 
 def pcast_varying(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     """Mark ``x`` device-varying along ``axis_name`` for the
-    varying-manual-axes checker (``lax.pcast(..., to="varying")``).
-
-    A no-op on pre-vma jax: the compat
-    :func:`pddl_tpu.core.mesh.shard_map` disables the legacy replication
-    checker there, so there is no vma state to update and the values
-    are already per-shard."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis_name, to="varying")
+    varying-manual-axes checker."""
+    return lax.pcast(x, axis_name, to="varying")

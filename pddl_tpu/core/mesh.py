@@ -39,59 +39,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def mesh_context(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    Newer jax spells it ``jax.set_mesh`` (with ``jax.sharding.use_mesh``
-    as the intermediate name); older releases use the ``Mesh`` object's
-    own context manager. The shardings this repo passes to ``jit`` are
-    explicit ``NamedSharding``s that carry their mesh, so the ambient
-    context is belt-and-braces — but version-gating it here keeps the
-    Trainer importable and RUNNABLE on every jax the container ships
-    instead of failing at the first ``init_state``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # old-style: `with mesh:` sets the ambient mesh
-
-
-def has_vma_checking() -> bool:
-    """True when this jax has the top-level ``jax.shard_map`` with the
-    varying-manual-axes checker (``check_vma``). Older releases only
-    ship ``jax.experimental.shard_map`` whose ``check_rep`` checker
-    predates vma propagation; tests pinning checker behaviour gate on
-    this instead of erroring at collection."""
-    return hasattr(jax, "shard_map")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              **kwargs):
-    """Version-gated ``jax.shard_map`` (the :func:`mesh_context` trick
-    applied to per-shard mapping).
-
-    Newer jax spells it ``jax.shard_map(..., check_vma=...)``; the
-    container's older release only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``. The
-    fallback always disables the legacy replication checker: it
-    predates varying-manual-axes propagation and rejects patterns
-    (pallas kernels, psum-into-replicated) the modern checker accepts,
-    and disabling it never affects numerics — only whether the claimed
-    out_specs replication is verified. Every shard_map in this repo
-    (flash/ring attention, the GPipe pipeline, the collectives tests)
-    goes through here so one jax upgrade flips them all together.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kwargs)
-    from jax.experimental.shard_map import shard_map as sm_exp  # noqa: PLC0415
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, **kwargs)
-
 # Canonical axis names, in canonical order.
 DATA_AXIS = "data"
 MODEL_AXIS = "model"

@@ -426,12 +426,45 @@ def generate(model: GPT, variables, prompt, max_new_tokens: int, *,
     # single dispatch for all max_new_tokens steps. A host-side
     # token-at-a-time loop costs one (or more) host→device round trips
     # per token, which dominates wall-clock wherever dispatch has
-    # latency (remote/tunneled transports, busy hosts); on-device scan
-    # makes generation latency the compute itself.
+    # latency (a busy host); on-device scan makes generation latency
+    # the compute itself.
     cache, logits = step(params, cache, prompt)
     if rng is None:
         rng = jax.random.key(0)  # unused under greedy; scan needs a value
     return jnp.concatenate([prompt, run(params, cache, logits, rng)], axis=1)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _teacher_forced_logits(model, variables, tokens):
+    return model.apply(variables, tokens, train=False)
+
+
+def greedy_gap(model, variables, tokens, prompt_len: int):
+    """How far below the argmax each GENERATED token of ``tokens`` sits
+    under the model's own teacher-forced conditional along ``tokens``'
+    own prefix: float32 ``[B, T - prompt_len]``, 0 where the token is
+    the argmax.
+
+    The hardware-honest test of a greedy decode. Two compiled programs
+    for the same math (the one-token tick and a k+1-wide verify block,
+    a paged kernel and a dense prefill, an engine stream and one-shot
+    :func:`generate`) produce bf16 logits that legitimately differ by
+    ulps, and on near-uniform logits (untrained weights: ties
+    everywhere) an ulp flips an argmax — so token strings may diverge
+    while both are valid greedy decodes. What must hold is that every
+    emitted token is an argmax or a numerical tie of it: a small gap. A
+    WRONG token (stale cache, wrong position, wrong block) lands at a
+    typical logit, a gap of the logits' whole spread. ``tokens`` is
+    int32 ``[B, T]`` (prompt + continuation, ``T <= model.max_len``);
+    the forward runs at exactly that ``T``, so a caller picks the shape
+    the attention kernel tiles.
+    """
+    tokens = jnp.asarray(tokens, jnp.int32)
+    logits = _teacher_forced_logits(model, variables, tokens)
+    logits = logits[:, prompt_len - 1:-1].astype(jnp.float32)
+    chosen = jnp.take_along_axis(
+        logits, tokens[:, prompt_len:, None], axis=-1)[..., 0]
+    return jax.device_get(logits.max(axis=-1) - chosen)
 
 
 def _decode_fns(dec, temperature, top_k, top_p, max_new_tokens,

@@ -33,7 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pddl_tpu.core.collectives import pcast_varying
-from pddl_tpu.core.mesh import DATA_AXIS, STAGE_AXIS, shard_map
+from pddl_tpu.core.mesh import DATA_AXIS, STAGE_AXIS
 
 PyTree = Any
 
@@ -139,7 +139,7 @@ def gpipe_apply(
     param_specs = jax.tree.map(
         lambda p: P(stage_axis, *([None] * (p.ndim - 1))), stage_params
     )
-    return shard_map(
+    return jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(param_specs, P(data_axis, *([None] * (x.ndim - 1)))),

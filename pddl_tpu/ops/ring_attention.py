@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pddl_tpu.core.collectives import axis_size, pcast_varying
-from pddl_tpu.core.mesh import shard_map
 from pddl_tpu.ops.attention import NEG_INF
 
 
@@ -262,7 +261,7 @@ def sequence_parallel_attention(
     # exists. tests/test_attention.py::test_flash_ring_check_vma_limitation
     # pins the exact failure so a jax upgrade that fixes it flips this
     # flag. The XLA ring path runs fully checked.
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=not use_flash,
     )(q, k, v)

@@ -1453,10 +1453,7 @@ class ServeEngine:
                 nxt = self._warm_spec()
             else:
                 self._cache, nxt, self._rng = self._tick_p(
-                    self._params, self._cache, self._positions,
-                    self._tables, self._tokens, self._temps,
-                    self._top_ks, self._top_ps, *self._tick_extra(),
-                    self._rng)
+                    *self._tick_args())
             jax.block_until_ready((tok, nxt))
             self._warm = True
             return
@@ -1496,12 +1493,30 @@ class ServeEngine:
         if self._spec_on:
             nxt = self._warm_spec()
         else:
-            self._cache, nxt, self._rng = self._tick_p(
-                self._params, self._cache, self._positions, self._tokens,
-                self._temps, self._top_ks, self._top_ps,
-                *self._tick_extra(), self._rng)
+            self._cache, nxt, self._rng = self._tick_p(*self._tick_args())
         jax.block_until_ready((tok, nxt))
         self._warm = True
+
+    def _tick_args(self) -> tuple:
+        """The one-token tick's arguments as they stand now. Warmup,
+        ``step()`` and :meth:`tick_lowering` all build them here, so the
+        three can never disagree on the program's signature."""
+        tables = (self._tables,) if self._paged else ()
+        return (self._params, self._cache, self._positions, *tables,
+                self._tokens, self._temps, self._top_ks, self._top_ps,
+                *self._tick_extra(), self._rng)
+
+    def tick_lowering(self):
+        """The one-token tick LOWERED at its serving shapes, for
+        inspection (``jax.stages.Lowered``): ``.as_text()`` shows
+        whether the paged Mosaic kernel is in the program
+        (``tpu_custom_call``) or a jnp/interpreted stand-in is,
+        ``.compile().memory_analysis()`` what it needs. Lowering runs
+        nothing and adds no executable to :meth:`compile_counts`."""
+        if self._spec_on:
+            raise ValueError("a speculative engine has no one-token tick "
+                             "(draft/verify replace it)")
+        return self._tick_p.lower(*self._tick_args())
 
     def _warm_spec(self):
         """Trace the draft/verify pair (and the draft model's admission
@@ -3505,18 +3520,8 @@ class ServeEngine:
                 self._lose_live_slots()
         elif live:
             try:
-                if self._paged:
-                    self._cache, nxt, self._rng = self._device_call(
-                        "tick", self._tick_p, self._params, self._cache,
-                        self._positions, self._tables, self._tokens,
-                        self._temps, self._top_ks, self._top_ps,
-                        *self._tick_extra(), self._rng)
-                else:
-                    self._cache, nxt, self._rng = self._device_call(
-                        "tick", self._tick_p, self._params, self._cache,
-                        self._positions, self._tokens, self._temps,
-                        self._top_ks, self._top_ps, *self._tick_extra(),
-                        self._rng)
+                self._cache, nxt, self._rng = self._device_call(
+                    "tick", self._tick_p, *self._tick_args())
             except _SlotStateLost:
                 self._lose_live_slots()
                 nxt = None

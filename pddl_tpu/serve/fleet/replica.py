@@ -517,8 +517,10 @@ class ProcessReplica:
     def _spawn(self, wait_ready: bool = True) -> None:
         # The worker must import pddl_tpu from wherever THIS process
         # found it — which may be a sys.path entry the child would not
-        # inherit (PYTHONPATH is appended to, never overwritten: other
-        # entries, e.g. platform-plugin site dirs, must survive).
+        # inherit. The rest of the environment is copied as it is, so
+        # every worker initialises jax on the parent's default backend:
+        # fine on CPU, but on a chip machine a chip belongs to one
+        # process, so the process fleet is CPU-only until ROADMAP R6.
         import pddl_tpu  # noqa: PLC0415
 
         pkg_root = os.path.dirname(os.path.dirname(

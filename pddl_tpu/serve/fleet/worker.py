@@ -63,21 +63,19 @@ FENCED_CMDS = ("submit", "cancel", "restore", "fence")
 
 def build_engine(config: Dict[str, object]):
     """Engine from a flat config dict (the fleet's one model family for
-    now: GPT with ``attention="reference"`` — the CPU-safe path)."""
+    now: GPT with ``attention="reference"``).
+
+    That makes the process fleet a CPU-only configuration: every
+    replica prefills with the O(S²) jnp attention whatever device it
+    lands on, and each worker initialises jax on the default backend,
+    so two of them cannot share one chip. No number from a process
+    fleet is a chip number until ROADMAP R6 gives the fleet a chip path
+    (``LocalReplica`` with device placement)."""
     import jax
     import jax.numpy as jnp
 
     from pddl_tpu.models.gpt import GPT
     from pddl_tpu.serve import ServeEngine
-
-    # Fleet determinism: every process deriving params from param_seed
-    # must draw the SAME bits. Newer jax defaults this True; older
-    # releases default False — pin it so a worker and the oracle
-    # comparing against it can never disagree on initialization.
-    try:
-        jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # noqa: BLE001 - flag gone once always-on
-        pass
 
     model = GPT(vocab_size=int(config.get("vocab", 256)),
                 max_len=int(config.get("max_len", 512)),

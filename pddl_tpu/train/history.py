@@ -12,6 +12,10 @@ class History:
     def __init__(self) -> None:
         self.history: Dict[str, List[float]] = {}
         self.epoch: List[int] = []
+        # The Trainer whose fit() produced this (keras's History.model):
+        # callers of run_experiment() get the History back and reach the
+        # trained state and compile counts through it.
+        self.trainer = None
 
     def append(self, epoch: int, logs: Dict[str, float]) -> None:
         self.epoch.append(epoch)

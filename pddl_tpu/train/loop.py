@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pddl_tpu.core.mesh import mesh_context
 from pddl_tpu.obs.trace import NULL_TRACER
 from pddl_tpu.parallel.base import Strategy
 from pddl_tpu.parallel.single import SingleDeviceStrategy
@@ -201,7 +200,7 @@ class Trainer:
 
         abstract = jax.eval_shape(_init, rng)
         self._state_shardings = self.strategy.state_sharding(abstract)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             self.state = jax.jit(_init, out_shardings=self._state_shardings)(rng)
         self._build_steps()
         return self.state
@@ -555,6 +554,7 @@ class Trainer:
             )
         self.steps_per_epoch = steps_per_epoch
         history = History()
+        history.trainer = self
         self.stop_training = False
         self.global_step = 0
         self._batches_consumed = 0

@@ -2,7 +2,7 @@
 
 The ROADMAP north star is a system that "handles as many scenarios as
 you can imagine"; at production scale device faults are ROUTINE, not
-exceptional — a transient ``XlaRuntimeError`` from a flaky
+exceptional — a transient ``JaxRuntimeError`` from a flaky
 interconnect, a ``RESOURCE_EXHAUSTED`` under HBM pressure, a latency
 spike from a neighbor, a SIGKILL from the scheduler. You cannot trust
 a recovery path you cannot exercise, so faults here are INJECTABLE and
@@ -26,7 +26,7 @@ one fault taxonomy, one recovery contract, serving AND training.
 Fault taxonomy and the caller's contract for each:
 
 - **TRANSIENT** (raises :class:`InjectedTransientError`, the stand-in
-  for an ``INTERNAL``/``UNAVAILABLE`` ``XlaRuntimeError``): the call is
+  for an ``INTERNAL``/``UNAVAILABLE`` ``JaxRuntimeError``): the call is
   retried with bounded exponential backoff; past ``max_retries`` the
   affected device state is declared lost and the subsystem's replay
   path runs (serving: token-exact request replay; training: restore
@@ -59,6 +59,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 
 class FaultKind(enum.Enum):
@@ -69,7 +70,7 @@ class FaultKind(enum.Enum):
 
 
 class InjectedTransientError(RuntimeError):
-    """Stand-in for a retryable ``XlaRuntimeError`` (INTERNAL /
+    """Stand-in for a retryable ``JaxRuntimeError`` (INTERNAL /
     UNAVAILABLE / ABORTED): the device call failed but nothing about
     the caller's resident state is invalidated."""
 
@@ -92,10 +93,17 @@ class KillPoint(BaseException):
 
 
 # What a fault-aware caller may see from jax itself. Classification is
-# by status-code marker in the message (jaxlib's XlaRuntimeError carries
-# the absl status string); anything unrecognized is NOT swallowed.
+# by status-code marker in the message (``jax.errors.JaxRuntimeError``
+# carries the absl status string); anything unrecognized is NOT swallowed.
 _TRANSIENT_MARKERS = ("INTERNAL", "UNAVAILABLE", "ABORTED", "DATA_LOSS",
                       "DEADLINE_EXCEEDED")
+# The same error class also carries the COMPILER's refusals, under the
+# same status codes: a Mosaic kernel it cannot lower is INTERNAL, a
+# program that does not fit the device is RESOURCE_EXHAUSTED. Those are
+# not run-time device faults — retrying or shedding cannot make the
+# program compile, and a caller that "recovered" would never have run
+# it — so they are never classified.
+_COMPILE_MARKERS = ("Mosaic failed to compile", "compile permanent error")
 
 
 def classify(err: BaseException) -> Optional[str]:
@@ -105,8 +113,10 @@ def classify(err: BaseException) -> Optional[str]:
         return "oom"
     if isinstance(err, InjectedTransientError):
         return "transient"
-    if type(err).__name__ == "XlaRuntimeError":
+    if isinstance(err, JaxRuntimeError):
         msg = str(err)
+        if any(m in msg for m in _COMPILE_MARKERS):
+            return None
         if "RESOURCE_EXHAUSTED" in msg:
             return "oom"
         if any(m in msg for m in _TRANSIENT_MARKERS):

@@ -8,7 +8,6 @@ import optax
 import pytest
 
 import pddl_tpu.compat.hvd as hvd
-from pddl_tpu.core.mesh import shard_map
 from pddl_tpu.data.synthetic import SyntheticImageClassification
 from pddl_tpu.models.resnet import tiny_resnet
 from pddl_tpu.parallel.mirrored import MirroredStrategy
@@ -69,7 +68,7 @@ def test_distributed_optimizer_pmeans_gradients_in_shard_map(mesh8):
             updates, _ = tx.update(g, opt_state, p)
             return optax.apply_updates(p, updates)
 
-        return shard_map(
+        return jax.shard_map(
             _inner, mesh=mesh8,
             in_specs=(P("data"), P("data")),
             out_specs=P("data"),

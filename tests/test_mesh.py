@@ -11,7 +11,6 @@ from pddl_tpu.core.mesh import (
     MeshConfig,
     build_mesh,
     mesh_num_replicas,
-    shard_map,
     validate_divisible,
 )
 
@@ -45,7 +44,7 @@ def test_psum_pmean_over_mesh(mesh8):
     def f(x):
         return collectives.psum(x, "data"), collectives.pmean(x, "data")
 
-    g = shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P())
+    g = jax.shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P())
     s, m = g(jnp.arange(8.0))
     assert s[0] == 28.0
     assert m[0] == 3.5
@@ -55,7 +54,7 @@ def test_broadcast_from_root(mesh8):
     def f(x):
         return collectives.broadcast(x, "data", root=3)
 
-    g = shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
+    g = jax.shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
     out = g(jnp.arange(8.0))
     np.testing.assert_array_equal(np.asarray(out), np.full(8, 3.0))
 
@@ -64,7 +63,7 @@ def test_ppermute_ring(mesh8):
     def f(x):
         return collectives.ppermute_ring(x, "data", shift=1)
 
-    g = shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
+    g = jax.shard_map(f, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
     out = np.asarray(g(jnp.arange(8.0)))
     # member i sends to i+1: position j holds value j-1 (mod 8)
     np.testing.assert_array_equal(out, np.roll(np.arange(8.0), 1))
@@ -76,6 +75,6 @@ def test_reduce_scatter(mesh8):
 
     # Each member holds a length-8 vector of ones; psum_scatter sums across
     # members then scatters: each member ends with 8/8=1 element == 8.0.
-    g = shard_map(f, mesh=mesh8, in_specs=P(None), out_specs=P("data"))
+    g = jax.shard_map(f, mesh=mesh8, in_specs=P(None), out_specs=P("data"))
     out = np.asarray(g(jnp.ones(8)))
     np.testing.assert_array_equal(out, np.full(8, 8.0))

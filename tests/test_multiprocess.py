@@ -17,20 +17,6 @@ import sys
 import time
 
 import numpy as np
-import pytest
-
-from pddl_tpu.core.mesh import has_vma_checking
-
-# The container's older jaxlib cannot compile cross-process collectives
-# on the CPU backend at all (children die with "INVALID_ARGUMENT:
-# Multiprocess computations aren't implemented on the CPU backend"), so
-# the whole real-2-process topology is unreachable there. The in-process
-# 8-device mesh covers the sharding/collective paths in tier-1; these
-# tests add the genuine multi-host bootstrap on a modern jax.
-pytestmark = pytest.mark.skipif(
-    not has_vma_checking(),
-    reason="container jaxlib lacks cross-process CPU collectives "
-           "(gloo multiprocess backend); covered on modern jax only")
 
 _CHILD = os.path.join(os.path.dirname(__file__), "_multiworker_child.py")
 

@@ -333,14 +333,36 @@ def test_oom_degrades_flushes_and_rearms(gpt_setup):
     assert eng.metrics.prefix_hits == hits_before + 1  # cache is back
 
 
+@pytest.mark.parametrize("message,kind", [
+    ("INTERNAL: interconnect hiccup mid-dispatch", "transient"),
+    ("UNAVAILABLE: device halted", "transient"),
+    ("RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+     "allocate 1.00G. That was not possible.", "oom"),
+    # The compiler's refusals (messages as libtpu words them for a
+    # described v5e) share those status codes but must stay loud.
+    ("INTERNAL: Mosaic failed to compile TPU kernel: infer-vector-layout: "
+     "unsupported shape cast", None),
+    ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+     "memory in memory space hbm. Used 32.00G of 15.75G hbm.", None),
+    ("INVALID_ARGUMENT: shapes do not match", None),
+])
+def test_classify_real_runtime_errors(message, kind):
+    """`classify` recognises the INSTALLED jax's runtime error class by
+    isinstance (a name match on the retired `XlaRuntimeError` spelling
+    classified nothing real), and never a compile-time refusal."""
+    from pddl_tpu.utils.faults import classify
+
+    assert classify(jax.errors.JaxRuntimeError(message)) == kind
+    assert classify(RuntimeError(message)) is None
+
+
 def test_real_error_on_donated_program_never_redispatches(gpt_setup):
     """A REAL device error (not an injected pre-dispatch fault) from a
     donated-buffer program may have consumed its input, so the engine
     must escalate immediately — rebuild the slot pool and replay —
-    instead of retrying into a deleted array. Simulated with a fake
-    XlaRuntimeError from the insert program."""
+    instead of retrying into a deleted array. Simulated by raising the
+    installed jax's own runtime error class from the insert program."""
     model, variables = gpt_setup
-    FakeXla = type("XlaRuntimeError", (RuntimeError,), {})
     reqs = [((np.arange(6) * 3 + 2) % 32, 6), ((np.arange(9) + 5) % 32, 5)]
     refs = [_ref_greedy(model, variables, p, n) for p, n in reqs]
     eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
@@ -354,7 +376,8 @@ def test_real_error_on_donated_program_never_redispatches(gpt_setup):
     def flaky_insert(*args):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise FakeXla("INTERNAL: interconnect hiccup mid-dispatch")
+            raise jax.errors.JaxRuntimeError(
+                "INTERNAL: interconnect hiccup mid-dispatch")
         return real_insert(*args)
 
     eng._insert_p = flaky_insert

@@ -1,19 +1,24 @@
 """On-chip test harness: REAL TPU, Mosaic-compiled kernels.
 
 The main suite (tests/conftest.py) pins an 8-device fake CPU mesh, which
-forces every Pallas kernel through interpret mode (ops/attention.py:207)
-— the Python interpreter of the kernel, not the compiled artifact. This
-directory is the complement (VERDICT r2 weak #5): no platform pinning,
+forces every Pallas kernel through interpret mode — the Python
+interpreter of the kernel, not the compiled artifact. This directory is
+the complement (VERDICT r2 weak #5): no platform pinning,
 `interpret=False` forced at the call sites, and every test SKIPS unless
-the default backend is a real TPU. Run on the bench chip:
-
-    python -m pytest tests_tpu/ -q    # or: -m tpu
-
-and commit the log under artifacts/tpu_pytest/.
+the default backend is a real TPU. Run on the chip through the builder's
+tool (`chiprun -- python -m pytest tests_tpu/ -q`) and commit the log
+under artifacts/tpu_pytest/.
 """
 
 import jax
 import pytest
+
+
+def pytest_report_header(config):
+    """The committed log names the device it ran on."""
+    devices = jax.devices()
+    return (f"jax {jax.__version__}; devices: {len(devices)} x "
+            f"{devices[0].device_kind} (platform {devices[0].platform})")
 
 
 def pytest_collection_modifyitems(config, items):
