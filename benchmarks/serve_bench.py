@@ -1158,11 +1158,11 @@ def _obs_leg(model, variables, *, n_requests: int, prompt_len: int,
 def _write_trace_artifact(model, variables, prompts, new_tokens: int,
                           slots: int, prefill_len: int, path: str) -> int:
     """One fully traced closed-loop pass whose span log IS the bench
-    artifact: every request's span, every engine tick (the tracer's
-    ``emit_ticks`` stream — complete, unlike the capacity-bounded
-    ring), the ring's per-site-wall records for the final window, and
-    the final metrics snapshot — a self-contained timeline
-    (`docs/OPERATIONS.md` § Observability)."""
+    artifact: every request's span, every engine step (the tracer's
+    ``emit_ticks`` stream: the telemetry ring's own records, all of
+    them, where the ring keeps the newest only), and the final metrics
+    snapshot — a self-contained timeline (`docs/OPERATIONS.md`
+    § Observability)."""
     with JsonlEventLog(path) as log:
         eng = ServeEngine(model, variables, max_slots=slots,
                           prefill_len=prefill_len,
@@ -1172,18 +1172,6 @@ def _write_trace_artifact(model, variables, prompts, new_tokens: int,
         handles = [eng.submit(p, new_tokens) for p in prompts]
         eng.run(max_steps=200000)
         assert all(h.done for h in handles)
-        ring = eng.telemetry
-        # The ring window is capacity-bounded; say so in the artifact
-        # instead of letting a truncated dump read as the whole run.
-        log.write({"kind": "ring_window", "recorded": len(ring),
-                   "total_ticks": ring.total_appended,
-                   "truncated": ring.total_appended > len(ring)})
-        for rec in ring.snapshot():
-            # A DISTINCT kind from the tracer's own "tick" records:
-            # ring records carry tick_wall_s/tokens/retries, tracer
-            # ticks carry wall_s/new_tokens — one kind per shape.
-            rec["kind"] = "ring_tick"
-            log.write(rec)
         log.write({"kind": "metrics",
                    "snapshot": eng.metrics.snapshot()})
         return log.records_written

@@ -5,9 +5,9 @@ prefix match → prefill chunks → decode ticks → retries/replays →
 finish), zero-cost when disabled via the no-op :class:`NullTracer`.
 `ring.py` — the engine's fixed-capacity per-tick telemetry ring.
 `export.py` — dependency-free exporters: atomic-append JSONL event
-log, Prometheus text exposition over ``ServeMetrics`` + engine +
-StepTimer + device-memory gauges, and an optional stdlib ``/metrics``
-HTTP endpoint. See docs/OPERATIONS.md § "Observability (serving)".
+log, Prometheus text exposition over ``ServeMetrics`` + engine
+gauges + the ring summary, and an optional stdlib ``/metrics`` HTTP
+endpoint. See docs/OPERATIONS.md § "Observability (serving)".
 
 Fleet-wide distributed tracing (ISSUE 19): `propagate.py` — wire
 trace contexts, the worker span shipper, and the router-side
@@ -27,7 +27,6 @@ from pddl_tpu.obs.export import (
     TTFT_BUCKETS_S,
     JsonlEventLog,
     MetricsHTTPServer,
-    device_memory_gauges,
     engine_gauges,
     fleet_exposition,
     parse_prometheus_text,
@@ -70,7 +69,6 @@ __all__ = [
     "estimate_offset",
     "stitch",
     "TelemetryRing",
-    "device_memory_gauges",
     "engine_gauges",
     "fleet_exposition",
     "parse_prometheus_text",

@@ -13,9 +13,9 @@ Two consumers, two formats, zero new dependencies:
   :func:`serve_exposition` convenience): the v0.0.4 text format over
   ``ServeMetrics.snapshot()`` plus engine gauges (`engine_gauges`:
   ``prefix_pool_nbytes``, ``live_slots``, ``degraded``, per-site
-  ``compile_counts``), the training `StepTimer` snapshot, and
-  device-memory stats (`device_memory_gauges`) — training and serving
-  share one export path. The renderer enumerates EVERY key of the
+  ``compile_counts``) and, through :func:`train_exposition`, the
+  Trainer's fault snapshot — training and serving share one renderer.
+  The renderer enumerates EVERY key of the
   snapshot it is handed (unknown keys render as gauges), which is what
   makes the snapshot-drift guard in `tests/test_obs.py` structural: a
   new counter cannot silently skip export.
@@ -126,6 +126,13 @@ SERVE_COUNTER_KEYS = frozenset({
     # host_tier_bytes_resident stays a gauge).
     "host_tier_spills", "host_tier_hits", "host_tier_promotions",
     "host_tier_promote_tokens_charged",
+    # Where a step's time goes (the engine's phase spans, always on):
+    # steps and their wall, the wall by phase (a labeled counter, one
+    # sample per `serve/metrics.PHASES` entry), decode ticks, and of
+    # fresh requests the scheduler pops and the admissions with their
+    # summed waits.
+    "engine_steps", "step_wall_s", "phase_wall_s", "decode_ticks",
+    "queue_pops", "queue_wait_s", "admissions", "admit_wall_s",
 })
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
@@ -183,7 +190,6 @@ def reservoir_histogram(reservoir,
 def render_prometheus(snapshot: Mapping[str, object], *,
                       prefix: str = "pddl",
                       counters: frozenset = frozenset(),
-                      help_text: Optional[Mapping[str, str]] = None,
                       histograms: Optional[Mapping[str, Mapping]] = None,
                       ) -> str:
     """Render a flat snapshot dict as Prometheus text exposition.
@@ -194,7 +200,8 @@ def render_prometheus(snapshot: Mapping[str, object], *,
     can tell "no samples yet" from "metric vanished"), booleans as
     0/1, and Mapping values become one labeled series
     ``{prefix}_{key}{{key="..."}}`` per entry (``compile_counts``,
-    per-device memory). Keys must already be exposition-legal
+    per-device memory; typed counter when ``key`` is in ``counters``,
+    as ``phase_wall_s``). Keys must already be exposition-legal
     (``[a-zA-Z0-9_]``) — snapshots in this repo are.
 
     ``histograms`` maps extra metric names to
@@ -213,10 +220,9 @@ def render_prometheus(snapshot: Mapping[str, object], *,
         is_counter = key in counters
         if is_counter and not name.endswith("_total"):
             name += "_total"
-        if help_text and key in help_text:
-            lines.append(f"# HELP {name} {help_text[key]}")
+        kind = "counter" if is_counter else "gauge"
         if isinstance(value, Mapping):
-            lines.append(f"# TYPE {name} gauge")
+            lines.append(f"# TYPE {name} {kind}")
             if not value:
                 # An OPEN label set with no members yet (e.g.
                 # requests_by_adapter before any tenant traffic) still
@@ -231,8 +237,7 @@ def render_prometheus(snapshot: Mapping[str, object], *,
                     f'{name}{{key="{_escape_label(str(label_val))}"}} '
                     f"{_fmt_value(value[label_val])}")
         else:
-            lines.append(f"# TYPE {name} "
-                         f"{'counter' if is_counter else 'gauge'}")
+            lines.append(f"# TYPE {name} {kind}")
             lines.append(f"{name} {_fmt_value(value)}")
     for key in (histograms or {}):
         spec = histograms[key]
@@ -357,20 +362,6 @@ def engine_gauges(engine) -> Dict[str, object]:
     }
 
 
-def device_memory_gauges() -> Dict[str, object]:
-    """`utils/profiling.device_memory_stats` reshaped for the renderer:
-    one labeled series per stat, one label per device."""
-    from pddl_tpu.utils.profiling import device_memory_stats
-
-    stats = device_memory_stats()
-    out: Dict[str, Dict[str, int]] = {
-        "bytes_in_use": {}, "peak_bytes_in_use": {}, "bytes_limit": {}}
-    for dev, fields in stats.items():
-        for k in out:
-            out[k][dev] = fields[k]
-    return out
-
-
 TRAIN_COUNTER_KEYS = frozenset({
     # Trainer.fault_snapshot() keys that are monotonic counters; the
     # rest render as gauges. The drift guard in tests/test_train_faults
@@ -380,27 +371,16 @@ TRAIN_COUNTER_KEYS = frozenset({
 })
 
 
-def train_exposition(trainer, *, step_timer=None,
-                     device_memory: bool = False) -> str:
+def train_exposition(trainer) -> str:
     """The training scrape body: the Trainer's fault/recovery snapshot
     (retries, in-process recoveries, replayed steps, checkpoint count
     and wall time, per-kind injections, per-site dispatch wall,
     compile counts — any ``compile_counts`` value above 1 on a scrape
-    is a recompile, the zero-recompile contract as a dashboard line),
-    optionally the `StepTimer` percentiles and per-device memory —
+    is a recompile, the zero-recompile contract as a dashboard line) —
     the SAME renderer and text format the serving engine exports
     through, so one Prometheus config scrapes both."""
-    parts = [render_prometheus(trainer.fault_snapshot(),
-                               prefix="pddl_train",
-                               counters=TRAIN_COUNTER_KEYS)]
-    if step_timer is not None:
-        parts.append(render_prometheus(
-            step_timer.snapshot(), prefix="pddl_train_step",
-            counters=frozenset({"steps_timed"})))
-    if device_memory:
-        parts.append(render_prometheus(device_memory_gauges(),
-                                       prefix="pddl_device_memory"))
-    return "".join(parts)
+    return render_prometheus(trainer.fault_snapshot(), prefix="pddl_train",
+                             counters=TRAIN_COUNTER_KEYS)
 
 
 # The canonical fleet-counter vocabulary: FleetMetrics.snapshot()
@@ -570,13 +550,9 @@ def fleet_exposition(router, autoscaler=None) -> str:
     return "".join(parts)
 
 
-def serve_exposition(metrics, engine=None, *,
-                     step_timer=None,
-                     device_memory: bool = False) -> str:
-    """The one scrape body: serving metrics (+ engine gauges + ring
-    summary when an engine is given), optionally the training
-    `StepTimer` snapshot and per-device memory — training and serving
-    through a single export path."""
+def serve_exposition(metrics, engine=None) -> str:
+    """The serving scrape body: serving metrics (+ engine gauges + ring
+    summary when an engine is given)."""
     parts = [render_prometheus(
         metrics.snapshot(), prefix="pddl_serve",
         counters=SERVE_COUNTER_KEYS,
@@ -599,13 +575,6 @@ def serve_exposition(metrics, engine=None, *,
         summary.pop("window_first_step", None)
         summary.pop("window_last_step", None)
         parts.append(render_prometheus(summary, prefix="pddl_serve_ring"))
-    if step_timer is not None:
-        parts.append(render_prometheus(
-            step_timer.snapshot(), prefix="pddl_train_step",
-            counters=frozenset({"steps_timed"})))
-    if device_memory:
-        parts.append(render_prometheus(device_memory_gauges(),
-                                       prefix="pddl_device_memory"))
     return "".join(parts)
 
 

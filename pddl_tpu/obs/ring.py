@@ -5,7 +5,8 @@ RECENT per-tick shape of the engine — was occupancy pinned, did one
 site's dispatch wall time spike, did retries cluster — without an
 unbounded log. This ring is that window: the engine appends one record
 per ``step()`` (occupancy, queue depth, tokens emitted, per-site
-``_device_call`` wall time, retries, degraded flag), capacity is fixed
+``_device_call`` wall time, per-phase wall time of the step's span
+tree, retries, degraded flag), capacity is fixed
 at construction, and the oldest record is overwritten in place.
 ``snapshot()`` hands benches and the drain path a stable oldest→newest
 copy; ``summary()`` collapses the window into the handful of gauges the
@@ -18,6 +19,20 @@ computed — appending can never add a device sync.
 from __future__ import annotations
 
 from typing import Dict, List, Optional
+
+# The record's nested per-label wall-time maps (``site_wall_s``: the
+# ``_device_call`` dispatch sites; ``phase_wall_s``: the step's phases,
+# `serve/metrics.PHASES`).
+_WALL_MAPS = ("site_wall_s", "phase_wall_s")
+
+
+def _copy(record: Dict[str, object]) -> Dict[str, object]:
+    """A record the caller may mutate: the nested maps copied too."""
+    c = dict(record)
+    for key in _WALL_MAPS:
+        if isinstance(c.get(key), dict):
+            c[key] = dict(c[key])
+    return c
 
 
 class TelemetryRing:
@@ -54,37 +69,28 @@ class TelemetryRing:
 
     def snapshot(self) -> List[Dict[str, object]]:
         """Oldest→newest copy of the current window (safe to mutate —
-        the nested ``site_wall_s`` dict is copied too, so
-        post-processing a snapshot can never corrupt the live ring)."""
+        the nested wall-time maps are copied too, so post-processing a
+        snapshot can never corrupt the live ring)."""
         if self._count < self.capacity:
             window = self._slots[:self._count]
         else:
             window = self._slots[self._next:] + self._slots[:self._next]
-        out = []
-        for r in window:
-            c = dict(r)
-            sw = c.get("site_wall_s")
-            if isinstance(sw, dict):
-                c["site_wall_s"] = dict(sw)
-            out.append(c)
-        return out
+        return [_copy(r) for r in window]
 
     def last(self) -> Optional[Dict[str, object]]:
         """Newest record — copied like :meth:`snapshot`, so a caller
         post-processing it can never corrupt the live ring."""
         if self._count == 0:
             return None
-        rec = dict(self._slots[(self._next - 1) % self.capacity])
-        sw = rec.get("site_wall_s")
-        if isinstance(sw, dict):
-            rec["site_wall_s"] = dict(sw)
-        return rec
+        return _copy(self._slots[(self._next - 1) % self.capacity])
 
     def summary(self) -> Dict[str, object]:
         """The window collapsed to export gauges: tick-wall percentiles,
         mean queue/occupancy, totals, and per-site wall-time sums —
         what the drain snapshot embeds and ``/metrics`` exposes without
-        shipping every record."""
+        shipping every record (the per-phase split has its cumulative
+        counters in ``ServeMetrics``: ``rate()`` over those is the
+        recent window)."""
         window = self.snapshot()
         if not window:
             return {"ticks": 0}

@@ -148,9 +148,10 @@ class NullTracer:
     def on_token(self, handle, step: int) -> None:
         """One decode-tick token appended to the stream."""
 
-    def on_tick(self, step: int, queue_depth: int, live_slots: int,
-                new_tokens: int, wall_s: float) -> None:
-        """One engine step completed (engine-level, not per-request)."""
+    def on_tick(self, record: Dict[str, object]) -> None:
+        """One engine step completed (engine-level, not per-request):
+        ``record`` is the telemetry ring's record of it (`obs/ring.py`
+        — read it, never mutate it)."""
 
     def on_retry(self, step: int, site: str, attempt: int) -> None:
         """A transient device failure is being retried."""
@@ -258,10 +259,11 @@ class RequestTracer(NullTracer):
         out of the overall cap.
       max_finished: retained finished-span records (a bounded deque —
         the sink holds the full history, the tracer a recent window).
-      emit_ticks: also write one ``kind="tick"`` record per engine
-        step to the sink (off by default — the engine's telemetry ring
-        already holds per-tick records; turn this on when the JSONL
-        log must be self-contained).
+      emit_ticks: also write the engine's per-step record — the one
+        its telemetry ring holds, as ``kind="tick"`` — to the sink
+        (off by default: the ring already holds the recent ones; turn
+        this on when the JSONL log must be complete and
+        self-contained).
     """
 
     enabled = True
@@ -404,14 +406,10 @@ class RequestTracer(NullTracer):
                            requeued=requeued)
 
     # ------------------------------------------------------ engine hooks
-    def on_tick(self, step: int, queue_depth: int, live_slots: int,
-                new_tokens: int, wall_s: float) -> None:
+    def on_tick(self, record: Dict[str, object]) -> None:
         if self._emit_ticks:
             self._emit({"schema": SCHEMA_VERSION, "kind": "tick",
-                        "t_s": self._clock(), "step": step,
-                        "queue_depth": queue_depth,
-                        "live_slots": live_slots,
-                        "new_tokens": new_tokens, "wall_s": wall_s})
+                        **record})
 
     def on_retry(self, step: int, site: str, attempt: int) -> None:
         self._engine_event("retry", step=step, site=site, attempt=attempt)

@@ -179,7 +179,7 @@ from pddl_tpu.serve.kvcache import (
     paged_decode_cache,
     pool_nbytes,
 )
-from pddl_tpu.serve.metrics import ServeMetrics
+from pddl_tpu.serve.metrics import PHASES, ServeMetrics
 from pddl_tpu.serve.request import (
     FinishReason,
     Priority,
@@ -247,6 +247,41 @@ _PAGED_DONATED_BY_SITE = {
 # entry is stamped PER ENGINE (only when a draft model is drafting).
 _SPEC_DONATED_ROW = {"verify": "cache"}
 _SPEC_DONATED_PAGED = {"verify": "pool", "draft_prefill": "pool"}
+
+# The step's span tree in the profiler's trace: ``pddl.serve.step`` (a
+# StepTraceAnnotation carrying ``step_num``), one ``pddl.serve.<phase>``
+# child per entry of a phase of `serve/metrics.PHASES`, and inside
+# ``admit`` one ``pddl.serve.admit_request`` per admission (a span
+# only: it carries the request's metadata, and its time is ``admit``'s).
+_SPAN_PREFIX = "pddl.serve."
+
+
+class _Phase:
+    """One phase boundary of ``step()``: entering opens a
+    ``jax.profiler.TraceAnnotation`` (a TraceMe — a flag test while no
+    profiler session runs, a host span on the device trace's own clock
+    while one does) and leaving adds the ``time.perf_counter``
+    duration to the engine's per-step ``phase_wall_s``. One
+    preallocated object per phase name; a phase never nests inside
+    itself."""
+
+    __slots__ = ("_name", "_label", "_wall", "_span", "_t0")
+
+    def __init__(self, name: str, wall: Dict[str, float]):
+        self._name = name
+        self._label = _SPAN_PREFIX + name
+        self._wall = wall
+        self._span = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> None:
+        # The TraceMe's span starts at construction.
+        self._span = jax.profiler.TraceAnnotation(self._label)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        self._wall[self._name] += time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
 
 
 class ServeEngine:
@@ -462,6 +497,9 @@ class ServeEngine:
         self._tracer = NULL_TRACER
         self.telemetry = TelemetryRing(telemetry_capacity)
         self._site_wall: Dict[str, float] = {}
+        self._phase_wall: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self._phase = {name: _Phase(name, self._phase_wall)
+                       for name in PHASES}
         self._last_wall_s = 0.0
         self._cur_step = 0
 
@@ -2734,7 +2772,10 @@ class ServeEngine:
             # A prefill is mid-flight from an earlier step: the resident
             # row is ITS pipeline — advance it first; only if it
             # finishes (or settles) may new admissions start.
-            if not self._continue_slice():
+            with self._admit_request_span(self._slice["handle"],
+                                          self._slice["sid"]):
+                settled = self._continue_slice()
+            if not settled:
                 return
         free = self._free_slot_ids()
         if not free:
@@ -2768,10 +2809,19 @@ class ServeEngine:
         # A kill mid-admission can leave a handle parked in
         # `_admitting`; it owns the first free slot before anything new
         # is popped.
-        self._admitting.extend(self.scheduler.admit(
+        popped = self.scheduler.admit(
             len(free) - len(self._admitting), on_cancelled=_queued_cancel,
             on_expired=_queued_expired, now_fn=self._clock,
-            cost_fn=self._prefill_cost if use_cost else None))
+            cost_fn=self._prefill_cost if use_cost else None)
+        if popped:
+            now = self._clock()
+            for handle in popped:
+                # The scheduler's own wait, of FRESH requests only (a
+                # replay's requeue is no queue progress).
+                if handle.admit_s is None and not handle.tokens:
+                    handle.admit_s = now
+                    self.metrics.record_queue_pop(now - handle.arrival_s)
+            self._admitting.extend(popped)
         while self._admitting and free:
             if (self._slice_tokens is not None
                     and self._slice_budget_left <= 0):
@@ -2779,15 +2829,24 @@ class ServeEngine:
             handle = self._admitting[0]
             sid = free.pop(0)
             try:
-                if self._slice_tokens is not None:
-                    if not self._start_slice(sid, handle):
+                with self._admit_request_span(handle, sid):
+                    if self._slice_tokens is None:
+                        self._admit_one(sid, handle)
+                    elif not self._start_slice(sid, handle):
                         return  # pending: handle stays in _admitting
-                else:
-                    self._admit_one(sid, handle)
             except _SlotStateLost as lost:
                 free.insert(0, sid)
                 self._unwind_admission(lost, handle)
             self._admitting.popleft()
+
+    def _admit_request_span(self, handle: RequestHandle, sid: int):
+        """The ``pddl.serve.admit_request`` span of one admission (or
+        of one slice of a time-sliced one)."""
+        return jax.profiler.TraceAnnotation(
+            _SPAN_PREFIX + "admit_request",
+            request_id=handle.request.request_id,
+            prompt_len=len(handle.request.prompt), slot=sid,
+            replay=bool(handle.tokens))
 
     def _paged_append_blocks(self) -> None:
         """Before a paged tick: every live slot about to write at a
@@ -3175,7 +3234,8 @@ class ServeEngine:
                     "sample_first", self._sample_first_p, logits,
                     *self._first_mask_args(fsm),
                     np.float32(t), np.int32(k), np.float32(p), self._rng)
-                first = int(tok[0])
+                with self._phase["first_token_wait"]:
+                    first = int(tok[0])
         except _SlotStateLost:
             if self._paged and private:
                 self._prefix.release(private)
@@ -3193,7 +3253,7 @@ class ServeEngine:
             handle.ttft_s = now - handle.arrival_s
             self.metrics.record_first_token(
                 handle.ttft_s, handle.request.priority.value)
-            self.metrics.record_admission(now)
+            self.metrics.record_admission(now, now - handle.admit_s)
             self._tracer.on_first_token(handle, handle.ttft_s)
         self._slots[sid] = handle
         self._positions[sid] = plen
@@ -3270,7 +3330,9 @@ class ServeEngine:
 
     # ------------------------------------------------- speculative tick
     def _dispatch_draft(self, forced_tok, forced_n):
-        """Run the draft program; returns host ``[S, spec_k]`` drafts.
+        """Dispatch the draft program; returns its ``[S, spec_k]``
+        drafts, still on the device (the caller reads them inside its
+        ``tick_wait`` phase).
 
         A draft failure is NEVER fatal to the streams: when the retry
         budget runs out without a consumed buffer (injected faults, or
@@ -3289,7 +3351,7 @@ class ServeEngine:
             else:
                 drafts = self._device_call(
                     "draft", self._draft_p, self._hist, self._positions)
-            return np.asarray(drafts)
+            return drafts
         except _SlotStateLost as lost:
             if lost.consumed is not None:
                 raise
@@ -3330,8 +3392,29 @@ class ServeEngine:
         ``spec_k+1`` known tokens per window). Raises
         :class:`_SlotStateLost` to the caller exactly like the plain
         tick — the caller's recovery is identical."""
+        ph = self._phase
+        with ph["tick_dispatch"]:
+            forced_tok, forced_n = self._spec_forced(live)
+            drafts = self._dispatch_draft(forced_tok, forced_n)
+        with ph["tick_wait"]:
+            drafts = np.asarray(drafts)
+        with ph["tick_dispatch"]:
+            win, acc, caps, drafted_tick = self._spec_verify(
+                live, drafts, forced_tok, forced_n)
+        with ph["tick_wait"]:
+            win = np.asarray(win)  # per-tick host sync (streaming)
+            acc = np.asarray(acc)
+        with ph["emit"]:
+            new_tokens, accepted_tick = self._spec_emit(
+                cur, live, win, acc, caps, forced_n)
+        self.metrics.record_spec_tick(drafted_tick, accepted_tick)
+        return new_tokens
+
+    def _spec_forced(self, live):
+        """The window's forced re-feeds: per replaying slot, the known
+        tokens to push through it (``forced_tok``) and how many
+        (``forced_n``; -1 = not replaying)."""
         s, k = self.max_slots, self._spec_k
-        w_width = k + 1
         forced_tok = np.zeros((s, k), np.int32)
         forced_n = np.full(s, -1, np.int32)
         for sid in live:
@@ -3345,8 +3428,15 @@ class ServeEngine:
                 if j > 0:
                     forced_tok[sid, :j] = pend[:j]
                 forced_n[sid] = j
-        drafts = self._dispatch_draft(forced_tok, forced_n)
-        block = np.zeros((s, w_width), np.int32)
+        return forced_tok, forced_n
+
+    def _spec_verify(self, live, drafts, forced_tok, forced_n):
+        """Build the ``[S, spec_k+1]`` window and the accept caps from
+        the drafts, and dispatch the ONE batched verify. Returns the
+        window and accept counts (still on the device), the caps and
+        the draft tokens offered."""
+        s, k = self.max_slots, self._spec_k
+        block = np.zeros((s, k + 1), np.int32)
         block[:, 0] = self._tokens
         block[:, 1:] = drafts
         caps = np.zeros(s, np.int32)
@@ -3390,8 +3480,15 @@ class ServeEngine:
                 self._positions, block, self._temps, self._top_ks,
                 self._top_ps, *self._verify_extra(), caps, forced_n,
                 self._rng)
-        win = np.asarray(win)  # per-tick host sync (streaming)
-        acc = np.asarray(acc)
+        self.metrics.record_decode_tick()
+        return win, acc, caps, drafted_tick
+
+    def _spec_emit(self, cur: int, live, win, acc, caps, forced_n):
+        """The host half of a verify window: per live slot, append
+        the accepted tokens, advance FSMs and positions, evict the
+        finished. Returns ``(tokens emitted, draft tokens
+        accepted)``."""
+        w_width = self._spec_k + 1
         new_tokens = 0
         accepted_tick = 0
         for sid in live:
@@ -3468,8 +3565,7 @@ class ServeEngine:
             if not evicted:
                 self._positions[sid] += n_emit
                 self._tokens[sid] = int(win[sid, n_emit - 1])
-        self.metrics.record_spec_tick(drafted_tick, accepted_tick)
-        return new_tokens
+        return new_tokens, accepted_tick
 
     def step(self) -> int:
         """One engine tick: (drain check) → reap → admit → one fused
@@ -3500,14 +3596,30 @@ class ServeEngine:
             self._faults.on_step(cur)
         self._step_idx = cur + 1
         self._site_wall = {}
+        wall = self._phase_wall
+        for name in wall:
+            wall[name] = 0.0
+        t_step = time.perf_counter()
+        with jax.profiler.StepTraceAnnotation(_SPAN_PREFIX + "step",
+                                              step_num=cur):
+            return self._step_phases(cur, t_step)
+
+    def _step_phases(self, cur: int, t_step: float) -> int:
+        """The body of :meth:`step`, phase by phase (`metrics.PHASES`).
+        Every host<-device read of the step sits in a ``*_wait``
+        phase."""
+        ph = self._phase
         retries_before = self.metrics.retries
         t0 = self._clock()
         emitted_before = self.metrics.tokens_emitted
-        self._maybe_rearm_degraded()
-        self._reap()
-        self._admit()
+        with ph["reap"]:
+            self._maybe_rearm_degraded()
+            self._reap()
+        with ph["admit"]:
+            self._admit()
         if self._paged:
-            self._paged_append_blocks()
+            with ph["append_blocks"]:
+                self._paged_append_blocks()
         live = [i for i, s in enumerate(self._slots) if s is not None]
         new_tokens = 0
         if live and self._spec_on:
@@ -3519,62 +3631,19 @@ class ServeEngine:
                 # the paged pool's fate): every live slot replays.
                 self._lose_live_slots()
         elif live:
+            nxt = None
             try:
-                self._cache, nxt, self._rng = self._device_call(
-                    "tick", self._tick_p, *self._tick_args())
+                with ph["tick_dispatch"]:
+                    self._cache, nxt, self._rng = self._device_call(
+                        "tick", self._tick_p, *self._tick_args())
+                self.metrics.record_decode_tick()
             except _SlotStateLost:
                 self._lose_live_slots()
-                nxt = None
             if nxt is not None:
-                nxt = np.asarray(nxt)  # per-tick host sync (streaming)
-                for sid in live:
-                    handle = self._slots[sid]
-                    if handle.replay_pending:
-                        # Rebuilding lost KV: the tick just re-wrote
-                        # this row's next known token — feed the
-                        # following one, discard the sampled output
-                        # (the caller already has these tokens).
-                        self._tokens[sid] = handle.replay_pending.pop(0)
-                        self._positions[sid] += 1
-                        continue
-                    tok = int(nxt[sid])
-                    handle.tokens.append(tok)
-                    new_tokens += 1
-                    self._positions[sid] += 1
-                    self._tokens[sid] = tok
-                    self._tracer.on_token(handle, cur)
-                    fsm_entry = (self._fsms[sid] if self._tenant_on
-                                 else None)
-                    if self.eos_token is not None and tok == self.eos_token:
-                        # For a constrained slot the mask only ever
-                        # allows eos in an ACCEPTING state, so this is
-                        # simultaneously grammar acceptance.
-                        self._evict(sid, RequestState.FINISHED,
-                                    FinishReason.EOS)
-                    elif fsm_entry is not None:
-                        fsm, state = fsm_entry
-                        state = fsm.advance(state, tok)
-                        if state < 0:  # masked sample: impossible
-                            raise RuntimeError(
-                                "constrained token escaped its state "
-                                "mask (engine bug)")
-                        self._fsms[sid] = (fsm, state)
-                        if fsm.is_dead_end(state, self.eos_token):
-                            # No legal continuation: the output is a
-                            # complete document (see FinishReason).
-                            self._evict(sid, RequestState.FINISHED,
-                                        FinishReason.GRAMMAR)
-                        elif len(handle.tokens) >= \
-                                handle.request.max_new_tokens:
-                            self._evict(sid, RequestState.FINISHED,
-                                        FinishReason.LENGTH)
-                        else:
-                            self._masks[sid] = fsm.allow_row(
-                                state, self.eos_token)
-                            self._masks_dirty = True
-                    elif len(handle.tokens) >= handle.request.max_new_tokens:
-                        self._evict(sid, RequestState.FINISHED,
-                                    FinishReason.LENGTH)
+                with ph["tick_wait"]:
+                    nxt = np.asarray(nxt)  # per-tick host sync (streaming)
+                with ph["emit"]:
+                    new_tokens = self._emit_tick(cur, live, nxt)
         now = self._clock()
         self.metrics.record_tick(
             now, self.scheduler.depth, len(live), self.max_slots,
@@ -3583,7 +3652,12 @@ class ServeEngine:
             self.metrics.record_paged_gauges(self.blocks_shared,
                                              self.block_table_fill)
         emitted = self.metrics.tokens_emitted - emitted_before
-        self.telemetry.append({
+        phase_wall = dict(self._phase_wall)
+        self.metrics.record_step(time.perf_counter() - t_step, phase_wall)
+        # The one per-step record: the ring keeps it, the tracer is
+        # handed the same object (`RequestTracer(emit_ticks=True)`
+        # writes it to its sink).
+        record = {
             "step": cur, "t_s": now,
             "queue_depth": self.scheduler.depth,
             "live_slots": len(live), "tokens": emitted,
@@ -3591,10 +3665,66 @@ class ServeEngine:
             "retries": self.metrics.retries - retries_before,
             "degraded": self._degraded,
             "site_wall_s": self._site_wall,
-        })
-        self._tracer.on_tick(cur, self.scheduler.depth, len(live),
-                             emitted, now - t0)
+            "phase_wall_s": phase_wall,
+        }
+        self.telemetry.append(record)
+        self._tracer.on_tick(record)
         return emitted
+
+    def _emit_tick(self, cur: int, live, nxt) -> int:
+        """The host half of a decode tick: per live slot, append the
+        sampled token (or re-feed a replay's next known one), advance
+        FSMs and positions, evict the finished. Returns tokens emitted."""
+        new_tokens = 0
+        for sid in live:
+            handle = self._slots[sid]
+            if handle.replay_pending:
+                # Rebuilding lost KV: the tick just re-wrote
+                # this row's next known token — feed the
+                # following one, discard the sampled output
+                # (the caller already has these tokens).
+                self._tokens[sid] = handle.replay_pending.pop(0)
+                self._positions[sid] += 1
+                continue
+            tok = int(nxt[sid])
+            handle.tokens.append(tok)
+            new_tokens += 1
+            self._positions[sid] += 1
+            self._tokens[sid] = tok
+            self._tracer.on_token(handle, cur)
+            fsm_entry = (self._fsms[sid] if self._tenant_on
+                         else None)
+            if self.eos_token is not None and tok == self.eos_token:
+                # For a constrained slot the mask only ever
+                # allows eos in an ACCEPTING state, so this is
+                # simultaneously grammar acceptance.
+                self._evict(sid, RequestState.FINISHED,
+                            FinishReason.EOS)
+            elif fsm_entry is not None:
+                fsm, state = fsm_entry
+                state = fsm.advance(state, tok)
+                if state < 0:  # masked sample: impossible
+                    raise RuntimeError(
+                        "constrained token escaped its state "
+                        "mask (engine bug)")
+                self._fsms[sid] = (fsm, state)
+                if fsm.is_dead_end(state, self.eos_token):
+                    # No legal continuation: the output is a
+                    # complete document (see FinishReason).
+                    self._evict(sid, RequestState.FINISHED,
+                                FinishReason.GRAMMAR)
+                elif len(handle.tokens) >= \
+                        handle.request.max_new_tokens:
+                    self._evict(sid, RequestState.FINISHED,
+                                FinishReason.LENGTH)
+                else:
+                    self._masks[sid] = fsm.allow_row(
+                        state, self.eos_token)
+                    self._masks_dirty = True
+            elif len(handle.tokens) >= handle.request.max_new_tokens:
+                self._evict(sid, RequestState.FINISHED,
+                            FinishReason.LENGTH)
+        return new_tokens
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive ``step()`` until queue and slots drain (or the step

@@ -35,6 +35,20 @@ from pddl_tpu.utils.summary import format_table
 # sets never appear/vanish with traffic.
 PRIORITY_CLASSES = tuple(p.value for p in Priority)
 
+# The timed phases of one ``ServeEngine.step()`` (the engine's span
+# tree below ``pddl.serve.step``; docs/OPERATIONS.md § "Observability
+# (serving)" has the table). The engine opens a ``pddl.serve.<phase>``
+# profiler span per entry and sums each phase's wall time per step;
+# this is the stable label set of ``phase_wall_s``, here and in the
+# telemetry ring's record — every phase always present, like the
+# priority classes. ``first_token_wait`` nests inside ``admit`` (under
+# the per-request ``admit_request`` span, which only the profiler's
+# trace carries: summed it would repeat ``admit``); the rest are direct
+# children of the step. The two ``*_wait`` phases are the step's only
+# host<-device reads.
+PHASES = ("reap", "admit", "first_token_wait", "append_blocks",
+          "tick_dispatch", "tick_wait", "emit")
+
 
 def _pct(values, q: float) -> Optional[float]:
     vals = list(values)
@@ -188,6 +202,25 @@ class ServeMetrics:
         self.degraded_entries = 0    # times the engine flipped degraded
         self.degraded_time_s = 0.0   # wall time spent degraded (closed
         #                              intervals; re-arm stamps them)
+        # Where a step's time goes (the engine's phase spans, always
+        # on): steps that got past the drain check and their summed
+        # wall, the same wall split by phase (``PHASES`` — the two
+        # ``*_wait`` phases are the host blocked on the device), decode
+        # ticks dispatched, and of FRESH requests (replays excluded):
+        # per scheduler pop the scheduler's own wait (submit → pop, on
+        # the engine's clock), per slot installed (`record_admission`)
+        # pop → first token sampled. A request cancelled or expired
+        # between the two is a pop and no admission.
+        # rate(phase_wall_s{admit}) / rate(step_wall_s) is the share of
+        # the loop in which admissions held every live stream.
+        self.engine_steps = 0
+        self.step_wall_s = 0.0
+        self.phase_wall_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.decode_ticks = 0
+        self.queue_pops = 0
+        self.queue_wait_s = 0.0
+        self.admissions = 0
+        self.admit_wall_s = 0.0
         # Recent admission timestamps: the QueueFull retry_after_s
         # estimator (a short window so the hint tracks CURRENT service
         # rate, not the all-time average).
@@ -210,6 +243,27 @@ class ServeMetrics:
         if self._first_activity_s is None:
             self._first_activity_s = now_s
         self._last_activity_s = now_s
+
+    def record_step(self, wall_s: float,
+                    phase_wall_s: Dict[str, float]) -> None:
+        """One ``step()`` ended: its wall time and the per-phase split
+        (both ``time.perf_counter`` durations, so their ratio holds
+        whatever clock the engine was given)."""
+        self.engine_steps += 1
+        self.step_wall_s += wall_s
+        mine = self.phase_wall_s
+        for phase, w in phase_wall_s.items():
+            mine[phase] += w
+
+    def record_decode_tick(self) -> None:
+        """One decode tick (or speculative verify window) dispatched."""
+        self.decode_ticks += 1
+
+    def record_queue_pop(self, queue_wait_s: float) -> None:
+        """One FRESH request popped by the scheduler, ``queue_wait_s``
+        after its submit."""
+        self.queue_pops += 1
+        self.queue_wait_s += queue_wait_s
 
     def record_first_token(self, ttft_s: float,
                            priority: Optional[str] = None) -> None:
@@ -272,11 +326,15 @@ class ServeMetrics:
     def record_degraded_exit(self, seconds: float) -> None:
         self.degraded_time_s += max(0.0, float(seconds))
 
-    def record_admission(self, now_s: float) -> None:
-        """One FRESH request admitted (replays excluded — they consume
-        admission work but represent no new queue progress, and the
-        retry_after hint estimates how fast the queue drains)."""
+    def record_admission(self, now_s: float, admit_wall_s: float) -> None:
+        """One FRESH request admitted — its slot installed, its first
+        token sampled ``admit_wall_s`` after its scheduler pop (replays
+        excluded — they consume admission work but represent no new
+        queue progress, and the retry_after hint estimates how fast
+        the queue drains)."""
         self._admission_times.append(float(now_s))
+        self.admissions += 1
+        self.admit_wall_s += admit_wall_s
 
     def recent_admission_interval_s(self) -> Optional[float]:
         """Mean gap between recent admissions, or ``None`` before two
@@ -454,6 +512,15 @@ class ServeMetrics:
             "requests_deadline_shed": self.requests_deadline_shed,
             "degraded_entries": self.degraded_entries,
             "degraded_time_s": round(self.degraded_time_s, 6),
+            "engine_steps": self.engine_steps,
+            "step_wall_s": self.step_wall_s,
+            # Labeled series, one sample per phase (closed label set).
+            "phase_wall_s": dict(self.phase_wall_s),
+            "decode_ticks": self.decode_ticks,
+            "queue_pops": self.queue_pops,
+            "queue_wait_s": self.queue_wait_s,
+            "admissions": self.admissions,
+            "admit_wall_s": self.admit_wall_s,
             # Per-priority splits: mappings render as labeled series
             # (one sample per class) through `obs/export.py`, so the
             # SLO runbook reads shed/finish/TTFT per class off one

@@ -213,6 +213,9 @@ class RequestHandle:
         self.state = RequestState.QUEUED
         self.finish_reason: Optional[FinishReason] = None
         self.ttft_s: Optional[float] = None  # submit → first token
+        # Engine-stamped at the scheduler's FIRST pop of a fresh request
+        # (its clock): `ServeMetrics.admit_wall_s` runs from here.
+        self.admit_s: Optional[float] = None
         self.finish_s: Optional[float] = None
         self.replays = 0
         self.replay_pending: List[int] = []

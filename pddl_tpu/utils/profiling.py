@@ -154,8 +154,7 @@ class StepTimer(Callback):
         every key always present, ``None`` where nothing was measured
         yet — render with
         ``obs.export.render_prometheus(timer.snapshot(),
-        prefix="pddl_train_step")`` or through
-        ``obs.export.serve_exposition(..., step_timer=timer)``."""
+        prefix="pddl_train_step")``."""
         stats = self.stats
         return {
             "step_time_mean_s": stats.get("step_time_mean_s"),

@@ -21,6 +21,11 @@ The contracts under test:
 - **Reservoirs**: `ServeMetrics` memory is bounded under sustained
   load while snapshot percentiles stay stable (capped uniform
   sampling), and zero-recompile holds with tracing enabled.
+- **Phase spans**: ``step()``'s span tree (`serve/metrics.PHASES`)
+  reaches its three sinks — the always-on ``ServeMetrics`` counters
+  (exact against an injected clock), the ring record (handed to the
+  tracer's ``on_tick`` as it is), and ``pddl.serve.*`` spans in a
+  ``jax.profiler`` trace.
 """
 
 import json
@@ -46,8 +51,14 @@ from pddl_tpu.obs import (
     render_prometheus,
     serve_exposition,
 )
-from pddl_tpu.serve import ServeEngine
-from pddl_tpu.serve.metrics import Reservoir, ServeMetrics
+from pddl_tpu.serve import (
+    FaultKind,
+    FaultPlan,
+    FaultSpec,
+    FinishReason,
+    ServeEngine,
+)
+from pddl_tpu.serve.metrics import PHASES, Reservoir, ServeMetrics
 from pddl_tpu.utils.profiling import StepTimer
 from conftest import ref_greedy as _ref_greedy
 
@@ -528,3 +539,255 @@ def test_tracer_hook_surface_matches_null():
     assert real_hooks <= null_hooks, \
         f"RequestTracer hooks unknown to the engine: " \
         f"{real_hooks - null_hooks}"
+
+
+# ----------------------------------------------------------- phase spans
+# The direct children of ``pddl.serve.step`` (``first_token_wait`` nests
+# in ``admit``, under the span-only ``admit_request``).
+TOP_LEVEL_PHASES = tuple(p for p in PHASES if p != "first_token_wait")
+ENGINE_KINDS = pytest.mark.parametrize("paged", [False, True],
+                                       ids=["copy", "paged"])
+
+
+class _Clock:
+    """The injected engine clock: moves only when told to."""
+
+    def __init__(self, t=100.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
+
+
+class _SlowAdmission(NullTracer):
+    """Stands in for the prefill's duration on the injected clock (every
+    admission takes ``dt`` between its pop and its first token), and
+    keeps what ``on_tick`` was handed."""
+
+    def __init__(self, clock, dt):
+        self.clock, self.dt, self.ticks = clock, dt, []
+
+    def on_admit(self, handle, slot, replay):
+        self.clock.t += self.dt
+
+    def on_tick(self, record):
+        self.ticks.append(record)
+
+
+@ENGINE_KINDS
+def test_phase_counters_read_what_the_schedule_implies(gpt_setup, paged):
+    """Two requests through one slot on an injected clock: the
+    scheduler's wait, the admission wall and the step/tick counts are
+    exactly what the submits and steps imply; a replayed stream adds
+    nothing to the pop and admission counters; the
+    top-level phases sum to no more than the steps' wall."""
+    model, variables = gpt_setup
+    clock = _Clock()
+    tracer = _SlowAdmission(clock, 2.0)
+    # Step 3's tick fails past its (zero) retry budget: A's slot state
+    # is lost and A replays.
+    plan = FaultPlan(scheduled=[FaultSpec(step=3, site="tick",
+                                          kind=FaultKind.TRANSIENT)])
+    eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
+                      paged=paged, clock=clock, tracer=tracer,
+                      fault_plan=plan, max_retries=0)
+    eng.warmup()
+    a = eng.submit((np.arange(6) * 3 + 1) % 32, 6)   # t = 100
+    clock.t = 101.0
+    b = eng.submit((np.arange(5) + 7) % 32, 2)       # t = 101
+    clock.t = 103.0
+    eng.step()                  # pops A: waited 3 s; admission takes 2 s
+    m = eng.metrics.snapshot()
+    assert (m["queue_pops"], m["queue_wait_s"]) == (1, 3.0)
+    assert (m["admissions"], m["admit_wall_s"]) == (1, 2.0)
+    assert (m["engine_steps"], m["decode_ticks"]) == (1, 1)
+    clock.t = 110.0
+    steps = 1
+    while not a.done:
+        eng.step()
+        steps += 1
+    assert a.replays == 1 and a.tokens == _ref_greedy(
+        model, variables, a.request.prompt, 6)
+    m = eng.metrics.snapshot()
+    # The replay popped and re-admitted A: neither counts (fresh
+    # requests only).
+    assert (m["queue_pops"], m["queue_wait_s"]) == (1, 3.0)
+    assert (m["admissions"], m["admit_wall_s"]) == (1, 2.0)
+    clock.t = 120.0
+    while not b.done:
+        eng.step()
+        steps += 1
+    m = eng.metrics.snapshot()
+    # B was popped at 120 (submitted at 101) and took its 2 s.
+    assert (m["queue_pops"], m["queue_wait_s"]) == (2, 3.0 + 19.0)
+    assert (m["admissions"], m["admit_wall_s"]) == (2, 4.0)
+    assert m["engine_steps"] == steps
+    # Every step had a live slot; the failed tick was never dispatched.
+    assert m["decode_ticks"] == steps - 1
+    assert set(m["phase_wall_s"]) == set(PHASES)
+    assert all((w > 0) == (paged or phase != "append_blocks")
+               for phase, w in m["phase_wall_s"].items())
+    top = sum(m["phase_wall_s"][p] for p in TOP_LEVEL_PHASES)
+    assert 0 < top <= m["step_wall_s"]
+    assert m["phase_wall_s"]["first_token_wait"] \
+        <= m["phase_wall_s"]["admit"]
+    # (d) the tracer was handed the ring's own records, one per step.
+    assert len(tracer.ticks) == steps
+    assert tracer.ticks[-1] == eng.telemetry.last()
+    assert [r["step"] for r in tracer.ticks] == list(range(steps))
+
+
+@ENGINE_KINDS
+def test_ring_record_carries_phase_wall(gpt_setup, paged):
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
+                      paged=paged, telemetry_capacity=64)
+    handles = [eng.submit((np.arange(6) + i) % 32, 3) for i in range(3)]
+    eng.run(max_steps=50)
+    assert all(h.done for h in handles)
+    window = eng.telemetry.snapshot()
+    for r in window:
+        assert set(r["phase_wall_s"]) == set(PHASES)
+        assert sum(r["phase_wall_s"][p] for p in TOP_LEVEL_PHASES) > 0
+    # last() and snapshot() hand out copies: mutating them leaves the
+    # live ring as it was.
+    last = eng.telemetry.last()
+    kept = dict(last["phase_wall_s"])
+    last["phase_wall_s"]["admit"] = -1.0
+    window[-1]["phase_wall_s"].clear()
+    assert eng.telemetry.last()["phase_wall_s"] == kept
+    # The ring's window and the lifetime counters agree while the ring
+    # still holds every step (the window's summary leaves the split to
+    # the counters: one series on /metrics, not two).
+    assert "phase_wall_s" not in eng.telemetry.summary()
+    window = eng.telemetry.snapshot()
+    assert len(window) == eng.metrics.engine_steps
+    for phase in PHASES:
+        assert eng.metrics.phase_wall_s[phase] == pytest.approx(
+            sum(r["phase_wall_s"][phase] for r in window))
+
+
+def test_emit_ticks_writes_the_ring_record(gpt_setup, tmp_path):
+    """`RequestTracer(emit_ticks=True)`: the sink's ``kind="tick"``
+    lines are the ring's records — one shape for a step, not two."""
+    model, variables = gpt_setup
+    path = str(tmp_path / "ticks.jsonl")
+    with JsonlEventLog(path) as log:
+        eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
+                          telemetry_capacity=64,
+                          tracer=RequestTracer(sink=log, emit_ticks=True))
+        h = eng.submit(np.arange(5) % 32, 4)
+        eng.run(max_steps=30)
+        assert h.done
+    ticks = [r for r in read_jsonl(path) if r["kind"] == "tick"]
+    ring = eng.telemetry.snapshot()
+    assert len(ticks) == len(ring) > 0
+    for line, rec in zip(ticks, ring):
+        assert line == json.loads(json.dumps(
+            {"schema": 1, "kind": "tick", **rec}))
+
+
+def _host_spans(trace_dir):
+    """[(name, start_ns, end_ns, {stat: value})] of every
+    ``pddl.serve.*`` span a profiler session left in its host planes."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("pddl.serve."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return out
+
+
+@ENGINE_KINDS
+def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path,
+                                                 paged):
+    """A few steps under ``jax.profiler.start_trace`` (TraceMe spans
+    only, as the benchmark's traced runs): one ``pddl.serve.step`` per
+    engine step, numbered, with ``tick_wait`` and ``admit_request``
+    nested inside and ``request_id`` on the latter."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
+                      paged=paged)
+    eng.warmup()
+    handles = [eng.submit((np.arange(6) + i) % 32, 4) for i in range(2)]
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        first = eng.metrics.engine_steps
+        eng.run(max_steps=20)
+        n_steps = eng.metrics.engine_steps - first
+    finally:
+        jax.profiler.stop_trace()
+    assert all(h.done for h in handles) and n_steps >= 3
+    spans = _host_spans(tmp_path)
+    steps = sorted(s for s in spans if s[0] == "pddl.serve.step")
+    assert [s[3]["step_num"] for s in steps] \
+        == list(range(first, first + n_steps))
+
+    def inside(child, parents):
+        return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0].removeprefix("pddl.serve."), []).append(s)
+    assert set(by_name) - {"step", "admit_request"} <= set(PHASES)
+    for phase in TOP_LEVEL_PHASES:
+        if phase == "append_blocks" and not paged:
+            assert phase not in by_name
+            continue
+        assert by_name[phase], phase
+        assert all(inside(s, steps) for s in by_name[phase]), phase
+    assert len(by_name["tick_wait"]) == n_steps
+    requests = by_name["admit_request"]
+    assert sorted(s[3]["request_id"] for s in requests) == sorted(
+        h.request.request_id for h in handles)
+    assert all(s[3]["prompt_len"] == 6 and not s[3]["replay"]
+               for s in requests)
+    assert all(inside(s, by_name["admit"]) for s in requests)
+    assert len(by_name["first_token_wait"]) == 2
+    assert all(inside(s, requests) for s in by_name["first_token_wait"])
+
+
+@ENGINE_KINDS
+def test_cancelled_mid_admission_is_a_pop_and_no_admission(gpt_setup,
+                                                           paged):
+    """A sliced prefill cancelled between its scheduler pop and its
+    slot: the pop and its wait count, and neither side of
+    ``admit_wall_s / admissions`` moves — both are taken at install."""
+    model, variables = gpt_setup
+    clock = _Clock()
+    eng = ServeEngine(model, variables, max_slots=1, prefill_len=32,
+                      prefix_chunk=8, prefill_slice_tokens=8, paged=paged,
+                      clock=clock)
+    eng.warmup()
+    h = eng.submit((np.arange(31) * 3) % 32, 3)      # t = 100
+    clock.t = 101.5
+    eng.step()               # popped; the first slice of four prefilled
+    assert not h.tokens and not h.done
+    h.cancel()
+    clock.t = 105.0
+    eng.step()
+    assert h.finish_reason is FinishReason.CANCELLED
+    m = eng.metrics.snapshot()
+    assert (m["queue_pops"], m["queue_wait_s"]) == (1, 1.5)
+    assert (m["admissions"], m["admit_wall_s"]) == (0, 0.0)
+    # The next request through is one admission, its wall its own.
+    clock.t = 106.0
+    b = eng.submit((np.arange(5) + 7) % 32, 2)
+    eng.run(max_steps=20)
+    assert b.done
+    m = eng.metrics.snapshot()
+    assert (m["queue_pops"], m["admissions"], m["admit_wall_s"]) \
+        == (2, 1, 0.0)

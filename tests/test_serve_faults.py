@@ -568,7 +568,7 @@ def test_retry_after_hint_monotone_nonnegative():
         t = 0.0
         for _ in range(int(rng.integers(0, 40))):
             t += float(rng.exponential(rng.uniform(0.01, 2.0)))
-            m.record_admission(t)
+            m.record_admission(t, 0.0)
         depths = sorted(int(rng.integers(0, 64)) for _ in range(10))
         hints = [m.estimate_retry_after_s(d) for d in depths]
         if m.recent_admission_interval_s() is None:
