@@ -279,7 +279,7 @@ def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
     interpret = jax.devices()[0].platform != "tpu"
     worst = {"flash_fwd": 0.0, "flash_bwd": 0.0, "paged": 0.0}
     for h, hkv, d in head_shapes:
-        ks = jax.random.split(jax.random.key(h * hkv), 6)
+        ks = jax.random.split(jax.random.key(h * hkv), 5)
         q = jax.random.normal(ks[0], (2, h, seq, d), jnp.bfloat16)
         k = jax.random.normal(ks[1], (2, hkv, seq, d), jnp.bfloat16)
         v = jax.random.normal(ks[2], (2, hkv, seq, d), jnp.bfloat16)
@@ -303,17 +303,16 @@ def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
         for bs in block_sizes:
             slots, t = 8, context // bs
             n = slots * t + 1  # block 0 is the scratch sink
-            kp = jax.random.normal(ks[4], (n, hkv, bs, d), jnp.bfloat16)
-            vp = jax.random.normal(ks[5], (n, hkv, bs, d), jnp.bfloat16)
+            pool = jax.random.normal(ks[4], (n, hkv, bs, 2 * d), jnp.bfloat16)
             table = jnp.asarray(np.random.RandomState(bs).permutation(
                 np.arange(1, n)).reshape(slots, t), jnp.int32)
             # Depths from empty to the last position, unaligned included.
             index = jnp.asarray(np.linspace(0, context - 1, slots), jnp.int32)
             q1 = q[:1, :, :slots].transpose(2, 1, 0, 3)  # [slots, h, 1, d]
             got = jax.jit(lambda *a: paged_decode_attention_kernel(
-                *a, interpret=interpret))(q1, kp, vp, table, index)
+                *a, interpret=interpret))(q1, pool, table, index)
             want = jax.jit(lambda *a: paged_decode_attention(
-                *a, kernel=False))(q1, kp, vp, table, index)
+                *a, kernel=False))(q1, pool, table, index)
             worst["paged"] = max(worst["paged"], _max_err(got, want))
     # bf16 in and out, f32 accumulation inside: the bounds
     # tests_tpu/test_on_chip_numerics.py holds the same kernels to.

@@ -25,7 +25,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from pddl_tpu.models.gpipe import GPipeModel
-from pddl_tpu.models.vit import TransformerBlock, remat_block
+from pddl_tpu.models.vit import (
+    BLOCK_TABLE_KEY,
+    TransformerBlock,
+    remat_block,
+)
 from pddl_tpu.ops.large_vocab import chunked_cross_entropy
 
 
@@ -525,14 +529,6 @@ def _decode_fns(dec, temperature, top_k, top_p, max_new_tokens,
 # counters by these names (never by scalar-int32 duck typing, which
 # would silently capture any future non-position scalar cache state).
 CACHE_INDEX_KEYS = frozenset({"pos_index", "cache_index"})
-
-# The paged-serving block-table leaf name (`ops/attention.paged_*`,
-# `serve/kvcache/block_pool.paged_decode_cache`): its PRESENCE in a
-# cache collection is what flips the attention modules onto the paged
-# path, so the name is a registry constant like CACHE_INDEX_KEYS — the
-# modules, the engine's stamp helper below, and the pool builder all
-# match by it, never by shape duck typing.
-BLOCK_TABLE_KEY = "block_table"
 
 
 def is_cache_index_path(path) -> bool:

@@ -47,7 +47,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from pddl_tpu.models.gpipe import GPipeModel
-from pddl_tpu.models.vit import remat_block
+from pddl_tpu.models.vit import (
+    BLOCK_TABLE_KEY,
+    paged_decode_step,
+    remat_block,
+)
 from pddl_tpu.ops.attention import (
     attention_reference,
     decode_attention,
@@ -210,13 +214,7 @@ class LlamaAttention(nn.Module):
         hkv = self.num_kv_heads
         ring = self._ring_len()
         cache_len = ring or self.max_decode_len
-        initialized = self.has_variable("cache", "cached_key")
-        cached_k = self.variable(
-            "cache", "cached_key", jnp.zeros,
-            (b, hkv, cache_len, head_dim), self.dtype)
-        cached_v = self.variable(
-            "cache", "cached_value", jnp.zeros,
-            (b, hkv, cache_len, head_dim), self.dtype)
+        paged = self.has_variable("cache", BLOCK_TABLE_KEY)
         index = self.variable(
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
 
@@ -237,37 +235,31 @@ class LlamaAttention(nn.Module):
                              theta=self.rope_theta)
         k = k.astype(self.dtype)
         v = v.astype(self.dtype)
-        if initialized and self.has_variable("cache", "block_table"):
-            # PAGED serving (see the vit MHA twin): pool-shaped cache
-            # leaves + an engine-stamped per-slot block table replace
-            # the contiguous row cache. Post-RoPE keys are cached at
-            # their ABSOLUTE positions like the row path, so a shared
-            # pool block stays bit-valid for every referencing slot —
-            # the same contract the prefix cache's copies relied on,
-            # now without the copies. Rolling (ring) caches are never
+        if paged:
+            # PAGED serving (see the vit MHA twin): one fused pool leaf
+            # + an engine-stamped per-slot block table replace the
+            # contiguous row cache. Post-RoPE keys are cached at their
+            # ABSOLUTE positions like the row path, so a shared pool
+            # block stays bit-valid for every referencing slot — the
+            # same contract the prefix cache's copies relied on, now
+            # without the copies. Rolling (ring) caches are never
             # paged; the serving engine refuses ring models outright.
             if ring is not None:
                 raise NotImplementedError(
                     "paged attention requires a full-length cache; "
                     "rolling sliding-window caches are not paged")
-            from pddl_tpu.ops.attention import (  # noqa: PLC0415
-                paged_cache_insert,
-                paged_decode_attention,
-            )
-
-            # Declared (not just read) so the mutated cache keeps the
-            # leaf and the donated tree's structure stays stable.
-            table = self.variable(
-                "cache", "block_table",
-                lambda: jnp.zeros((1, 1), jnp.int32)).value
-            cached_k.value = paged_cache_insert(cached_k.value, k, table, i)
-            cached_v.value = paged_cache_insert(cached_v.value, v, table, i)
-            index.value = i + s
-            o = paged_decode_attention(q, cached_k.value, cached_v.value,
-                                       table, i, window=self.sliding_window)
+            o = paged_decode_step(self, index, q, k, v,
+                                  window=self.sliding_window)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, s, self.num_heads * head_dim)
             return dense(features=self.num_heads * head_dim, name="out")(o)
+        initialized = self.has_variable("cache", "cached_key")
+        cached_k = self.variable(
+            "cache", "cached_key", jnp.zeros,
+            (b, hkv, cache_len, head_dim), self.dtype)
+        cached_v = self.variable(
+            "cache", "cached_value", jnp.zeros,
+            (b, hkv, cache_len, head_dim), self.dtype)
         # Pre-write ring state: the multi-token ring path attends history
         # from here (the block's own writes below may overwrite in-window
         # history slots that this block's EARLY queries still need).

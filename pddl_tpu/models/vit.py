@@ -36,7 +36,40 @@ from pddl_tpu.ops.attention import (
     attention_reference,
     decode_attention,
     flash_attention,
+    paged_cache_insert,
+    paged_decode_attention,
 )
+
+# The paged-serving cache leaf names (`ops/attention.paged_*`,
+# `serve/kvcache/block_pool.paged_decode_cache`). The block table's
+# PRESENCE in a cache collection is what flips the attention modules
+# (this file's MHA and llama's) onto the paged path, so the names are
+# registry constants like `gpt.CACHE_INDEX_KEYS` — the modules, the
+# engine's stamp helper and the pool builder all match by them, never
+# by shape duck typing.
+BLOCK_TABLE_KEY = "block_table"
+PAGED_KV_KEY = "cached_kv"
+
+
+def paged_decode_step(module: nn.Module, index, q, k, v, *,
+                      window: Optional[int] = None):
+    """The paged branch of an attention module's decode step, shared by
+    the MHA below and `llama.LlamaAttention`: write this call's K/V
+    ``[B, H_kv, s, D]`` (cache dtype, post-RoPE) into the pool through
+    the slot's block table, advance the counter ``index`` (the module's
+    own ``cache_index`` variable), attend over the pool. The paged
+    cache collection holds exactly three leaves per module — the fused
+    pool ``[N, H_kv, block_size, 2D]``, the counter and the table —
+    DECLARED (not just read) so the mutated cache keeps them and the
+    donated tree's structure stays stable. Returns ``[B, H, s, D]``."""
+    pool = module.variable("cache", PAGED_KV_KEY, lambda: None)
+    table = module.variable(
+        "cache", BLOCK_TABLE_KEY,
+        lambda: jnp.zeros((1, 1), jnp.int32)).value
+    i = index.value
+    pool.value = paged_cache_insert(pool.value, k, v, table, i)
+    index.value = i + q.shape[2]
+    return paged_decode_attention(q, pool.value, table, i, window=window)
 
 
 class MultiHeadAttention(nn.Module):
@@ -116,6 +149,23 @@ class MultiHeadAttention(nn.Module):
         or rejected-draft junk past the cache edge never lands.
         """
         h = self.num_heads
+        index = self.variable(
+            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
+        if self.has_variable("cache", BLOCK_TABLE_KEY):
+            # PAGED serving: the one K/V leaf is the engine's shared
+            # block POOL ``[N, H, block_size, 2D]`` (K and V side by
+            # side, `ops/attention.py`'s paged section) and the
+            # per-slot block table (engine-stamped, like the position
+            # counter) resolves every read/write — K/V of a shared
+            # prefix exists once regardless of how many slots reference
+            # it. Writes land in the slot's private tail block (or the
+            # scratch sink for parked slots / padding junk) by the
+            # engine's table discipline; reads sweep the table with the
+            # same masking as the row path below.
+            o = paged_decode_step(self, index, q, k.astype(self.dtype),
+                                  v.astype(self.dtype))
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * head_dim)
+            return dense(features=h * head_dim, name="out")(o)
         # During init() the cache variables don't exist yet: create them
         # but DON'T mutate, so init returns a pristine cache (index 0).
         initialized = self.has_variable("cache", "cached_key")
@@ -125,39 +175,8 @@ class MultiHeadAttention(nn.Module):
         cached_v = self.variable(
             "cache", "cached_value", jnp.zeros,
             (b, h, self.max_decode_len, head_dim), self.dtype)
-        index = self.variable(
-            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
 
         i = index.value
-        if initialized and self.has_variable("cache", "block_table"):
-            # PAGED serving: the cache leaves are the engine's shared
-            # block POOL ``[N, H, block_size, D]`` and the per-slot
-            # block table (engine-stamped, like the position counters)
-            # resolves every read/write — K/V of a shared prefix exists
-            # once regardless of how many slots reference it. Writes
-            # land in the slot's private tail block (or the scratch
-            # sink for parked slots / padding junk) by the engine's
-            # table discipline; reads sweep the table with the same
-            # masking as the row path below.
-            from pddl_tpu.ops.attention import (  # noqa: PLC0415
-                paged_cache_insert,
-                paged_decode_attention,
-            )
-
-            # Declared (not just read) so the mutated cache keeps the
-            # leaf and the donated tree's structure stays stable.
-            table = self.variable(
-                "cache", "block_table",
-                lambda: jnp.zeros((1, 1), jnp.int32)).value
-            cached_k.value = paged_cache_insert(
-                cached_k.value, k.astype(self.dtype), table, i)
-            cached_v.value = paged_cache_insert(
-                cached_v.value, v.astype(self.dtype), table, i)
-            index.value = i + s
-            o = paged_decode_attention(q, cached_k.value, cached_v.value,
-                                       table, i)
-            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * head_dim)
-            return dense(features=h * head_dim, name="out")(o)
         if initialized:
             if i.ndim:
                 # Per-row scatter at i[b] + arange(s): single-token

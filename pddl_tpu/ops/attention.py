@@ -1032,8 +1032,37 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
 #   :func:`decode_attention`) is the CPU/tier-1 numerics ORACLE; the
 #   Pallas path (:func:`paged_decode_attention_kernel`) is the TPU
 #   hot-path kernel — the block table rides in SMEM via scalar
-#   prefetch and drives the K/V BlockSpec index maps, so each grid
+#   prefetch and drives the pool BlockSpec index map, so each grid
 #   step DMAs exactly one pool block.
+#
+# THE POOL LEAF. One leaf per layer, K and V side by side in the last
+# dimension: ``[num_blocks, kv_heads, block_size, 2 * head_dim]``, K in
+# lanes ``[0, D)`` and V in ``[D, 2D)`` (:func:`paged_kv_fuse` /
+# :func:`paged_kv_split`). The reason is the layout the leaf has AT
+# REST, between programs, which nothing in the engine chooses: a jitted
+# program receives and hands back every argument in the chip's default
+# layout for its shape. For separate 64-wide leaves
+# (``bf16[3073,20,16,64]``, GPT-2-large's) that default is
+# ``{0,3,2,1:T(8,128)(2,1)}`` — the block index minor-most, since a
+# 64-wide minor dimension would waste half of every 128-lane tile —
+# while the Mosaic kernel demands row-major ``{3,2,1,0}`` and so do the
+# whole-block gather and scatter; every program therefore relayouted
+# every leaf on the way in and again on the way out (three pool-sized
+# copies a leaf in the tick: 75 % of the chip's time, PERF.md §6 PR
+# 29). Pinning the 64-wide leaf row-major is no way out: its 64 lanes
+# pad to 128, 251.7 MB a leaf for 125.9 MB of data, 72 leaves = 18.1 GB
+# on a 16 GB chip. Fused, the minor dimension is 128 (or 256) lanes,
+# the default layout IS row-major and unpadded (36 leaves x 251.7 MB =
+# 9.06 GB, the same bytes as the 72 before), and the kernel, the chunk
+# path and the writes all work on it in place.
+# ``tests/test_tpu_aot_compile.py`` holds the engine's compiled tick
+# and chunk programs to that: no pool-sized copy, every leaf aliased.
+#
+# Every WRITE is block-granular for the same reason: a scatter that
+# indexes (block, offset) with heads and lanes as its window makes XLA
+# give the operand a third layout (``{3,1,2,0}``) and copy the pool in
+# and out around it; a gather of whole blocks, a splice, and a scatter
+# of whole blocks on dimension 0 alone keep the leaf where it is.
 #
 # Safety contract shared with `serve/kvcache/block_pool.py`: block 0
 # is the reserved scratch sink — parked slots' table rows are all
@@ -1042,63 +1071,84 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
 # construction and harmless by masking.
 
 
-def paged_cache_insert(pool: jnp.ndarray, kv: jnp.ndarray, block_table,
-                       index) -> jnp.ndarray:
-    """Write ``kv [B, H_kv, s, D]`` at global positions
+def paged_kv_fuse(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """K and V ``[..., D]`` side by side as the pool leaf stores them:
+    ``[..., 2D]``, K in lanes ``[0, D)``, V in ``[D, 2D)``."""
+    if k.shape != v.shape:
+        raise ValueError(f"k {k.shape} and v {v.shape} differ")
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def paged_kv_split(kv: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The K and V halves of a fused ``[..., 2D]`` array."""
+    d = kv.shape[-1] // 2
+    return kv[..., :d], kv[..., d:]
+
+
+def _check_fused_pool(pool: jnp.ndarray, d: int) -> None:
+    if pool.ndim != 4 or pool.shape[-1] != 2 * d:
+        raise ValueError(
+            f"pool leaf must be [N, H_kv, block_size, 2*D={2 * d}], got "
+            f"{pool.shape}")
+
+
+def paged_cache_insert(pool: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                       block_table, index) -> jnp.ndarray:
+    """Write ``k``/``v`` ``[B, H_kv, s, D]`` at global positions
     ``index (+ arange(s))`` into pool blocks resolved through
-    ``block_table [B, T]`` (``pool [N, H_kv, block_size, D]``).
+    ``block_table [B, T]`` (``pool [N, H_kv, block_size, 2D]``).
 
     ``index`` is a scalar (batch-1 chunk prefill at a traced offset) or
     a per-row ``[B]`` vector (the serving tick: every slot writes one
-    token at its own depth). Positions whose block index falls outside
-    the table are deflected to the scratch block — padded prefill junk
-    beyond a prompt's allocated blocks can never reach a real block.
-    Distinct valid positions map to distinct (block, offset) pairs, so
-    the scatter has no write conflicts except on scratch, whose content
-    is junk by contract.
+    token at its own depth; the speculative verify: ``s`` tokens).
+    Positions whose block index falls outside the table are deflected
+    to the scratch block — padded prefill junk beyond a prompt's
+    allocated blocks can never reach a real block. Distinct valid
+    positions map to distinct (block, offset) pairs and a slot writes
+    only blocks private to it (the engine's table discipline), so the
+    scatter has no write conflicts except on scratch, whose content is
+    junk by contract.
 
-    The multi-token (batch-1 chunk prefill) path works at BLOCK
-    granularity: read the span's blocks, splice the chunk in
-    contiguously, scatter whole rows back. A per-token scatter of a
-    [C]-token chunk costs C strided row-strip writes (measured ~20x a
-    contiguous write on XLA CPU); a dozen whole-block copies cost
-    memcpy.
+    Every shape works at BLOCK granularity (the section header says
+    why): read the blocks each row's span touches, splice the new
+    tokens in, scatter whole blocks back on dimension 0. A block of the
+    span the tokens do not reach is written back as it was read.
     """
-    n, hkv, bs, d = pool.shape
-    b, _, s, _ = kv.shape
+    n, hkv, bs, d2 = pool.shape
+    b, _, s, d = k.shape
+    _check_fused_pool(pool, d)
     block_table = jnp.asarray(block_table, jnp.int32)
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
             f"block_table must be [B={b}, T], got {block_table.shape}")
     t = block_table.shape[1]
-    index = jnp.asarray(index, jnp.int32)
-    if s > 1 and b == 1:
-        # Block-granular read-modify-write over the chunk's span.
-        first = index // bs                       # traced span start block
-        n_span = -(-s // bs) + 1                  # static span width
-        span = first + jnp.arange(n_span)
-        ids = jnp.where(span < t,
-                        jnp.take(block_table[0], jnp.minimum(span, t - 1)),
-                        0)                        # off-table -> scratch
-        blocks = jnp.take(pool, ids, axis=0)      # [n_span, Hkv, bs, D]
-        flat = jnp.moveaxis(blocks, 0, 1).reshape(hkv, n_span * bs, d)
-        flat = jax.lax.dynamic_update_slice(
-            flat, kv[0].astype(pool.dtype), (0, index % bs, 0))
-        blocks = jnp.moveaxis(flat.reshape(hkv, n_span, bs, d), 1, 0)
-        return pool.at[ids].set(blocks)
-    pos = index[..., None] + jnp.arange(s, dtype=jnp.int32)   # [s] or [B, s]
-    pos = jnp.broadcast_to(pos, (b, s))
-    blk = pos // bs
-    off = pos % bs
-    bid = jnp.take_along_axis(block_table, jnp.minimum(blk, t - 1), axis=1)
-    bid = jnp.where(blk < t, bid, 0)  # out-of-table junk -> scratch
-    updates = jnp.moveaxis(kv, 2, 1).reshape(b * s, hkv, d)   # [B*s, Hkv, D]
-    return pool.at[bid.reshape(-1), :, off.reshape(-1)].set(
-        updates.astype(pool.dtype))
+    index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
+    kv = paged_kv_fuse(k, v).astype(pool.dtype)
+    n_span = (bs + s - 2) // bs + 1     # most blocks s tokens can touch
+    span = (index // bs)[:, None] + jnp.arange(n_span)         # [B, n_span]
+    ids = jnp.where(
+        span < t,
+        jnp.take_along_axis(block_table, jnp.minimum(span, t - 1), axis=1),
+        0).reshape(-1)                                # off-table -> scratch
+    blocks = jnp.take(pool, ids, axis=0)              # [B*n_span, Hkv, bs, 2D]
+    flat = jnp.moveaxis(blocks.reshape(b, n_span, hkv, bs, d2), 1, 2) \
+        .reshape(b, hkv, n_span * bs, d2)
+    off = index % bs
+    if b == 1:
+        flat = jax.lax.dynamic_update_slice(flat, kv, (0, 0, off[0], 0))
+    else:
+        rel = jnp.arange(n_span * bs) - off[:, None]              # [B, P]
+        new = kv if s == 1 else jnp.take_along_axis(
+            kv, jnp.clip(rel, 0, s - 1)[:, None, :, None], axis=2)
+        flat = jnp.where(((rel >= 0) & (rel < s))[:, None, :, None],
+                         new, flat)
+    blocks = jnp.moveaxis(flat.reshape(b, hkv, n_span, bs, d2), 2, 1) \
+        .reshape(b * n_span, hkv, bs, d2)
+    return pool.at[ids].set(blocks)
 
 
 def paged_decode_attention(
-    q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
+    q: jnp.ndarray, kv_pool: jnp.ndarray,
     block_table, index, *, window: Optional[int] = None,
     scale: Optional[float] = None, blocks_per_chunk: Optional[int] = None,
     kernel: Optional[bool] = None, interpret: Optional[bool] = None,
@@ -1114,9 +1164,10 @@ def paged_decode_attention(
     Args:
       q: ``[B, H, s, D]`` post-RoPE queries (``s == 1`` on the decode
         tick; ``s > 1`` for chunked prefill continuing at ``index``).
-      k_pool/v_pool: ``[N, H_kv, block_size, D]`` pool leaves; the
-        current tokens must already be written
-        (:func:`paged_cache_insert` runs first, like the row path).
+      kv_pool: the fused ``[N, H_kv, block_size, 2D]`` pool leaf (K in
+        lanes ``[0, D)``, V in ``[D, 2D)``); the current tokens must
+        already be written (:func:`paged_cache_insert` runs first, like
+        the row path).
       block_table: ``[B, T]`` int32 pool block ids; entries beyond a
         slot's depth are scratch (never read — masked).
       index: tokens in the (virtual) cache before this call; scalar or
@@ -1137,8 +1188,9 @@ def paged_decode_attention(
     Returns ``[B, H, s, D]`` in q's dtype.
     """
     b, h, s, d = q.shape
-    n, hkv, bs, _ = k_pool.shape
-    rep = _gqa_rep(q, k_pool)
+    _check_fused_pool(kv_pool, d)
+    n, hkv, bs, d2 = kv_pool.shape
+    rep = _gqa_rep(q, kv_pool)
     block_table = jnp.asarray(block_table, jnp.int32)
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
@@ -1160,7 +1212,7 @@ def paged_decode_attention(
                 f"steps only (got a {s}-token block); multi-token "
                 "prefill takes the jnp path (kernel=False)")
         return paged_decode_attention_kernel(
-            q, k_pool, v_pool, block_table, index, scale=scale_v,
+            q, kv_pool, block_table, index, scale=scale_v,
             window=window, interpret=interpret)
 
     # ---- jnp reference path (the tier-1 oracle) ----
@@ -1181,14 +1233,12 @@ def paged_decode_attention(
         start_blk = jnp.minimum(c * cb, t - cb)       # clamped tail
         ids = jax.lax.dynamic_slice(block_table, (0, start_blk),
                                     (b, cb))          # [B, cb]
-        kc = jnp.take(k_pool, ids.reshape(-1), axis=0)
-        vc = jnp.take(v_pool, ids.reshape(-1), axis=0)
-        # [B*cb, Hkv, bs, D] -> [B, Hkv, cb*bs, D]
-        kc = jnp.moveaxis(kc.reshape(b, cb, hkv, bs, d), 1, 2) \
-            .reshape(b, hkv, chunk, d)
-        vc = jnp.moveaxis(vc.reshape(b, cb, hkv, bs, d), 1, 2) \
-            .reshape(b, hkv, chunk, d)
-        sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(k_pool.dtype), kc,
+        kvc = jnp.take(kv_pool, ids.reshape(-1), axis=0)
+        # [B*cb, Hkv, bs, 2D] -> [B, Hkv, cb*bs, 2D]
+        kvc = jnp.moveaxis(kvc.reshape(b, cb, hkv, bs, d2), 1, 2) \
+            .reshape(b, hkv, chunk, d2)
+        kc, vc = paged_kv_split(kvc)
+        sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(kv_pool.dtype), kc,
                         preferred_element_type=jnp.float32) * scale_v
         pos = start_blk * bs + jnp.arange(chunk)
         dedup = pos >= c * chunk  # drop the clamped tail's re-read overlap
@@ -1202,7 +1252,7 @@ def paged_decode_attention(
         p = jnp.exp(sb - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * alpha + jnp.einsum(
-            "bgrqk,bgkd->bgrqd", p.astype(v_pool.dtype), vc,
+            "bgrqk,bgkd->bgrqd", p.astype(kv_pool.dtype), vc,
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
@@ -1217,18 +1267,26 @@ def paged_decode_attention(
     return (acc / jnp.maximum(l, 1e-30)).reshape(b, h, s, d).astype(q.dtype)
 
 
-def _paged_decode_kernel(table_ref, index_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_decode_kernel(table_ref, index_ref, q_ref, kv_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, scale: float, bs: int,
                          num_t: int, window: Optional[int]):
     """One (slot, table-entry) grid step of paged decode attention.
 
     ``table_ref``/``index_ref`` are scalar-prefetched (SMEM): the table
-    drove this step's K/V BlockSpec index maps (the DMA fetched pool
-    block ``table[b, j]``), and the per-slot depth gates the compute —
-    blocks past the slot's live prefix are skipped entirely, so the
-    sweep costs what the slot's depth costs, exactly like the chunked
-    jnp path. Running max / denominator / accumulator persist in VMEM
-    scratch across the (sequential, innermost) table sweep.
+    drove this step's pool BlockSpec index map (the DMA fetched pool
+    block ``table[b, j]``, K and V in one transfer), and the per-slot
+    depth gates the compute — blocks past the slot's live prefix are
+    skipped entirely, so the sweep costs what the slot's depth costs,
+    exactly like the chunked jnp path. Running max / denominator /
+    accumulator persist in VMEM scratch across the (sequential,
+    innermost) table sweep.
+
+    Everything in here is ``2D`` lanes wide and nothing is sliced or
+    reshaped (Mosaic cannot re-tile a 64-lane minor dim): ``q_ref``
+    carries zeros in lanes ``[D, 2D)``, so ``q . [k|v]`` is ``q . k``
+    exactly (the V half adds exact zeros to the f32 sum), and
+    ``p . [k|v]`` accumulates ``p . v`` in lanes ``[D, 2D)``, which the
+    wrapper slices off in HBM.
     """
     bq = pl.program_id(0)
     j = pl.program_id(1)
@@ -1246,13 +1304,12 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _compute():
-        # [Hkv, rep, D] x [Hkv, bs, D] -> [Hkv, rep, bs], batched on the
+        # [Hkv, rep, 2D] x [Hkv, bs, 2D] -> [Hkv, rep, bs], batched on the
         # kv-head dim, f32 accumulation on the MXU. q, the output and the
         # scratches all carry the [Hkv, rep, ...] grouping (the wrapper
-        # reshapes in HBM, where it is free): Mosaic cannot re-tile a
-        # 64-lane minor dim, so nothing is reshaped in here.
+        # reshapes in HBM, where it is free).
         sb = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((2,), (2,)), ((0,), (0,))),
+            q_ref[0], kv_ref[0], (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, sb.shape, 2)
         mask = pos <= depth
@@ -1265,8 +1322,8 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sb - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(  # [Hkv, rep, bs] x [Hkv, bs, D]
-            p.astype(v_ref.dtype), v_ref[0],
+        pv = jax.lax.dot_general(  # [Hkv, rep, bs] x [Hkv, bs, 2D]
+            p.astype(kv_ref.dtype), kv_ref[0],
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * alpha + pv
@@ -1280,7 +1337,7 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention_kernel(
-    q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
+    q: jnp.ndarray, kv_pool: jnp.ndarray,
     block_table, index, *, scale: Optional[float] = None,
     window: Optional[int] = None, interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
@@ -1288,19 +1345,20 @@ def paged_decode_attention_kernel(
 
     Grid ``(B, T)`` with the table sweep innermost (sequential TPU grid
     order, like the flash kernels): the scalar-prefetched block table
-    steers each step's K/V BlockSpec at pool block
-    ``block_table[b, j]`` — indirection happens in the DMA index map,
-    never as a gathered copy in HBM — and the per-slot depth (also
-    prefetched) skips dead blocks, so a parked slot costs one skipped
-    sweep and a live one exactly its prefix. Numerics match the jnp
-    reference path of :func:`paged_decode_attention` (same masking and
-    online softmax; pinned by `tests/test_serve_paged.py`).
+    steers each step's pool BlockSpec at block ``block_table[b, j]`` of
+    the fused leaf — indirection happens in the DMA index map, never as
+    a gathered copy in HBM — and the per-slot depth (also prefetched)
+    skips dead blocks, so a parked slot costs one skipped sweep and a
+    live one exactly its prefix. Numerics match the jnp reference path
+    of :func:`paged_decode_attention` (same masking and online softmax;
+    pinned by `tests/test_serve_paged.py`).
     """
     b, h, s, d = q.shape
     if s != 1:
         raise ValueError(f"decode kernel takes single-token steps, got s={s}")
-    n, hkv, bs, _ = k_pool.shape
-    rep = _gqa_rep(q, k_pool)
+    _check_fused_pool(kv_pool, d)
+    n, hkv, bs, d2 = kv_pool.shape
+    rep = _gqa_rep(q, kv_pool)
     t = jnp.asarray(block_table, jnp.int32).shape[1]
     scale_v = (1.0 / math.sqrt(d)) if scale is None else scale
     if interpret is None:
@@ -1308,32 +1366,33 @@ def paged_decode_attention_kernel(
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
-    qg = q.reshape(b, hkv, rep, d)  # kv-head grouping, done in HBM
-    q_spec = pl.BlockSpec((1, hkv, rep, d),
+    # kv-head grouping and the zero V-half of q, both done in HBM.
+    qg = jnp.pad(q.reshape(b, hkv, rep, d), ((0, 0),) * 3 + ((0, d),))
+    q_spec = pl.BlockSpec((1, hkv, rep, d2),
                           lambda bq, j, tbl, idx: (bq, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, hkv, bs, d),
+    kv_spec = pl.BlockSpec((1, hkv, bs, d2),
                            lambda bq, j, tbl, idx: (tbl[bq, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, t),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((hkv, rep, LANES), jnp.float32),  # running max
             pltpu.VMEM((hkv, rep, LANES), jnp.float32),  # running denom
-            pltpu.VMEM((hkv, rep, d), jnp.float32),      # output accumulator
+            pltpu.VMEM((hkv, rep, d2), jnp.float32),     # [junk | output]
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale_v, bs=bs,
                           num_t=t, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d2), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=bool(interpret),
-    )(jnp.asarray(block_table, jnp.int32), index, qg, k_pool, v_pool)
-    return out.reshape(b, h, 1, d)
+    )(jnp.asarray(block_table, jnp.int32), index, qg, kv_pool)
+    return out[..., d:].reshape(b, h, 1, d)
 
 
 def decode_attention(

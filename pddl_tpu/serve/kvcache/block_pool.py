@@ -15,6 +15,12 @@ embedding before the block stack; Llama caches post-RoPE keys rotated
 at their global positions), so a prefix block computed by one request
 is bit-valid for every later request sharing those prompt tokens.
 
+The PAGED engine has no slot cache and no side pool: its cache tree IS
+a pool (:func:`paged_decode_cache`), one fused leaf per attention layer
+``[N, kv_heads, block_size, 2*D]`` with K and V side by side — the shape
+whose layout at rest every paged program uses in place
+(`ops/attention.py`, paged section).
+
 Block id 0 is reserved as a WRITE SINK ("scratch"): fixed-shape gather
 and scatter programs pad their runtime id vectors with 0, so one
 compiled program serves every hit depth and donation width while the
@@ -36,6 +42,7 @@ from pddl_tpu.models.gpt import (
     _decode_cache_shapes,
     is_cache_index_path,
 )
+from pddl_tpu.models.vit import PAGED_KV_KEY
 from pddl_tpu.ops.attention import cache_blocks_gather, cache_blocks_scatter
 
 # The reserved write-sink block id (see module docstring).
@@ -75,9 +82,12 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
     Where :func:`kv_block_pool` builds a pool that sits BESIDE the
     engine's resident slot cache (the copy-in/copy-out prefix cache),
     this builds the cache tree the paged engine hands straight to
-    ``dec.apply``: every K/V leaf is a block pool
-    ``[num_blocks, ..., block_size, D]``, position counters and
-    per-slot block tables are CANONICAL PLACEHOLDERS (scalar 0 /
+    ``dec.apply``: each attention module's K and V become ONE fused
+    block pool ``[num_blocks, kv_heads, block_size, 2 * head_dim]``
+    (K in lanes ``[0, D)``, V in ``[D, 2D)`` — the one leaf shape whose
+    layout at rest every paged program reads and writes in place;
+    `ops/attention.py`'s paged section says why), position counters
+    and per-slot block tables are CANONICAL PLACEHOLDERS (scalar 0 /
     ``[1, 1]``) that every paged program re-stamps from engine-owned
     host state on entry and restores on exit — one tree structure
     across the fused tick ([S] counters, [S, T] tables) and the
@@ -99,7 +109,7 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
 
     def _build(tree):
         out = {}
-        has_kv = False
+        kv = {}
         for key, val in tree.items():
             name = str(key)
             if hasattr(val, "items"):
@@ -107,11 +117,16 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
             elif name in CACHE_INDEX_KEYS:
                 out[name] = jnp.zeros((), jnp.int32)
             else:
-                has_kv = True
-                out[name] = jnp.zeros(
-                    (num_blocks,) + val.shape[1:-2]
-                    + (block_size, val.shape[-1]), val.dtype)
-        if has_kv:
+                kv[name] = val
+        if kv:
+            k, v = kv.pop("cached_key"), kv.pop("cached_value")
+            if kv or k.shape != v.shape or k.dtype != v.dtype:
+                raise ValueError(
+                    f"cannot fuse K/V leaves {k.shape} {v.shape} "
+                    f"{sorted(kv)} into one paged pool leaf")
+            _, hkv, _, d = k.shape
+            out[PAGED_KV_KEY] = jnp.zeros(
+                (num_blocks, hkv, block_size, 2 * d), k.dtype)
             out[BLOCK_TABLE_KEY] = jnp.zeros((1, 1), jnp.int32)
         return out
 

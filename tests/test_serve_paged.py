@@ -36,10 +36,13 @@ from pddl_tpu.models.gpt import tiny_gpt
 from pddl_tpu.models.llama import tiny_llama
 from pddl_tpu.obs.export import parse_prometheus_text, serve_exposition
 from pddl_tpu.ops.attention import (
+    attention_reference,
     decode_attention,
     paged_cache_insert,
     paged_decode_attention,
     paged_decode_attention_kernel,
+    paged_kv_fuse,
+    paged_kv_split,
 )
 from pddl_tpu.serve import ServeEngine
 from pddl_tpu.serve.faults import FaultPlan
@@ -68,19 +71,35 @@ def llama_setup():
 
 # ------------------------------------------------------------- op level
 def _random_paged(rng, b, hkv, bs, t, d):
-    """A pool + disjoint per-row linear tables + the DENSE cache they
-    spell (the oracle's view)."""
+    """A fused pool ``[N, Hkv, bs, 2D]`` + disjoint per-row linear
+    tables + the DENSE K and V caches they spell (the oracle's view)."""
     n = 1 + b * t
-    kp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
+    pool = jnp.asarray(rng.randn(n, hkv, bs, 2 * d), jnp.float32)
     table = np.zeros((b, t), np.int32)
     for i in range(b):
         table[i] = 1 + i * t + np.arange(t)
-    kc = np.asarray(kp)[table].transpose(0, 2, 1, 3, 4).reshape(
-        b, hkv, t * bs, d)
-    vc = np.asarray(vp)[table].transpose(0, 2, 1, 3, 4).reshape(
-        b, hkv, t * bs, d)
-    return kp, vp, table, jnp.asarray(kc), jnp.asarray(vc)
+    dense = np.asarray(pool)[table].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, t * bs, 2 * d)
+    return pool, table, jnp.asarray(dense[..., :d]), jnp.asarray(dense[..., d:])
+
+
+def test_paged_kv_fuse_and_split_are_inverse():
+    """K in lanes [0, D), V in [D, 2D): the one definition of the pool
+    leaf's last dimension."""
+    rng = np.random.RandomState(9)
+    k = jnp.asarray(rng.randn(2, 3, 4, 8), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 3, 4, 8), jnp.float32)
+    kv = paged_kv_fuse(k, v)
+    assert kv.shape == (2, 3, 4, 16)
+    np.testing.assert_array_equal(np.asarray(kv[..., :8]), np.asarray(k))
+    k2, v2 = paged_kv_split(kv)
+    np.testing.assert_array_equal(np.asarray(k2), np.asarray(k))
+    np.testing.assert_array_equal(np.asarray(v2), np.asarray(v))
+    with pytest.raises(ValueError, match="differ"):
+        paged_kv_fuse(k, v[:, :2])
+    with pytest.raises(ValueError, match=r"2\*D"):
+        paged_decode_attention(k[:, :, :1], kv[..., :8],
+                               np.zeros((2, 1), np.int32), np.int32(0))
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
@@ -90,11 +109,11 @@ def test_paged_reference_matches_dense_decode(hq, hkv):
     cache."""
     rng = np.random.RandomState(0)
     b, bs, t, d = 3, 4, 6, 8
-    kp, vp, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
+    pool, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
     q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
     index = np.array([5, 17, 0], np.int32)
     ref = decode_attention(q, kc, vc, index)
-    got = paged_decode_attention(q, kp, vp, table, index, kernel=False,
+    got = paged_decode_attention(q, pool, table, index, kernel=False,
                                  blocks_per_chunk=2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -105,16 +124,16 @@ def test_paged_reference_multi_token_and_window():
     sliding-window masking both match the dense oracle."""
     rng = np.random.RandomState(1)
     b, hkv, bs, t, d, s = 1, 2, 4, 6, 8, 5
-    kp, vp, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
+    pool, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
     q = jnp.asarray(rng.randn(b, 4, s, d), jnp.float32)
     ref = decode_attention(q, kc, vc, np.int32(7))
-    got = paged_decode_attention(q, kp, vp, table, np.int32(7),
+    got = paged_decode_attention(q, pool, table, np.int32(7),
                                  kernel=False, blocks_per_chunk=3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     q1 = jnp.asarray(rng.randn(b, 4, 1, d), jnp.float32)
     ref_w = decode_attention(q1, kc, vc, np.int32(13), window=6)
-    got_w = paged_decode_attention(q1, kp, vp, table, np.int32(13),
+    got_w = paged_decode_attention(q1, pool, table, np.int32(13),
                                    window=6, kernel=False)
     np.testing.assert_allclose(np.asarray(got_w), np.asarray(ref_w),
                                rtol=1e-5, atol=1e-5)
@@ -123,18 +142,92 @@ def test_paged_reference_multi_token_and_window():
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
 def test_paged_kernel_matches_reference(hq, hkv):
     """The Pallas kernel (scalar-prefetched block table driving the
-    K/V index maps), interpret mode on CPU, == the jnp oracle — per-row
+    pool index map), interpret mode on CPU, == the jnp oracle — per-row
     depths including a zero-depth (freshly admitted) row."""
     rng = np.random.RandomState(2)
     b, bs, t, d = 3, 4, 6, 8
-    kp, vp, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
+    pool, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
     q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
     index = np.array([23, 0, 8], np.int32)
-    ref = paged_decode_attention(q, kp, vp, table, index, kernel=False)
-    got = paged_decode_attention_kernel(q, kp, vp, table, index,
+    ref = paged_decode_attention(q, pool, table, index, kernel=False)
+    got = paged_decode_attention_kernel(q, pool, table, index,
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
+@pytest.mark.parametrize("path", ["jnp", "kernel"])
+def test_paged_paths_match_attention_reference(path, hq, hkv, window):
+    """Both readers of the fused leaf — the jnp sweep every prefill
+    chunk takes and the kernel (interpret mode) — against the plain
+    ``attention_reference`` over each row's virtual cache cut to its
+    depth, MHA and GQA, with and without a sliding window."""
+    rng = np.random.RandomState(4)
+    b, bs, t, d = 3, 4, 6, 8
+    pool, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
+    q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
+    index = np.array([23, 0, 9], np.int32)
+    if path == "kernel":
+        got = paged_decode_attention_kernel(q, pool, table, index,
+                                            window=window, interpret=True)
+    else:
+        got = paged_decode_attention(q, pool, table, index, window=window,
+                                     kernel=False, blocks_per_chunk=2)
+    for i, depth in enumerate(index):
+        # The query sits at position `depth`, the last of depth + 1 keys:
+        # k_offset 0 with one query row means causal is "all keys".
+        lo = 0 if window is None else max(0, depth + 1 - window)
+        ref = attention_reference(
+            q[i:i + 1], kc[i:i + 1, :, lo:depth + 1],
+            vc[i:i + 1, :, lo:depth + 1], causal=False)
+        np.testing.assert_allclose(np.asarray(got[i:i + 1]),
+                                   np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def _scatter_insert(pool, k, v, table, index):
+    """The per-token two-dimension scatter the tick used to write with
+    (``pool.at[bid, :, off].set``), over the fused leaf: the oracle of
+    the block-granular write."""
+    b, hkv, s, d = k.shape
+    bs, t = pool.shape[2], table.shape[1]
+    pos = np.broadcast_to(np.asarray(index).reshape(-1, 1)
+                          + np.arange(s), (b, s))
+    blk, off = pos // bs, pos % bs
+    bid = np.where(blk < t,
+                   np.take_along_axis(table, np.minimum(blk, t - 1), 1), 0)
+    upd = jnp.moveaxis(jnp.concatenate([k, v], -1), 2, 1).reshape(
+        b * s, hkv, 2 * d)
+    return pool.at[bid.reshape(-1), :, off.reshape(-1)].set(upd)
+
+
+@pytest.mark.parametrize("s", [1, 3, 6])
+def test_paged_insert_matches_per_token_scatter(s):
+    """The block-granular write against the old scatter's result on a
+    random table: parked slots (all-scratch rows), a position on a block
+    boundary, the last position of a block, and a position out of the
+    table. ``s == 1`` is the tick, ``s > 1`` with per-row depths the
+    speculative verify (a window that crosses into the next block and
+    one that runs off the table). Everything but the scratch block —
+    junk by contract, and where duplicate writes race — is equal."""
+    rng = np.random.RandomState(5)
+    b, hkv, bs, t, d = 6, 2, 4, 6, 8
+    n = 1 + b * t
+    pool = jnp.asarray(rng.randn(n, hkv, bs, 2 * d), jnp.float32)
+    table = rng.permutation(np.arange(1, n)).reshape(b, t).astype(np.int32)
+    table[1] = 0
+    table[4] = 0                                   # parked slots
+    index = np.array([4, 9, 7, t * bs - 1, 2, t * bs], np.int32)
+    k = jnp.asarray(rng.randn(b, hkv, s, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, hkv, s, d), jnp.float32)
+    got = paged_cache_insert(pool, k, v, table, index)
+    want = _scatter_insert(pool, k, v, table, index)
+    np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(want[1:]))
+    # Row 0's first token really is where the table says.
+    np.testing.assert_array_equal(
+        np.asarray(got[table[0, 1], :, 0]),
+        np.asarray(jnp.concatenate([k, v], -1)[0, :, 0]))
 
 
 def test_paged_cache_insert_and_scratch_deflection():
@@ -143,18 +236,22 @@ def test_paged_cache_insert_and_scratch_deflection():
     the write set changes."""
     rng = np.random.RandomState(3)
     b, hkv, bs, t, d = 3, 2, 4, 6, 8
-    kp, vp, table, _, _ = _random_paged(rng, b, hkv, bs, t, d)
+    pool, table, _, _ = _random_paged(rng, b, hkv, bs, t, d)
     index = np.array([5, 17, 0], np.int32)
-    kv = jnp.asarray(rng.randn(b, hkv, 1, d), jnp.float32)
-    out = paged_cache_insert(kp, kv, table, index)
+    k = jnp.asarray(rng.randn(b, hkv, 1, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, hkv, 1, d), jnp.float32)
+    out = paged_cache_insert(pool, k, v, table, index)
     for i in range(b):
         got = np.asarray(out[table[i, index[i] // bs], :, index[i] % bs])
-        np.testing.assert_array_equal(got, np.asarray(kv[i, :, 0]))
+        np.testing.assert_array_equal(got[:, :d], np.asarray(k[i, :, 0]))
+        np.testing.assert_array_equal(got[:, d:], np.asarray(v[i, :, 0]))
     # Batch-1 multi-token chunk write (the block-granular RMW path):
     # tokens land contiguously at their (block, offset) homes...
-    kv2 = jnp.asarray(rng.randn(1, hkv, 10, d), jnp.float32)
+    k2 = jnp.asarray(rng.randn(1, hkv, 10, d), jnp.float32)
+    v2 = jnp.asarray(rng.randn(1, hkv, 10, d), jnp.float32)
+    kv2 = paged_kv_fuse(k2, v2)
     start = 9  # mid-block start, spans blocks 2..4
-    out2 = paged_cache_insert(kp, kv2, table[:1], np.int32(start))
+    out2 = paged_cache_insert(pool, k2, v2, table[:1], np.int32(start))
     for j in range(10):
         pos = start + j
         got = np.asarray(out2[table[0, pos // bs], :, pos % bs])
@@ -162,12 +259,12 @@ def test_paged_cache_insert_and_scratch_deflection():
     # ...earlier tokens in the first span block survive the RMW...
     np.testing.assert_array_equal(
         np.asarray(out2[table[0, start // bs], :, : start % bs]),
-        np.asarray(kp[table[0, start // bs], :, : start % bs]))
+        np.asarray(pool[table[0, start // bs], :, : start % bs]))
     # ...and a write running off the table's end deflects to scratch:
     # no real block outside row 0's own table changes.
-    out3 = paged_cache_insert(kp, kv2, table[:1], np.int32(t * bs - 3))
+    out3 = paged_cache_insert(pool, k2, v2, table[:1], np.int32(t * bs - 3))
     np.testing.assert_array_equal(np.asarray(out3[1 + t:]),
-                                  np.asarray(kp[1 + t:]))
+                                  np.asarray(pool[1 + t:]))
     # The in-table tail tokens still landed.
     for j in range(3):
         pos = t * bs - 3 + j

@@ -19,8 +19,11 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
+import re  # noqa: E402
+
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
@@ -63,24 +66,138 @@ def _compile_with_kernel(fn, *shapes, kernel=True):
     (12, 12, 64, 16),
     (12, 4, 64, 8),     # Llama-small (GQA)
     (12, 4, 64, 16),
-    (16, 16, 128, 8),   # the width that always compiled
+    (16, 16, 128, 8),   # 128-wide heads: a 256-lane fused leaf
+    (16, 16, 128, 16),
+    (20, 20, 64, 16),   # GPT-2-large, the benchmark's configuration
 ])
 def test_paged_decode_kernel_compiles_for_v5e(v5e_chip, heads, kv_heads,
                                               head_dim, block_size):
-    """Eight slots over a 1024-token context, bf16, as the engine's paged
-    tick calls it."""
+    """Eight slots over a 1024-token context, bf16, over the fused pool
+    leaf ``[N, Hkv, bs, 2D]``, as the engine's paged tick calls it."""
     slots, table = 8, 1024 // block_size
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-    pool = sds((slots * table + 1, kv_heads, block_size, head_dim),
+    pool = sds((slots * table + 1, kv_heads, block_size, 2 * head_dim),
                jnp.bfloat16)
     _compile_with_kernel(
-        lambda q, k, v, t, i: paged_decode_attention_kernel(
-            q, k, v, t, i, interpret=False),
-        sds((slots, heads, 1, head_dim), jnp.bfloat16), pool, pool,
+        lambda q, kv, t, i: paged_decode_attention_kernel(
+            q, kv, t, i, interpret=False),
+        sds((slots, heads, 1, head_dim), jnp.bfloat16), pool,
         sds((slots, table), jnp.int32), sds((slots,), jnp.int32))
+
+
+# ----------------------------------------------- the engine's own programs
+_POOL_BLOCKS, _SLOTS, _BLOCK = 3073, 48, 16   # the benchmark cell's pool
+
+
+def _paged_engine_for_v5e(family):
+    """A ``ServeEngine(paged=True)`` at the benchmark cell's pool shapes
+    (48 slots, block 16, 3073 blocks, 1024 positions, bf16), two layers
+    of 1280: GPT-2-large's 20x64 heads, or a GQA 12/4x64 Llama."""
+    from pddl_tpu.models.gpt import GPT
+    from pddl_tpu.models.llama import Llama
+    from pddl_tpu.serve import ServeEngine
+
+    kw = dict(vocab_size=512, max_len=1024, depth=2, dtype=jnp.bfloat16,
+              param_dtype=jnp.bfloat16)
+    if family == "gpt_20x64":
+        model = GPT(embed_dim=1280, num_heads=20, **kw)
+    else:
+        model = Llama(embed_dim=768, num_heads=12, num_kv_heads=4,
+                      intermediate_dim=2048, **kw)
+    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32),
+                        train=False)["params"]
+    return ServeEngine(model, {"params": params}, paged=True,
+                       max_slots=_SLOTS, prefill_len=768,
+                       prefix_block_size=_BLOCK,
+                       prefix_cache_blocks=_POOL_BLOCKS)
+
+
+@pytest.mark.parametrize("family", ["gpt_20x64", "llama_gqa_12_4x64"])
+def test_paged_programs_keep_the_pool_in_place_on_v5e(v5e_chip, family,
+                                                      monkeypatch):
+    """The engine's own ``_tick_paged``, ``_chunk_paged`` and
+    ``_chunk_paged_wide``, compiled for the described chip with the pool
+    donated and no layout passed anywhere: the compiler must find
+    nothing to relayout. The parent of PR 29 fails every assertion
+    below on its 64-wide K and V leaves (three pool-sized copies a leaf
+    in each program, 75 % of the chip's time in both benchmark cells).
+
+    The engine asks ``jax.default_backend()`` whether to use the Mosaic
+    kernel; here that is the CPU, so the test answers for the chip."""
+    eng = _paged_engine_for_v5e(family)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shapes(*args):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                np.shape(x), np.asarray(x).dtype if not hasattr(x, "dtype")
+                else x.dtype, sharding=v5e_chip), args)
+
+    t1 = np.zeros((1, eng._table_width), np.int32)
+
+    def chunk_args(width):
+        return shapes(eng._params, eng._cache,
+                      np.zeros((1, width), np.int32), np.int32(1),
+                      np.int32(0), t1)
+
+    programs = {
+        "_tick_paged": (eng._tick_p, shapes(*eng._tick_args())),
+        "_chunk_paged": (eng._chunk_p, chunk_args(eng._chunk)),
+        "_chunk_paged_wide": (eng._chunk_wide_p,
+                              chunk_args(eng.prefill_len)),
+    }
+    leaves = [leaf for leaf in jax.tree.leaves(eng._cache) if leaf.ndim == 4]
+    assert len(leaves) == 2                     # one fused leaf a layer
+    pool_shape = f"bf16[{','.join(map(str, leaves[0].shape))}]"
+    leaf_bytes = leaves[0].size * 2
+    logical = leaf_bytes * len(leaves)
+    for name, (prog, args) in programs.items():
+        compiled = prog.lower(*args).compile()
+        text = compiled.as_text()
+        header = text.split("\n", 1)[0]
+        assert f"jit_{name}" in header
+        # (1) No op copies or rematerialises a pool-sized array, and no
+        # temporary of any name is as large as one leaf.
+        shape = r"\(?" + re.escape(pool_shape)
+        moved = [
+            line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(", line)
+            or re.search(rf"%\S*remat\S* = {shape}", line)]
+        assert not moved, f"{name}: pool-sized copies\n" + "\n".join(moved)
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < leaf_bytes, name
+        # (2) Every pool leaf is donated in place.
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                            header).group(1)
+        entry = re.search(
+            r"entry_computation_layout=\{\((.*)\)->\((.*)\)\}", header)
+        array = r"\w+\[[\d,]*\]\{[^}]*\}"     # shape{layout}, in order
+        params = re.findall(array, entry.group(1))
+        results = re.findall(array, entry.group(2))
+        pool_params = [i for i, p in enumerate(params)
+                       if p.startswith(pool_shape)]
+        assert len(pool_params) == len(leaves)
+        aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
+        assert set(pool_params) <= aliased, (name, aliases)
+        # (3) In and out in one layout, the shape's default: row-major,
+        # which nothing in the engine asks for.
+        pool_layouts = ({params[i] for i in pool_params}
+                        | {r for r in results if r.startswith(pool_shape)})
+        assert len(pool_layouts) == 1, pool_layouts
+        assert pool_layouts.pop().startswith(pool_shape + "{3,2,1,0:T(8,128)")
+        # (4) The Mosaic kernel serves the tick; chunks take the jnp path.
+        assert ("tpu_custom_call" in text) == (name == "_tick_paged")
+        # (5) No padding: what the program holds of the pool is what the
+        # pool's data weighs (a 64-lane leaf pinned row-major is 2x).
+        held = memory.argument_size_in_bytes
+        others = sum(
+            np.prod(a.shape, dtype=np.int64) * a.dtype.itemsize
+            for a in jax.tree.leaves(args) if a.ndim != 4)
+        assert held - others <= 1.05 * logical, (name, held, others, logical)
+        assert held - others >= 0.95 * logical
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])

@@ -15,9 +15,12 @@ import pytest
 from pddl_tpu.models.gpt import greedy_gap
 from pddl_tpu.ops.attention import (
     attention_reference,
+    decode_attention,
     flash_attention,
+    paged_cache_insert,
     paged_decode_attention,
     paged_decode_attention_kernel,
+    paged_kv_split,
 )
 from pddl_tpu.ops.augment import standard_augment
 from pddl_tpu.ops.large_vocab import chunked_cross_entropy
@@ -119,31 +122,69 @@ def test_flash_gqa_matches_reference_on_chip(causal):
 
 
 @pytest.mark.parametrize("block_size", [8, 16])
-@pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])
-def test_paged_decode_kernel_matches_oracle_on_chip(heads, kv_heads,
+@pytest.mark.parametrize("heads,kv_heads,d", [
+    (12, 12, 64), (12, 4, 64), (20, 20, 64), (16, 16, 128)])
+def test_paged_decode_kernel_matches_oracle_on_chip(heads, kv_heads, d,
                                                     block_size):
-    """The serving tick's kernel, Mosaic-compiled at GPT-small (12x64) and
-    Llama-small (12/4x64) head shapes, vs the jnp path of
-    ``paged_decode_attention``: eight slots over a 1024-token context,
-    depths from a freshly admitted slot (0) to the last position, block
-    ids scattered over the pool."""
-    slots, d = 8, 64
+    """The serving tick's kernel, Mosaic-compiled over the fused pool
+    leaf at GPT-small (12x64), Llama-small (12/4x64), GPT-2-large
+    (20x64) and 128-wide head shapes, vs the jnp path of
+    ``paged_decode_attention`` and vs the dense oracle over the virtual
+    cache: eight slots over a 1024-token context, depths from a freshly
+    admitted slot (0) to the last position, block ids scattered over
+    the pool."""
+    slots = 8
     t = 1024 // block_size
     n = slots * t + 1  # block 0 is the scratch sink
-    ks = jax.random.split(jax.random.key(heads + kv_heads + block_size), 3)
+    ks = jax.random.split(jax.random.key(heads + kv_heads + block_size), 2)
     q = jax.random.normal(ks[0], (slots, heads, 1, d), jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (n, kv_heads, block_size, d), jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (n, kv_heads, block_size, d), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (n, kv_heads, block_size, 2 * d),
+                             jnp.bfloat16)
     table = jnp.asarray(np.random.RandomState(0).permutation(
         np.arange(1, n)).reshape(slots, t), jnp.int32)
     index = jnp.asarray([0, 1, 7, 8, 100, 511, 1000, 1023], jnp.int32)
     got = jax.jit(lambda *a: paged_decode_attention_kernel(
-        *a, interpret=False))(q, kp, vp, table, index)
+        *a, interpret=False))(q, pool, table, index)
     want = jax.jit(lambda *a: paged_decode_attention(
-        *a, kernel=False))(q, kp, vp, table, index)
+        *a, kernel=False))(q, pool, table, index)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=2e-2, rtol=2e-2)
+    # The virtual cache the table spells, attended densely.
+    kc, vc = paged_kv_split(jnp.moveaxis(pool[table], 1, 2).reshape(
+        slots, kv_heads, 1024, 2 * d))
+    dense = jax.jit(decode_attention)(q, kc, vc, index)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(dense, np.float32),
+        atol=2e-2, rtol=2e-2)
+
+
+def test_paged_tick_write_in_place_on_chip():
+    """The tick's block-granular token write, compiled with the pool
+    donated: every slot's K/V row lands at (table[pos // bs], pos % bs)
+    of the fused leaf, parked slots (all-scratch rows) and a position
+    past the table touch only the scratch block, and nothing else in
+    the pool changes."""
+    slots, hkv, bs, d, t = 8, 20, 16, 64, 64
+    n = slots * t + 1
+    ks = jax.random.split(jax.random.key(29), 3)
+    pool = jax.random.normal(ks[0], (n, hkv, bs, 2 * d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (slots, hkv, 1, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (slots, hkv, 1, d), jnp.bfloat16)
+    table = np.random.RandomState(1).permutation(
+        np.arange(1, n)).reshape(slots, t).astype(np.int32)
+    table[2] = 0
+    table[5] = 0                                  # parked slots
+    index = np.asarray([0, 15, 3, 16, 511, 9, 1023, 1024], np.int32)
+    before = np.asarray(pool, np.float32)
+    out = jax.jit(paged_cache_insert, donate_argnums=(0,))(
+        pool, k, v, table, index)
+    out = np.asarray(out, np.float32)
+    want = before.copy()
+    new = np.asarray(jnp.concatenate([k, v], -1)[:, :, 0], np.float32)
+    for i in (0, 1, 3, 4, 6):
+        want[table[i, index[i] // bs], :, index[i] % bs] = new[i]
+    np.testing.assert_array_equal(out[1:], want[1:])
 
 
 def test_decode_attention_on_chip():
