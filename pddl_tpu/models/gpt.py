@@ -583,6 +583,19 @@ def set_cache_block_tables(cache, tables):
         cache)
 
 
+def set_cache_valid_len(cache, length):
+    """Stamp every ``valid_len`` leaf of a cache (the routed layers'
+    serving counters, `ops/moe.py`) with the number of real tokens in
+    the chunk about to run; a tree without such leaves is returned as
+    it is."""
+    from pddl_tpu.ops.moe import VALID_LEN_KEY
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: length if path and str(
+            getattr(path[-1], "key", path[-1])) == VALID_LEN_KEY else leaf,
+        cache)
+
+
 def insert_cache_slot(cache, row_cache, slot, position):
     """Insert a finished batch-1 prefill (``row_cache``) as slot ``slot``
     of a pooled cache, and stamp the slot's position counter to
@@ -653,13 +666,17 @@ def prefill_row_from(dec, params, prompt, length, row_cache, start, *,
     meaningful to sample from).
     """
     pt = param_transform or (lambda p: p)
+    p2 = pt(params)
     cache = set_cache_positions(row_cache, jnp.asarray(start, jnp.int32))
-    logits, mutated = dec.apply(
-        {"params": pt(params), "cache": cache}, prompt,
-        train=False, mutable=["cache"])
+    cache = set_cache_valid_len(cache, jnp.asarray(length, jnp.int32))
+    # The head runs on the one row that is sampled from, not on the
+    # chunk: at a 152k vocabulary the chunk's logits would be gigabytes.
+    feats, mutated = dec.apply(
+        {"params": p2, "cache": cache}, prompt,
+        train=False, mutable=["cache"], features_only=True)
     last = jax.lax.dynamic_slice(
-        logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[:, 0]
-    return mutated["cache"], last
+        feats, (0, length - 1, 0), (1, 1, feats.shape[-1]))
+    return mutated["cache"], lm_head_logits(dec, p2, last)[:, 0]
 
 
 def lm_head_logits(model, params, feats):

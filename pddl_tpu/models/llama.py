@@ -29,6 +29,15 @@ Llama/Mistral/Qwen lineage — on the same substrate:
   (:class:`pddl_tpu.ops.moe.SwitchFFN`, ``expert_act="swiglu"``);
   import/export via :func:`pddl_tpu.ckpt.hf_import.load_hf_mixtral` /
   ``export_hf_llama``; shard with ``LLAMA_EP_RULES``.
+- **Per-layer declarations** on the one block, for the hybrids of the
+  lineage: an explicit ``head_dim`` (q width ``num_heads * head_dim``
+  need not equal the embedding), ``sliding_window_layout`` /
+  ``rope_layout`` (a 0/1 tuple a layer: which layers attend through the
+  window, which rotate; a layer with neither is full attention with NO
+  position encoding and caches its keys as projected), ReLU-gated
+  experts (``moe_act="reglu"``), and ``moe_router_input="attn"``: the
+  router reads the ATTENTION's normed input, so a runtime can fetch the
+  chosen experts' weights while attention runs.
 
 Everything else — flash/ring attention, Megatron TP (use
 ``LLAMA_TP_RULES`` from :mod:`pddl_tpu.parallel.tensor_parallel`),
@@ -105,6 +114,8 @@ class LlamaAttention(nn.Module):
 
     num_heads: int
     num_kv_heads: int
+    head_dim: Optional[int] = None  # None: embed // num_heads
+    rope: bool = True  # False: no position encoding (NoPE layer)
     rope_theta: float = 10000.0
     attention: str = "flash"  # "flash" | "reference" | "ring" | "ring_flash"
     sliding_window: Optional[int] = None  # Mistral-style SWA width
@@ -115,10 +126,15 @@ class LlamaAttention(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
+    def _rotate(self, q, k, positions):
+        if not self.rope:
+            return q, k
+        return apply_rope_qk(q, k, positions, theta=self.rope_theta)
+
     @nn.compact
     def __call__(self, x):
         b, s, e = x.shape
-        if e % self.num_heads:
+        if self.head_dim is None and e % self.num_heads:
             raise ValueError(f"embed dim {e} not divisible by {self.num_heads} heads")
         if self.num_heads % self.num_kv_heads:
             raise ValueError(
@@ -129,7 +145,7 @@ class LlamaAttention(nn.Module):
             # rejects it too, not just the flash/reference kernels.
             raise ValueError(
                 f"sliding_window must be >= 1, got {self.sliding_window}")
-        head_dim = e // self.num_heads
+        head_dim = self.head_dim or e // self.num_heads
         dense = functools.partial(
             nn.DenseGeneral, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype,
@@ -143,9 +159,9 @@ class LlamaAttention(nn.Module):
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, S, D]
 
         if self.decode:
-            return self._decode_step(q, k, v, b, s, head_dim, dense)
+            return self._decode_step(q, k, v, b, s, e, head_dim, dense)
 
-        q, k = apply_rope_qk(q, k, jnp.arange(s), theta=self.rope_theta)
+        q, k = self._rotate(q, k, jnp.arange(s))
 
         # K/V stay at kv-head shape [B, H_kv, S, D] through every kernel:
         # the attention ops consume grouped K/V natively (q-head → kv-head
@@ -173,14 +189,14 @@ class LlamaAttention(nn.Module):
         else:
             raise ValueError(f"unknown attention {self.attention!r}")
 
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, e)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, self.num_heads * head_dim)
         return dense(features=e, name="out")(o)
 
     def _ring_len(self) -> Optional[int]:
         """Rolling-cache length for SWA decode (see :func:`ring_len`)."""
         return ring_len(self.sliding_window, self.max_decode_len)
 
-    def _decode_step(self, q, k, v, b, s, head_dim, dense):
+    def _decode_step(self, q, k, v, b, s, e, head_dim, dense):
         """KV-cache decoding at the bandwidth roofline.
 
         The cache holds POST-RoPE keys at KV-head granularity in the
@@ -212,9 +228,12 @@ class LlamaAttention(nn.Module):
         CLAMPS out-of-range starts rather than failing.)
         """
         hkv = self.num_kv_heads
-        ring = self._ring_len()
-        cache_len = ring or self.max_decode_len
         paged = self.has_variable("cache", BLOCK_TABLE_KEY)
+        # A paged cache is full length for every layer: a window layer
+        # lives in the same pool as a global one, masked (and its dead
+        # blocks skipped) to its band, so no ring exists there.
+        ring = None if paged else self._ring_len()
+        cache_len = ring or self.max_decode_len
         index = self.variable(
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
 
@@ -231,8 +250,7 @@ class LlamaAttention(nn.Module):
         # [..., None] keeps one expression for both index ranks: scalar
         # i → positions [s]; per-row i → [B, s] (rope broadcasts a head
         # axis for the 2-D form).
-        q, k = apply_rope_qk(q, k, i[..., None] + jnp.arange(s),
-                             theta=self.rope_theta)
+        q, k = self._rotate(q, k, i[..., None] + jnp.arange(s))
         k = k.astype(self.dtype)
         v = v.astype(self.dtype)
         if paged:
@@ -242,17 +260,15 @@ class LlamaAttention(nn.Module):
             # ABSOLUTE positions like the row path, so a shared pool
             # block stays bit-valid for every referencing slot — the
             # same contract the prefix cache's copies relied on, now
-            # without the copies. Rolling (ring) caches are never
-            # paged; the serving engine refuses ring models outright.
-            if ring is not None:
-                raise NotImplementedError(
-                    "paged attention requires a full-length cache; "
-                    "rolling sliding-window caches are not paged")
-            o = paged_decode_step(self, index, q, k, v,
-                                  window=self.sliding_window)
+            # without the copies (a NoPE layer's keys are cached as
+            # projected, which is position-pure a fortiori).
+            with jax.named_scope("attn_window" if self.sliding_window
+                                 else "attn_global"):
+                o = paged_decode_step(self, index, q, k, v,
+                                      window=self.sliding_window)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, s, self.num_heads * head_dim)
-            return dense(features=self.num_heads * head_dim, name="out")(o)
+            return dense(features=e, name="out")(o)
         initialized = self.has_variable("cache", "cached_key")
         cached_k = self.variable(
             "cache", "cached_key", jnp.zeros,
@@ -332,17 +348,22 @@ class LlamaAttention(nn.Module):
                 q, cached_k.value, cached_v.value, i,
                 window=self.sliding_window, rolling=ring is not None)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, self.num_heads * head_dim)
-        return dense(features=self.num_heads * head_dim, name="out")(o)
+        return dense(features=e, name="out")(o)
 
 
 class LlamaBlock(nn.Module):
     """Pre-RMSNorm residual block: attention then a SwiGLU MLP — dense,
-    or routed over ``moe_experts`` SwiGLU experts (the Mixtral block:
-    ``block_sparse_moe`` with top-``moe_top_k`` routing)."""
+    or routed over ``moe_experts`` gated experts (the Mixtral block:
+    ``block_sparse_moe`` with top-``moe_top_k`` routing).
+    ``moe_router_input="attn"`` moves the router in front of the
+    attention: logits from the attention's normed input (a bias-free
+    ``router`` of the block's own), experts applied to the MLP's."""
 
     num_heads: int
     num_kv_heads: int
     intermediate_dim: int
+    head_dim: Optional[int] = None
+    rope: bool = True
     rope_theta: float = 10000.0
     attention: str = "flash"
     sliding_window: Optional[int] = None
@@ -353,7 +374,9 @@ class LlamaBlock(nn.Module):
     moe_experts: int = 0  # >0: Mixtral-style routed SwiGLU experts
     moe_top_k: int = 2
     moe_capacity_factor: float = 2.0
-    moe_eval_dropless: bool = True  # eval/serving capacity = S (dropless)
+    moe_eval_dropless: bool = True  # eval/serving is dropless
+    moe_act: str = "swiglu"  # "swiglu" | "reglu"
+    moe_router_input: str = "mlp"  # "mlp" | "attn"
     rms_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -365,9 +388,19 @@ class LlamaBlock(nn.Module):
         # MoE capacity rule: routed blocks drop over-capacity tokens in
         # training but run DROPLESS at eval/serving.)
         e = x.shape[-1]
+        if self.moe_router_input not in ("mlp", "attn"):
+            raise ValueError(
+                f"unknown moe_router_input {self.moe_router_input!r}")
         h = _rms_norm(self.rms_eps, self.param_dtype, "ln1")(x)
+        router_logits = None
+        if self.moe_experts and self.moe_router_input == "attn":
+            with jax.named_scope("moe_router"):
+                router_logits = nn.Dense(
+                    self.moe_experts, use_bias=False, dtype=jnp.float32,
+                    param_dtype=self.param_dtype, name="router")(h)
         h = LlamaAttention(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim, rope=self.rope,
             rope_theta=self.rope_theta, attention=self.attention,
             sliding_window=self.sliding_window, qkv_bias=self.qkv_bias,
             mesh=self.mesh, decode=self.decode,
@@ -386,9 +419,9 @@ class LlamaBlock(nn.Module):
                 hidden_dim=self.intermediate_dim, top_k=self.moe_top_k,
                 capacity_factor=self.moe_capacity_factor,
                 eval_dropless=self.moe_eval_dropless,
-                expert_act="swiglu", dtype=self.dtype,
+                expert_act=self.moe_act, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="moe",
-            )(h, train)
+            )(h, train, router_logits=router_logits)
             return x + h
         dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
                                   param_dtype=self.param_dtype)
@@ -424,7 +457,14 @@ class Llama(nn.Module):
     intermediate_dim: Optional[int] = None  # None → SwiGLU-standard ~8E/3
     rope_theta: float = 10000.0
     attention: str = "flash"
+    head_dim: Optional[int] = None  # None: embed_dim // num_heads
     sliding_window: Optional[int] = None  # Mistral-style SWA width
+    # Per-layer layouts (a 0/1 entry a layer, the published
+    # `sliding_window_layout` / `rope_layout` of the window/NoPE
+    # hybrids). None: every layer attends through `sliding_window` (if
+    # set) and every layer rotates.
+    sliding_window_layout: Optional[tuple] = None
+    rope_layout: Optional[tuple] = None
     qkv_bias: bool = False  # Qwen2-style q/k/v biases
     mesh: Optional[Any] = None
     remat: str = "none"
@@ -434,19 +474,52 @@ class Llama(nn.Module):
     moe_top_k: int = 2  # Mixtral's num_experts_per_tok
     moe_every: int = 1  # Mixtral puts MoE in EVERY layer
     moe_capacity_factor: float = 2.0
-    moe_eval_dropless: bool = True  # eval/serving capacity = S (dropless)
+    moe_eval_dropless: bool = True  # eval/serving is dropless
+    moe_act: str = "swiglu"  # "swiglu" | "reglu" (ReLU-gated experts)
+    moe_router_input: str = "mlp"  # "attn": router before attention
     rms_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
+    def layer_window(self, i: int) -> Optional[int]:
+        """Layer ``i``'s attention window (None: full attention)."""
+        if self.sliding_window_layout is None \
+                or self.sliding_window_layout[i]:
+            return self.sliding_window
+        return None
+
+    def layer_rope(self, i: int) -> bool:
+        return self.rope_layout is None or bool(self.rope_layout[i])
+
+    def moe_layer(self, i: int) -> bool:
+        """Interleaved MoE blocks: every ``moe_every``-th, counted from
+        the back like ViT (Mixtral's ``moe_every=1``: every block)."""
+        return bool(self.moe_experts) \
+            and (self.depth - 1 - i) % self.moe_every == 0
+
     @property
     def uses_ring_cache(self) -> bool:
-        """True when SWA decode allocates a rolling ring cache (slots
-        recycle — cannot be rewound; speculative decoding checks this).
-        Same decision, same code as the cache allocation:
+        """True when SWA decode (outside a paged engine) allocates a
+        rolling ring cache (slots recycle — cannot be rewound;
+        speculative decoding and the row-mode serving engine check
+        this). Same decision, same code as the cache allocation:
         :func:`ring_len` over the blocks' ``max_decode_len`` (=
-        ``max_len``, line where the blocks are built)."""
-        return ring_len(self.sliding_window, self.max_len) is not None
+        ``max_len``, line where the blocks are built), for any layer."""
+        return any(ring_len(self.layer_window(i), self.max_len) is not None
+                   for i in range(self.depth))
+
+    def paged_cache_extras(self) -> dict:
+        """Cache leaves a PAGED serving engine adds beside each
+        attention's pool (`kvcache.paged_decode_cache` merges them in):
+        per routed block, the expert-load counters the MoE layer
+        accumulates over prompt tokens and the valid-length scalar the
+        chunk programs stamp (`ops/moe.py` ``EXPERT_LOAD_KEY``)."""
+        from pddl_tpu.ops.moe import EXPERT_LOAD_KEY, VALID_LEN_KEY
+
+        return {f"block{i}": {"moe": {
+            EXPERT_LOAD_KEY: jnp.zeros((self.moe_experts,), jnp.int32),
+            VALID_LEN_KEY: jnp.zeros((), jnp.int32)}}
+            for i in range(self.depth) if self.moe_layer(i)}
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = True,
@@ -466,22 +539,26 @@ class Llama(nn.Module):
 
         block_cls = (LlamaBlock if self.decode
                      else remat_block(LlamaBlock, self.remat))
+        for layout in (self.sliding_window_layout, self.rope_layout):
+            if layout is not None and len(layout) != self.depth:
+                raise ValueError(
+                    f"a per-layer layout needs {self.depth} entries, got "
+                    f"{len(layout)}")
         for i in range(self.depth):
-            # Interleave MoE blocks (every moe_every-th, counted from the
-            # back like ViT — Mixtral's moe_every=1 makes every block
-            # routed).
-            moe = (self.moe_experts
-                   if (self.depth - 1 - i) % self.moe_every == 0 else 0)
             x = block_cls(
                 num_heads=self.num_heads, num_kv_heads=kv,
-                intermediate_dim=inter, rope_theta=self.rope_theta,
+                intermediate_dim=inter, head_dim=self.head_dim,
+                rope=self.layer_rope(i), rope_theta=self.rope_theta,
                 attention=self.attention,
-                sliding_window=self.sliding_window,
+                sliding_window=self.layer_window(i),
                 qkv_bias=self.qkv_bias, mesh=self.mesh,
                 decode=self.decode, max_decode_len=self.max_len,
-                moe_experts=moe, moe_top_k=self.moe_top_k,
+                moe_experts=self.moe_experts if self.moe_layer(i) else 0,
+                moe_top_k=self.moe_top_k,
                 moe_capacity_factor=self.moe_capacity_factor,
                 moe_eval_dropless=self.moe_eval_dropless,
+                moe_act=self.moe_act,
+                moe_router_input=self.moe_router_input,
                 rms_eps=self.rms_eps, dtype=self.dtype,
                 param_dtype=self.param_dtype, name=f"block{i}",
             )(x, train)
@@ -526,6 +603,48 @@ Llama_300M = functools.partial(
 Llama_1B = functools.partial(
     Llama, embed_dim=2048, depth=16, num_heads=32, num_kv_heads=8,
     intermediate_dim=8192, rope_theta=500000.0, max_len=4096)
+
+
+# SmallThinker-21B-A3B-Instruct (PowerInfer, 2025-07; HF config.json):
+# 52 layers x 2560, 28 q / 4 kv heads of 128 (q width 3584), every layer
+# 64 ReLU-gated experts of 768 top-6 behind a bias-free router that reads
+# the attention's normed input; period of four layers [full attention
+# with NO position encoding, then three with a 4096 window and RoPE at
+# theta 1.5e6]; RMSNorm eps 1e-6; untied head over 151,936 tokens; 16,384
+# positions. `depth` is the caller's (a chip holds 12 of the 52 in bf16):
+# the layouts repeat the published period. Served at random weights
+# only — no checkpoint import maps the published tensor names yet.
+SMALLTHINKER_PERIOD = (0, 1, 1, 1)
+
+
+def _smallthinker_layout(depth: int) -> tuple:
+    return tuple(SMALLTHINKER_PERIOD[i % 4] for i in range(depth))
+
+
+def SmallThinker_21B_A3B(depth: int = 52, **kwargs) -> Llama:
+    layout = _smallthinker_layout(depth)
+    kwargs.setdefault("max_len", 16384)
+    return Llama(
+        vocab_size=151936, embed_dim=2560, depth=depth, num_heads=28,
+        num_kv_heads=4, head_dim=128, intermediate_dim=768,
+        rope_theta=1.5e6, sliding_window=4096,
+        sliding_window_layout=layout, rope_layout=layout,
+        moe_experts=64, moe_top_k=6, moe_act="reglu",
+        moe_router_input="attn", rms_eps=1e-6, **kwargs)
+
+
+def tiny_smallthinker(vocab_size: int = 64, **kwargs) -> Llama:
+    """The same block at test size: one period [global+NoPE, 3 x
+    window+RoPE], window 8, 7:1 GQA with ``head_dim != embed/heads``,
+    8 ReLU-gated experts top-2 routed from the attention's input."""
+    layout = _smallthinker_layout(kwargs.get("depth", 4))
+    defaults = dict(
+        depth=4, max_len=128, embed_dim=40, num_heads=7, num_kv_heads=1,
+        head_dim=8, intermediate_dim=16, rope_theta=1.5e6,
+        sliding_window=8, sliding_window_layout=layout, rope_layout=layout,
+        moe_experts=8, moe_top_k=2, moe_act="reglu",
+        moe_router_input="attn", rms_eps=1e-6, attention="reference")
+    return Llama(vocab_size=vocab_size, **{**defaults, **kwargs})
 
 
 class _LlamaEmbed(nn.Module):

@@ -133,6 +133,9 @@ SERVE_COUNTER_KEYS = frozenset({
     # summed waits.
     "engine_steps", "step_wall_s", "phase_wall_s", "decode_ticks",
     "queue_pops", "queue_wait_s", "admissions", "admit_wall_s",
+    # What prefill cost in tokens: prompt tokens installed, and chunk
+    # programs dispatched by compiled width (a labeled counter).
+    "prefill_tokens", "prefill_chunks",
 })
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")

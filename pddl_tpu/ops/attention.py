@@ -1071,6 +1071,12 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
 # construction and harmless by masking.
 
 
+# Rows of a multi-token chunk the jnp sweep takes at once (see
+# `paged_decode_attention`): every chunk width the engines had before
+# the 12k-wide one is under it, so their programs are unchanged.
+PAGED_SWEEP_MAX_ROWS = 2048
+
+
 def paged_kv_fuse(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     """K and V ``[..., D]`` side by side as the pool leaf stores them:
     ``[..., 2D]``, K in lanes ``[0, D)``, V in ``[D, 2D)``."""
@@ -1221,50 +1227,74 @@ def paged_decode_attention(
     cb = min(int(blocks_per_chunk), t)
     chunk = cb * bs
     n_chunks = -(-t // cb)
-    qg = q.reshape(b, hkv, rep, s, d)
-    total = index + s
-    q_pos = index[..., None] + jnp.arange(s)
 
     def _bcast(mask):
         return mask if mask.ndim == 2 else mask[:, None, None]
 
-    def body(c, carry):
-        m, l, acc = carry
-        start_blk = jnp.minimum(c * cb, t - cb)       # clamped tail
-        ids = jax.lax.dynamic_slice(block_table, (0, start_blk),
-                                    (b, cb))          # [B, cb]
-        kvc = jnp.take(kv_pool, ids.reshape(-1), axis=0)
-        # [B*cb, Hkv, bs, 2D] -> [B, Hkv, cb*bs, 2D]
-        kvc = jnp.moveaxis(kvc.reshape(b, cb, hkv, bs, d2), 1, 2) \
-            .reshape(b, hkv, chunk, d2)
-        kc, vc = paged_kv_split(kvc)
-        sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(kv_pool.dtype), kc,
-                        preferred_element_type=jnp.float32) * scale_v
-        pos = start_blk * bs + jnp.arange(chunk)
-        dedup = pos >= c * chunk  # drop the clamped tail's re-read overlap
-        mask = pos[..., None, :] <= q_pos[..., :, None]
-        if window is not None:
-            mask &= pos[..., None, :] > q_pos[..., :, None] - window
-        mask &= dedup[None, :]
-        sb = jnp.where(_bcast(mask), sb, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(sb, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(sb - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.einsum(
-            "bgrqk,bgkd->bgrqd", p.astype(kv_pool.dtype), vc,
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    def sweep(qg, index):
+        """``qg [B, Hkv, rep, sq, D]`` at positions ``index (+
+        arange(sq))`` over the chunks its rows can see."""
+        sq = qg.shape[3]
+        total = index + sq
+        q_pos = index[..., None] + jnp.arange(sq)
 
-    live = jnp.minimum((jnp.max(total) + chunk - 1) // chunk, n_chunks)
-    m0 = jnp.full((b, hkv, rep, s, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, hkv, rep, s, 1), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, rep, s, d), jnp.float32)
-    if n_chunks == 1:
-        m, l, acc = body(0, (m0, l0, acc0))
-    else:
-        m, l, acc = jax.lax.fori_loop(0, live, body, (m0, l0, acc0))
-    return (acc / jnp.maximum(l, 1e-30)).reshape(b, h, s, d).astype(q.dtype)
+        def body(c, carry):
+            m, l, acc = carry
+            start_blk = jnp.minimum(c * cb, t - cb)       # clamped tail
+            ids = jax.lax.dynamic_slice(block_table, (0, start_blk),
+                                        (b, cb))          # [B, cb]
+            kvc = jnp.take(kv_pool, ids.reshape(-1), axis=0)
+            # [B*cb, Hkv, bs, 2D] -> [B, Hkv, cb*bs, 2D]
+            kvc = jnp.moveaxis(kvc.reshape(b, cb, hkv, bs, d2), 1, 2) \
+                .reshape(b, hkv, chunk, d2)
+            kc, vc = paged_kv_split(kvc)
+            sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(kv_pool.dtype),
+                            kc, preferred_element_type=jnp.float32) * scale_v
+            pos = start_blk * bs + jnp.arange(chunk)
+            dedup = pos >= c * chunk  # drop the clamped tail's re-read
+            mask = pos[..., None, :] <= q_pos[..., :, None]
+            if window is not None:
+                mask &= pos[..., None, :] > q_pos[..., :, None] - window
+            mask &= dedup[None, :]
+            sb = jnp.where(_bcast(mask), sb, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sb, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sb - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "bgrqk,bgkd->bgrqd", p.astype(kv_pool.dtype), vc,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        live = jnp.minimum((jnp.max(total) + chunk - 1) // chunk, n_chunks)
+        # A window layer's sweep starts at the first chunk any of these
+        # rows' bands reaches: history wholly under the band is neither
+        # gathered nor multiplied (at 12k of history and a 4k window,
+        # two thirds of it).
+        first = 0 if window is None else \
+            jnp.maximum(jnp.min(index) - (window - 1), 0) // chunk
+        m0 = jnp.full((b, hkv, rep, sq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((b, hkv, rep, sq, 1), jnp.float32)
+        acc0 = jnp.zeros((b, hkv, rep, sq, d), jnp.float32)
+        if n_chunks == 1:
+            m, l, acc = body(0, (m0, l0, acc0))
+        else:
+            m, l, acc = jax.lax.fori_loop(first, live, body, (m0, l0, acc0))
+        return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+
+    qg = q.reshape(b, hkv, rep, s, d)
+    sq = _largest_dividing_block(s, PAGED_SWEEP_MAX_ROWS)
+    if sq == s:
+        return sweep(qg, index).reshape(b, h, s, d)
+    # A chunk wider than PAGED_SWEEP_MAX_ROWS goes a stretch of rows at a
+    # time: each stretch sweeps to ITS causal edge and from ITS band's
+    # start (half the multiplies of the whole square, less for a window
+    # layer), and the score block stays the stretch's size.
+    tiles = jnp.moveaxis(qg.reshape(b, hkv, rep, s // sq, sq, d), 3, 0)
+    out = jax.lax.map(
+        lambda a: sweep(a[0], index + a[1] * sq),
+        (tiles, jnp.arange(s // sq, dtype=jnp.int32)))
+    return jnp.moveaxis(out, 0, 3).reshape(b, h, s, d)
 
 
 def _paged_decode_kernel(table_ref, index_ref, q_ref, kv_ref, o_ref,

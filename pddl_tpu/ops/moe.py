@@ -21,14 +21,131 @@ expert parallelism as absent; the mesh reserves an ``expert`` axis for it,
 - **Load-balancing aux loss** (Switch loss: ``n·Σ fᵢ·Pᵢ``) is exported via
   ``self.sow("losses", ...)``; the Trainer adds every sown loss to the
   task loss.
+
+That is the TRAINING path. At eval and serving (``train=False``, the
+default ``eval_dropless``) the layer runs TOKEN-MAJOR and dropless
+(:func:`grouped_expert_ffn`): the token–expert pairs are sorted by
+expert, each expert's run of rows goes through its own weights a tile
+at a time, and every token gathers its ``top_k`` results back. It
+computes the routed pairs and at most ``num_experts`` tiles of padding
+over them, builds nothing ``[B, S, N, S]``-shaped (a 12k-token prefill
+chunk is a 74k-row sort, not a 9.6-billion-entry dispatch tensor), and a
+decode step reads the weights of the experts its rows hit and no others.
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+# Cache-collection leaves a serving MoE layer accumulates into when the
+# paged engine put them there (`Llama.paged_cache_extras`): per-expert
+# counts of the routed pairs of prompt tokens, and the number of rows of
+# the chunk that hold real tokens (stamped by `gpt.prefill_row_from`).
+EXPERT_LOAD_KEY = "expert_load"
+VALID_LEN_KEY = "valid_len"
+
+EXPERT_ACTS = {"gelu": nn.gelu, "swiglu": nn.silu, "reglu": nn.relu}
+
+
+def expert_tile(pairs: int, num_experts: int) -> int:
+    """Rows per tile of :func:`grouped_expert_ffn`: about one expert's
+    even share of the pairs, a power of two in [16, 512]. One tile reads
+    its expert's weights once, so a tile should hold the expert's whole
+    run where it can (a prefill chunk is bound by those reads until a
+    run is some 256 rows long); 16 rows is the narrowest bf16 tile, which
+    is what a decode step's one or two rows per expert pad to."""
+    share = -(-pairs // num_experts)
+    return min(512, max(16, 1 << (share - 1).bit_length()))
+
+
+def grouped_expert_ffn(x, expert_index, gates, w_in, w_out, *,
+                       act: str, w_gate=None, b_in=None, b_out=None,
+                       tile: Optional[int] = None):
+    """Dropless routed FFN, token-major: ``y[t] = sum_j gates[t, j] *
+    FFN_{expert_index[t, j]}(x[t])``.
+
+    ``x [T, d]``; ``expert_index``/``gates`` ``[T, k]``; expert-major
+    weights ``w_in [N, d, h]``, ``w_out [N, h, d]``, for gated experts
+    ``w_gate [N, d, h]`` (``act(x w_gate) * (x w_in)``), for the biased
+    GELU expert ``b_in [N, h]`` / ``b_out [N, d]``.
+
+    The ``P = T k`` pairs are sorted by expert (stable, so a token's
+    pairs keep their order). Expert ``e``'s run of ``c_e`` rows is cut
+    into ``ceil(c_e / tile)`` tiles; one loop iteration gathers a tile's
+    rows of ``x``, multiplies them through expert ``e``'s weights and
+    writes the result at the run's offset. A run's last tile reaches
+    into the next expert's rows; that expert's own tile comes later in
+    the loop and overwrites them, so no mask and no padded copy of ``x``
+    is needed. Rows computed: ``sum_e ceil(c_e / tile) * tile <= P + N *
+    (tile - 1)``, whatever the routing (every token to one expert
+    included); experts with no pair cost nothing, not even a read of
+    their weights.
+    """
+    # Weights may arrive as host arrays (an imported checkpoint): the
+    # loop indexes them with a traced expert.
+    w_in, w_out, w_gate, b_in, b_out = (
+        None if w is None else jnp.asarray(w)
+        for w in (w_in, w_out, w_gate, b_in, b_out))
+    t, d = x.shape
+    k = expert_index.shape[1]
+    n = w_in.shape[0]
+    pairs = t * k
+    tile = expert_tile(pairs, n) if tile is None else int(tile)
+    fn = EXPERT_ACTS[act]
+    with jax.named_scope("moe_dispatch"):
+        flat = expert_index.reshape(pairs).astype(jnp.int32)
+        sorted_e, order = jax.lax.sort_key_val(
+            flat, jnp.arange(pairs, dtype=jnp.int32))
+        # Pair p of token p // k; `tile` rows of padding so a run's last
+        # tile may read past the end.
+        tok_sorted = jnp.concatenate(
+            [order // k, jnp.zeros((tile,), jnp.int32)])
+        bounds = jnp.searchsorted(
+            sorted_e, jnp.arange(n + 1, dtype=jnp.int32),
+            method="compare_all").astype(jnp.int32)
+        starts, counts = bounds[:-1], bounds[1:] - bounds[:-1]
+        tiles_per = (counts + tile - 1) // tile
+        tile_cum = jnp.cumsum(tiles_per)
+        max_tiles = -(-pairs // tile) + n
+        ids = jnp.arange(max_tiles, dtype=jnp.int32)
+        tile_e = jnp.minimum(
+            jnp.searchsorted(tile_cum, ids, side="right",
+                             method="compare_all"), n - 1
+        ).astype(jnp.int32)
+        tile_row0 = starts[tile_e] + (
+            ids - (tile_cum[tile_e] - tiles_per[tile_e])) * tile
+
+    def body(i, y_sorted):
+        e, r0 = tile_e[i], tile_row0[i]
+        xs = x[jax.lax.dynamic_slice(tok_sorted, (r0,), (tile,))]
+        up = xs @ w_in[e]
+        if b_in is not None:
+            up = up + b_in[e]
+        hid = fn(xs @ w_gate[e]) * up if w_gate is not None else fn(up)
+        y = hid @ w_out[e]
+        if b_out is not None:
+            y = y + b_out[e]
+        return jax.lax.dynamic_update_slice(y_sorted, y.astype(x.dtype),
+                                            (r0, 0))
+
+    with jax.named_scope("moe_ffn"):
+        y_sorted = jax.lax.fori_loop(
+            0, tile_cum[-1], body, jnp.zeros((pairs + tile, d), x.dtype))
+    with jax.named_scope("moe_combine"):
+        # Where pair p landed in the sorted order: the inverse of `order`.
+        _, where = jax.lax.sort_key_val(
+            order, jnp.arange(pairs, dtype=jnp.int32))
+        where = where.reshape(t, k)
+        gates = gates.astype(jnp.float32)
+        # One choice at a time: a [T, k, d] float32 gather would be the
+        # layer's largest temporary.
+        out = sum(y_sorted[where[:, j]].astype(jnp.float32)
+                  * gates[:, j:j + 1] for j in range(k))
+        return out.astype(x.dtype)
 
 
 class SwitchFFN(nn.Module):
@@ -50,6 +167,13 @@ class SwitchFFN(nn.Module):
       ``hidden_dim`` (the Mixtral expert; parameter names w1/w3/w2
       follow the HF checkpoint layout so
       :func:`pddl_tpu.ckpt.hf_import.load_hf_llama` maps them 1:1).
+    - ``"reglu"`` — the same with ``relu`` for the gate (sparse ReGLU
+      experts: a zero gate zeroes the row of ``w2`` it would read).
+
+    ``router_logits`` (call argument): logits ``[B, S, N]`` computed
+    outside the layer — a block that routes from its attention's normed
+    input, so that expert weights can be fetched while attention runs,
+    hands them in and the layer declares no router of its own.
     """
 
     num_experts: int
@@ -57,7 +181,7 @@ class SwitchFFN(nn.Module):
     hidden_dim: int | None = None  # overrides mlp_ratio * embed when set
     top_k: int = 1
     capacity_factor: float = 1.25
-    expert_act: str = "gelu"  # "gelu" | "swiglu" (Mixtral)
+    expert_act: str = "gelu"  # "gelu" | "swiglu" (Mixtral) | "reglu"
     normalize_gates: bool = True  # top_k >= 2: g_j / sum_j g_j
     aux_loss_weight: float = 0.01
     # Eval/serving (train=False) uses capacity == seq — enough for the
@@ -66,14 +190,14 @@ class SwitchFFN(nn.Module):
     # receive at most S tokens per batch row. Inference is therefore
     # DROPLESS regardless of capacity_factor. Real Mixtral checkpoints
     # assume dropless routing; without this, an imbalanced prompt
-    # silently diverges from the reference logits. The price is
-    # dispatch/combine tensors growing to [B, S, N, S] at eval.
+    # silently diverges from the reference logits. The dropless path
+    # is token-major (`grouped_expert_ffn`), not a capacity-S dispatch.
     eval_dropless: bool = True
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, train: bool = True, /):
+    def __call__(self, x, train: bool = True, /, router_logits=None):
         # train is positional-only to match the transformer blocks'
         # remat static_argnums convention (vit.TransformerBlock).
         b, s, d = x.shape
@@ -81,24 +205,40 @@ class SwitchFFN(nn.Module):
         if not 1 <= self.top_k <= n:
             raise ValueError(
                 f"top_k={self.top_k} must be in [1, num_experts={n}]")
-        if self.expert_act not in ("gelu", "swiglu"):
+        if self.expert_act not in EXPERT_ACTS:
             raise ValueError(f"unknown expert_act {self.expert_act!r}")
+        gated = self.expert_act != "gelu"
         # Batch rows are the dispatch groups (the Switch/Mesh-TF "group"
         # dim): capacity is per group, so dispatch/combine are
         # [B, S, N, C] — linear in batch, never quadratic in total tokens.
         # top-2 doubles routed token-slots, so capacity scales with k.
-        if not train and self.eval_dropless:
-            capacity = s
-        else:
-            capacity = max(1, int(self.capacity_factor * self.top_k * s / n))
+        capacity = max(1, int(self.capacity_factor * self.top_k * s / n))
         hidden = self.hidden_dim if self.hidden_dim is not None \
             else d * self.mlp_ratio
 
         # Router (f32 for a stable softmax regardless of compute dtype).
-        router_logits = nn.Dense(
-            n, dtype=jnp.float32, param_dtype=self.param_dtype, name="router"
-        )(x.astype(jnp.float32))
-        probs = nn.softmax(router_logits, axis=-1)            # (B, S, N)
+        with jax.named_scope("moe_router"):
+            if router_logits is None:
+                router_logits = nn.Dense(
+                    n, dtype=jnp.float32,
+                    param_dtype=self.param_dtype, name="router"
+                )(x.astype(jnp.float32))
+            probs = nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+
+        # Expert-major parameters: dim 0 shards over the `expert` mesh axis.
+        # batch_axis=(0,): the expert dim must not count toward fan-in, or
+        # every expert initializes sqrt(n) too small.
+        he = nn.initializers.he_normal(batch_axis=(0,))
+        param = lambda name, init, *shape: self.param(
+            name, init, shape, self.param_dtype).astype(self.dtype)
+        w1 = param("w1", he, n, d, hidden)       # gate (gated) / in (gelu)
+        w3 = param("w3", he, n, d, hidden) if gated else None       # up
+        b1 = None if gated else param("b1", nn.initializers.zeros, n, hidden)
+        w2 = param("w2", he, n, hidden, d)
+        b2 = None if gated else param("b2", nn.initializers.zeros, n, d)
+
+        if not train and self.eval_dropless:
+            return self._serve(x, probs, w1, w3, b1, w2, b2)
 
         # k sequential choices (k is tiny and static — an unrolled Python
         # loop of MXU-friendly one-hot ops, no sorting network needed).
@@ -157,34 +297,54 @@ class SwitchFFN(nn.Module):
         combine = combine.astype(self.dtype)
         xc = x.astype(self.dtype)
 
-        # Expert-major parameters: dim 0 shards over the `expert` mesh axis.
-        # batch_axis=(0,): the expert dim must not count toward fan-in, or
-        # every expert initializes sqrt(n) too small.
-        he = nn.initializers.he_normal(batch_axis=(0,))
-
         # Dispatch -> expert FFN -> combine: all MXU einsums, static shapes.
         expert_in = jnp.einsum("bsnc,bsd->bncd", dispatch, xc)
-        if self.expert_act == "swiglu":
-            w1 = self.param("w1", he, (n, d, hidden),
-                            self.param_dtype).astype(self.dtype)  # gate
-            w3 = self.param("w3", he, (n, d, hidden),
-                            self.param_dtype).astype(self.dtype)  # up
-            w2 = self.param("w2", he, (n, hidden, d),
-                            self.param_dtype).astype(self.dtype)  # down
+        if gated:
             gate_h = jnp.einsum("bncd,ndh->bnch", expert_in, w1)
             up_h = jnp.einsum("bncd,ndh->bnch", expert_in, w3)
-            expert_out = jnp.einsum("bnch,nhd->bncd",
-                                    nn.silu(gate_h) * up_h, w2)
+            expert_out = jnp.einsum(
+                "bnch,nhd->bncd",
+                EXPERT_ACTS[self.expert_act](gate_h) * up_h, w2)
         else:
-            w1 = self.param("w1", he, (n, d, hidden),
-                            self.param_dtype).astype(self.dtype)
-            b1 = self.param("b1", nn.initializers.zeros, (n, hidden),
-                            self.param_dtype).astype(self.dtype)
-            w2 = self.param("w2", he, (n, hidden, d),
-                            self.param_dtype).astype(self.dtype)
-            b2 = self.param("b2", nn.initializers.zeros, (n, d),
-                            self.param_dtype).astype(self.dtype)
             h = nn.gelu(jnp.einsum("bncd,ndh->bnch", expert_in, w1)
                         + b1[:, None, :])
             expert_out = jnp.einsum("bnch,nhd->bncd", h, w2) + b2[:, None, :]
         return jnp.einsum("bsnc,bncd->bsd", combine, expert_out)
+
+    def _serve(self, x, probs, w1, w3, b1, w2, b2):
+        """Eval and serving: dropless, token-major. The k choices and
+        their gates are the training path's (k largest probabilities,
+        ties to the lower expert; renormalised over the kept ones, which
+        is a softmax over the selected logits); what differs is that no
+        expert has a capacity."""
+        b, s, d = x.shape
+        n, k = self.num_experts, self.top_k
+        with jax.named_scope("moe_router"):
+            gates, index = jax.lax.top_k(probs.reshape(b * s, n), k)
+            if k > 1 and self.normalize_gates:
+                gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+            if s > 1 and self.has_variable("cache", EXPERT_LOAD_KEY):
+                # Serving statistics (module docstring of the keys):
+                # pairs of the chunk's real tokens only. The tick's rows
+                # are not counted: a parked slot's junk row would be.
+                load = self.variable("cache", EXPERT_LOAD_KEY, lambda: None)
+                valid = self.variable("cache", VALID_LEN_KEY,
+                                      lambda: None).value
+                real = (jnp.arange(b * s) % s < valid)[:, None, None]
+                load.value = load.value + jnp.sum(
+                    real & (index[:, :, None] == jnp.arange(n)),
+                    axis=(0, 1), dtype=load.value.dtype)
+        self.sow("intermediates", "expert_index", index.reshape(b, s, k))
+        # What the training path sows, for validation logs: the Switch
+        # loss over first choices, and a drop rate that is 0 by
+        # construction.
+        frac = jnp.mean(nn.one_hot(index[:, 0], n), axis=0)
+        self.sow("losses", "moe_aux_loss", self.aux_loss_weight * n
+                 * jnp.sum(frac * jnp.mean(probs, axis=(0, 1))))
+        self.sow("metrics", "moe_drop_rate", jnp.zeros((), jnp.float32))
+        gated = w3 is not None
+        y = grouped_expert_ffn(
+            x.reshape(b * s, d).astype(self.dtype), index, gates,
+            w3 if gated else w1, w2, act=self.expert_act,
+            w_gate=w1 if gated else None, b_in=b1, b_out=b2)
+        return y.reshape(b, s, d)

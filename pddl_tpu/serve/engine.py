@@ -199,6 +199,9 @@ from pddl_tpu.serve.tenant import (
 )
 
 
+_WIDE_PROGRAM_BELOW_CHUNK = 1024  # see ServeEngine._wide_program_pays
+
+
 class _SlotStateLost(RuntimeError):
     """Internal escalation: a device call outlasted its retry budget
     (or failed in a way that may have consumed a donated buffer), so
@@ -466,11 +469,16 @@ class ServeEngine:
                  tracer=None, telemetry_capacity: int = 512):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        if getattr(model, "uses_ring_cache", False):
+        if getattr(model, "uses_ring_cache", False) and not paged:
+            # A paged engine gives every layer the full-length paged
+            # cache (window layers masked and block-skipped to their
+            # band); only the row cache would allocate the ring.
             raise NotImplementedError(
-                "the serving engine needs full-length KV caches; "
-                f"sliding_window={model.sliding_window} allocates a "
-                "rolling ring cache whose slot reuse is not supported yet")
+                "the row-cache serving engine needs full-length KV "
+                f"caches; sliding_window={model.sliding_window} allocates "
+                "a rolling ring cache whose slot reuse is not supported "
+                "yet (pass paged=True: window layers then live in the "
+                "block pool)")
         self.model = model
         self.max_slots = int(max_slots)
         self.prefill_len = int(prefill_len if prefill_len is not None
@@ -1140,10 +1148,7 @@ class ServeEngine:
             self._gather_p = None
             self._chunk_p = jax.jit(_chunk_paged_t if ten else _chunk_paged,
                                     donate_argnums=(1,))
-            self._has_wide = (
-                self._chunk < self.prefill_len
-                and self.prefill_len + self.prefill_len // 4
-                <= model.max_len)
+            self._has_wide = self._wide_program_pays(model.max_len)
             self._chunk_wide_p = (jax.jit(_chunk_paged_wide_t if ten
                                           else _chunk_paged_wide,
                                           donate_argnums=(1,))
@@ -1212,10 +1217,7 @@ class ServeEngine:
             # program can start as deep as prefill_len/4 (the width
             # policy's threshold), so it also needs its positions to
             # stay in range at that offset.
-            self._has_wide = (
-                self._chunk < self.prefill_len
-                and self.prefill_len + self.prefill_len // 4
-                <= model.max_len)
+            self._has_wide = self._wide_program_pays(model.max_len)
             self._chunk_wide_p = (jax.jit(_chunk_wide_t if ten
                                           else _chunk_prefill_wide,
                                           donate_argnums=(1,))
@@ -1250,6 +1252,22 @@ class ServeEngine:
         self._warm = False
         if tracer is not None:
             self.set_tracer(tracer)
+
+    def _wide_program_pays(self, max_len: int) -> bool:
+        """Whether to build the second, ``prefill_len``-wide chunk
+        program. It exists to spare a cold prompt the fixed cost of
+        ``ceil(plen / chunk)`` applies; a chunk of
+        ``_WIDE_PROGRAM_BELOW_CHUNK`` tokens or more amortises that cost
+        by itself (a dispatch is about a millisecond, such a chunk tens
+        of them), while a wide program's temporaries and its padding
+        grow with its width: at ``prefill_len`` 12,288 it held 2.7 GB
+        of them, more than the chip had left (PERF.md, PR 30). The
+        other two conditions are the old ones: something narrower to
+        be wider than, and positions that stay in range from the
+        deepest offset the width policy starts it at."""
+        return (self._chunk < self.prefill_len
+                and self._chunk < _WIDE_PROGRAM_BELOW_CHUNK
+                and self.prefill_len + self.prefill_len // 4 <= max_len)
 
     def _init_host_tier(self, host_tier) -> None:
         """Arm the host-RAM spill tier (the ``host_tier`` arg docs):
@@ -1465,16 +1483,11 @@ class ServeEngine:
             # All-scratch tables: every warmup write lands in the junk
             # sink, the radix index stays empty, and every program
             # traces once with its serving shapes.
-            t1 = np.zeros((1, self._table_width), np.int32)
             self._cache, logits = self._chunk_p(
-                self._params, self._cache,
-                np.zeros((1, self._chunk), np.int32), np.int32(1),
-                np.int32(0), t1, *self._chunk_extra(0))
+                *self._chunk_args_paged(self._chunk))
             if self._has_wide:
                 self._cache, logits = self._chunk_wide_p(
-                    self._params, self._cache,
-                    np.zeros((1, self.prefill_len), np.int32), np.int32(1),
-                    np.int32(0), t1, *self._chunk_extra(0))
+                    *self._chunk_args_paged(self.prefill_len))
             tok, self._rng = self._sample_first_p(
                 logits, *first_mask, np.float32(0.0), np.int32(0),
                 np.float32(2.0), self._rng)
@@ -1544,6 +1557,14 @@ class ServeEngine:
                 self._tokens, self._temps, self._top_ks, self._top_ps,
                 *self._tick_extra(), self._rng)
 
+    def _chunk_args_paged(self, width: int) -> tuple:
+        """A paged chunk program's arguments for one real token at
+        offset 0 through an all-scratch table: what warmup dispatches
+        and :meth:`program_lowerings` lowers."""
+        t1 = np.zeros((1, self._table_width), np.int32)
+        return (self._params, self._cache, np.zeros((1, width), np.int32),
+                np.int32(1), np.int32(0), t1, *self._chunk_extra(0))
+
     def tick_lowering(self):
         """The one-token tick LOWERED at its serving shapes, for
         inspection (``jax.stages.Lowered``): ``.as_text()`` shows
@@ -1555,6 +1576,39 @@ class ServeEngine:
             raise ValueError("a speculative engine has no one-token tick "
                              "(draft/verify replace it)")
         return self._tick_p.lower(*self._tick_args())
+
+    def program_lowerings(self) -> Dict[str, object]:
+        """The tick and (paged engines) the chunk programs LOWERED at
+        their serving shapes, by site name. ``.compile().as_text()``
+        names every instruction with the scope it came from
+        (``metadata={op_name=...}``: the flax module path and the
+        ``jax.named_scope`` names — ``moe_router``, ``moe_dispatch``,
+        ``moe_ffn``, ``moe_combine``, ``attn_window``, ``attn_global``),
+        which is how a device trace's op names, which carry no scope,
+        are put down to a scope."""
+        out = {"tick": self.tick_lowering()}
+        if self._paged:
+            out["chunk_prefill"] = self._chunk_p.lower(
+                *self._chunk_args_paged(self._chunk))
+            if self._has_wide:
+                out["chunk_prefill_wide"] = self._chunk_wide_p.lower(
+                    *self._chunk_args_paged(self.prefill_len))
+        return out
+
+    def expert_load(self) -> Dict[str, np.ndarray]:
+        """Routed pairs of PROMPT tokens per expert, by routed layer
+        (``"block3/moe"`` -> int ``[experts]``), accumulated on the
+        device by the chunk programs since the pool was built; empty
+        for a model without routed layers or a row-cache engine. One
+        device read: call it at a window's edges, not in the loop."""
+        from pddl_tpu.ops.moe import EXPERT_LOAD_KEY
+
+        found = {
+            "/".join(str(getattr(k, "key", k)) for k in path[:-1]): leaf
+            for path, leaf in jax.tree_util.tree_leaves_with_path(
+                self._cache)
+            if str(getattr(path[-1], "key", path[-1])) == EXPERT_LOAD_KEY}
+        return {k: np.asarray(v) for k, v in jax.device_get(found).items()}
 
     def _warm_spec(self):
         """Trace the draft/verify pair (and the draft model's admission
@@ -2598,6 +2652,7 @@ class ServeEngine:
             chunk_toks = np.zeros((1, width), np.int32)
             chunk_toks[0, :w] = prompt[off:off + w]
             logits = dispatch(site, prog, chunk_toks, w, off)
+            self.metrics.record_prefill_chunk(width)
             self._tracer.on_prefill_chunk(handle, site, off, w,
                                           self._last_wall_s)
             off += w
@@ -3129,6 +3184,7 @@ class ServeEngine:
                     "chunk_prefill", self._chunk_p, self._params,
                     self._row, chunk_toks, np.int32(w), np.int32(off),
                     *extra)
+            self.metrics.record_prefill_chunk(self._chunk)
             self._tracer.on_prefill_chunk(handle, "chunk_prefill", off, w,
                                           self._last_wall_s)
             sl["off"] = off + w
@@ -3253,7 +3309,8 @@ class ServeEngine:
             handle.ttft_s = now - handle.arrival_s
             self.metrics.record_first_token(
                 handle.ttft_s, handle.request.priority.value)
-            self.metrics.record_admission(now, now - handle.admit_s)
+            self.metrics.record_admission(now, now - handle.admit_s,
+                                          prompt_tokens=plen)
             self._tracer.on_first_token(handle, handle.ttft_s)
         self._slots[sid] = handle
         self._positions[sid] = plen

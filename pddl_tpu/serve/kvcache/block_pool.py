@@ -130,7 +130,21 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
             out[BLOCK_TABLE_KEY] = jnp.zeros((1, 1), jnp.int32)
         return out
 
-    return _build(row)
+    cache = _build(row)
+    extras = getattr(dec, "paged_cache_extras", None)
+    if extras is not None:
+        # Leaves the model keeps beside its pools in a paged engine
+        # (the Llama family's expert-load counters): merged by module
+        # path, carried and donated with the tree like any other leaf.
+        def _merge(into, extra):
+            for key, val in extra.items():
+                if hasattr(val, "items"):
+                    _merge(into.setdefault(key, {}), val)
+                else:
+                    into[key] = val
+
+        _merge(cache, extras())
+    return cache
 
 
 def pool_nbytes(pool) -> int:

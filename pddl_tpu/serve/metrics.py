@@ -221,6 +221,14 @@ class ServeMetrics:
         self.queue_wait_s = 0.0
         self.admissions = 0
         self.admit_wall_s = 0.0
+        # What prefill cost in tokens: prompt tokens of the FRESH
+        # requests installed (counted at the install, beside
+        # `admissions`), and the chunk programs dispatched, by their
+        # compiled width (counted at the dispatch, replays and sliced
+        # admissions included). sum(width * chunks) / prefill_tokens is
+        # the padding the fixed widths cost.
+        self.prefill_tokens = 0
+        self.prefill_chunks: Dict[str, int] = {}
         # Recent admission timestamps: the QueueFull retry_after_s
         # estimator (a short window so the hint tracks CURRENT service
         # rate, not the all-time average).
@@ -326,15 +334,22 @@ class ServeMetrics:
     def record_degraded_exit(self, seconds: float) -> None:
         self.degraded_time_s += max(0.0, float(seconds))
 
-    def record_admission(self, now_s: float, admit_wall_s: float) -> None:
+    def record_admission(self, now_s: float, admit_wall_s: float,
+                         prompt_tokens: int = 0) -> None:
         """One FRESH request admitted — its slot installed, its first
-        token sampled ``admit_wall_s`` after its scheduler pop (replays
-        excluded — they consume admission work but represent no new
-        queue progress, and the retry_after hint estimates how fast
-        the queue drains)."""
+        token sampled ``admit_wall_s`` after its scheduler pop, its
+        ``prompt_tokens`` prefilled (replays excluded — they consume
+        admission work but represent no new queue progress, and the
+        retry_after hint estimates how fast the queue drains)."""
         self._admission_times.append(float(now_s))
         self.admissions += 1
         self.admit_wall_s += admit_wall_s
+        self.prefill_tokens += int(prompt_tokens)
+
+    def record_prefill_chunk(self, width: int) -> None:
+        """One chunk-prefill program of compiled ``width`` dispatched."""
+        key = str(int(width))
+        self.prefill_chunks[key] = self.prefill_chunks.get(key, 0) + 1
 
     def recent_admission_interval_s(self) -> Optional[float]:
         """Mean gap between recent admissions, or ``None`` before two
@@ -521,6 +536,9 @@ class ServeMetrics:
             "queue_wait_s": self.queue_wait_s,
             "admissions": self.admissions,
             "admit_wall_s": self.admit_wall_s,
+            "prefill_tokens": self.prefill_tokens,
+            # Labeled series, one sample per compiled chunk width.
+            "prefill_chunks": dict(self.prefill_chunks),
             # Per-priority splits: mappings render as labeled series
             # (one sample per class) through `obs/export.py`, so the
             # SLO runbook reads shed/finish/TTFT per class off one

@@ -200,6 +200,76 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(v5e_chip, family,
         assert held - others >= 0.95 * logical
 
 
+def test_smallthinker_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
+    """The benchmark cell `st21b_longdoc_steady`'s own `_tick_paged` and
+    `_chunk_paged` at the published widths (2560, 28 q / 4 kv heads of
+    128, window 4096, 64 ReGLU experts of 768 top-6, vocabulary 151,936,
+    16,384 positions; 8 slots, block 16, 8,193 blocks, 2,048-wide chunks),
+    two layers (one full-attention NoPE, one window+RoPE), weights as
+    shapes. The Mosaic kernel with `window` and 7 q heads a kv head is in
+    the tick; the pool leaves `bf16[8193,4,16,256]` are aliased and never
+    copied; no dispatch tensor anywhere near `[S, N, S]` exists; and no
+    `prefill_len`-wide second program is built beside a 2,048-wide chunk."""
+    from pddl_tpu.models.llama import SmallThinker_21B_A3B
+    from pddl_tpu.serve import ServeEngine
+
+    model = SmallThinker_21B_A3B(depth=2, dtype=jnp.bfloat16,
+                                 param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.ones((1, 8), jnp.int32), train=False))[
+            "params"]
+    eng = ServeEngine(model, {"params": params}, paged=True, max_slots=8,
+                      prefill_len=12288, prefix_block_size=16,
+                      prefix_cache_blocks=8193, prefix_chunk=2048)
+    assert not eng._has_wide
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shapes(*args):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype if hasattr(x, "dtype")
+                else np.asarray(x).dtype, sharding=v5e_chip), args)
+
+    pool_shape = "bf16[8193,4,16,256]"
+    weight_dims = {",".join(map(str, leaf.shape))
+                   for leaf in jax.tree.leaves(params)}
+    weight_dims |= {"2560,151936", "151936,2560"}   # either orientation
+    programs = {
+        "_tick_paged": (eng._tick_p, eng._tick_args()),
+        "_chunk_paged": (eng._chunk_p, eng._chunk_args_paged(eng._chunk)),
+    }
+    for name, (prog, args) in programs.items():
+        compiled = prog.lower(*shapes(*args)).compile()
+        text = compiled.as_text()
+        header = text.split("\n", 1)[0]
+        assert f"jit_{name}" in header
+        assert ("tpu_custom_call" in text) == (name == "_tick_paged")
+        shape = r"\(?" + re.escape(pool_shape)
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
+                              line)]
+        assert not moved, f"{name}: pool-sized copies\n" + "\n".join(moved)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                            header).group(1)
+        entry = re.search(
+            r"entry_computation_layout=\{\((.*)\)->\((.*)\)\}", header)
+        params_in = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", entry.group(1))
+        pool_params = {i for i, p in enumerate(params_in)
+                       if p.startswith(pool_shape)}
+        assert len(pool_params) == 2
+        aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
+        assert pool_params <= aliased, (name, aliases)
+        # The largest array any instruction makes (pool and weight
+        # matrices apart): far under the [tokens, experts, tokens] of a
+        # capacity-S dispatch, 268 M entries at this chunk.
+        tokens = eng._chunk if name == "_chunk_paged" else 8
+        largest = max(
+            int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            for dims in re.findall(r"= \(?\w+\[([\d,]*)\]", text)
+            if "8193" not in dims and dims not in weight_dims)
+        assert largest < 64 * 2048 * 2048 // 8, (name, tokens, largest)
+
+
 @pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])
 def test_flash_forward_and_fused_backward_compile_for_v5e(v5e_chip, heads,
                                                           kv_heads):

@@ -159,6 +159,77 @@ def test_paged_decode_kernel_matches_oracle_on_chip(heads, kv_heads, d,
         atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_paged_window_kernel_at_smallthinker_shapes_on_chip(window):
+    """The tick's kernel as the SmallThinker cell calls it: 28 q heads
+    over 4 kv heads of 128 (7 q heads a kv head), eight slots over a
+    16,384-token context (table width 1,024), with and without the 4,096
+    window (blocks wholly under the band are skipped), against the jnp
+    path — and the jnp path's multi-token sweep, 2,048 rows continuing
+    at 9,000 tokens of history, against the same rows one at a time."""
+    slots, heads, kv_heads, d, bs = 8, 28, 4, 128, 16
+    t = 16384 // bs
+    n = slots * t + 1
+    ks = jax.random.split(jax.random.key(28), 3)
+    q = jax.random.normal(ks[0], (slots, heads, 1, d), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (n, kv_heads, bs, 2 * d), jnp.bfloat16)
+    table = jnp.asarray(np.random.RandomState(1).permutation(
+        np.arange(1, n)).reshape(slots, t), jnp.int32)
+    index = jnp.asarray([0, 15, 4095, 4096, 5000, 9000, 12288, 16383],
+                        jnp.int32)
+    got = jax.jit(lambda *a: paged_decode_attention_kernel(
+        *a, window=window, interpret=False))(q, pool, table, index)
+    want = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window, kernel=False))(q, pool, table, index)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2, rtol=2e-2)
+    rows = jax.random.normal(ks[2], (1, heads, 2048, d), jnp.bfloat16)
+    sweep = jax.jit(lambda q_, i: paged_decode_attention(
+        q_, pool, table[:1], i, window=window, kernel=False))
+    chunk = sweep(rows, jnp.int32(9000))
+    for r in (0, 1000, 2047):
+        one = sweep(rows[:, :, r:r + 1], jnp.int32(9000 + r))
+        np.testing.assert_allclose(
+            np.asarray(chunk[:, :, r:r + 1], np.float32),
+            np.asarray(one, np.float32), atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("tokens", [8, 2048])
+def test_grouped_expert_ffn_matches_dense_oracle_on_chip(tokens):
+    """The serving expert path at the SmallThinker cell's shapes (64
+    ReGLU experts of 2560-768-2560, top-6, bf16) at decode size and at a
+    prefill chunk's, against every token through every expert, masked."""
+    from pddl_tpu.ops.moe import grouped_expert_ffn
+
+    n, k, d, h = 64, 6, 2560, 768
+    ks = jax.random.split(jax.random.key(tokens), 6)
+    x = jax.random.normal(ks[0], (tokens, d), jnp.bfloat16)
+    w1, w3 = (0.02 * jax.random.normal(key, (n, d, h), jnp.bfloat16)
+              for key in ks[1:3])
+    w2 = 0.02 * jax.random.normal(ks[3], (n, h, d), jnp.bfloat16)
+    gates, index = jax.lax.top_k(
+        jax.nn.softmax(jax.random.normal(ks[4], (tokens, n)), -1), k)
+    got = jax.jit(lambda *a: grouped_expert_ffn(
+        a[0], a[1], a[2], a[4], a[5], act="reglu", w_gate=a[3]))(
+            x, index, gates, w1, w3, w2)
+
+    @jax.jit
+    def dense(x, index, gates, w1, w3, w2):
+        def one(y, w):
+            g = jnp.sum(jnp.where(index == w["e"], gates, 0.0), -1)
+            hid = jax.nn.relu(x @ w["w1"]) * (x @ w["w3"])
+            return y + g[:, None] * (hid @ w["w2"]).astype(jnp.float32), 0
+        y, _ = jax.lax.scan(one, jnp.zeros((tokens, d), jnp.float32),
+                            {"w1": w1, "w3": w3, "w2": w2,
+                             "e": jnp.arange(n)})
+        return y
+
+    want = dense(x, index, gates, w1, w3, w2)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want), atol=2e-2, rtol=5e-2)
+
+
 def test_paged_tick_write_in_place_on_chip():
     """The tick's block-granular token write, compiled with the pool
     donated: every slot's K/V row lands at (table[pos // bs], pos % bs)
