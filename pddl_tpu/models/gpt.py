@@ -249,6 +249,42 @@ def sample_logits(rng, logits, *, temperature: float = 1.0,
                              top_k=top_k, top_p=top_p), axis=-1)
 
 
+def _sort_descending(logits):
+    """One stable descending sort over the last axis that carries its
+    values: ``(sorted values, their vocabulary ids)``. Equal values keep
+    ascending-id order, exactly as ``jnp.argsort(-logits)`` orders them,
+    and no gather is needed to fetch the sorted values afterwards."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    neg, ids = jax.lax.sort((-logits, ids), dimension=logits.ndim - 1,
+                            is_stable=True, num_keys=1)
+    return -neg, ids
+
+
+def _nucleus_keep(logits, sorted_logits, sorted_ids, top_p):
+    """The nucleus (top-p) rule both filter pipelines share: a boolean
+    mask in VOCABULARY order of the smallest set of ``logits`` whose
+    softmax mass reaches ``top_p``.
+
+    ``sorted_logits`` / ``sorted_ids`` are ``logits`` in stable
+    descending order (:func:`_sort_descending`); ``top_p`` broadcasts
+    against ``[..., 1]``. An entry is kept while the CDF *before* it is
+    ``< top_p`` (the first always is), and the CDF is monotone, so the
+    kept entries are a prefix of the sorted row and its length names
+    them. A bare value threshold would keep EVERY token tied with the
+    boundary logit and exceed the nucleus; the stable sort put ties in
+    ascending-id order, so the prefix ends at one (value, id) pair and,
+    back in vocabulary order, holds exactly what is larger than that
+    value, or equal to it with an id no higher: an elementwise test, no
+    inverse permutation and no gather over the vocabulary."""
+    cdf = jnp.cumsum(jax.nn.softmax(sorted_logits, axis=-1), axis=-1)
+    last = jnp.sum(cdf[..., :-1] < top_p, axis=-1, keepdims=True,
+                   dtype=jnp.int32)              # n_keep - 1
+    v_last = jnp.take_along_axis(sorted_logits, last, axis=-1)
+    id_last = jnp.take_along_axis(sorted_ids, last, axis=-1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return (logits > v_last) | ((logits == v_last) & (ids <= id_last))
+
+
 def filtered_logits(logits, *, temperature: float,
                     top_k: Optional[int] = None,
                     top_p: Optional[float] = None):
@@ -262,21 +298,7 @@ def filtered_logits(logits, *, temperature: float,
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -jnp.inf, logits)
     if top_p is not None:
-        sort_idx = jnp.argsort(-logits, axis=-1)  # stable descending
-        sorted_logits = jnp.take_along_axis(logits, sort_idx, axis=-1)
-        cdf = jnp.cumsum(jax.nn.softmax(sorted_logits, axis=-1), axis=-1)
-        # Smallest set whose mass >= top_p: keep entries whose CDF
-        # *before* them is < top_p (the first token is always kept).
-        keep_sorted = jnp.concatenate(
-            [jnp.zeros_like(cdf[..., :1]), cdf[..., :-1]], axis=-1
-        ) < top_p
-        # Scatter the keep mask back to vocab order through the inverse
-        # permutation. A value threshold would instead keep EVERY token
-        # tied with the boundary logit, exceeding the nucleus; the stable
-        # descending argsort resolves boundary ties toward lower vocab
-        # ids, so the kept set is exactly the smallest one reaching top_p.
-        inv_idx = jnp.argsort(sort_idx, axis=-1)
-        keep = jnp.take_along_axis(keep_sorted, inv_idx, axis=-1)
+        keep = _nucleus_keep(logits, *_sort_descending(logits), top_p)
         logits = jnp.where(keep, logits, -jnp.inf)
     return logits
 
@@ -296,10 +318,11 @@ def batched_filtered_logits(logits, *, temperature, top_k, top_p):
 
     Row-by-row this matches ``filtered_logits`` exactly for enabled
     filters: the top-k threshold is the k-th sorted value (ties at the
-    boundary kept, like ``lax.top_k``'s), and the nucleus keep-set comes
-    from the same stable-descending CDF rule. ``top_k`` trades
-    ``lax.top_k`` (static k) for one full sort shared with the nucleus
-    pass — the price of k as data.
+    boundary kept, like ``lax.top_k``'s), and the nucleus keep-set is
+    the same rule (:func:`_nucleus_keep`). With k as data, the top-k
+    threshold comes from ONE full sort that carries its values and that
+    the nucleus pass shares; every other step is elementwise or a
+    reduction over the row, so nothing gathers over ``[B, V]``.
     """
     logits = logits.astype(jnp.float32)
     b, v = logits.shape
@@ -307,25 +330,18 @@ def batched_filtered_logits(logits, *, temperature, top_k, top_p):
     kk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (b,))
     pp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (b,))
     warped = logits / jnp.where(t > 0, t, 1.0)[:, None]
-    sort_idx = jnp.argsort(-warped, axis=-1)  # stable descending
-    sorted_l = jnp.take_along_axis(warped, sort_idx, axis=-1)
+    sorted_l, sorted_ids = _sort_descending(warped)
     # Top-k: per-row k-th sorted value as the threshold (same
-    # keep-boundary-ties rule as lax.top_k in filtered_logits).
+    # keep-boundary-ties rule as lax.top_k in filtered_logits). The
+    # masked entries are exactly the tail of the descending order, so
+    # the same comparison masks the sorted row and the one sort stays
+    # valid after masking — no re-sort.
     kth = jnp.take_along_axis(
         sorted_l, (jnp.clip(kk, 1, v) - 1)[:, None], axis=-1)
-    keep_topk = (kk[:, None] <= 0) | (warped >= kth)
-    warped = jnp.where(keep_topk, warped, -jnp.inf)
-    # Nucleus over the top-k-masked values. Masked entries are exactly
-    # the tail of the descending order (values below the threshold), so
-    # the one sort stays valid after masking — no re-sort.
-    sorted_m = jnp.where(
-        jnp.take_along_axis(keep_topk, sort_idx, axis=-1),
-        sorted_l, -jnp.inf)
-    cdf = jnp.cumsum(jax.nn.softmax(sorted_m, axis=-1), axis=-1)
-    keep_sorted = jnp.concatenate(
-        [jnp.zeros_like(cdf[:, :1]), cdf[:, :-1]], axis=-1) < pp[:, None]
-    inv_idx = jnp.argsort(sort_idx, axis=-1)
-    keep = (jnp.take_along_axis(keep_sorted, inv_idx, axis=-1)
+    no_topk = kk[:, None] <= 0
+    warped = jnp.where(no_topk | (warped >= kth), warped, -jnp.inf)
+    sorted_l = jnp.where(no_topk | (sorted_l >= kth), sorted_l, -jnp.inf)
+    keep = (_nucleus_keep(warped, sorted_l, sorted_ids, pp[:, None])
             | (pp[:, None] >= 1.0))
     return jnp.where(keep, warped, -jnp.inf)
 

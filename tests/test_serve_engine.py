@@ -28,6 +28,7 @@ from pddl_tpu.models.gpt import (
     batched_filtered_logits,
     filtered_logits,
     generate,
+    sample_logits_batched,
     tiny_gpt,
 )
 from pddl_tpu.models.llama import tiny_llama
@@ -109,24 +110,154 @@ def test_per_slot_sampling_isolation(gpt_setup):
     assert all(0 <= t < 32 for t in hc.tokens) and len(hc.tokens) == 6
 
 
-def test_batched_filter_matches_static_per_row():
-    """The per-slot sampler's filter pipeline must equal the compiled
-    single-request one row by row (same top-k tie rule, same nucleus
-    CDF rule) — the engine's sampling is generate()'s, just batched."""
-    logits = jax.random.normal(jax.random.key(3), (4, 33)) * 3.0
-    cfgs = [(1.0, 5, 0.9), (0.7, 0, 2.0), (2.0, 1, 2.0), (0.5, 0, 0.3)]
-    t = jnp.array([c[0] for c in cfgs])
-    k = jnp.array([c[1] for c in cfgs], jnp.int32)
-    p = jnp.array([c[2] for c in cfgs])
-    batched = batched_filtered_logits(logits, temperature=t, top_k=k,
-                                      top_p=p)
-    for i, (ti, ki, pi) in enumerate(cfgs):
-        ref = filtered_logits(logits[i:i + 1], temperature=ti,
-                              top_k=ki or None,
-                              top_p=pi if pi <= 1.0 else None)
-        np.testing.assert_allclose(np.asarray(batched[i:i + 1]),
-                                   np.asarray(ref), rtol=1e-6,
-                                   err_msg=f"row {i} cfg {cfgs[i]}")
+def _oracle_filtered_row(row, t, k, p):
+    """The filter rule in plain NumPy, one row: warp, top-k by the k-th
+    sorted value (boundary ties kept), then the smallest set of the
+    stable descending order whose mass reaches ``p`` (ties to the lower
+    id). Returns the float32 filtered row and how far the CDF stays from
+    ``p`` (a case whose CDF grazes ``p`` would test rounding, not the
+    rule)."""
+    x = row.astype(np.float32) / np.float32(t if t > 0 else 1.0)
+    v = x.size
+    order = np.argsort(-x, kind="stable")
+    keep = np.ones(v, bool)
+    if k > 0:
+        keep &= x >= x[order[min(k, v) - 1]]
+    margin = np.inf
+    if p < 1.0:
+        xs = np.where(keep[order], x[order], -np.inf).astype(np.float64)
+        probs = np.exp(xs - xs.max())
+        cdf = np.cumsum(probs / probs.sum())
+        margin = np.abs(cdf - p).min()
+        n_keep = 1 + int(np.sum(cdf[:-1] < p))
+        nucleus = np.zeros(v, bool)
+        nucleus[order[:n_keep]] = True
+        keep &= nucleus
+    return np.where(keep, x, -np.inf).astype(np.float32), margin
+
+
+def _filter_case(name):
+    """``(logits [B, V], temperature, top_k, top_p, dtype)`` of one named
+    case; sentinels as the engine passes them (0 = no top-k, 2.0 = no
+    nucleus)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    f32 = jnp.float32
+
+    def normal(b, v, scale=3.0):
+        return (rng.normal(size=(b, v)) * scale).astype(np.float32)
+
+    if name == "random_mixed":
+        return (normal(4, 33), [1.0, 0.7, 2.0, 0.5], [5, 0, 1, 0],
+                [0.9, 2.0, 2.0, 0.3], f32)
+    if name == "ties_at_top_k_boundary":
+        # Integer logits: the k-th value is shared by several tokens.
+        return (np.round(normal(4, 96, 1.5)), [1.0, 0.7, 1.3, 1.0],
+                [3, 10, 25, 60], [2.0, 2.0, 2.0, 2.0], f32)
+    if name == "ties_at_nucleus_boundary":
+        return (np.round(normal(4, 96, 1.5)), [1.0, 0.7, 1.3, 1.0],
+                [0, 0, 0, 0], [0.9, 0.5, 0.7, 0.97], f32)
+    if name == "ties_at_both_boundaries":
+        return (np.round(normal(4, 200, 1.2)), [1.0, 0.8, 1.0, 1.5],
+                [40, 25, 90, 12], [0.9, 0.8, 0.95, 0.6], f32)
+    if name == "whole_row_tied":
+        return (np.full((4, 33), 1.5, np.float32), [1.0, 0.7, 1.0, 2.0],
+                [0, 5, 0, 40], [0.9, 2.0, 0.3, 0.7], f32)
+    if name == "top_k_0_1_and_above_v":
+        return (normal(4, 50), [1.0, 1.0, 0.7, 1.0], [0, 1, 51, 5000],
+                [2.0, 2.0, 0.9, 2.0], f32)
+    if name == "top_p_1_0p9_and_1e-6":
+        x = np.round(normal(4, 50), 1)
+        x[3, [7, 11]] = x[3].max() + 1.0      # 1e-6 keeps ONE of a tie
+        return (x, [1.0, 1.0, 0.5, 1.0], [0, 0, 0, 0],
+                [1.0, 0.9, 1e-6, 1e-6], f32)
+    if name == "temperature_zero_rows":
+        return (normal(4, 64), [0.0, 0.7, 0.0, -1.0], [0, 4, 3, 0],
+                [2.0, 0.9, 0.8, 0.5], f32)
+    if name == "rows_holding_neg_inf":
+        # Grammar masks: most of the vocabulary disallowed; row 3 keeps
+        # fewer legal tokens than its top_k.
+        x = np.round(normal(4, 130), 1)
+        x[0, rng.random(130) < 0.8] = -np.inf
+        x[1, 5:] = -np.inf
+        x[2, ::2] = -np.inf
+        x[3, 3:] = -np.inf
+        return (x, [1.0, 0.7, 1.0, 1.0], [0, 3, 20, 8],
+                [0.9, 0.9, 2.0, 0.95], f32)
+    if name == "vocab_not_a_multiple_of_128":
+        return (np.round(normal(3, 1001), 1), [1.0, 0.7, 1.2],
+                [0, 300, 129], [0.9, 0.95, 0.5], f32)
+    if name == "bf16_input":
+        # bf16 rounding makes ties of its own among 257 normal draws.
+        return (normal(4, 257, 2.0), [1.0, 0.7, 0.0, 1.1], [0, 20, 0, 129],
+                [0.9, 0.8, 2.0, 0.6], jnp.bfloat16)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "random_mixed", "ties_at_top_k_boundary", "ties_at_nucleus_boundary",
+    "ties_at_both_boundaries", "whole_row_tied", "top_k_0_1_and_above_v",
+    "top_p_1_0p9_and_1e-6", "temperature_zero_rows", "rows_holding_neg_inf",
+    "vocab_not_a_multiple_of_128", "bf16_input"])
+def test_batched_filter_matches_static_per_row(case):
+    """The per-slot sampler's filter pipeline against an independent
+    NumPy oracle of the rule (sort, CDF, smallest set, ties to the lower
+    id), and against the compiled single-request pipeline row by row —
+    the engine's sampling is generate()'s, just batched. The kept set is
+    exact; the kept values are the warped logits to float32 rounding."""
+    x, t, k, p, dtype = _filter_case(case)
+    logits = jnp.asarray(x).astype(dtype)
+    x = np.asarray(logits.astype(jnp.float32))   # what the filter sees
+    params = dict(temperature=jnp.array(t, jnp.float32),
+                  top_k=jnp.array(k, jnp.int32),
+                  top_p=jnp.array(p, jnp.float32))
+    batched = np.asarray(jax.jit(batched_filtered_logits)(logits, **params))
+    assert batched.dtype == np.float32 and batched.shape == x.shape
+    for i, (ti, ki, pi) in enumerate(zip(t, k, p)):
+        want, margin = _oracle_filtered_row(x[i], ti, ki, pi)
+        assert margin > 1e-5, f"{case} row {i}: the CDF grazes top_p"
+        msg = f"{case} row {i} (t={ti}, k={ki}, p={pi})"
+        np.testing.assert_array_equal(np.isfinite(batched[i]),
+                                      np.isfinite(want), err_msg=msg)
+        np.testing.assert_allclose(batched[i], want, rtol=1e-6, err_msg=msg)
+        if ti > 0:
+            ref = filtered_logits(logits[i:i + 1], temperature=ti,
+                                  top_k=min(ki, x.shape[1]) or None,
+                                  top_p=pi if pi <= 1.0 else None)
+            np.testing.assert_array_equal(batched[i:i + 1], np.asarray(ref),
+                                          err_msg=msg)
+    # Greedy rows take the argmax of the RAW logits, whatever the filter
+    # made of their row.
+    drawn = np.asarray(sample_logits_batched(jax.random.key(0), logits,
+                                             **params))
+    for i, ti in enumerate(t):
+        if ti <= 0:
+            assert drawn[i] == int(np.argmax(x[i]))
+        else:
+            assert np.isfinite(batched[i, drawn[i]])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_seeded_draw_returns_the_parents_tokens(dtype):
+    """``sample_logits_batched`` draws the SAME tokens for one fixed key
+    as it did before the filter lost its gathers (PR 31): the expected
+    values were recorded from the parent commit's sampler on this batch
+    — ties, a tied row, a grammar-masked row, greedy rows, every filter
+    on and off — so "same draw" is held, not only "same distribution"."""
+    rng = np.random.default_rng(31)
+    x = np.round(rng.normal(size=(12, 257)) * 2.0, 1).astype(np.float32)
+    x[3] = 0.5
+    x[4, rng.random(257) < 0.8] = -np.inf
+    t = np.array([0.7, 1.0, 0.0, 1.0, 0.9, 2.0, 0.7, 0.0, 1.3, 1.0, 0.5,
+                  1.0], np.float32)
+    k = np.array([0, 5, 0, 40, 0, 1, 300, 0, 50, 2, 0, 7], np.int32)
+    p = np.array([0.9, 2.0, 2.0, 0.5, 0.9, 2.0, 0.3, 2.0, 0.95, 1.0, 1e-6,
+                  0.99], np.float32)
+    got = sample_logits_batched(jax.random.key(31),
+                                jnp.asarray(x).astype(dtype),
+                                temperature=t, top_k=k, top_p=p)
+    assert got.dtype == jnp.int32
+    assert np.asarray(got).tolist() == [
+        127, 99, 50, 65, 245, 21, 212, 89, 18, 195, 0, 191]
 
 
 def test_cancellation_mid_decode_frees_the_slot(gpt_setup):
