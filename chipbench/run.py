@@ -170,8 +170,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     trace_ctx = None
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        trace_ctx = {"dir": TRACE_DIR, "start_s": 0.3 * seconds,
-                     "length_s": min(12.0, 0.25 * seconds)}
+        # The window's last seconds: the profiler takes some fifteen
+        # seconds to collect each second it traced, and while it collects
+        # the host is not the cell's; so it collects after the close.
+        length = min(6.0, 0.25 * seconds)
+        trace_ctx = {"dir": TRACE_DIR, "start_s": seconds - length,
+                     "length_s": length}
     ctx = {"cfg": cfg, "traffic": spec, "seed": int(seed),
            "seconds": float(seconds), "trace": trace_ctx, "log": log,
            "mark_setup_done": mark_setup_done, "devices": devices,

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import traffic as traffic_lib
+from chipbench.hostprobe import HostProbe, Pin, python_speed_ms
 from chipbench.reference import gpt2 as reference
 from chipbench.trace_reduce import STEP_SPAN
 from chipbench.weights import make_gpt_weights, seed_key
@@ -92,6 +93,7 @@ class Recorder:
         self.requests = []      # dicts, one per planned request submitted
         self.steps = []         # dicts, one per engine.step()
         self._open = []         # (record, handle) still streaming
+        self.probe = HostProbe()  # what the host did in a stalled step
 
     def submit(self, req, now):
         rec = {"index": req.index, "due_s": req.due_s, "submit_s": now,
@@ -116,8 +118,10 @@ class Recorder:
             self.engine.step()
         b = time.perf_counter() - t0
         tel = self.engine.telemetry.last() or {}
+        self.probe.step(len(self.steps), a, b, tel)
         step = {"t0": a, "t1": b,
                 "sites": dict(tel.get("site_wall_s") or {}),
+                "phases": tel.get("phase_wall_s") or {},
                 "live": int(tel.get("live_slots", 0)),
                 "queue": int(tel.get("queue_depth", 0)),
                 "fill": float(self.engine.metrics.block_table_fill),
@@ -186,6 +190,16 @@ def warm_requests(engine, cfg, log):
 def run_window(engine, cfg, spec, plan, seconds, trace, log,
                mark_setup_done, ramp=()):
     """The measured window. Returns (recorder, window facts)."""
+    pin = Pin()
+    try:
+        return _run_window(engine, cfg, spec, plan, seconds, trace, log,
+                           mark_setup_done, ramp, pin)
+    finally:
+        pin.release()
+
+
+def _run_window(engine, cfg, spec, plan, seconds, trace, log,
+                mark_setup_done, ramp, pin):
     rec = Recorder(engine)
     n = len(plan)
     backlog = spec["kind"] == "backlog"
@@ -229,19 +243,27 @@ def run_window(engine, cfg, spec, plan, seconds, trace, log,
     # whole, so that a later per-layer reader finds what it needs in
     # ``obs`` without this module changing.
     facts["counters_open"] = engine.metrics.snapshot()
+    # The loop's thread gets a core of its own for the window and the
+    # drain (hostprobe.py); how fast that core runs Python is read here and
+    # at the close, for the log.
+    facts["host"] = {"cpu": pin.hold(), "speed_open_ms": python_speed_ms()}
     gc.collect()
     gc.freeze()
     mark_setup_done()
     t0 = time.perf_counter()
+    rec.probe.open(t0)
+
+    def collect_trace():
+        pin.free_this_thread()
+        jax.profiler.stop_trace()
 
     def stop_trace():
-        # Off the loop's thread: collecting and writing a 12 s trace takes
-        # about ten seconds, nearly all of it with the interpreter lock
-        # released; on this thread it stalled every request in flight.
+        # Off the loop's thread and off its core: collecting and writing
+        # the trace takes minutes, nearly all of it with the interpreter
+        # lock released; on this thread it stalled every request in flight.
         facts["trace"].update(t1=time.perf_counter() - t0,
                               step1=len(rec.steps))
-        facts["trace_writer"] = threading.Thread(
-            target=jax.profiler.stop_trace)
+        facts["trace_writer"] = threading.Thread(target=collect_trace)
         facts["trace_writer"].start()
 
     while True:
@@ -272,6 +294,7 @@ def run_window(engine, cfg, spec, plan, seconds, trace, log,
         if engine.has_work:
             rec.step(t0)
         else:
+            rec.probe.idle()
             nxt = plan[i].due_s if i < n else seconds
             time.sleep(max(0.0, min(nxt - now, 0.002)))
     facts["window_s"] = time.perf_counter() - t0
@@ -297,8 +320,31 @@ def run_window(engine, cfg, spec, plan, seconds, trace, log,
                 break
     facts["drain_s"] = time.perf_counter() - t0 - facts["window_s"]
     facts["counters_close"] = engine.metrics.snapshot()
+    rec.probe.close()
+    facts["host"]["speed_close_ms"] = python_speed_ms()
     gc.unfreeze()
+    facts["stalls"] = rec.probe.summary(rec.steps[:facts["steps_in_window"]])
+    _log_stalls(facts["stalls"], facts["host"], len(rec.steps), log)
     return rec, facts
+
+
+def _log_stalls(found, host, steps, log):
+    """Unjudged: the core the loop's thread held and its speed, the loop's
+    CPU a step, the window's steps of half a second or more, the
+    collector's work inside the window, and for every stalled step or
+    stretch (the drain's too) what the host was doing in it."""
+    log(f"host: the loop's thread on core {host['cpu']}, fixed Python work "
+        f"in {host['speed_open_ms']:.3f} ms at the open and "
+        f"{host['speed_close_ms']:.3f} at the close; the loop's CPU "
+        f"{1e3 * found['thread_cpu_s'] / max(1, steps):.2f} ms a step, "
+        f"the process's {1e3 * found['process_cpu_s'] / max(1, steps):.2f}")
+    log(f"stalls: {found['long_steps']} steps of {found['stall_s']}s or "
+        f"more in the window, {found['long_steps_s']:.3f}s in them; "
+        f"collections by generation {found['gc']}")
+    for s in found["stalls"]:
+        log("stall: " + ", ".join(
+            f"{k} {round(v, 4) if isinstance(v, float) else v}"
+            for k, v in s.items()))
 
 
 def check_sample(requests, seed, check):

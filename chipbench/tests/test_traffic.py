@@ -90,3 +90,49 @@ def test_another_kind_is_a_generator_module_of_its_own(tmp_path,
 
     monkeypatch.setitem(sys.modules, "chipbench.generators.echo", echo)
     assert traffic.generate({"kind": "echo"}, 4, 1.0, 8, 8, 8) == ["echo", 4]
+
+
+# What chat_poisson.json held before PR 33 re-placed its rate: the mix is
+# the same, only the rate (and so the count of requests) moved.
+OLD_CHAT_MIX = {
+    "prompt_len": {"median": 192, "sigma": 0.8, "min": 32, "max": 768},
+    "output_len": {"median": 64, "sigma": 0.6, "min": 16, "max": 192},
+    "strata": 8, "order_seed": 0,
+    "sampling_mix": [{"share": 0.5, "temperature": 0.0},
+                     {"share": 0.5, "temperature": 0.7, "top_p": 0.9}],
+    "slo": {"ttft_ms": 2000, "tpot_ms": 600},
+}
+OLD_CHAT_REQUESTS = 62   # 1.24 requests/s x 50 s
+
+
+def test_chat_poisson_re_placed_keeps_its_mix():
+    spec = traffic.load_traffic("chat_poisson")
+    # four fifths of the knee, to the file's one decimal
+    assert abs(spec["rate_per_s"] - 0.8 * spec["knee_per_s"]) <= 0.05 + 1e-9
+    assert 1 <= spec["ramp_live"] <= 46
+    for key, old in OLD_CHAT_MIX.items():
+        assert spec[key] == old, key
+    n = round(spec["rate_per_s"] * 50.0)
+    assert n > 4 * OLD_CHAT_REQUESTS
+    plans = [traffic.generate(spec, seed, 50.0, 50257, 768, 1024)
+             for seed in (3, 2 ** 31 + 77)]
+    assert len(plans[0]) == len(plans[1]) == n
+    # the same schedule whatever the seed: lengths and due instants
+    for a, b in zip(*plans):
+        assert (a.prompt.size, a.max_new_tokens, a.due_s) == \
+            (b.prompt.size, b.max_new_tokens, b.due_s)
+    # the lengths are the same distributions' quantiles, cut finer: each
+    # quantile the old 62 requests held is met again within a rank's step
+    for which, pick in (("prompt_len", lambda r: r.prompt.size),
+                        ("output_len", lambda r: r.max_new_tokens)):
+        new = sorted(pick(r) for r in plans[0])
+        assert new == sorted(traffic.lognormal_quantiles(spec[which], n))
+        old = traffic.lognormal_quantiles(OLD_CHAT_MIX[which],
+                                          OLD_CHAT_REQUESTS)
+        for i, v in enumerate(old):
+            lo = new[int(n * i / OLD_CHAT_REQUESTS)]
+            hi = new[min(n - 1, -(-n * (i + 1) // OLD_CHAT_REQUESTS))]
+            assert lo <= v <= hi, (which, i)
+        assert abs(np.mean(new) / np.mean(old) - 1) < 0.01, which
+    # the answers are dealt as before: exactly half greedy
+    assert sum(r.greedy for r in plans[0]) == n // 2

@@ -79,6 +79,11 @@ def main(argv=None) -> int:
             "tpot_p50_ms": 1e3 * L.pct(tpot, 50),
             "tpot_p90_ms": 1e3 * L.pct(tpot, 90),
             "drain_s": obs["facts"]["drain_s"],
+            "gen_late_p99_ms": run.metric_reader("gen_late_p99_ms")(obs),
+            "step_ms_p50": 1e3 * L.pct(
+                [s["t1"] - s["t0"] for s in steps], 50),
+            "long_steps": obs["facts"]["stalls"]["long_steps"],
+            "long_steps_s": obs["facts"]["stalls"]["long_steps_s"],
         }
         print(json.dumps(line), flush=True)
         with open(path, "a") as f:
