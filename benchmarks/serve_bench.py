@@ -29,12 +29,6 @@ the ONLINE layer (`pddl_tpu/serve/`) the way a serving owner would:
    fault-tolerant engine degrades gracefully (ratio near 1, every
    request terminal), a fail-stop one cliffs to zero. Retries, replays,
    degraded entries, and failed-request counts land in the artifact.
-5. **Paged-attention leg** (`--paged-only`, standalone
-   r13 artifact) — paged vs resident-row engines, PAIRED: live-stream
-   KV bytes at matched total allocation (the duplicate-KV-elimination
-   ratio) and prefix-hit admission TTFT head-to-head with the
-   per-admission gather+insert copy wall (`admission_copy_us`) shown
-   going to zero.
 6. **Observability leg** (`--obs-only` for a standalone artifact) —
    the tracing tax (`pddl_tpu/obs/`): the same closed-loop workload
    with per-request tracing OFF (the default no-op tracer) vs ON
@@ -211,13 +205,10 @@ def _sequential_baseline(model, variables, prompts, new_tokens: int,
 def _engine_concurrent(model, variables, prompts, new_tokens: int,
                        slots: int, prefill_len: int, repeats: int = 3):
     """All requests submitted up front (closed-loop, max concurrency).
-    The legacy head-to-head leg runs with the prefix cache OFF so the
-    continuous-batching ratio stays comparable across rounds (prompts
-    here are random — nothing to share anyway)."""
+    Prompts here are random — nothing for the radix index to share."""
     eng = ServeEngine(model, variables, max_slots=slots,
                       prefill_len=prefill_len,
-                      max_queue_depth=len(prompts) + 1,
-                      prefix_cache_blocks=0)
+                      max_queue_depth=len(prompts) + 1)
     eng.warmup()
     samples = []
     for _ in range(repeats):
@@ -237,8 +228,10 @@ def _prefix_ttft_leg(model, variables, *, n_requests: int,
                      prompt_len: int, shared_frac: float, new_tokens: int,
                      slots: int, prefill_len: int, block_size: int,
                      chunk: int, vocab: int, repeats: int, seed: int = 3):
-    """The prefix-cache lever: identical shared-prefix workload through
-    the engine with the radix cache ON vs OFF; returns the artifact
+    """The prefix-cache lever: a shared-prefix workload (radix hits,
+    "on") vs the same lengths with nothing shared (every admission
+    cold, "off") through identical engines — the pool is the KV cache,
+    so there is no engine without it; returns the artifact
     fragment (median mean-TTFT ratio over ``repeats``, hit telemetry,
     compile counts).
 
@@ -255,22 +248,18 @@ def _prefix_ttft_leg(model, variables, *, n_requests: int,
         shared,
         rng.integers(0, vocab, size=prompt_len - shared_len)
         .astype(np.int32)]) for _ in range(n_requests)]
-    # Pool sized for the workload (one shared chain + each request's
-    # unique suffix blocks, with slack) instead of the engine's generic
-    # auto-sizing — the leg measures reuse, not eviction.
-    pool_blocks = (2 + prompt_len // block_size
-                   + n_requests * ((prompt_len - shared_len) // block_size
-                                   + 2))
+    cold_prompts = [rng.integers(0, vocab, size=prompt_len)
+                    .astype(np.int32) for _ in range(n_requests)]
 
-    def run_once(prefix_blocks):
+    def run_once(workload):
+        # The auto-sized pool holds every slot at max_len plus
+        # headroom: the leg measures reuse, not eviction.
         eng = ServeEngine(
             model, variables, max_slots=slots, prefill_len=prefill_len,
             max_queue_depth=n_requests + 1,
-            prefix_cache_blocks=prefix_blocks,
-            prefix_block_size=block_size,
-            prefix_chunk=chunk if prefix_blocks else None)
+            prefix_block_size=block_size, prefix_chunk=chunk)
         eng.warmup()
-        handles = [eng.submit(p, new_tokens) for p in prompts]
+        handles = [eng.submit(p, new_tokens) for p in workload]
         eng.run(max_steps=100000)
         assert all(h.done for h in handles)
         ttfts = [h.ttft_s for h in handles]
@@ -283,8 +272,8 @@ def _prefix_ttft_leg(model, variables, *, n_requests: int,
         # headline is the median of per-pair ratios — host load drift
         # hits both runs of a pair and cancels in the quotient, where
         # it would inflate the spread of the raw TTFT medians.
-        t_on, eng_on = run_once(pool_blocks)
-        t_off, eng_off = run_once(0)
+        t_on, eng_on = run_once(prompts)
+        t_off, eng_off = run_once(cold_prompts)
         on_ttfts.append(t_on)
         off_ttfts.append(t_off)
         ratios.append(t_off / t_on)
@@ -309,140 +298,6 @@ def _prefix_ttft_leg(model, variables, *, n_requests: int,
         "prefix_evictions": snap["prefix_evictions"],
         "engine_compile_counts_prefix_on": eng_on.compile_counts(),
         "engine_compile_counts_prefix_off": eng_off.compile_counts(),
-    }
-
-
-def _paged_leg(model, variables, *, prompt_len: int, shared_frac: float,
-               new_tokens: int, slots: int, prefill_len: int,
-               block_size: int, chunk: int, vocab: int, repeats: int,
-               seed: int = 17):
-    """True paged attention vs the resident-row prefix cache, PAIRED.
-
-    Two questions, both from the same warm shared-prefix workload with
-    every slot live at once:
-
-    1. **Capacity** — ``duplicate_kv_eliminated_x``: HBM holding the
-       live streams' KV, row / paged, from
-       ``ServeEngine.resident_kv_report()``. The row engine holds each
-       slot's 80%-shared prefix privately plus one pool copy; the
-       paged engine holds every DISTINCT block once, so the ratio is
-       the duplicate KV paging deletes (the effective-capacity
-       multiplier at this sharing level).
-    2. **Admission** — prefix-HIT mean TTFT, paged vs row, per-pair
-       ratio: the paged admission must not be slower than the gather
-       path even though it runs the same suffix chunks (it drops the
-       pool→row gather and the row→slot insert copy entirely);
-       ``admission_copy_us`` (per-admission gather+insert dispatch
-       wall from the telemetry ring) shows the copy cost that
-       disappeared.
-
-    The paged pool is sized to at most the row engine's TOTAL KV
-    allocation (slot cache + pool), so the capacity ratio is measured
-    at no-worse-than-identical pool bytes.
-    """
-    rng = np.random.default_rng(seed)
-    shared_len = int(prompt_len * shared_frac)
-    shared = rng.integers(0, vocab, size=shared_len).astype(np.int32)
-    prompts = [np.concatenate([
-        shared,
-        rng.integers(0, vocab, size=prompt_len - shared_len)
-        .astype(np.int32)]) for _ in range(slots)]
-    row_pool_blocks = (2 + prompt_len // block_size
-                      + slots * ((prompt_len - shared_len) // block_size
-                                 + 2))
-    max_len = model.max_len
-    table_width = -(-max_len // block_size)
-    paged_floor = slots * table_width + 1
-    # Identical-or-smaller footprint: the row engine's slot cache holds
-    # slots*max_len tokens and its pool row_pool_blocks*bs more; the
-    # paged pool gets at most that token budget (floor-checked).
-    paged_pool_blocks = max(
-        paged_floor,
-        (slots * max_len + row_pool_blocks * block_size) // block_size)
-
-    def run_once(paged: bool):
-        eng = ServeEngine(
-            model, variables, max_slots=slots, prefill_len=prefill_len,
-            max_queue_depth=2 * slots + 2,
-            prefix_cache_blocks=(paged_pool_blocks if paged
-                                 else row_pool_blocks),
-            prefix_block_size=block_size, prefix_chunk=chunk,
-            paged=paged)
-        eng.warmup()
-        # Wave 1 (cold): warms the cache; run to completion.
-        w1 = [eng.submit(p, 4) for p in prompts]
-        eng.run(max_steps=100000)
-        assert all(h.done for h in w1)
-        # Wave 2 (hit): every slot live on the warm prefix; snapshot
-        # residency mid-decode, then finish.
-        w2 = [eng.submit(p, new_tokens) for p in prompts]
-        while eng.live_slots < slots:
-            eng.step()
-        for _ in range(2):
-            eng.step()
-        report = eng.resident_kv_report()
-        report["blocks_shared"] = eng.blocks_shared
-        eng.run(max_steps=100000)
-        assert all(h.done for h in w2)
-        ttft = float(np.mean([h.ttft_s for h in w2]))
-        # Per-admission copy dispatch wall (gather + insert), from the
-        # ring: the cost line paging deletes (0 by construction there).
-        copy_s = sum(r["site_wall_s"].get("gather", 0.0)
-                     + r["site_wall_s"].get("insert", 0.0)
-                     for r in eng.telemetry.snapshot())
-        admissions = max(eng.metrics.prefix_lookups, 1)
-        return ttft, report, 1e6 * copy_s / admissions, eng
-
-    paged_ttfts, row_ttfts, ratios, cap_ratios = [], [], [], []
-    cap_paged = cap_row = None
-    eng_paged = eng_row = None
-    for _ in range(repeats):
-        t_row, cap_row, copy_row_us, eng_row = run_once(False)
-        t_paged, cap_paged, copy_paged_us, eng_paged = run_once(True)
-        row_ttfts.append(t_row)
-        paged_ttfts.append(t_paged)
-        ratios.append(t_row / t_paged)
-        cap_ratios.append(cap_row["kv_bytes_used"]
-                          / max(cap_paged["kv_bytes_used"], 1))
-    ttft_row_med, _ = median_spread(row_ttfts)
-    ttft_paged_med, _ = median_spread(paged_ttfts)
-    ratio_med, ratio_spread = median_spread(ratios)
-    cap_med, cap_spread = median_spread(cap_ratios)
-    snap = eng_paged.metrics.snapshot()
-    return {
-        "shared_frac": shared_frac,
-        "prompt_len": prompt_len,
-        "concurrent_streams": slots,
-        "prefix_block_size": block_size,
-        "paged_pool_blocks": paged_pool_blocks,
-        "row_pool_blocks": row_pool_blocks,
-        "kv_bytes_used_row": cap_row["kv_bytes_used"],
-        "kv_bytes_used_paged": cap_paged["kv_bytes_used"],
-        "kv_bytes_allocated_row": cap_row["kv_bytes_allocated"],
-        "kv_bytes_allocated_paged": cap_paged["kv_bytes_allocated"],
-        "tokens_resident": cap_paged["tokens_resident"],
-        "duplicate_kv_eliminated_x": round(cap_med, 3),
-        "duplicate_kv_eliminated_per_pair": [round(r, 3)
-                                             for r in cap_ratios],
-        "duplicate_kv_spread_pct": round(cap_spread, 2),
-        "effective_cached_tokens_per_byte_row": round(
-            cap_row["tokens_resident"]
-            / max(cap_row["kv_bytes_used"], 1), 9),
-        "effective_cached_tokens_per_byte_paged": round(
-            cap_paged["tokens_resident"]
-            / max(cap_paged["kv_bytes_used"], 1), 9),
-        "hit_admission_ttft_row_s": round(ttft_row_med, 5),
-        "hit_admission_ttft_paged_s": round(ttft_paged_med, 5),
-        "hit_admission_speedup_x": round(ratio_med, 3),
-        "hit_admission_speedup_per_pair": [round(r, 3) for r in ratios],
-        "spread_pct": round(ratio_spread, 2),
-        "admission_copy_us_row": round(copy_row_us, 1),
-        "admission_copy_us_paged": round(copy_paged_us, 1),
-        "blocks_shared_live": cap_paged["blocks_shared"],
-        "copy_bytes_avoided": snap["copy_bytes_avoided"],
-        "prefix_hit_rate": round(snap["prefix_hit_rate"], 3),
-        "engine_compile_counts_paged": eng_paged.compile_counts(),
-        "engine_compile_counts_row": eng_row.compile_counts(),
     }
 
 
@@ -627,8 +482,7 @@ def _spec_leg(model, variables, *, n_requests: int, prompt_len: int,
     def build(k, fault_plan=None):
         return ServeEngine(model, variables, max_slots=slots,
                            prefill_len=prefill_len,
-                           max_queue_depth=n_requests + 1,
-                           prefix_cache_blocks=0, spec_k=k,
+                           max_queue_depth=n_requests + 1, spec_k=k,
                            fault_plan=fault_plan,
                            backoff_sleep=lambda s: None)
 
@@ -777,7 +631,11 @@ def _tier_leg(model, variables, *, repeats: int, mults=(4, 8, 16, 32),
     bs, prompt_len, prefill_len, chunk = 48, 384, 384, 96
     blocks_per_prompt = prompt_len // bs
     pool_prompts = 2
-    pool_blocks = pool_prompts * blocks_per_prompt + 1
+    # The pool is the KV cache, so it cannot go under the engine's
+    # floor (every slot at max_len + scratch); at max_len 512 that is
+    # 23 blocks: the one live stream's 9 and ~2 prompts' cached chains.
+    pool_blocks = max(pool_prompts * blocks_per_prompt + 1,
+                      2 * -(-model.max_len // bs) + 1)
     # K+V bytes per block: 2 leaves x embed x f32 x depth x block_size.
     kv_block_bytes = 2 * model.embed_dim * 4 * model.depth * bs
 
@@ -911,7 +769,7 @@ def _tier_fleet_leg(model, variables, *, repeats: int, seed: int = 37):
     def factory():
         return ServeEngine(
             model, variables, max_slots=4, prefill_len=prefill_len,
-            max_queue_depth=16, prefix_cache_blocks=64,
+            max_queue_depth=16,
             prefix_block_size=bs, prefix_chunk=16,
             host_tier=1 << 24)
 
@@ -1212,12 +1070,10 @@ def _poisson_load(model, variables, offered_rps: float, n_requests: int,
     arrivals = np.cumsum(rng.exponential(1.0 / offered_rps, n_requests))
     prompts = _make_requests(n_requests, prompt_len, new_tokens, vocab,
                              seed=seed + 1)
-    # Prefix cache off: the Poisson prompts are random (nothing to
-    # share), and the load curve stays comparable with r06.
+    # The Poisson prompts are random (nothing to share).
     eng = ServeEngine(model, variables, max_slots=slots,
                       prefill_len=prefill_len,
-                      max_queue_depth=max_queue_depth,
-                      prefix_cache_blocks=0)
+                      max_queue_depth=max_queue_depth)
     eng.warmup()
     rejected = 0
     i = 0
@@ -1257,12 +1113,7 @@ def _fleet_worker_config(args) -> dict:
                 embed_dim=args.embed_dim, depth=args.depth,
                 heads=args.heads, slots=args.slots,
                 prefill_len=args.prefill_len,
-                max_queue_depth=4 * args.slots, param_seed=0,
-                # Prefix reuse OFF: this leg's prompts share nothing
-                # (the pool would only add overhead) and the committed
-                # r11 artifact was measured on the 4-program engine —
-                # keep reruns comparable to it.
-                prefix_cache_blocks=0)
+                max_queue_depth=4 * args.slots, param_seed=0)
 
 
 def _fleet_spawn(n: int, cfg: dict):
@@ -1788,7 +1639,7 @@ def _disagg_engine_factory(args, model, variables):
         return ServeEngine(
             model, variables, max_slots=args.slots,
             prefill_len=_disagg_prefill_len(args),
-            prefix_cache_blocks=256, prefix_block_size=8,
+            prefix_block_size=8,
             prefix_chunk=_DISAGG_CHUNK,
             host_tier=1 << 28, max_queue_depth=2 * args.slots)
     return make
@@ -2092,14 +1943,12 @@ def _autoscale_cfg(args) -> dict:
     (~1.1k tok/s: depth 6, 4 slots) that genuine overload is
     expressible at request rates the single-threaded router loop
     sustains — a faster engine turns the open-loop replay into a
-    de-facto closed loop and no static baseline can ever saturate.
-    Prefix reuse off: the 4-program engine keeps the zero-recompile
-    pin exact."""
+    de-facto closed loop and no static baseline can ever saturate."""
     del args
     return dict(vocab=64, max_len=128, embed_dim=192, depth=6, heads=4,
                 slots=4, prefill_len=64,
                 max_queue_depth=8, param_seed=0,
-                aging_s=3.0, prefix_cache_blocks=0)
+                aging_s=3.0)
 
 
 def _autoscale_admission():
@@ -2413,7 +2262,7 @@ def _ctrlplane_cfg() -> dict:
     slows the referee)."""
     return dict(vocab=64, max_len=128, embed_dim=64, depth=2, heads=2,
                 slots=4, prefill_len=32, max_queue_depth=96,
-                param_seed=0, prefix_cache_blocks=0)
+                param_seed=0)
 
 
 def _ctrl_wave(fleet, prompts, new_tokens: int, *, hang_s: float = 300.0,
@@ -2698,8 +2547,7 @@ def _ctrlplane_recovery_leg(model, variables, args,
 
     def factory():
         return ServeEngine(model, variables, max_slots=4,
-                           prefill_len=32, max_queue_depth=96,
-                           prefix_cache_blocks=0)
+                           prefill_len=32, max_queue_depth=96)
 
     def replicas():
         return [LocalReplica(i, factory) for i in range(2)]
@@ -2918,8 +2766,7 @@ def _ha_failover_leg(model, variables, args, repeats: int) -> dict:
 
     def factory():
         return ServeEngine(model, variables, max_slots=4,
-                           prefill_len=32, max_queue_depth=96,
-                           prefix_cache_blocks=0)
+                           prefill_len=32, max_queue_depth=96)
 
     def replicas():
         return [LocalReplica(i, factory) for i in range(2)]
@@ -3088,8 +2935,7 @@ def _chaosd_availability_leg(model, variables, args,
 
     def factory():
         return ServeEngine(model, variables, max_slots=4,
-                           prefill_len=32, max_queue_depth=96,
-                           prefix_cache_blocks=0)
+                           prefill_len=32, max_queue_depth=96)
 
     d = tempfile.mkdtemp(prefix="pddl-chaosd-wal-")
     sp = StorageFaultPlan(seed=0)
@@ -3407,12 +3253,6 @@ def main() -> None:
                         "adapters + constrained decoding; r14 artifact)")
     p.add_argument("--tenant-adapters", type=int, default=8,
                    help="distinct LoRA adapters in the tenant leg")
-    p.add_argument("--paged-only", action="store_true",
-                   help="run ONLY the paged-attention leg (paged vs "
-                        "resident-row engines, paired: duplicate-KV "
-                        "elimination at matched pool bytes + prefix-hit "
-                        "admission head-to-head) and write a standalone "
-                        "artifact (r13_serve_paged.json)")
     p.add_argument("--fault-rate", type=float, default=0.01,
                    help="injected fault probability per device dispatch "
                         "in the fault leg (transient; OOM rides at a "
@@ -4088,53 +3928,6 @@ def main() -> None:
              f"{tenant['tenant_throughput_retained_x']}x the plain "
              f"engine; constrained decode mask overhead "
              f"{tenant['mask_overhead_x']}x")
-        _write_record(record, args.out)
-        return
-
-    if args.paged_only:
-        _log(f"paged leg only: {args.slots} concurrent streams x "
-             f"{args.prefix_prompt_len}-token prompts at "
-             f"{args.prefix_shared_frac:.0%} shared, paged vs "
-             f"resident-row, {model_desc}")
-        paged = _paged_leg(
-            model, variables, prompt_len=args.prefix_prompt_len,
-            shared_frac=args.prefix_shared_frac,
-            new_tokens=args.prefix_new_tokens + 24,
-            slots=args.slots,
-            prefill_len=max(args.prefill_len, args.prefix_prompt_len),
-            block_size=args.prefix_block_size, chunk=args.prefix_chunk,
-            vocab=args.vocab, repeats=args.repeats)
-        record = {
-            "metric": "online_serving_paged_attention",
-            "unit": "ratio (row/paged KV bytes for the same live "
-                    "streams; row/paged prefix-hit admission TTFT)",
-            "config": {
-                "model": model_desc,
-                "slots": args.slots,
-                "prefill_len": args.prefill_len,
-                "prompt_len": args.prefix_prompt_len,
-                "shared_frac": args.prefix_shared_frac,
-                "prefix_block_size": args.prefix_block_size,
-                "paged": "per-slot block tables over the shared pool; "
-                         "pin-on-admit, in-place suffix append, "
-                         "bookkeeping-only donation "
-                         "(ops/attention.paged_decode_attention, "
-                         "serve/engine.py paged mode)",
-            },
-            "provenance": provenance(args.repeats),
-            "results": {"paged": paged},
-            "device": jax.devices()[0].device_kind,
-        }
-        _log(f"paged: duplicate KV eliminated "
-             f"{paged['duplicate_kv_eliminated_x']}x at matched pool "
-             f"bytes ({paged['kv_bytes_used_row']} -> "
-             f"{paged['kv_bytes_used_paged']} bytes for "
-             f"{paged['tokens_resident']} resident tokens); prefix-hit "
-             f"admission {paged['hit_admission_speedup_x']}x vs gather "
-             f"({paged['hit_admission_ttft_row_s']}s -> "
-             f"{paged['hit_admission_ttft_paged_s']}s; copy "
-             f"{paged['admission_copy_us_row']}us -> "
-             f"{paged['admission_copy_us_paged']}us per admission)")
         _write_record(record, args.out)
         return
 

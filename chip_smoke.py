@@ -11,11 +11,10 @@ models the repo ships, on one chip:
    ``run_experiment`` -> ``Trainer.fit``): ResNet-50, 224 px, batch 32,
    bf16, synthetic data, a few steps of one epoch.
 2. *serve* — ``GPT_Small`` (vocab 50257, context 1024, bf16, weights from
-   a fixed seed) through ``ServeEngine(paged=True)``: warmup, a wave of
-   greedy requests of mixed prompt length, every stream checked against
-   the model's own conditional and against one-shot ``generate()``; the
-   tick must contain the Mosaic paged-decode kernel. Then a short wave
-   through the default constructor (``paged=False``).
+   a fixed seed) through ``ServeEngine``: warmup, a wave of greedy
+   requests of mixed prompt length, every stream checked against the
+   model's own conditional and against one-shot ``generate()``; the
+   tick must contain the Mosaic paged-decode kernel.
 3. *kernels* — flash forward + fused backward and the paged decode
    kernel against their jnp oracles at GPT-small (12x64) and Llama-small
    (12/4x64) head shapes, compiled, not interpreted.
@@ -237,7 +236,7 @@ def serve_phase(model=None,
     prompts = [rng.randint(0, model.vocab_size, size=n).astype(np.int32)
                for n in prompt_lens]
 
-    engine = ServeEngine(model, variables, paged=True)
+    engine = ServeEngine(model, variables)
     # The platform decides, not an option: on a TPU the tick must carry
     # the Mosaic kernel (an interpreted or jnp tick cannot pass); off it
     # (the CPU rehearsal) the jnp oracle serves and no kernel can be there.
@@ -247,15 +246,9 @@ def serve_phase(model=None,
             f"paged tick on {jax.devices()[0].platform}: Mosaic kernel "
             f"present={has_kernel}")
     width = max(prompt_lens) + new_tokens
-    facts = {"paged": _serve_wave(engine, model, variables, prompts,
-                                  new_tokens, width, gap_tol),
-             "tick_has_mosaic_kernel": has_kernel}
-    del engine
-    # What users get today: the default constructor (row cache).
-    facts["default"] = _serve_wave(
-        ServeEngine(model, variables), model, variables, prompts[:3],
-        new_tokens, width, gap_tol)
-    return facts
+    return {"paged": _serve_wave(engine, model, variables, prompts,
+                                 new_tokens, width, gap_tol),
+            "tick_has_mosaic_kernel": has_kernel}
 
 
 # ---------------------------------------------------------------- kernels

@@ -559,27 +559,11 @@ def is_block_table_path(path) -> bool:
         str(getattr(path[-1], "key", path[-1])) == BLOCK_TABLE_KEY)
 
 
-def slot_decode_cache(dec, slots: int):
-    """A pooled ``slots``-row decode cache for the serving engine.
-
-    K/V leaves are the batch-1 cache's with the batch dim widened to
-    ``slots`` (one row per request slot); position counters become
-    ``[slots]`` int32 VECTORS — the per-row index form the decode
-    modules and :func:`~pddl_tpu.ops.attention.decode_attention` accept,
-    so every slot advances at its own depth inside one fused tick.
-    """
-    row = _decode_cache_shapes(dec, 1)
-    return jax.tree_util.tree_map_with_path(
-        lambda path, sd: (jnp.zeros((slots,), jnp.int32)
-                          if is_cache_index_path(path)
-                          else jnp.zeros((slots,) + sd.shape[1:], sd.dtype)),
-        row)
-
-
 def set_cache_positions(cache, positions):
-    """Overwrite every position counter of a pooled cache with
-    ``positions [slots]`` (the engine owns the authoritative per-slot
-    positions; the tick program stamps them in before each apply)."""
+    """Overwrite every position counter of a cache tree with
+    ``positions`` (``[slots]`` for the fused tick, a scalar for a
+    batch-1 chunk; the engine owns the authoritative per-slot
+    positions and every program stamps them in before its apply)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: positions if is_cache_index_path(path) else leaf,
         cache)
@@ -612,70 +596,29 @@ def set_cache_valid_len(cache, length):
         cache)
 
 
-def insert_cache_slot(cache, row_cache, slot, position):
-    """Insert a finished batch-1 prefill (``row_cache``) as slot ``slot``
-    of a pooled cache, and stamp the slot's position counter to
-    ``position`` (the request's prompt length). K/V rows go through
-    :func:`~pddl_tpu.ops.attention.cache_slot_insert`; the row cache's
-    own scalar counters are discarded — the pool's vectors are
-    authoritative. ``slot``/``position`` are runtime values: one
-    compiled program admits into any slot."""
-    from pddl_tpu.ops.attention import cache_slot_insert
-
-    def _ins(path, pool, row):
-        if is_cache_index_path(path):
-            return pool.at[slot].set(jnp.asarray(position, pool.dtype))
-        return cache_slot_insert(pool, row, slot)
-
-    return jax.tree_util.tree_map_with_path(_ins, cache, row_cache)
-
-
-def prefill_row(dec, params, prompt, length, *, param_transform=None):
-    """One request's prefill on a FRESH batch-1 cache: the serving
-    engine's admission building block (family-generic — duck-typed over
-    GPT/Llama like :func:`generate`).
-
-    ``prompt`` is int32 ``[1, P_pad]`` RIGHT-padded to the engine's
-    fixed prefill width (one compiled program for all prompt lengths);
-    ``length`` (traced int32) is the true token count. Padding is
-    harmless by the same invariant speculative decoding relies on:
-    causal attention means positions ``< length`` never see the junk
-    suffix, the returned logits row is taken at ``length - 1``, and the
-    junk K/V beyond ``length`` sits past the slot's position counter
-    where the prefix-bounded sweep never reads it (decode overwrites it
-    position by position as the request generates).
-
-    Returns ``(row_cache, last_logits [1, V])``.
-    """
-    pt = param_transform or (lambda p: p)
-    cache = jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype),
-                         _decode_cache_shapes(dec, 1))
-    logits, mutated = dec.apply(
-        {"params": pt(params), "cache": cache}, prompt,
-        train=False, mutable=["cache"])
-    last = jax.lax.dynamic_slice(
-        logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[:, 0]
-    return mutated["cache"], last
-
-
 def prefill_row_from(dec, params, prompt, length, row_cache, start, *,
                      param_transform=None):
-    """Chunked prefill CONTINUING an existing batch-1 row cache: the
-    prefix-cache admission building block (family-generic like
-    :func:`prefill_row` — GPT's decode embed and the Llama/vit decode
-    attention both run multi-token blocks at any starting index).
+    """Chunked prefill CONTINUING an existing batch-1 cache: the
+    serving engine's admission building block (family-generic —
+    duck-typed over GPT/Llama like :func:`generate`; GPT's decode embed
+    and the Llama/vit decode attention both run multi-token blocks at
+    any starting index).
 
     ``row_cache`` already holds ``start`` valid tokens of K/V (e.g. a
-    gathered shared-prefix chain); ``prompt`` is int32 ``[1, C]``
-    RIGHT-padded, ``length <= C`` its true token count, both traced —
-    one compiled program per chunk width. The chunk's tokens take global
-    positions ``start .. start+C-1``, so the caller must keep
+    pinned shared-prefix chain behind the cache's block table);
+    ``prompt`` is int32 ``[1, C]`` RIGHT-padded, ``length <= C`` its
+    true token count, both traced — one compiled program per chunk
+    width. The chunk's tokens take global positions
+    ``start .. start+C-1``, so the caller must keep
     ``start + C <= dec.max_len`` (the embed/cache dynamic slices CLAMP
     out-of-range starts, which would silently mis-position the block).
-    Padding junk is harmless by the :func:`prefill_row` invariant:
-    causal masking hides it from positions ``< start + length``, its K/V
-    lands beyond the position counter the caller stamps at insert, and
-    decode overwrites it before the counter crosses.
+    Padding is harmless by the same invariant speculative decoding
+    relies on: causal masking hides the junk suffix from positions
+    ``< start + length``, the returned logits row is taken at
+    ``length - 1``, and the junk K/V lands beyond the position counter
+    the caller keeps for the slot, where the prefix-bounded sweep
+    never reads it (decode overwrites it position by position as the
+    request generates).
 
     Returns ``(row_cache, last_logits [1, V])`` with the logits row
     taken at ``length - 1`` (only the FINAL chunk's logits are
@@ -724,13 +667,11 @@ def lm_head_logits(model, params, feats):
 
 def prefill_row_features(dec, params, prompt, length, row_cache, start, *,
                          param_transform=None):
-    """The tenant twin of :func:`prefill_row`/:func:`prefill_row_from`:
-    one prefill chunk that ALSO returns the last position's pre-head
-    features, so the caller can compose LoRA deltas into the sampled
-    logits. ``row_cache=None`` starts a fresh batch-1 cache (the
-    whole-prompt ``prefill_row`` shape); otherwise the chunk continues
-    the given cache at global offset ``start`` (``prefill_row_from``
-    semantics, same clamping caveats).
+    """The tenant twin of :func:`prefill_row_from`: one prefill chunk
+    that ALSO returns the last position's pre-head features, so the
+    caller can compose LoRA deltas into the sampled logits. The chunk
+    continues the given cache at global offset ``start``
+    (``prefill_row_from`` semantics, same clamping caveats).
 
     Returns ``(row_cache, last_logits [1, V], last_feats [1, d])``.
     The logits are computed through :func:`lm_head_logits` over the
@@ -740,12 +681,7 @@ def prefill_row_features(dec, params, prompt, length, row_cache, start, *,
     """
     pt = param_transform or (lambda p: p)
     p2 = pt(params)
-    if row_cache is None:
-        cache = jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype),
-                             _decode_cache_shapes(dec, 1))
-    else:
-        cache = set_cache_positions(row_cache,
-                                    jnp.asarray(start, jnp.int32))
+    cache = set_cache_positions(row_cache, jnp.asarray(start, jnp.int32))
     feats, mutated = dec.apply(
         {"params": p2, "cache": cache}, prompt,
         train=False, mutable=["cache"], features_only=True)

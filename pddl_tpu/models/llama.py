@@ -221,7 +221,7 @@ class LlamaAttention(nn.Module):
         on which request computed it. This is what makes the prefix
         cache's shared KV blocks (`pddl_tpu/serve/kvcache/`) bit-valid
         across requests, and what `gpt.prefill_row_from` relies on when
-        it continues a row cache assembled from gathered blocks: a
+        it continues a cache whose table points at a pinned chain: a
         suffix chunk at starting index ``i`` reproduces exactly the K/V
         a full prefill would have written there. (The caller keeps
         ``i + s <= max_decode_len`` — the cache write's dynamic slice
@@ -501,8 +501,8 @@ class Llama(nn.Module):
     def uses_ring_cache(self) -> bool:
         """True when SWA decode (outside a paged engine) allocates a
         rolling ring cache (slots recycle — cannot be rewound;
-        speculative decoding and the row-mode serving engine check
-        this). Same decision, same code as the cache allocation:
+        speculative decoding and the serving engine's draft-model
+        check read this). Same decision, same code as the cache allocation:
         :func:`ring_len` over the blocks' ``max_decode_len`` (=
         ``max_len``, line where the blocks are built), for any layer."""
         return any(ring_len(self.layer_window(i), self.max_len) is not None

@@ -218,9 +218,7 @@ class Trace:
                 continue
             wall = max(0.0, float(e.get("wall_s") or 0.0))
             site = e.get("site")
-            if site == "gather":
-                seg["prefix_match"] += wall
-            elif site == "host_promote":
+            if site == "host_promote":
                 seg["host_promote"] += wall
             else:
                 seg["prefill"] += wall

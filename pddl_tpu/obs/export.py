@@ -335,9 +335,10 @@ def engine_gauges(engine) -> Dict[str, object]:
         "degraded": engine.degraded,
         "drained": engine.drained,
         "prefix_pool_nbytes": engine.prefix_pool_nbytes,
-        # Paged-attention gauges (0 / 0.0 on a copy-mode engine): live
-        # cross-slot sharing and table occupancy, the dashboard's view
-        # of the in-place prefix sharing (`ServeEngine.paged`).
+        # Block-pool gauges: live cross-slot sharing and table
+        # occupancy, the dashboard's view of the in-place prefix
+        # sharing. ``paged`` is a constant series since PR 34 (one
+        # engine), kept for dashboards keyed on it.
         "paged": getattr(engine, "paged", False),
         "blocks_shared": getattr(engine, "blocks_shared", 0),
         "block_table_fill": getattr(engine, "block_table_fill", 0.0),

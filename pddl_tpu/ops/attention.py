@@ -932,45 +932,18 @@ def flash_attention_lse(
 _SINGLE_SHOT_MAX_KC_BYTES = 1024 * 1024
 
 
-def cache_slot_insert(pool: jnp.ndarray, row: jnp.ndarray, slot) -> jnp.ndarray:
-    """Insert a batch-1 cache leaf ``row [1, ...]`` as row ``slot`` of the
-    pooled leaf ``pool [S, ...]`` (the serving engine's slot model: one
-    resident cache whose batch dim is a pool of request slots).
-
-    ``slot`` is a traced int32 scalar — slot choice is a runtime value,
-    so admitting into any slot reuses one compiled program. The whole
-    row is overwritten, which is what makes stale K/V from the slot's
-    previous occupant unreachable-by-construction after an admit.
-    """
-    if row.shape != (1,) + pool.shape[1:]:
-        raise ValueError(
-            f"row {row.shape} is not a batch-1 slice of pool {pool.shape}")
-    return jax.lax.dynamic_update_slice(
-        pool, row.astype(pool.dtype), (slot,) + (0,) * (pool.ndim - 1))
-
-
-def cache_slot_reset(pool: jnp.ndarray, slot) -> jnp.ndarray:
-    """Zero one slot row of a pooled cache leaf (evict hygiene — not
-    required for correctness, since :func:`cache_slot_insert` overwrites
-    the whole row on the next admit, but useful for tests/debugging)."""
-    return cache_slot_insert(
-        pool, jnp.zeros((1,) + pool.shape[1:], pool.dtype), slot)
-
-
 def cache_blocks_gather(pool: jnp.ndarray, block_ids) -> jnp.ndarray:
     """Gather KV blocks ``block_ids [M]`` from a block-pool leaf
     ``[N, ..., block_size, D]`` into one contiguous batch-1 cache prefix
     ``[1, ..., M*block_size, D]`` (block ``j``'s tokens land at positions
     ``[j*block_size, (j+1)*block_size)``).
 
-    The prefix-cache twin of :func:`cache_slot_insert`: ``block_ids`` is
-    a runtime int32 vector of FIXED length, so one compiled program
-    serves every hit depth — callers pad short chains with the reserved
-    scratch block (id 0), whose junk lands at positions the suffix
-    prefill overwrites or the slot's position counter parks. The gather
-    COPIES: a admitted request's slot never aliases pool storage, which
-    is what makes pool eviction safe while the request decodes
-    (copy-on-write by construction).
+    The host tier's D2H read (`serve/engine.py` demotion and chain
+    export): ``block_ids`` is a runtime int32 vector of FIXED length,
+    so one compiled program serves every chain depth — callers pad
+    short chains with the reserved scratch block (id 0), whose junk
+    lands in tail slices they do not take. The gather COPIES, so the
+    host payload never aliases pool storage.
     """
     block_ids = jnp.asarray(block_ids, jnp.int32)
     if block_ids.ndim != 1:
@@ -990,8 +963,8 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
     """Write a batch-1 cache row's tokens
     ``[start_block*block_size, (start_block+M)*block_size)`` into pool
     blocks ``block_ids [M]`` of a ``[N, ..., block_size, D]`` leaf — the
-    donation half of the prefix cache (a finished prefill's prompt K/V
-    becomes shared, immutable pool blocks).
+    host tier's H2D promotion (`serve/engine.py` ``host_promote``: a
+    demoted chain's K/V becomes shared, immutable pool blocks again).
 
     ``start_block`` is a traced int32 block index; ``block_ids`` is a
     fixed-length runtime vector (pad with the scratch block 0 — its

@@ -11,59 +11,72 @@ of aggregate tokens/s at realistic request mixes (vLLM, SOSP '23).
 The slot model, under JAX's fixed-shape discipline:
 
 - ONE resident compiled decode program with a fixed pool of ``S``
-  batch slots: the pooled KV cache is ``[S, H_kv, L, D]`` per layer
-  with PER-SLOT position counters (``[S]`` int32 — the vector-index
-  decode path in `ops/attention.py` / the model families), so every
-  slot advances at its own depth inside one fused tick.
-- Each ``step()``: (a) ADMIT queued requests into free slots — a
-  batch-1 prefill over the right-padded prompt
-  (:func:`~pddl_tpu.models.gpt.prefill_row`), inserted into the slot
-  (:func:`~pddl_tpu.models.gpt.insert_cache_slot`), first token
-  sampled immediately (that's TTFT); (b) one fused DECODE TICK for all
-  live slots with per-slot sampling params as batched runtime arrays
+  batch slots over ONE block pool that IS the KV cache
+  (`pddl_tpu/serve/kvcache/`, vLLM PagedAttention / SGLang
+  RadixAttention composed): per attention layer a fused leaf
+  ``[N, H_kv, block, 2*D]``
+  (:func:`~pddl_tpu.serve.kvcache.paged_decode_cache`), read through
+  a per-slot ``[S, T]`` block table with PER-SLOT position counters
+  (``[S]`` int32 — the vector-index decode path in `ops/attention.py`
+  / the model families), so every slot advances at its own depth
+  inside one fused tick
+  (:func:`~pddl_tpu.ops.attention.paged_decode_attention`; the Pallas
+  kernel on TPU, the chunked jnp oracle elsewhere).
+- Each ``step()``: (a) ADMIT queued requests into free slots — the
+  prompt is matched against a host-side radix index over token ids
+  (`kvcache/radix.py`), the matched chain's blocks are PINNED and the
+  slot's table row points at them in place, private blocks are
+  allocated for the uncached suffix, and fixed-width chunk programs
+  (:func:`~pddl_tpu.models.gpt.prefill_row_from`) write the suffix's
+  K/V straight into those pool blocks; the first token is sampled
+  immediately (that's TTFT); (b) every live slot about to cross a
+  block boundary gets a fresh private block appended to its table
+  row, then one fused DECODE TICK runs for all live slots with
+  per-slot sampling params as batched runtime arrays
   (:func:`~pddl_tpu.models.gpt.sample_logits_batched`); (c) EVICT
   finished slots (eos / length / cancel / deadline) host-side — the
-  next admit overwrites the whole cache row, so stale K/V is
-  unreachable by construction.
-- Exactly FOUR compiled programs (prefill, insert, tick, first-token
-  sample), each traced once at ``warmup()`` and never again: prompt
-  lengths enter as a traced ``length`` over one fixed padded width,
-  slots/positions/sampling params are runtime arrays, and the pooled
-  cache is DONATED through insert and tick so the resident buffers are
-  reused in place. ``compile_counts()`` exposes the executable counts;
-  the suite pins them at 1 after a mixed workload.
+  table row goes all-scratch, the slot's private blocks return to the
+  free list, and the prompt's full blocks stay cached under the radix
+  index, so stale K/V is unreachable by construction.
+- A CLOSED set of compiled programs (the narrow chunk prefill, a
+  ``prefill_len``-wide one where it pays, the tick, the first-token
+  sample; speculation, tenancy and the host tier each add their own),
+  each traced once at ``warmup()`` and never again: prompt lengths
+  enter as a traced ``length`` over fixed chunk widths,
+  tables/positions/sampling params are runtime arrays, and the pool
+  tree is DONATED through every program that touches it so the
+  resident buffers are reused in place. ``compile_counts()`` exposes
+  the executable counts; the suite pins them at 1 after a mixed
+  workload.
 
-Dead slots tick too (fixed shapes — their writes land at parked
-position 0 and are overwritten by the next admit); the cost is one
-batch row of compute, which is what buys zero recompiles.
+Dead slots tick too (fixed shapes — their table rows are all scratch,
+so their writes land in block 0, a sink the radix index never
+references); the cost is one batch row of compute, which is what buys
+zero recompiles.
 
-Prefix-aware KV reuse (`pddl_tpu/serve/kvcache/`): production traffic
-is dominated by shared prompt prefixes (system prompts, few-shot
-templates — the vLLM/SGLang observation), so admission consults a
-host-side radix index over token ids (`kvcache/radix.py`) backed by a
-device-resident pool of fixed-size KV token blocks
-(`kvcache/block_pool.py`). On a hit, the matched chain's blocks are
-GATHERED (copied) into the request's fresh row cache and only the
-uncached SUFFIX is prefilled — in fixed-width chunks, so compute and
-the admission budget both scale with the suffix, not the prompt. After
-prefill, the prompt's uncovered full blocks are DONATED (copied) back
-into the pool under refcounts; both directions copy, so a concurrent
-hit never aliases a live slot and LRU eviction never reaches under a
-decoding request. Token-exactness is structural: both families' caches
-are position-absolute (GPT adds position embeddings before the blocks;
-Llama caches post-RoPE keys), so a shared-prefix block is bit-valid
-for every request with those prompt tokens.
+Prefix-aware KV reuse: production traffic is dominated by shared
+prompt prefixes (system prompts, few-shot templates — the vLLM/SGLang
+observation), so compute and the admission budget both scale with the
+uncached SUFFIX, not the prompt. A hit copies nothing: the matched
+blocks are referenced in place under a refcount pin, donation after
+prefill is a pure ownership hand-off of blocks the chunks already
+wrote, and a shared prefix's KV exists ONCE in HBM no matter how many
+live slots reference it. A pinned chain is never evicted, so LRU
+reclaim never reaches under a decoding request; a slot only ever
+WRITES blocks it owns privately. Token-exactness is structural: both
+families' caches are position-absolute (GPT adds position embeddings
+before the blocks; Llama caches post-RoPE keys), so a shared-prefix
+block is bit-valid for every request with those prompt tokens.
 
 int8 serving composes exactly like ``generate()``: pass
 ``param_transform=pddl_tpu.ops.quant.dequantize`` and the int8 tensors
-are what lives in HBM, dequantized inside the compiled programs (the
-prefix-cache programs included — what the pool stores is K/V, which
-int8 weight storage never touches).
+are what lives in HBM, dequantized inside the compiled programs (what
+the pool stores is K/V, which int8 weight storage never touches).
 
-Ring-cache (rolling SWA) models are refused for now: slot reuse over a
-ring whose slots already wrapped needs per-slot wrap bookkeeping this
-engine doesn't carry yet. Full-length-cache models (GPT, Llama, SWA
-with ``window >= max_len``) are all eligible.
+Sliding-window layers live in the same full-length pool (masked and
+block-skipped to their band), so window, NoPE-global and full-attention
+models are all eligible; the reference for every one of them is
+``generate()``.
 
 Fault tolerance (`serve/faults.py`, `serve/drain.py`,
 `docs/OPERATIONS.md` § "Failure modes & recovery"): every device
@@ -74,8 +87,8 @@ KV is declared LOST and the requests REPLAY — the prompt re-prefills
 through the normal admission path and the already-emitted tokens are
 re-fed one per fused tick (known token in, sampled output discarded)
 until the stream's live edge is rebuilt, which is token-exact because
-the caches are position-absolute and costs no new compiled program in
-either prefix mode. RESOURCE_EXHAUSTED flips the engine DEGRADED:
+the caches are position-absolute and costs no new compiled program.
+RESOURCE_EXHAUSTED flips the engine DEGRADED:
 prefix-cache donations stop, unpinned pool blocks flush, serving
 continues on the cold path, and the cache re-arms after a cool-down.
 A request whose replays exceed ``max_replays`` fails terminally
@@ -91,7 +104,7 @@ al.'s speculative decoding lifted into Orca-style iteration-level
 scheduling. Each step a ``draft`` program proposes up to ``spec_k``
 tokens per slot (the shared n-gram drafter from
 `models/speculative.py` by default — zero extra weights — or a small
-draft model whose KV rides the same paged block pool as a second
+draft model whose KV rides the same block pool as a second
 cache tree), and ONE batched ``verify`` dispatch runs the target
 model over the ``[S, spec_k+1]`` block at per-slot positions through
 the same multi-token machinery chunked prefill uses. Greedy slots
@@ -103,7 +116,7 @@ before. Accepted length comes back as a runtime ``[S]`` int32 array:
 mixed accept counts across the batch are DATA, never a recompile,
 exactly the invariant the grammar masks and LoRA ids already hold.
 Rejected draft suffixes roll back by stamping the host-side position
-counters (and, paged, by the table discipline): the stale K/V sits
+counters (and by the table discipline): the stale K/V sits
 beyond the counter where the prefix-bounded sweep never reads it and
 the next window overwrites it — a rewind is a counter stamp, never a
 KV copy. Grammar-constrained slots speculate under the same FSM
@@ -147,16 +160,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from pddl_tpu.models.gpt import (
-    _decode_cache_shapes,
-    insert_cache_slot,
     lm_head_logits,
-    prefill_row,
     prefill_row_features,
     prefill_row_from,
     sample_logits_batched,
     set_cache_block_tables,
     set_cache_positions,
-    slot_decode_cache,
 )
 from pddl_tpu.models.speculative import ngram_drafts
 from pddl_tpu.obs.ring import TelemetryRing
@@ -173,9 +182,6 @@ from pddl_tpu.serve.kvcache import (
     HostTierCache,
     HostTierConfig,
     RadixPrefixCache,
-    donate_prefix_blocks,
-    gather_prefix_into_row,
-    kv_block_pool,
     paged_decode_cache,
     pool_nbytes,
 )
@@ -205,13 +211,13 @@ _WIDE_PROGRAM_BELOW_CHUNK = 1024  # see ServeEngine._wide_program_pays
 class _SlotStateLost(RuntimeError):
     """Internal escalation: a device call outlasted its retry budget
     (or failed in a way that may have consumed a donated buffer), so
-    whatever slot/row state it touched must be rebuilt, not reused.
+    whatever slot state it touched must be rebuilt, not reused.
     Never escapes the engine — admission turns it into a request
     replay/failure, the tick into a full live-slot replay.
-    ``consumed`` names the resident resource (``cache``/``row``/
-    ``pool``) a REAL mid-dispatch error may have eaten through
-    donation; ``None`` for injected faults, which fire before the
-    program runs and consume nothing."""
+    ``consumed`` names the resident resource (``pool`` — the one tree
+    every program donates) a REAL mid-dispatch error may have eaten
+    through donation; ``None`` for injected faults, which fire before
+    the program runs and consume nothing."""
 
     def __init__(self, site: str, cause: BaseException,
                  consumed: Optional[str] = None):
@@ -221,35 +227,28 @@ class _SlotStateLost(RuntimeError):
 
 
 # Which resident donated tree each site's program consumes on dispatch
-# (prefill and sample_first donate nothing). A REAL error from one of
-# these can leave the donated input deleted, so it is never re-dispatched
-# — the escalation path rebuilds the resource instead.
-_DONATED_BY_SITE = {
-    "tick": "cache", "insert": "cache",
-    "gather": "row", "chunk_prefill": "row", "chunk_prefill_wide": "row",
-    "donate": "pool",
-}
-
-# The PAGED engine's site map: the pool IS the cache, and every paged
-# program (tick and both chunk widths) donates it — a real mid-dispatch
+# (sample_first donates nothing). A REAL error from one of these can
+# leave the donated input deleted, so it is never re-dispatched — the
+# escalation path rebuilds the resource instead. The pool IS the cache,
+# and the tick and both chunk widths donate it — a real mid-dispatch
 # error from any of them may have consumed the one tree holding every
 # live stream's KV, so recovery is always the full pool rebuild + live
 # -slot replay.
-_PAGED_DONATED_BY_SITE = {
+#
+# Speculative engines (`spec_k > 0`): the ``verify`` program replaces
+# ``tick`` and donates the same resident tree; the draft-MODEL program
+# and its admission chunk donate the draft cache tree, which lives in
+# the same block-id space as the pool — a consumed draft tree
+# therefore recovers exactly like a consumed pool (full rebuild +
+# live-slot replay). The n-gram ``draft`` program donates nothing and
+# is deliberately absent here — a lost draft call degrades to fallback
+# drafts, never to a KV rebuild — so the ``draft`` entry is stamped PER
+# ENGINE (only when a draft model is drafting). Each engine keeps the
+# entries of the sites it compiled.
+_DONATED_BY_SITE = {
     "tick": "pool", "chunk_prefill": "pool", "chunk_prefill_wide": "pool",
+    "verify": "pool", "draft_prefill": "pool",
 }
-
-# Speculative-engine additions (`spec_k > 0`): the ``verify`` program
-# replaces ``tick`` and donates the same resident tree; the draft-MODEL
-# program and its admission chunk donate the draft cache tree, which in
-# paged mode lives in the same block-id space as the pool — a consumed
-# draft tree therefore recovers exactly like a consumed pool (full
-# paged-world rebuild + live-slot replay). The n-gram ``draft`` program
-# donates nothing and is deliberately absent here — a lost draft call
-# degrades to fallback drafts, never to a KV rebuild — so the ``draft``
-# entry is stamped PER ENGINE (only when a draft model is drafting).
-_SPEC_DONATED_ROW = {"verify": "cache"}
-_SPEC_DONATED_PAGED = {"verify": "pool", "draft_prefill": "pool"}
 
 # The step's span tree in the profiler's trace: ``pddl.serve.step`` (a
 # StepTraceAnnotation carrying ``step_num``), one ``pddl.serve.<phase>``
@@ -291,15 +290,14 @@ class ServeEngine:
     """Online multiplexer of generate requests onto one decode program.
 
     Args:
-      model: a non-decode GPT/Llama (anything ``generate()``-compatible
-        with a full-length KV cache); the decode twin is cloned here.
+      model: a non-decode GPT/Llama (anything ``generate()``-
+        compatible); the decode twin is cloned here.
       variables: ``{"params": ...}`` — kept on device, always a jit
         ARGUMENT (new same-shape checkpoints never recompile).
       max_slots: the batch-slot pool size ``S`` — the max concurrent
         requests in one fused tick.
-      prefill_len: the fixed padded prompt width (every prompt must fit;
-        one compiled prefill serves all lengths). Defaults to
-        ``model.max_len // 2``.
+      prefill_len: the longest admissible prompt (and the wide chunk
+        program's width). Defaults to ``model.max_len // 2``.
       max_queue_depth / prefill_token_budget / aging_s: admission
         knobs, see `scheduler.py` — the scheduler pops priority-first
         (interactive > batch > best_effort), EDF within a class, with
@@ -310,9 +308,8 @@ class ServeEngine:
         ``step()`` (narrow chunks only; the wide program is skipped)
         and the fused decode tick runs between slices, so one 32k cold
         prompt is time-sliced against the running streams instead of
-        stalling every next token behind its whole prefill. Requires
-        the prefix-cache engine (the chunk programs ARE the slicing
-        mechanism); ``None`` (default) keeps whole-prompt admission.
+        stalling every next token behind its whole prefill. ``None``
+        (default) keeps whole-prompt admission.
       eos_token: optional stop token (included in the stream when hit).
       param_transform: the ``generate()`` int8 hook — applied INSIDE the
         compiled programs (:mod:`pddl_tpu.ops.quant`).
@@ -322,43 +319,27 @@ class ServeEngine:
         even for an all-greedy workload).
       clock: injectable monotonic clock (tests drive deadlines with a
         fake one).
-      prefix_cache_blocks: KV block-pool size (block 0 is a reserved
-        scratch sink). ``None`` (default) auto-sizes to hold about two
-        full prompts per slot — or disables caching cleanly when no
-        block can ever fit (``prefix_block_size >= prefill_len``, e.g.
-        very short engines; check ``prefix_cache_enabled``). ``0``
-        disables prefix caching entirely (the original four-program
-        engine). An EXPLICIT size demands a workable config: it
-        requires ``prefill_len + prefix_chunk <= max_len`` (chunk
-        positions must never clamp) and a usable block size —
-        violations then raise rather than silently degrade.
-      prefix_block_size: tokens per shared KV block — the reuse (and
+      prefix_cache_blocks: the block pool's size — every stream's K/V
+        lives here, live and cached alike (block 0 is a reserved
+        scratch sink). ``None`` (default) auto-sizes to hold every
+        slot at ``max_len`` plus shared-cache headroom of about two
+        full prompts per slot; an explicit size must cover
+        ``max_slots * ceil(max_len/block_size) + 1`` so a live stream
+        can never starve for a writable block. The config must be
+        workable: ``prefix_block_size < prefill_len`` and
+        ``prefill_len + prefix_chunk <= max_len`` (chunk positions
+        must never clamp) — violations raise.
+      prefix_block_size: tokens per KV block — the paging, reuse (and
         radix-tree) granularity. Smaller blocks match more of a prefix
         but cost more pool rows per prompt.
       prefix_chunk: suffix-prefill chunk width (one compiled program;
         admission prefills ``ceil(suffix/chunk)`` chunks, so prefill
         work scales with the UNCACHED suffix). Default
         ``max(prefix_block_size, prefill_len // 4)``.
-      paged: TRUE PAGED ATTENTION (vLLM PagedAttention / SGLang
-        RadixAttention composed): the resident slot cache disappears —
-        every stream's K/V lives in the block pool and decode reads it
-        through a per-slot ``[S, T]`` block table
-        (:func:`~pddl_tpu.ops.attention.paged_decode_attention`; the
-        Pallas kernel on TPU, the chunked jnp oracle elsewhere). A
-        prefix hit PINS the matched blocks in place instead of
-        copying them into a row (admission cost loses the pool→slot
-        gather and the insert copy), donation becomes a pure refcount
-        hand-off of blocks the prefill already wrote, and a shared
-        prefix's KV exists ONCE in HBM no matter how many live slots
-        reference it — which is what roughly doubles effective cache
-        capacity at high prefix sharing. Requires the prefix machinery
-        (``prefix_cache_blocks != 0``); with ``None`` the pool
-        auto-sizes to hold every slot at ``max_len`` plus shared
-        headroom, and an explicit size must cover
-        ``max_slots * ceil(max_len/block_size) + 1`` so a live stream
-        can never starve for a writable block. Token-exact against the
-        resident-row engine (the oracle) for every family/quant
-        config; same drain/replay/chaos contracts.
+      paged: accepts only ``True`` (the module docstring's engine is
+        the only one; callers written against the two-engine
+        constructor still pass it). ``False`` raises: the resident-row
+        engine was removed in PR 34.
       host_tier: TIERED KV CACHE (module docstring, ISSUE 13): a
         :class:`~pddl_tpu.serve.kvcache.HostTierConfig` (or a plain
         int byte budget) arming the host-RAM spill tier under the
@@ -366,9 +347,9 @@ class ServeEngine:
         instead of freeing them, and admission promotes host-tier hits
         back through the ``host_promote`` program, charged against the
         prefill budget at ``promote_tokens_per_block`` per block.
-        Requires the prefix machinery; refused (for now) alongside
-        ``spec_draft_model`` — a promoted block carries target K/V
-        only, and the draft tree's twin block would be junk. ``None``
+        Refused (for now) alongside ``spec_draft_model`` — a promoted
+        block carries target K/V only, and the draft tree's twin block
+        would be junk. ``None``
         (default) or byte budget 0 disables the tier with a
         bit-identical engine (same compiled-program set, same tokens —
         the cold-path contract `tests/test_kv_tier.py` pins).
@@ -403,7 +384,7 @@ class ServeEngine:
         runtime ``[S, V]`` array ahead of the batched sampler; FSM
         state re-derives from emitted tokens, so replay/drain/
         migration stay token-exact). The v1 adaptation target is the
-        LM HEAD, which keeps KV adapter-invariant — prefix/paged KV
+        LM HEAD, which keeps KV adapter-invariant — prefix KV
         sharing stays valid ACROSS tenants. ``None`` (default) compiles
         the plain programs: a non-tenant engine pays nothing.
       spec_k: SPECULATIVE SERVING (module docstring, ISSUE 12): draft
@@ -419,8 +400,8 @@ class ServeEngine:
       spec_ngram: the n-gram drafter's lookup key length (the shared
         :func:`~pddl_tpu.models.speculative.ngram_drafts` definition —
         one drafter for the one-shot and serving paths).
-      spec_draft_model / spec_draft_variables: optional DRAFT MODEL
-        (paged engines only): a small ``generate()``-compatible model
+      spec_draft_model / spec_draft_variables: optional DRAFT MODEL:
+        a small ``generate()``-compatible model
         whose per-slot KV rides the same block pool as a second cache
         tree — same block ids, same tables, same radix sharing/dedup
         (draft K/V is position-absolute and token-pure exactly like
@@ -455,7 +436,7 @@ class ServeEngine:
                  prefix_cache_blocks: Optional[int] = None,
                  prefix_block_size: int = 8,
                  prefix_chunk: Optional[int] = None,
-                 paged: bool = False,
+                 paged: bool = True,
                  host_tier=None,
                  fault_plan=None, max_retries: int = 3,
                  retry_backoff_s: float = 0.02,
@@ -469,16 +450,11 @@ class ServeEngine:
                  tracer=None, telemetry_capacity: int = 512):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        if getattr(model, "uses_ring_cache", False) and not paged:
-            # A paged engine gives every layer the full-length paged
-            # cache (window layers masked and block-skipped to their
-            # band); only the row cache would allocate the ring.
-            raise NotImplementedError(
-                "the row-cache serving engine needs full-length KV "
-                f"caches; sliding_window={model.sliding_window} allocates "
-                "a rolling ring cache whose slot reuse is not supported "
-                "yet (pass paged=True: window layers then live in the "
-                "block pool)")
+        if paged is not True:
+            raise ValueError(
+                f"paged={paged!r}: the resident-row engine was removed "
+                "in PR 34; ServeEngine is the paged engine (the block "
+                "pool is the KV cache) and accepts only paged=True")
         self.model = model
         self.max_slots = int(max_slots)
         self.prefill_len = int(prefill_len if prefill_len is not None
@@ -536,7 +512,7 @@ class ServeEngine:
         self._snapshot: Optional[Dict[str, object]] = None
         self._prev_handlers: Dict[int, object] = {}
 
-        # Prefix-cache configuration (static — the compiled programs'
+        # Block-pool configuration (static — the compiled programs'
         # shapes derive from these).
         bs = int(prefix_block_size)
         if bs < 1:
@@ -549,74 +525,52 @@ class ServeEngine:
         self._donate_cap = self.prefill_len // bs
         chunk = (int(prefix_chunk) if prefix_chunk is not None
                  else max(bs, self.prefill_len // 4))
-        self._paged = bool(paged)
-        # Paged mode: T table entries cover every position a stream can
-        # reach; the pool must hold at least one writable block per
-        # live position-block plus the scratch sink, or a decode tick
-        # could starve mid-stream.
+        # T table entries cover every position a stream can reach; the
+        # pool must hold at least one writable block per live
+        # position-block plus the scratch sink, or a decode tick could
+        # starve mid-stream.
         self._table_width = -(-model.max_len // bs)
-        paged_floor = self.max_slots * self._table_width + 1
+        pool_floor = self.max_slots * self._table_width + 1
         if prefix_cache_blocks is None:
-            if self._paged:
-                # Live worst case + the same shared-cache headroom the
-                # copy engine's default bought (two prompts per slot).
-                pool_blocks = (paged_floor
-                               + 2 * self.max_slots * max(self._donate_cap,
-                                                          1))
-            else:
-                pool_blocks = (2 * self.max_slots * max(self._donate_cap, 1)
-                               + 1) if self._match_cap >= 1 else 0
+            # Live worst case + shared-cache headroom (two prompts per
+            # slot).
+            pool_blocks = (pool_floor
+                           + 2 * self.max_slots * max(self._donate_cap, 1))
         else:
             pool_blocks = int(prefix_cache_blocks)
-        self._prefix_on = pool_blocks > 0
-        if self._paged:
-            if not self._prefix_on:
-                raise ValueError(
-                    "paged=True needs the block-pool machinery; "
-                    "prefix_cache_blocks=0 disables it")
-            if pool_blocks < paged_floor:
-                raise ValueError(
-                    f"paged=True needs prefix_cache_blocks >= "
-                    f"{paged_floor} (max_slots * ceil(max_len/"
-                    f"block_size) + scratch) so live streams can never "
-                    f"starve for a writable block; got {pool_blocks}")
-        if self._prefix_on:
-            if self._match_cap < 1:
-                raise ValueError(
-                    f"prefix_block_size {bs} leaves no cacheable block "
-                    f"under prefill_len {self.prefill_len} (need "
-                    f"block_size < prefill_len); pass "
-                    "prefix_cache_blocks=0 to disable prefix caching")
-            if not 1 <= chunk or self.prefill_len + chunk > model.max_len:
-                raise ValueError(
-                    f"prefix_chunk {chunk} needs 1 <= chunk and "
-                    f"prefill_len + chunk <= max_len "
-                    f"({self.prefill_len} + {chunk} > {model.max_len}): "
-                    "a chunk starting at the deepest cached offset would "
-                    "clamp its positions")
-            if pool_blocks < 2:
-                raise ValueError(
-                    f"prefix_cache_blocks must be >= 2 (block 0 is the "
-                    f"reserved scratch sink), got {pool_blocks}")
+        if pool_blocks <= 0:
+            raise ValueError(
+                "the block pool IS the KV cache; prefix_cache_blocks="
+                f"{pool_blocks} would leave the engine none")
+        if pool_blocks < pool_floor:
+            raise ValueError(
+                f"the engine needs prefix_cache_blocks >= "
+                f"{pool_floor} (max_slots * ceil(max_len/"
+                f"block_size) + scratch) so live streams can never "
+                f"starve for a writable block; got {pool_blocks}")
+        if self._match_cap < 1:
+            raise ValueError(
+                f"prefix_block_size {bs} leaves no cacheable block "
+                f"under prefill_len {self.prefill_len} (need "
+                f"block_size < prefill_len)")
+        if not 1 <= chunk or self.prefill_len + chunk > model.max_len:
+            raise ValueError(
+                f"prefix_chunk {chunk} needs 1 <= chunk and "
+                f"prefill_len + chunk <= max_len "
+                f"({self.prefill_len} + {chunk} > {model.max_len}): "
+                "a chunk starting at the deepest cached offset would "
+                "clamp its positions")
         self.prefix_block_size = bs
         self._chunk = chunk
 
         # Chunked-prefill fairness: at most `prefill_slice_tokens` of
         # prompt prefill per step(), the decode tick interleaved
-        # between slices. One slice in flight at a time (the resident
-        # row cache is the single admission pipeline); `_slice` holds
+        # between slices. One slice in flight at a time; `_slice` holds
         # its resumable state across steps.
-        if prefill_slice_tokens is not None:
-            if not self._prefix_on:
-                raise ValueError(
-                    "prefill_slice_tokens requires the prefix-cache "
-                    "engine (its chunk programs are the slicing "
-                    "mechanism); leave prefix_cache_blocks enabled or "
-                    "unset prefill_slice_tokens")
-            if prefill_slice_tokens < 1:
-                raise ValueError(
-                    f"prefill_slice_tokens must be >= 1, got "
-                    f"{prefill_slice_tokens}")
+        if prefill_slice_tokens is not None and prefill_slice_tokens < 1:
+            raise ValueError(
+                f"prefill_slice_tokens must be >= 1, got "
+                f"{prefill_slice_tokens}")
         self._slice_tokens = (int(prefill_slice_tokens)
                               if prefill_slice_tokens is not None else None)
         self._slice: Optional[Dict[str, object]] = None
@@ -650,11 +604,6 @@ class ServeEngine:
                 raise ValueError(
                     "spec_draft_model needs spec_k >= 1 (the draft "
                     "model only exists to fill the verify window)")
-            if not self._paged:
-                raise ValueError(
-                    "spec_draft_model rides the paged KV block pool as "
-                    "a second cache tree; pass paged=True (the n-gram "
-                    "drafter serves resident-row engines)")
             if spec_draft_variables is None:
                 raise ValueError(
                     "spec_draft_model needs spec_draft_variables "
@@ -670,9 +619,8 @@ class ServeEngine:
                     "must cover every position a stream can reach")
             if getattr(spec_draft_model, "uses_ring_cache", False):
                 raise NotImplementedError(
-                    "draft models with rolling ring caches are not "
-                    "supported (same slot-reuse constraint as the "
-                    "target)")
+                    "draft models with sliding-window layers are not "
+                    "supported (the draft tree gets no window masking)")
             self._ddec = spec_draft_model.clone(decode=True)
             self._dparams = spec_draft_variables["params"]
         elif spec_draft_variables is not None:
@@ -764,67 +712,14 @@ class ServeEngine:
 
         dec, pt = self._dec, param_transform
 
-        def _prefill(params, prompt, length):
-            return prefill_row(dec, params, prompt, length,
-                               param_transform=pt)
-
-        def _gather(pool, block_ids, row):
-            # Overwrite the RESIDENT row cache's prefix region
-            # [0, match_cap*bs) with the matched chain (row donated —
-            # the admission pipeline reuses one set of row buffers).
-            # Everything beyond is stale: scratch-padded gather junk,
-            # or the previous admission's K/V — all of it either
-            # overwritten by the suffix chunks or parked beyond the
-            # position counter the slot insert stamps, exactly the
-            # invariant the padded one-shot prefill already relies on.
-            return gather_prefix_into_row(pool, row, block_ids)
-
-        def _chunk_prefill(params, row, tokens, length, start):
-            # One fixed-width suffix chunk continuing the row cache at
-            # global offset `start` (all of length/start runtime values).
-            return prefill_row_from(dec, params, tokens, length, row,
-                                    start, param_transform=pt)
-
-        def _chunk_prefill_wide(params, row, tokens, length, start):
-            # The same computation at the wide width — a DISTINCT
-            # function object, so its jit cache (and compile_counts
-            # entry) never shares entries with the narrow program's
-            # (same reason _insert is a per-engine closure).
-            return prefill_row_from(dec, params, tokens, length, row,
-                                    start, param_transform=pt)
-
-        def _donate(pool, row, block_ids, start_block):
-            return donate_prefix_blocks(pool, row, block_ids, start_block)
-
-        def _tick(params, cache, positions, tokens, temps, top_ks, top_ps,
-                  rng):
-            rng, sub = jax.random.split(rng)
-            cache = set_cache_positions(cache, positions)
-            logits, mutated = dec.apply(
-                {"params": (pt(params) if pt is not None else params),
-                 "cache": cache},
-                tokens[:, None], train=False, mutable=["cache"])
-            nxt = sample_logits_batched(
-                sub, logits[:, -1], temperature=temps, top_k=top_ks,
-                top_p=top_ps)
-            return mutated["cache"], nxt, rng
-
         def _sample_first(logits, temp, top_k, top_p, rng):
             rng, sub = jax.random.split(rng)
             tok = sample_logits_batched(sub, logits, temperature=temp,
                                         top_k=top_k, top_p=top_p)
             return tok, rng
 
-        def _insert(cache, row_cache, slot, position):
-            # A per-engine closure (not the bare module-level function):
-            # jax.jit keyed on the same function object would SHARE its
-            # tracing cache across engines, making compile_counts()
-            # report other instances' pool shapes.
-            return insert_cache_slot(cache, row_cache, slot, position)
-
-        # --- paged program bodies (see the `paged` arg docs) ---
-        # Every paged program stamps the engine-owned positions/tables
-        # on entry and restores CANONICAL placeholders (scalar counter,
+        # Every program stamps the engine-owned positions/tables on
+        # entry and restores CANONICAL placeholders (scalar counter,
         # [1,1] table) on exit, so the donated resident tree keeps one
         # structure across the fused tick and the batch-1 chunk widths
         # — shape-stable donation is what keeps the set at zero
@@ -856,8 +751,9 @@ class ServeEngine:
             return _canon_paged(cache), logits
 
         def _chunk_paged_wide(params, cache, tokens, length, start, table):
-            # Distinct function object for a distinct compile_counts
-            # entry, like the row-mode wide chunk.
+            # The same computation at the wide width — a DISTINCT
+            # function object, so its jit cache (and compile_counts
+            # entry) never shares entries with the narrow program's.
             cache = set_cache_block_tables(cache, table)
             cache, logits = prefill_row_from(dec, params, tokens, length,
                                              cache, start,
@@ -883,9 +779,10 @@ class ServeEngine:
                 return tok, rng
 
             def _adapter_load(pool_a, pool_b, row, a, b):
-                # Per-engine closure (the _insert rationale): a shared
-                # module-level jit would mix pool shapes across engines
-                # in compile_counts.
+                # A per-engine closure (not the bare module-level
+                # function): jax.jit keyed on the same function object
+                # would SHARE its tracing cache across engines, making
+                # compile_counts() report other instances' pool shapes.
                 return adapter_pool_load(pool_a, pool_b, row, a, b)
 
             def _tick_body(params, cache, tokens, temps, top_ks, top_ps,
@@ -903,15 +800,6 @@ class ServeEngine:
                     temperature=temps, top_k=top_ks, top_p=top_ps)
                 return mutated["cache"], nxt
 
-            def _tick_t(params, cache, positions, tokens, temps, top_ks,
-                        top_ps, masks, pool_a, pool_b, arows, rng):
-                rng, sub = jax.random.split(rng)
-                cache = set_cache_positions(cache, positions)
-                cache, nxt = _tick_body(params, cache, tokens, temps,
-                                        top_ks, top_ps, masks, pool_a,
-                                        pool_b, arows, sub)
-                return cache, nxt, rng
-
             def _tick_paged_t(params, cache, positions, tables, tokens,
                               temps, top_ks, top_ps, masks, pool_a,
                               pool_b, arows, rng):
@@ -928,27 +816,6 @@ class ServeEngine:
                     last_feats, pool_a, pool_b,
                     jnp.full((1,), aid, jnp.int32))
 
-            def _prefill_t(params, prompt, length, aid, pool_a, pool_b):
-                cache, last, lf = prefill_row_features(
-                    dec, params, prompt, length, None, 0,
-                    param_transform=pt)
-                return cache, _lora1(last, lf, pool_a, pool_b, aid)
-
-            def _chunk_t(params, row, tokens, length, start, aid,
-                         pool_a, pool_b):
-                row, last, lf = prefill_row_features(
-                    dec, params, tokens, length, row, start,
-                    param_transform=pt)
-                return row, _lora1(last, lf, pool_a, pool_b, aid)
-
-            def _chunk_wide_t(params, row, tokens, length, start, aid,
-                              pool_a, pool_b):
-                # Distinct function object (wide-program discipline).
-                row, last, lf = prefill_row_features(
-                    dec, params, tokens, length, row, start,
-                    param_transform=pt)
-                return row, _lora1(last, lf, pool_a, pool_b, aid)
-
             def _chunk_paged_t(params, cache, tokens, length, start,
                                table, aid, pool_a, pool_b):
                 cache = set_cache_block_tables(cache, table)
@@ -960,6 +827,7 @@ class ServeEngine:
 
             def _chunk_paged_wide_t(params, cache, tokens, length, start,
                                     table, aid, pool_a, pool_b):
+                # Distinct function object (wide-program discipline).
                 cache = set_cache_block_tables(cache, table)
                 cache, last, lf = prefill_row_features(
                     dec, params, tokens, length, cache, start,
@@ -992,18 +860,6 @@ class ServeEngine:
                     sub, logits[:, 0], temperature=temps, top_k=top_ks,
                     top_p=top_ps)
                 return y.at[:, 0].set(first), acc
-
-            def _verify(params, cache, positions, block, temps, top_ks,
-                        top_ps, caps, forced, rng):
-                rng, sub = jax.random.split(rng)
-                cache = set_cache_positions(cache, positions)
-                logits, mutated = dec.apply(
-                    {"params": (pt(params) if pt is not None else params),
-                     "cache": cache},
-                    block, train=False, mutable=["cache"])
-                w, acc = _verify_core(logits, block, temps, top_ks,
-                                      top_ps, caps, forced, sub)
-                return mutated["cache"], w, acc, rng
 
             def _verify_paged(params, cache, positions, tables, block,
                               temps, top_ks, top_ps, caps, forced, rng):
@@ -1041,16 +897,6 @@ class ServeEngine:
                     w, acc = _verify_core(logits, block, temps, top_ks,
                                           top_ps, caps, forced, sub)
                     return mutated["cache"], w, acc
-
-                def _verify_t(params, cache, positions, block, temps,
-                              top_ks, top_ps, masks, pool_a, pool_b,
-                              arows, caps, forced, rng):
-                    rng, sub = jax.random.split(rng)
-                    cache = set_cache_positions(cache, positions)
-                    cache, w, acc = _verify_body_t(
-                        params, cache, block, temps, top_ks, top_ps,
-                        masks, pool_a, pool_b, arows, caps, forced, sub)
-                    return cache, w, acc, rng
 
                 def _verify_paged_t(params, cache, positions, tables,
                                     block, temps, top_ks, top_ps, masks,
@@ -1111,29 +957,22 @@ class ServeEngine:
                                                  length, dcache, start)
                     return _canon_paged(dcache)
 
-        # The resident programs (four without prefix caching; gather /
-        # chunk-prefill / donate replace the one-shot prefill with it
-        # on; in PAGED mode the set shrinks to tick + chunk widths +
-        # sample_first — no gather, no insert, no donate scatter: the
-        # prefill writes K/V in place and sharing is pure host
-        # bookkeeping). Donation discipline: the pooled slot cache (or
-        # the paged pool tree) is donated through every program that
-        # touches it — the engine always adopts the returned trees, so
-        # the resident HBM buffers are reused in place and a stale
-        # reference can never be used by mistake.
-        self._donated_by_site = dict(_PAGED_DONATED_BY_SITE if self._paged
-                                     else _DONATED_BY_SITE)
-        if self._spec_on:
-            self._donated_by_site.update(
-                _SPEC_DONATED_PAGED if self._paged else _SPEC_DONATED_ROW)
-            if self._draft_on:
-                # The draft-MODEL program donates the draft tree (the
-                # n-gram program donates nothing, so this entry exists
-                # only with a draft model): a REAL mid-dispatch error
-                # must never re-dispatch the consumed dcache — it
-                # escalates straight to the pool-class rebuild, which
-                # reconstructs both trees.
-                self._donated_by_site["draft"] = "pool"
+        # The resident programs: tick + chunk widths + sample_first —
+        # the prefill writes K/V in place and sharing is pure host
+        # bookkeeping. Donation discipline: the pool tree is donated
+        # through every program that touches it — the engine always
+        # adopts the returned trees, so the resident HBM buffers are
+        # reused in place and a stale reference can never be used by
+        # mistake.
+        self._donated_by_site = dict(_DONATED_BY_SITE)
+        if self._draft_on:
+            # The draft-MODEL program donates the draft tree (the
+            # n-gram program donates nothing, so this entry exists
+            # only with a draft model): a REAL mid-dispatch error
+            # must never re-dispatch the consumed dcache — it
+            # escalates straight to the pool-class rebuild, which
+            # reconstructs both trees.
+            self._donated_by_site["draft"] = "pool"
         ten = self._tenant_on
         self._sample_first_p = jax.jit(_sample_first_t if ten
                                        else _sample_first)
@@ -1141,114 +980,69 @@ class ServeEngine:
         # ops/lora.adapter_pool_load), so a faulted load retries
         # against the intact pool like any transient site.
         self._adapter_load_p = jax.jit(_adapter_load) if ten else None
-        if self._paged:
-            self._insert_p = None
-            self._tick_p = jax.jit(_tick_paged_t if ten else _tick_paged,
-                                   donate_argnums=(1,))
-            self._gather_p = None
-            self._chunk_p = jax.jit(_chunk_paged_t if ten else _chunk_paged,
-                                    donate_argnums=(1,))
-            self._has_wide = self._wide_program_pays(model.max_len)
-            self._chunk_wide_p = (jax.jit(_chunk_paged_wide_t if ten
-                                          else _chunk_paged_wide,
-                                          donate_argnums=(1,))
-                                  if self._has_wide else None)
-            self._donate_p = None
-            self._pool = None
-            self._prefix = RadixPrefixCache(bs, pool_blocks)
-            self._row = None
-            self._cache = paged_decode_cache(dec, pool_blocks, bs)
-            # Host-authoritative per-slot block tables (scratch-filled
-            # for parked slots) and the private (not-yet-shared) block
-            # ids each slot owns.
-            self._tables = np.zeros(
-                (self.max_slots, self._table_width), np.int32)
-            self._private: List[List[int]] = [
-                [] for _ in range(self.max_slots)]
-            # KV bytes one token occupies across every leaf — what one
-            # avoided gather copy is worth (`copy_bytes_avoided`).
-            kv_bytes = sum(
-                int(leaf.size) * leaf.dtype.itemsize
-                for path, leaf in jax.tree_util.tree_leaves_with_path(
-                    self._cache)
-                if leaf.ndim > 2)
-            self._kv_token_bytes = kv_bytes // (pool_blocks * bs)
-            self._verify_p = self._draft_p = self._dchunk_p = None
-            self._draft_model_p = None
-            self._dcache = None
-            if self._spec_on:
-                self._verify_p = jax.jit(
-                    _verify_paged_t if ten else _verify_paged,
-                    donate_argnums=(1,))
-                if self._draft_on:
-                    # A DISTINCT attribute from the (non-donating)
-                    # n-gram program: this one donates the draft tree.
-                    self._draft_model_p = jax.jit(_draft_model_fn,
-                                                  donate_argnums=(1,))
-                    self._dchunk_p = jax.jit(_draft_chunk,
-                                             donate_argnums=(1,))
-                    # The second cache tree riding the same pool: one
-                    # block-id space, one table, two KV trees (target +
-                    # draft) — sharing, dedup, flush, and reset all act
-                    # on both through the same ids.
-                    self._dcache = paged_decode_cache(self._ddec,
-                                                      pool_blocks, bs)
-                else:
-                    self._draft_p = jax.jit(_draft_ngram)
-            self._init_host_tier(host_tier)
-            self._warm = False
-            if tracer is not None:
-                self.set_tracer(tracer)
-            return
-        self._insert_p = jax.jit(_insert, donate_argnums=(0,))
-        self._tick_p = jax.jit(_tick_t if ten else _tick,
+        self._tick_p = jax.jit(_tick_paged_t if ten else _tick_paged,
                                donate_argnums=(1,))
-        if self._prefix_on:
-            self._prefill_p = None
-            self._gather_p = jax.jit(_gather, donate_argnums=(2,))
-            self._chunk_p = jax.jit(_chunk_t if ten else _chunk_prefill,
-                                    donate_argnums=(1,))
-            # A second, WIDE chunk program (full prefill_len) for cold /
-            # barely-cached prompts: one fixed per-apply cost instead of
-            # ceil(plen/chunk) of them, so enabling the prefix cache
-            # never slows a cold admission below the one-shot prefill.
-            # Two separate jits (not two shapes through one jit) keep
-            # the one-executable-per-program pin meaningful. The wide
-            # program can start as deep as prefill_len/4 (the width
-            # policy's threshold), so it also needs its positions to
-            # stay in range at that offset.
-            self._has_wide = self._wide_program_pays(model.max_len)
-            self._chunk_wide_p = (jax.jit(_chunk_wide_t if ten
-                                          else _chunk_prefill_wide,
-                                          donate_argnums=(1,))
-                                  if self._has_wide else None)
-            self._donate_p = jax.jit(_donate, donate_argnums=(0,))
-            self._pool = kv_block_pool(dec, pool_blocks, bs)
-            self._prefix = RadixPrefixCache(bs, pool_blocks)
-            # The resident admission row cache: donated through gather
-            # and every chunk, adopted back each time — one set of
-            # batch-1 buffers serves every admission.
-            self._row = jax.tree.map(
-                lambda sd: jnp.zeros(sd.shape, sd.dtype),
-                _decode_cache_shapes(dec, 1))
-        else:
-            self._prefill_p = jax.jit(_prefill_t if ten else _prefill)
-            self._gather_p = self._chunk_p = self._donate_p = None
-            self._chunk_wide_p = None
-            self._has_wide = False
-            self._pool = None
-            self._prefix = None
-            self._row = None
-
+        self._chunk_p = jax.jit(_chunk_paged_t if ten else _chunk_paged,
+                                donate_argnums=(1,))
+        # A second, WIDE chunk program (full prefill_len) for cold /
+        # barely-cached prompts: one fixed per-apply cost instead of
+        # ceil(plen/chunk) of them. Two separate jits (not two shapes
+        # through one jit) keep the one-executable-per-program pin
+        # meaningful. The wide program can start as deep as
+        # prefill_len/4 (the width policy's threshold), so it also
+        # needs its positions to stay in range at that offset.
+        self._has_wide = self._wide_program_pays(model.max_len)
+        self._chunk_wide_p = (jax.jit(_chunk_paged_wide_t if ten
+                                      else _chunk_paged_wide,
+                                      donate_argnums=(1,))
+                              if self._has_wide else None)
+        self._prefix = RadixPrefixCache(bs, pool_blocks)
+        self._cache = paged_decode_cache(dec, pool_blocks, bs)
+        # Host-authoritative per-slot block tables (scratch-filled
+        # for parked slots) and the private (not-yet-shared) block
+        # ids each slot owns.
+        self._tables = np.zeros(
+            (self.max_slots, self._table_width), np.int32)
+        self._private: List[List[int]] = [
+            [] for _ in range(self.max_slots)]
+        # KV bytes one token occupies across every leaf — what one
+        # avoided gather copy is worth (`copy_bytes_avoided`).
+        kv_bytes = sum(
+            int(leaf.size) * leaf.dtype.itemsize
+            for path, leaf in jax.tree_util.tree_leaves_with_path(
+                self._cache)
+            if leaf.ndim > 2)
+        self._kv_token_bytes = kv_bytes // (pool_blocks * bs)
         self._verify_p = self._draft_p = self._dchunk_p = None
         self._draft_model_p = None
         self._dcache = None
         if self._spec_on:
-            self._verify_p = jax.jit(_verify_t if ten else _verify,
-                                     donate_argnums=(1,))
-            self._draft_p = jax.jit(_draft_ngram)
-        self._cache = slot_decode_cache(dec, self.max_slots)
+            self._verify_p = jax.jit(
+                _verify_paged_t if ten else _verify_paged,
+                donate_argnums=(1,))
+            if self._draft_on:
+                # A DISTINCT attribute from the (non-donating)
+                # n-gram program: this one donates the draft tree.
+                self._draft_model_p = jax.jit(_draft_model_fn,
+                                              donate_argnums=(1,))
+                self._dchunk_p = jax.jit(_draft_chunk,
+                                         donate_argnums=(1,))
+                # The second cache tree riding the same pool: one
+                # block-id space, one table, two KV trees (target +
+                # draft) — sharing, dedup, flush, and reset all act
+                # on both through the same ids.
+                self._dcache = paged_decode_cache(self._ddec,
+                                                  pool_blocks, bs)
+            else:
+                self._draft_p = jax.jit(_draft_ngram)
         self._init_host_tier(host_tier)
+        # Only the sites THIS engine compiled stay in the map (a
+        # speculative engine has no tick, an n-gram one no
+        # draft_prefill): its keys are `compile_counts()` keys.
+        compiled = self.compile_counts()
+        self._donated_by_site = {
+            site: tree for site, tree in self._donated_by_site.items()
+            if site in compiled}
         self._warm = False
         if tracer is not None:
             self.set_tracer(tracer)
@@ -1288,20 +1082,14 @@ class ServeEngine:
                else HostTierConfig(byte_budget=int(host_tier)))
         if cfg.byte_budget == 0:
             return
-        if not self._prefix_on:
-            raise ValueError(
-                "host_tier needs the prefix-cache machinery (the radix "
-                "eviction path is what demotes); leave "
-                "prefix_cache_blocks enabled or pass host_tier=None")
         if self._draft_on:
             raise NotImplementedError(
                 "host_tier with spec_draft_model is not supported yet: "
                 "a promoted block carries target K/V only, and the "
                 "draft tree's twin block would be junk — mirroring the "
                 "second cache tree through the tier is follow-on work")
-        target = self._cache if self._paged else self._pool
         spec = {}
-        for path, leaf in jax.tree_util.tree_leaves_with_path(target):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache):
             if leaf.ndim < 3:
                 continue
             spec[jax.tree_util.keystr(path)] = (
@@ -1319,7 +1107,7 @@ class ServeEngine:
             # KV leaf over the donated pool tree, no model compute;
             # padded ids land their junk in the scratch sink, and
             # non-KV leaves (counters, tables) pass through untouched
-            # so the paged tree keeps its canonical placeholders.
+            # so the tree keeps its canonical placeholders.
             def _s(path, pool_leaf, row_leaf):
                 if pool_leaf.ndim < 3:
                     return pool_leaf
@@ -1328,13 +1116,13 @@ class ServeEngine:
 
         self._promote_p = jax.jit(_host_promote, donate_argnums=(0,))
         # A REAL mid-dispatch promotion error may have consumed the
-        # donated pool tree — recovery is the pool-class rebuild
-        # (paged: the full live-slot replay), like donate/chunk.
+        # donated pool tree — recovery is the pool rebuild (the full
+        # live-slot replay), like a chunk's.
         self._donated_by_site["host_promote"] = "pool"
 
         def _host_demote(pool, ids):
-            # The D2H read, same primitive as the admission gather
-            # (`ops.attention.cache_blocks_gather`) but jitted over
+            # The D2H read (`ops.attention.cache_blocks_gather`),
+            # jitted over
             # the whole tree at a FIXED scratch-padded id width: the
             # reclaim batch becomes ONE dispatch that traces once
             # (per-leaf eager gathers re-specialize per batch width
@@ -1461,11 +1249,11 @@ class ServeEngine:
     # ---------------------------------------------------------- plumbing
     def warmup(self) -> None:
         """Trace/compile every resident program before traffic (one
-        dummy admission into slot 0 + one all-dead tick; the junk K/V
-        lands at parked positions and is overwritten by the first real
-        admit — the dummy gather/donate use only the scratch block, so
-        the radix index stays empty). Implicit on the first ``step()``
-        if not called."""
+        dummy chunk per width + one all-dead tick through all-scratch
+        tables: every warmup write lands in the junk sink, the radix
+        index stays empty, and every program traces once with its
+        serving shapes). Implicit on the first ``step()`` if not
+        called."""
         if self._warm:
             return
         first_mask = self._first_mask_args(None)  # () on a plain engine
@@ -1479,68 +1267,23 @@ class ServeEngine:
                          np.float32),
                 np.zeros((self._registry.rank, self.model.vocab_size),
                          np.float32))
-        if self._paged:
-            # All-scratch tables: every warmup write lands in the junk
-            # sink, the radix index stays empty, and every program
-            # traces once with its serving shapes.
-            self._cache, logits = self._chunk_p(
-                *self._chunk_args_paged(self._chunk))
-            if self._has_wide:
-                self._cache, logits = self._chunk_wide_p(
-                    *self._chunk_args_paged(self.prefill_len))
-            tok, self._rng = self._sample_first_p(
-                logits, *first_mask, np.float32(0.0), np.int32(0),
-                np.float32(2.0), self._rng)
-            if self._host is not None:
-                # All-scratch promote: junk lands in the sink, the
-                # host tier stays empty, the program traces once —
-                # and the demote gather's one program likewise.
-                self._cache = self._promote_p(
-                    self._cache, self._assemble_promote_rows([]),
-                    np.zeros(self._match_cap, np.int32))
-                self._demote_p(self._cache,
-                               np.zeros(self._match_cap, np.int32))
-            if self._spec_on:
-                nxt = self._warm_spec()
-            else:
-                self._cache, nxt, self._rng = self._tick_p(
-                    *self._tick_args())
-            jax.block_until_ready((tok, nxt))
-            self._warm = True
-            return
-        if self._prefix_on:
-            row = self._gather_p(
-                self._pool, np.zeros(self._match_cap, np.int32),
-                self._row)
-            row, logits = self._chunk_p(
-                self._params, row, np.zeros((1, self._chunk), np.int32),
-                np.int32(1), np.int32(0), *self._chunk_extra(0))
-            if self._has_wide:
-                row, logits = self._chunk_wide_p(
-                    self._params, row,
-                    np.zeros((1, self.prefill_len), np.int32),
-                    np.int32(1), np.int32(0), *self._chunk_extra(0))
-            self._pool = self._donate_p(
-                self._pool, row, np.zeros(self._donate_cap, np.int32),
-                np.int32(0))
-            self._row = row
-            if self._host is not None:
-                # All-scratch promote (the paged branch's twin): the
-                # host_promote program traces once at warmup too,
-                # and the demote gather's one program likewise.
-                self._pool = self._promote_p(
-                    self._pool, self._assemble_promote_rows([]),
-                    np.zeros(self._match_cap, np.int32))
-                self._demote_p(self._pool,
-                               np.zeros(self._match_cap, np.int32))
-        else:
-            dummy = np.zeros((1, self.prefill_len), np.int32)
-            row, logits = self._prefill_p(self._params, dummy, 1,
-                                          *self._chunk_extra(0))
-        self._cache = self._insert_p(self._cache, row, 0, 0)
+        self._cache, logits = self._chunk_p(
+            *self._chunk_args_paged(self._chunk))
+        if self._has_wide:
+            self._cache, logits = self._chunk_wide_p(
+                *self._chunk_args_paged(self.prefill_len))
         tok, self._rng = self._sample_first_p(
             logits, *first_mask, np.float32(0.0), np.int32(0),
             np.float32(2.0), self._rng)
+        if self._host is not None:
+            # All-scratch promote: junk lands in the sink, the host
+            # tier stays empty, the program traces once — and the
+            # demote gather's one program likewise.
+            self._cache = self._promote_p(
+                self._cache, self._assemble_promote_rows([]),
+                np.zeros(self._match_cap, np.int32))
+            self._demote_p(self._cache,
+                           np.zeros(self._match_cap, np.int32))
         if self._spec_on:
             nxt = self._warm_spec()
         else:
@@ -1552,13 +1295,12 @@ class ServeEngine:
         """The one-token tick's arguments as they stand now. Warmup,
         ``step()`` and :meth:`tick_lowering` all build them here, so the
         three can never disagree on the program's signature."""
-        tables = (self._tables,) if self._paged else ()
-        return (self._params, self._cache, self._positions, *tables,
+        return (self._params, self._cache, self._positions, self._tables,
                 self._tokens, self._temps, self._top_ks, self._top_ps,
                 *self._tick_extra(), self._rng)
 
     def _chunk_args_paged(self, width: int) -> tuple:
-        """A paged chunk program's arguments for one real token at
+        """A chunk program's arguments for one real token at
         offset 0 through an all-scratch table: what warmup dispatches
         and :meth:`program_lowerings` lowers."""
         t1 = np.zeros((1, self._table_width), np.int32)
@@ -1578,8 +1320,8 @@ class ServeEngine:
         return self._tick_p.lower(*self._tick_args())
 
     def program_lowerings(self) -> Dict[str, object]:
-        """The tick and (paged engines) the chunk programs LOWERED at
-        their serving shapes, by site name. ``.compile().as_text()``
+        """The tick and the chunk programs LOWERED at their serving
+        shapes, by site name. ``.compile().as_text()``
         names every instruction with the scope it came from
         (``metadata={op_name=...}``: the flax module path and the
         ``jax.named_scope`` names — ``moe_router``, ``moe_dispatch``,
@@ -1587,19 +1329,18 @@ class ServeEngine:
         which is how a device trace's op names, which carry no scope,
         are put down to a scope."""
         out = {"tick": self.tick_lowering()}
-        if self._paged:
-            out["chunk_prefill"] = self._chunk_p.lower(
-                *self._chunk_args_paged(self._chunk))
-            if self._has_wide:
-                out["chunk_prefill_wide"] = self._chunk_wide_p.lower(
-                    *self._chunk_args_paged(self.prefill_len))
+        out["chunk_prefill"] = self._chunk_p.lower(
+            *self._chunk_args_paged(self._chunk))
+        if self._has_wide:
+            out["chunk_prefill_wide"] = self._chunk_wide_p.lower(
+                *self._chunk_args_paged(self.prefill_len))
         return out
 
     def expert_load(self) -> Dict[str, np.ndarray]:
         """Routed pairs of PROMPT tokens per expert, by routed layer
         (``"block3/moe"`` -> int ``[experts]``), accumulated on the
         device by the chunk programs since the pool was built; empty
-        for a model without routed layers or a row-cache engine. One
+        for a model without routed layers. One
         device read: call it at a window's edges, not in the loop."""
         from pddl_tpu.ops.moe import EXPERT_LOAD_KEY
 
@@ -1613,10 +1354,9 @@ class ServeEngine:
     def _warm_spec(self):
         """Trace the draft/verify pair (and the draft model's admission
         chunk) with all-dead inputs: caps 0 + forced -1 accept nothing,
-        junk writes land at parked positions (row mode) or the scratch
-        sink (paged all-scratch tables), so warmup leaves no trace in
-        any live state. Returns the verify window for the caller's
-        block_until_ready."""
+        junk writes land in the scratch sink (all-scratch tables), so
+        warmup leaves no trace in any live state. Returns the verify
+        window for the caller's block_until_ready."""
         s, k = self.max_slots, self._spec_k
         forced_tok = np.zeros((s, k), np.int32)
         forced_n = np.full(s, -1, np.int32)
@@ -1633,86 +1373,49 @@ class ServeEngine:
             drafts = self._draft_p(self._hist, self._positions)
         block = np.zeros((s, k + 1), np.int32)
         caps = np.zeros(s, np.int32)
-        if self._paged:
-            self._cache, w, acc, self._rng = self._verify_p(
-                self._params, self._cache, self._positions, self._tables,
-                block, self._temps, self._top_ks, self._top_ps,
-                *self._verify_extra(), caps, forced_n, self._rng)
-        else:
-            self._cache, w, acc, self._rng = self._verify_p(
-                self._params, self._cache, self._positions, block,
-                self._temps, self._top_ks, self._top_ps,
-                *self._verify_extra(), caps, forced_n, self._rng)
+        self._cache, w, acc, self._rng = self._verify_p(
+            self._params, self._cache, self._positions, self._tables,
+            block, self._temps, self._top_ks, self._top_ps,
+            *self._verify_extra(), caps, forced_n, self._rng)
         jax.block_until_ready(drafts)
         return w
 
     def compile_counts(self) -> Dict[str, int]:
         """Compiled-executable count per resident program (the
         zero-recompiles-after-warmup contract: every entry stays at 1).
-        With prefix caching on, admission runs gather → N×chunk-prefill
-        → donate instead of the one-shot prefill — chunk width, block-id
-        vector lengths, and every offset/length are fixed shapes or
-        runtime values, so the program set stays closed here too."""
-        if self._paged:
-            counts = {
-                "sample_first": self._sample_first_p._cache_size(),
-                "chunk_prefill": self._chunk_p._cache_size(),
-            }
-            if self._spec_on:
-                # Speculative engines swap the one-token tick for the
-                # draft/verify pair (+ the draft model's admission
-                # chunk) — the site vocabulary graftlint keeps in
-                # lockstep with FaultPlan.SITES.
-                counts["verify"] = self._verify_p._cache_size()
-                counts["draft"] = (self._draft_model_p if self._draft_on
-                                   else self._draft_p)._cache_size()
-                if self._draft_on:
-                    counts["draft_prefill"] = \
-                        self._dchunk_p._cache_size()
-            else:
-                counts["tick"] = self._tick_p._cache_size()
-            if self._has_wide:
-                counts["chunk_prefill_wide"] = \
-                    self._chunk_wide_p._cache_size()
-            if self._tenant_on:
-                counts["adapter_load"] = \
-                    self._adapter_load_p._cache_size()
-            if self._host is not None:
-                counts["host_promote"] = self._promote_p._cache_size()
-            return counts
+        Chunk widths, table shapes, and every offset/length are fixed
+        shapes or runtime values, so the program set stays closed."""
         counts = {
-            "insert": self._insert_p._cache_size(),
             "sample_first": self._sample_first_p._cache_size(),
+            "chunk_prefill": self._chunk_p._cache_size(),
         }
         if self._spec_on:
+            # Speculative engines swap the one-token tick for the
+            # draft/verify pair (+ the draft model's admission
+            # chunk) — the site vocabulary graftlint keeps in
+            # lockstep with FaultPlan.SITES.
             counts["verify"] = self._verify_p._cache_size()
-            counts["draft"] = self._draft_p._cache_size()
+            counts["draft"] = (self._draft_model_p if self._draft_on
+                               else self._draft_p)._cache_size()
+            if self._draft_on:
+                counts["draft_prefill"] = self._dchunk_p._cache_size()
         else:
             counts["tick"] = self._tick_p._cache_size()
+        if self._has_wide:
+            counts["chunk_prefill_wide"] = \
+                self._chunk_wide_p._cache_size()
         if self._tenant_on:
             counts["adapter_load"] = self._adapter_load_p._cache_size()
-        if self._prefix_on:
-            counts["gather"] = self._gather_p._cache_size()
-            counts["chunk_prefill"] = self._chunk_p._cache_size()
-            if self._has_wide:
-                counts["chunk_prefill_wide"] = \
-                    self._chunk_wide_p._cache_size()
-            counts["donate"] = self._donate_p._cache_size()
-            if self._host is not None:
-                counts["host_promote"] = self._promote_p._cache_size()
-        else:
-            counts["prefill"] = self._prefill_p._cache_size()
+        if self._host is not None:
+            counts["host_promote"] = self._promote_p._cache_size()
         return counts
 
     @property
-    def prefix_cache_enabled(self) -> bool:
-        return self._prefix_on
-
-    @property
     def paged(self) -> bool:
-        """True when decode reads K/V straight from the block pool
-        through per-slot block tables (no resident slot cache)."""
-        return self._paged
+        """Always True: decode reads K/V straight from the block pool
+        through per-slot block tables (kept for the exposition's
+        ``paged`` series and callers of the two-engine era)."""
+        return True
 
     @property
     def host_tier_enabled(self) -> bool:
@@ -1918,11 +1621,8 @@ class ServeEngine:
     @property
     def blocks_shared(self) -> int:
         """Pool blocks referenced by MORE THAN ONE live slot's block
-        table right now — each is one block of KV the copy engine
-        would have duplicated per referencing slot. 0 outside paged
-        mode."""
-        if not self._paged:
-            return 0
+        table right now — each is one block of KV a private-copy
+        design would have duplicated per referencing slot."""
         live = [sid for sid, h in enumerate(self._slots) if h is not None]
         if len(live) < 2:
             return 0
@@ -1934,52 +1634,11 @@ class ServeEngine:
         ids, counts = np.unique(rows[rows != 0], return_counts=True)
         return int((counts > 1).sum())
 
-    def resident_kv_report(self) -> Dict[str, int]:
-        """Live-stream KV accounting, comparable across engine modes
-        (the capacity half of `benchmarks/serve_bench.py --paged-only`):
-
-        - ``tokens_resident``: summed depth of every live stream — the
-          user-visible context currently held, identical for both
-          modes at the same workload snapshot.
-        - ``kv_bytes_used``: HBM actually holding that state. The
-          resident-row engine pays each live slot's depth PRIVATELY
-          plus one pool copy of every cached block; the paged engine
-          pays each DISTINCT referenced block once — shared prefixes
-          collapse, which is the whole point.
-        - ``kv_bytes_allocated``: the reserved footprint (slot cache +
-          pool, or the paged pool tree).
-        """
-        live = [sid for sid, h in enumerate(self._slots) if h is not None]
-        if self._paged:
-            tokens = int(sum(int(self._positions[sid]) for sid in live))
-            distinct = set()
-            for sid in live:
-                distinct.update(
-                    int(b) for b in self._tables[sid] if b != 0)
-            used = len(distinct) * self.prefix_block_size \
-                * self._kv_token_bytes
-            return {"tokens_resident": tokens, "kv_bytes_used": used,
-                    "kv_bytes_allocated": pool_nbytes(self._cache)}
-        cache_bytes = pool_nbytes(self._cache)
-        token_bytes = cache_bytes // (self.max_slots * self.model.max_len)
-        tokens = int(sum(int(self._positions[sid]) for sid in live))
-        used = tokens * token_bytes
-        allocated = cache_bytes
-        if self._prefix_on:
-            used += (self._prefix.blocks_live * self.prefix_block_size
-                     * token_bytes)
-            allocated += pool_nbytes(self._pool)
-        return {"tokens_resident": tokens, "kv_bytes_used": used,
-                "kv_bytes_allocated": allocated}
-
     @property
     def block_table_fill(self) -> float:
         """Mean fraction of live slots' table entries pointing at real
         (non-scratch) blocks — how much of the paged address space the
-        current streams occupy. 0.0 with no live slots or outside
-        paged mode."""
-        if not self._paged:
-            return 0.0
+        current streams occupy. 0.0 with no live slots."""
         live = [sid for sid, h in enumerate(self._slots) if h is not None]
         if not live:
             return 0.0
@@ -1995,14 +1654,11 @@ class ServeEngine:
 
     @property
     def prefix_pool_nbytes(self) -> int:
-        """Device bytes the resident KV block pool holds (0 with the
-        cache off) — in the copy engine the HBM degraded mode can
-        shed; in PAGED mode the pool is the whole serving KV (live
-        streams included), so only its unpinned cached fraction is
-        sheddable (docs/OPERATIONS.md § "Failure modes & recovery")."""
-        if self._paged:
-            return pool_nbytes(self._cache)
-        return pool_nbytes(self._pool) if self._prefix_on else 0
+        """Device bytes the resident KV block pool holds: the whole
+        serving KV (live streams included), so only its unpinned
+        cached fraction is sheddable by degraded mode
+        (docs/OPERATIONS.md § "Failure modes & recovery")."""
+        return pool_nbytes(self._cache)
 
     @property
     def drained(self) -> bool:
@@ -2092,17 +1748,16 @@ class ServeEngine:
     def _enter_degraded(self) -> None:
         """OOM response: flush every unpinned prefix block (the one
         large sheddable HBM consumer), stop donations, keep serving on
-        the cold path. Live slots' pinned chains stay — their gathered
-        copies are private and their index entries must survive until
-        unpin. A repeat OOM pushes the re-arm time out."""
+        the cold path. Live slots' pinned chains stay — their tables
+        reference those blocks in place. A repeat OOM pushes the
+        re-arm time out."""
         now = self._clock()
         if not self._degraded:
             self._degraded = True
             self._degraded_entered_s = now
             self.metrics.record_degraded_entry()
             self._tracer.on_degraded_entry(self._cur_step)
-            if self._prefix_on:
-                self._prefix.flush_unpinned()
+            self._prefix.flush_unpinned()
         self._degraded_until_s = now + self._degraded_cooldown_s
 
     def _maybe_rearm_degraded(self) -> None:
@@ -2112,27 +1767,6 @@ class ServeEngine:
             self.metrics.record_degraded_exit(now - self._degraded_entered_s)
             self._tracer.on_degraded_exit(
                 self._cur_step, now - self._degraded_entered_s)
-
-    def _reset_prefix_pool(self) -> None:
-        """A REAL failure of the donating scatter may have consumed the
-        resident pool buffers: reallocate them (same shapes — nothing
-        recompiles) and start a fresh index, since every stored chain
-        points into the dead storage. Live slots keep decoding — their
-        gathered copies are private — and their pins die with the old
-        tree."""
-        if not self._prefix_on:
-            return
-        self._pool = kv_block_pool(self._dec, self._prefix.num_blocks,
-                                   self.prefix_block_size)
-        self._prefix = RadixPrefixCache(self.prefix_block_size,
-                                        self._prefix.num_blocks)
-        self._slot_nodes = [None] * self.max_slots
-        if self._host is not None:
-            # The old index died wholesale WITHOUT demotion (its
-            # storage may be consumed); the fresh one demotes again.
-            # Host-tier contents are independent host copies and
-            # survive the rebuild — still promotable.
-            self._prefix.on_evict = self._demote_blocks
 
     def _reset_paged_pool(self) -> None:
         """Rebuild the paged world after its one donated tree may have
@@ -2155,35 +1789,28 @@ class ServeEngine:
         self._private = [[] for _ in range(self.max_slots)]
         self._slot_nodes = [None] * self.max_slots
         if self._host is not None:
-            # Same rule as the row-mode reset: the dead index demoted
-            # nothing, the fresh one does; host copies survive.
+            # The old index died wholesale WITHOUT demotion (its
+            # storage may be consumed); the fresh one demotes again.
+            # Host-tier contents are independent host copies and
+            # survive the rebuild — still promotable.
             self._prefix.on_evict = self._demote_blocks
 
     def _recover_consumed(self, lost: _SlotStateLost) -> None:
-        """Rebuild whatever resident donated tree a real mid-dispatch
-        error may have eaten (`_SlotStateLost.consumed`). The row cache
-        is rebuilt unconditionally by the admission unwind; the slot
-        pool rebuild doubles as a full live-slot replay. In PAGED mode
-        every consuming site donates the ONE pool tree holding all
-        live KV, so recovery is always the full live-slot replay
+        """Rebuild the resident donated tree a real mid-dispatch
+        error may have eaten (`_SlotStateLost.consumed`). Every
+        consuming site donates the ONE pool tree holding all live KV,
+        so recovery is always the full live-slot replay
         (`_lose_live_slots` parks, resets the paged world, and
         requeues)."""
-        if lost.consumed == "cache":
+        if lost.consumed == "pool":
             self._lose_live_slots()
-        elif lost.consumed == "pool":
-            if self._paged:
-                self._lose_live_slots()
-            else:
-                self._reset_prefix_pool()
 
     def _park_slot(self, slot_id: int) -> None:
-        """Park a vacated row: position 0, greedy params. Its future
-        junk writes land at position 0 and the next admit overwrites
-        the whole cache row anyway (paged: the table row goes all-
-        scratch, so junk lands in the sink, and the slot's PRIVATE
-        blocks — tail + generated tokens, never shared — return to the
-        free list; donated prompt blocks stay cached under the radix
-        index, unpinned below)."""
+        """Park a vacated row: position 0, greedy params. The table
+        row goes all-scratch, so its future junk writes land in the
+        sink, and the slot's PRIVATE blocks — tail + generated tokens,
+        never shared — return to the free list; donated prompt blocks
+        stay cached under the radix index, unpinned below."""
         self._slots[slot_id] = None
         if self._tenant_on:
             # Release the slot's adapter pin (the weights stay resident
@@ -2201,11 +1828,10 @@ class ServeEngine:
                 self._masks_w[slot_id, :, :] = True
                 self._masks_w_dirty = True
             self._fsms[slot_id] = None
-        if self._paged:
-            if self._private[slot_id]:
-                self._prefix.release(self._private[slot_id])
-                self._private[slot_id] = []
-            self._tables[slot_id, :] = 0
+        if self._private[slot_id]:
+            self._prefix.release(self._private[slot_id])
+            self._private[slot_id] = []
+        self._tables[slot_id, :] = 0
         if self._slot_nodes[slot_id] is not None:
             # Release the request's pin on its prefix chain: the blocks
             # stay cached (that's the point) but become LRU-evictable
@@ -2240,8 +1866,8 @@ class ServeEngine:
 
     def _lose_live_slots(self) -> None:
         """The fused tick's retry budget ran out: every live slot's KV
-        must be presumed gone (the pooled cache is donated through the
-        tick). Reallocate the pool cache (same shapes — nothing
+        must be presumed gone (the pool tree is donated through the
+        tick). Reallocate the pool (same shapes — nothing
         recompiles), release every pin, and requeue the live requests
         FCFS-front for replay; each rebuilds token-exactly from prompt
         + emitted tokens at its re-admission."""
@@ -2252,25 +1878,22 @@ class ServeEngine:
             self._park_slot(sid)  # releases pins/private into the OLD index
             if self._mark_replay(handle):
                 requeue.append(handle)
-        if self._paged:
-            # A parked mid-prefill slice holds private ids and a pinned
-            # node of the index about to be retired: DROP it without
-            # releasing (the whole old index dies with the reset — a
-            # release would double-own the ids in the fresh free list).
-            # Its handle is still at the head of `_admitting`, so the
-            # next step re-admits it from scratch against the fresh
-            # pool, token-exactly. Its ADAPTER pin is different: the
-            # adapter pool does NOT die with the paged reset, so the
-            # pin unwinds normally (re-admission re-acquires).
-            if self._slice is not None:
-                self._release_adapter(self._slice.get("arow", 0))
-            self._slice = None
-            # The pool held every live stream's KV (and the cached
-            # chains): rebuild the whole paged world — same shapes,
-            # nothing recompiles.
-            self._reset_paged_pool()
-        else:
-            self._cache = slot_decode_cache(self._dec, self.max_slots)
+        # A parked mid-prefill slice holds private ids and a pinned
+        # node of the index about to be retired: DROP it without
+        # releasing (the whole old index dies with the reset — a
+        # release would double-own the ids in the fresh free list).
+        # Its handle is still at the head of `_admitting`, so the
+        # next step re-admits it from scratch against the fresh
+        # pool, token-exactly. Its ADAPTER pin is different: the
+        # adapter pool does NOT die with the paged reset, so the
+        # pin unwinds normally (re-admission re-acquires).
+        if self._slice is not None:
+            self._release_adapter(self._slice.get("arow", 0))
+        self._slice = None
+        # The pool held every live stream's KV (and the cached
+        # chains): rebuild the whole paged world — same shapes,
+        # nothing recompiles.
+        self._reset_paged_pool()
         self.scheduler.requeue_front(requeue)
 
     def _expired(self, handle: RequestHandle, now: float) -> bool:
@@ -2292,7 +1915,7 @@ class ServeEngine:
 
     def _match_blocks(self, prompt) -> int:
         """Cap on the matchable chain for one prompt (blocks): leave at
-        least one suffix token, never exceed the gather vector."""
+        least one suffix token, never exceed ``match_cap``."""
         return min(self._match_cap, (len(prompt) - 1) // self.prefix_block_size)
 
     def _prefill_cost(self, handle) -> int:
@@ -2304,7 +1927,7 @@ class ServeEngine:
         ``FCFSScheduler.admit``). Degraded mode charges the full prompt
         (the cache is not consulted on the cold path)."""
         prompt = handle.request.prompt
-        if self._degraded or not self._prefix_on:
+        if self._degraded:
             cost = len(prompt)
         else:
             match = self._prefix.match(
@@ -2388,7 +2011,6 @@ class ServeEngine:
         host-side splits (copies, so an evicted sibling cannot pin
         the batch buffer alive). Padded tail slices read scratch junk
         and are simply not taken."""
-        target = self._cache if self._paged else self._pool
         bs = self.prefix_block_size
         w = self._match_cap
         n = len(block_ids)
@@ -2397,7 +2019,7 @@ class ServeEngine:
             ids = np.zeros(w, np.int32)
             chunk = block_ids[c:c + w]
             ids[:len(chunk)] = chunk
-            staged.append(self._demote_p(target, ids))
+            staged.append(self._demote_p(self._cache, ids))
         pulled = jax.device_get(staged)
         out: List[Dict[str, np.ndarray]] = []
         for c, st in zip(range(0, n, w), pulled):
@@ -2416,7 +2038,6 @@ class ServeEngine:
         are scalar placeholders. Fixed width, so the program traces
         once."""
         bs = self.prefix_block_size
-        target = self._cache if self._paged else self._pool
 
         def _leaf(path, leaf):
             if leaf.ndim < 3:
@@ -2429,7 +2050,7 @@ class ServeEngine:
                 row[..., j * bs:(j + 1) * bs, :] = b[key]
             return row
 
-        return jax.tree_util.tree_map_with_path(_leaf, target)
+        return jax.tree_util.tree_map_with_path(_leaf, self._cache)
 
     def _promote_host_chain(self, prompt: np.ndarray, handle=None) -> int:
         """Promotion (module docstring): extend the device match with
@@ -2482,17 +2103,13 @@ class ServeEngine:
                 self._host.chain_data(node, k))
             dids = np.zeros(self._match_cap, np.int32)
             dids[:k] = ids
-            target = self._cache if self._paged else self._pool
             try:
-                out = self._device_call("host_promote", self._promote_p,
-                                        target, rows, dids)
+                self._cache = self._device_call(
+                    "host_promote", self._promote_p, self._cache, rows,
+                    dids)
             except _SlotStateLost:
                 self._prefix.release(ids)
                 raise
-            if self._paged:
-                self._cache = out
-            else:
-                self._pool = out
             self._prefix.extend(anchor, prompt[m * bs:(m + k) * bs], ids)
             self.metrics.record_host_promotion(
                 k, k * self._host_promote_tokens,
@@ -2503,137 +2120,13 @@ class ServeEngine:
         finally:
             self._prefix.unpin(anchor)
 
-    def _prefill_into_row(self, prompt: np.ndarray, handle=None, aid=0):
-        """Prefill one prompt into a row cache, reusing any cached
-        prefix: gather the matched chain into the resident row buffers,
-        chunk-prefill the suffix, donate the prompt's uncovered full
-        blocks, pin the chain. ``handle`` is the admission's request
-        (tracing only — each dispatch lands on its span); ``aid`` the
-        tenant adapter pool row (0 = base model, ignored on a plain
-        engine). Returns ``(row_cache, last_logits,
-        pinned_node_or_None)``."""
-        plen = prompt.size
-        bs = self.prefix_block_size
-        tr = self._tracer
-        if not self._prefix_on:
-            padded = np.zeros((1, self.prefill_len), np.int32)
-            padded[0, :plen] = prompt
-            row, logits = self._device_call(
-                "prefill", self._prefill_p, self._params, padded, plen,
-                *self._chunk_extra(aid))
-            tr.on_prefill_chunk(handle, "prefill", 0, plen,
-                                self._last_wall_s)
-            return row, logits, None
-        if self._host is not None:
-            # Tiered admission: promote any host-tier continuation of
-            # the device match FIRST, so the match below simply sees a
-            # deeper chain (self-unwinding; a promotion fault escalates
-            # exactly like any admission dispatch).
-            self._promote_host_chain(prompt, handle)
-        # Degraded mode (post-OOM cool-down): the cache is neither
-        # consulted nor grown — a pure cold chunked prefill, so serving
-        # continues while the pool stays shed.
-        use_prefix = not self._degraded
-        if use_prefix:
-            match = self._prefix.match(prompt,
-                                       max_blocks=self._match_blocks(prompt))
-            n_cached = match.n_blocks * bs
-            tr.on_prefix_match(handle, match.n_blocks, n_cached)
-        else:
-            match, n_cached = None, 0
-        if n_cached > 0:
-            ids = np.zeros(self._match_cap, np.int32)  # scratch-padded
-            ids[:match.n_blocks] = match.block_ids
-            row = self._device_call("gather", self._gather_p,
-                                    self._pool, ids, self._row)
-            tr.on_prefill_chunk(handle, "gather", 0, n_cached,
-                                self._last_wall_s)
-            self._row = row
-        else:
-            # Full miss: no gather dispatch — the chunks overwrite
-            # [0, plen) of the resident row and everything beyond parks
-            # past the position counter the insert stamps.
-            row = self._row
-        # Fixed-width chunks over the suffix (shared width policy —
-        # :meth:`_chunk_loop`). The resident row is adopted after EVERY
-        # dispatch (each chunk donates it), so a mid-chunk fault
-        # escalation never leaves `self._row` pointing at a consumed
-        # buffer.
-        row_box = [row]
-
-        def _dispatch(site, prog, chunk_toks, w, off):
-            row_box[0], lg = self._device_call(
-                site, prog, self._params, row_box[0], chunk_toks,
-                np.int32(w), np.int32(off), *self._chunk_extra(aid))
-            self._row = row_box[0]
-            return lg
-
-        logits = self._chunk_loop(prompt, n_cached, handle, _dispatch)
-        row = row_box[0]
-        if not use_prefix:
-            return row, logits, None
-        node = self._donate_tail(prompt, row, match, n_cached)
-        # Adopt the row buffers for the next admission (the slot insert
-        # COPIES the row, so reuse is safe and saves a fresh full-length
-        # cache allocation per admission).
-        self._row = row
-        return row, logits, node
-
-    def _donate_tail(self, prompt: np.ndarray, row, match,
-                     n_cached: int):
-        """Donate the prompt's uncovered FULL blocks and pin the chain;
-        ``match`` must be CURRENT (the sliced path re-matches at finish
-        time — ticks ran between its slices and an OOM flush could have
-        detached a start-time node). First descend any chain ALREADY
-        stored past the (capped) gather match — those chunks must not
-        have fresh blocks allocated, or a full pool would evict useful
-        blocks to supply ids the index hands straight back. Pin before
-        allocating so this admission's own eviction pass can never free
-        the blocks just gathered from. Donation order is
-        write-then-index: the pool scatter runs BEFORE ``extend``
-        attaches the ids, so a fault mid-donation can never leave the
-        index pointing at blocks that hold junk — the unwind releases
-        the unattached ids and the pin, restoring the pre-admission
-        refcount baseline exactly. Returns the pinned node."""
-        bs = self.prefix_block_size
-        plen = len(prompt)
-        node, stored_blocks = self._prefix.descend(
-            match.node, prompt, match.n_blocks)
-        self._prefix.pin(node)
-        want = plen // bs - stored_blocks
-        if want > 0:
-            new_ids = self._prefix.allocate(min(want, self._donate_cap))
-            if new_ids:
-                dids = np.zeros(self._donate_cap, np.int32)
-                dids[:len(new_ids)] = new_ids
-                try:
-                    self._pool = self._device_call(
-                        "donate", self._donate_p, self._pool, row, dids,
-                        np.int32(stored_blocks))
-                except _SlotStateLost:
-                    self._prefix.release(new_ids)
-                    self._prefix.unpin(node)
-                    raise
-                tip = self._prefix.extend(
-                    node,
-                    prompt[stored_blocks * bs:
-                           (stored_blocks + len(new_ids)) * bs],
-                    new_ids)
-                self._prefix.unpin(node)
-                self._prefix.pin(tip)
-                node = tip
-        self.metrics.record_prefix_lookup(
-            n_cached, blocks_live=self._prefix.blocks_live,
-            evictions=self._prefix.evictions)
-        return node
-
     def _chunk_loop(self, prompt: np.ndarray, off: int, handle,
                     dispatch):
-        """The whole-prompt suffix chunk loop, ONE width policy for the
-        row and paged admissions (coarse cost model — each apply pays a
-        fixed dispatch cost plus per-token compute): a long remainder
-        (>= 3/4 of the wide width) takes the WIDE program in one apply,
-        so a cold prompt costs what the one-shot prefill did; short
+        """The whole-prompt suffix chunk loop and its width policy
+        (coarse cost model — each apply pays a fixed dispatch cost plus
+        per-token compute): a long remainder (>= 3/4 of the wide width)
+        takes the WIDE program in one apply, so a cold prompt costs
+        one apply, not ``ceil(plen / chunk)`` of them; short
         suffixes — the prefix-hit case — take narrow chunks and pay
         only for the uncached tail. ``dispatch(site, prog, chunk_toks,
         w, off)`` runs the program, adopts whatever resident tree it
@@ -2679,9 +2172,9 @@ class ServeEngine:
                 table)
             off += w
 
-    # ------------------------------------------------- paged admission
+    # ------------------------------------------------------- admission
     def _paged_match_and_allocate(self, prompt: np.ndarray, handle=None):
-        """The shared front half of every paged admission (whole-prompt
+        """The shared front half of every admission (whole-prompt
         AND sliced): match → pin → allocate private suffix blocks →
         stamp the table row. ONE definition because the ordering is
         safety-critical — the pin must land BEFORE any allocation (with
@@ -2697,9 +2190,10 @@ class ServeEngine:
         table_row = np.zeros(self._table_width, np.int32)
         node, m = None, 0
         if self._host is not None:
-            # Tiered admission (the row path's twin): host-tier blocks
-            # promote into the pool first, so the match below pins the
-            # deeper chain in place.
+            # Tiered admission: host-tier blocks promote into the pool
+            # FIRST, so the match below pins the deeper chain in place
+            # (self-unwinding; a promotion fault escalates exactly like
+            # any admission dispatch).
             self._promote_host_chain(prompt, handle)
         if not self._degraded:
             match = self._prefix.match(
@@ -2727,11 +2221,17 @@ class ServeEngine:
         return node, m, table_row, private
 
     def _prefill_paged(self, prompt: np.ndarray, handle=None, aid=0):
-        """The paged twin of :meth:`_prefill_into_row`: a prefix hit
+        """Prefill one prompt, reusing any cached prefix: a prefix hit
         PINS the matched chain and points the slot's block table at it
         in place (no gather copy), private blocks are allocated for the
         suffix, and the chunk programs write K/V straight into those
-        pool blocks. ``aid`` as in :meth:`_prefill_into_row`. Returns
+        pool blocks; then the prompt's full blocks are donated.
+        ``handle`` is the admission's request (tracing only — each
+        dispatch lands on its span); ``aid`` the tenant adapter pool
+        row (0 = base model, ignored on a plain engine). Degraded mode
+        (post-OOM cool-down) neither consults nor grows the cache — a
+        pure cold chunked prefill, so serving continues while the pool
+        stays shed. Returns
         ``(last_logits, pinned_node_or_None, table_row [T] np.int32,
         private_ids)``; raises :class:`_SlotStateLost` with its own
         resources unwound."""
@@ -2777,9 +2277,9 @@ class ServeEngine:
         already written in the pool — hand their ownership to the radix
         index (they become the stored chain) and keep the slot's pin.
         When a chain segment is ALREADY stored (the block-aligned-tail
-        case the copy engine deduped with `descend`), the slot's table
-        is SWAPPED onto the stored blocks — token-identity implies
-        bit-identical KV under the position-absolute cache contract —
+        case), the slot's table is SWAPPED onto the stored blocks —
+        token-identity implies bit-identical KV under the
+        position-absolute cache contract —
         and the duplicate private blocks go back to the free list, so a
         repeat prompt holds the pool at its deduplicated size. Returns
         the pinned chain tip (or ``node`` unchanged when the prompt has
@@ -2824,9 +2324,9 @@ class ServeEngine:
             # decode tick below is never more than one allowance away.
             self._slice_budget_left = self._slice_tokens
         if self._slice is not None:
-            # A prefill is mid-flight from an earlier step: the resident
-            # row is ITS pipeline — advance it first; only if it
-            # finishes (or settles) may new admissions start.
+            # A prefill is mid-flight from an earlier step — advance it
+            # first; only if it finishes (or settles) may new
+            # admissions start.
             with self._admit_request_span(self._slice["handle"],
                                           self._slice["sid"]):
                 settled = self._continue_slice()
@@ -2859,8 +2359,7 @@ class ServeEngine:
         # The suffix-priced (and adapter-load-priced, and spec-replay-
         # priced) cost_fn walks the radix tree per pop; only pay that
         # when a budget actually consumes the result.
-        use_cost = ((self._prefix_on or self._tenant_on or self._spec_on)
-                    and self.scheduler.prefill_token_budget is not None)
+        use_cost = self.scheduler.prefill_token_budget is not None
         # A kill mid-admission can leave a handle parked in
         # `_admitting`; it owns the first free slot before anything new
         # is popped.
@@ -2904,11 +2403,10 @@ class ServeEngine:
             replay=bool(handle.tokens))
 
     def _paged_append_blocks(self) -> None:
-        """Before a paged tick: every live slot about to write at a
+        """Before a tick: every live slot about to write at a
         block boundary gets a fresh PRIVATE block appended to its
-        table (block-table growth is a runtime-array update — the
-        in-place append that replaces the copy engine's whole-row
-        insert). Allocation LRU-evicts unpinned cached chains under
+        table (block-table growth is a runtime-array update, never a
+        KV copy). Allocation LRU-evicts unpinned cached chains under
         pressure; with the pool at its validated floor it cannot fail
         for a live stream, but if a mis-sized explicit pool ever does,
         the slot is parked and REPLAYED rather than writing into a
@@ -2978,31 +2476,21 @@ class ServeEngine:
                           handle: RequestHandle) -> None:
         """A dispatch died during this handle's admission. The
         per-request unwind already released any pin; the slot never
-        became live. Rebuild the resident row buffers defensively (a
-        real device error may have consumed them via donation) — same
-        shapes, nothing recompiles — rebuild anything else the failed
-        dispatch consumed (slot pool → live-slot replay; block pool →
-        fresh pool + index), and charge the request a replay."""
+        became live. Rebuild what the failed dispatch consumed (the
+        pool → fresh pool + index + live-slot replay; same shapes,
+        nothing recompiles) and charge the request a replay."""
         sl, self._slice = self._slice, None
         if sl is not None:
-            # A parked slice owns its adapter pin (the whole-prompt
-            # paths release their own before raising, and then
-            # self._slice was never set). The adapter pool does NOT
-            # die with any KV rebuild, so the pin must unwind exactly.
+            # A parked slice owns its adapter pin, its chain pin and its
+            # private blocks (the whole-prompt path releases its own
+            # before raising, and then self._slice was never set). The
+            # adapter pool does NOT die with any KV rebuild, so that
+            # pin must unwind exactly.
             self._release_adapter(sl.get("arow", 0))
-        if self._paged:
-            # A parked slice still owns its pin + private blocks (the
-            # whole-prompt paged path releases its own before raising,
-            # and then self._slice was never set).
-            if sl is not None:
-                if sl.get("private"):
-                    self._prefix.release(sl["private"])
-                if sl.get("node") is not None:
-                    self._prefix.unpin(sl["node"])
-        elif self._prefix_on:
-            self._row = jax.tree.map(
-                lambda sd: jnp.zeros(sd.shape, sd.dtype),
-                _decode_cache_shapes(self._dec, 1))
+            if sl.get("private"):
+                self._prefix.release(sl["private"])
+            if sl.get("node") is not None:
+                self._prefix.unpin(sl["node"])
         self._recover_consumed(lost)
         if self._mark_replay(handle):
             self.scheduler.requeue_front([handle])
@@ -3019,31 +2507,19 @@ class ServeEngine:
         self._tracer.on_admit(handle, sid, replay)
         prompt = np.asarray(handle.request.prompt, np.int32)
         arow, fsm = self._tenant_admit(handle)
-        if self._paged:
-            try:
-                logits, node, table_row, private = self._prefill_paged(
-                    prompt, handle, arow)
-            except _SlotStateLost:
-                self._release_adapter(arow)
-                raise
-            self._install_slot(sid, handle, None, logits, node,
-                               table_row=table_row, private=private,
-                               arow=arow, fsm=fsm)
-            return
         try:
-            row, logits, node = self._prefill_into_row(prompt, handle,
-                                                       arow)
+            logits, node, table_row, private = self._prefill_paged(
+                prompt, handle, arow)
         except _SlotStateLost:
             self._release_adapter(arow)
             raise
-        self._install_slot(sid, handle, row, logits, node, arow=arow,
-                           fsm=fsm)
+        self._install_slot(sid, handle, logits, node, table_row, private,
+                           arow=arow, fsm=fsm)
 
     # ------------------------------------------------ sliced admission
     def _start_slice(self, sid: int, handle: RequestHandle) -> bool:
-        """Begin a time-sliced admission: match + gather now (cheap,
-        and the gathered KV copy is private — later evictions cannot
-        reach it), then chunk-prefill under the per-step allowance.
+        """Begin a time-sliced admission: match + pin + allocate now
+        (host-only), then chunk-prefill under the per-step allowance.
         Returns True when the admission completed within this step's
         budget; False parks it in ``self._slice`` to resume next step
         — the decode tick runs in between, which is the whole point."""
@@ -3061,44 +2537,19 @@ class ServeEngine:
         # explicitly or a refcount would underflow.
         created = False
         try:
-            if self._paged:
-                # Pin + allocate now (host-only, no gather dispatch —
-                # the matched blocks are referenced in place); the pin
-                # is what keeps the chain under this admission across
-                # the decode ticks that run between slices.
-                node, m, table_row, private = \
-                    self._paged_match_and_allocate(prompt, handle)
-                n_cached = m * self.prefix_block_size
-                self._slice = {"handle": handle, "sid": sid,
-                               "prompt": prompt, "off": n_cached,
-                               "n_cached": n_cached, "logits": None,
-                               "node": node, "table": table_row,
-                               "private": private, "arow": arow,
-                               "fsm": fsm}
-                created = True
-                return self._advance_slice(self._slice)
-            if self._host is not None:
-                # Tiered sliced admission: promote before the gather so
-                # the matched chain below includes the host-tier blocks.
-                self._promote_host_chain(prompt, handle)
-            n_cached = 0
-            if not self._degraded:
-                match = self._prefix.match(
-                    prompt, max_blocks=self._match_blocks(prompt))
-                n_cached = match.n_blocks * self.prefix_block_size
-                self._tracer.on_prefix_match(handle, match.n_blocks,
-                                             n_cached)
-            if n_cached > 0:
-                ids = np.zeros(self._match_cap, np.int32)  # scratch-pad
-                ids[:match.n_blocks] = match.block_ids
-                self._row = self._device_call("gather", self._gather_p,
-                                              self._pool, ids, self._row)
-                self._tracer.on_prefill_chunk(handle, "gather", 0,
-                                              n_cached,
-                                              self._last_wall_s)
-            self._slice = {"handle": handle, "sid": sid, "prompt": prompt,
-                           "off": n_cached, "n_cached": n_cached,
-                           "logits": None, "arow": arow, "fsm": fsm}
+            # Pin + allocate now (host-only, no gather dispatch — the
+            # matched blocks are referenced in place); the pin is what
+            # keeps the chain under this admission across the decode
+            # ticks that run between slices.
+            node, m, table_row, private = \
+                self._paged_match_and_allocate(prompt, handle)
+            n_cached = m * self.prefix_block_size
+            self._slice = {"handle": handle, "sid": sid,
+                           "prompt": prompt, "off": n_cached,
+                           "n_cached": n_cached, "logits": None,
+                           "node": node, "table": table_row,
+                           "private": private, "arow": arow,
+                           "fsm": fsm}
             created = True
             return self._advance_slice(self._slice)
         except _SlotStateLost:
@@ -3115,16 +2566,13 @@ class ServeEngine:
         now = self._clock()
         if handle.cancelled or self._expired(handle, now):
             # Not in a slot yet, so _reap cannot see it: settle here.
-            # The partially-prefilled row is abandoned junk the next
-            # admission overwrites (the padded-prefill invariant; in
-            # paged mode the private blocks return to the free list,
-            # where their junk is unreachable until reallocated and
-            # fully rewritten).
-            if self._paged:
-                if sl.get("private"):
-                    self._prefix.release(sl["private"])
-                if sl.get("node") is not None:
-                    self._prefix.unpin(sl["node"])
+            # The partially-prefilled private blocks return to the free
+            # list, where their junk is unreachable until reallocated
+            # and fully rewritten.
+            if sl.get("private"):
+                self._prefix.release(sl["private"])
+            if sl.get("node") is not None:
+                self._prefix.unpin(sl["node"])
             self._release_adapter(sl.get("arow", 0))
             self._slice = None
             if handle.cancelled:
@@ -3166,24 +2614,18 @@ class ServeEngine:
             chunk_toks = np.zeros((1, self._chunk), np.int32)
             chunk_toks[0, :w] = prompt[off:off + w]
             extra = self._chunk_extra(sl.get("arow", 0))
-            if self._paged:
-                self._cache, sl["logits"] = self._device_call(
-                    "chunk_prefill", self._chunk_p, self._params,
-                    self._cache, chunk_toks, np.int32(w), np.int32(off),
-                    sl["table"][None], *extra)
-                if self._draft_on:
-                    # The draft tree advances in lockstep with the
-                    # slices (same chunk, same blocks), so fairness and
-                    # the budget charge stay one number per slice.
-                    self._dcache = self._device_call(
-                        "draft_prefill", self._dchunk_p, self._dparams,
-                        self._dcache, chunk_toks, np.int32(w),
-                        np.int32(off), sl["table"][None])
-            else:
-                self._row, sl["logits"] = self._device_call(
-                    "chunk_prefill", self._chunk_p, self._params,
-                    self._row, chunk_toks, np.int32(w), np.int32(off),
-                    *extra)
+            self._cache, sl["logits"] = self._device_call(
+                "chunk_prefill", self._chunk_p, self._params,
+                self._cache, chunk_toks, np.int32(w), np.int32(off),
+                sl["table"][None], *extra)
+            if self._draft_on:
+                # The draft tree advances in lockstep with the
+                # slices (same chunk, same blocks), so fairness and
+                # the budget charge stay one number per slice.
+                self._dcache = self._device_call(
+                    "draft_prefill", self._dchunk_p, self._dparams,
+                    self._dcache, chunk_toks, np.int32(w),
+                    np.int32(off), sl["table"][None])
             self.metrics.record_prefill_chunk(self._chunk)
             self._tracer.on_prefill_chunk(handle, "chunk_prefill", off, w,
                                           self._last_wall_s)
@@ -3194,63 +2636,49 @@ class ServeEngine:
         return True
 
     def _finish_slice(self, sl: Dict[str, object]) -> None:
-        """The prompt is fully in the row cache: donate/pin (off a
-        FRESH match — decode ticks and possibly an OOM flush ran
-        between slices, so a start-time node may be detached), then
-        install the slot exactly like the whole-prompt path."""
+        """The prompt is fully in the pool: donate/pin, then install
+        the slot exactly like the whole-prompt path."""
         handle, sid = sl["handle"], sl["sid"]
         prompt = sl["prompt"]
-        if self._paged:
-            node = sl["node"]
-            if int(sl["n_cached"]) > 0:
-                # Recorded at FINISH like the whole-prompt path, so a
-                # mid-slice unwind + replay can never double-count.
-                self.metrics.record_copy_avoided(
-                    int(sl["n_cached"]) * self._kv_token_bytes)
-            if not self._degraded:
-                # The start-time pin survived the interleaved ticks
-                # (flush_unpinned spares pinned chains), so donation
-                # descends from it directly. While degraded, the
-                # matched blocks stay pinned-but-undonated: the table
-                # references them in place, so the pin must outlive
-                # the slot either way.
-                node = self._donate_tail_paged(
-                    prompt, node, sl["table"], sl["private"],
-                    int(sl["n_cached"]) // self.prefix_block_size)
-                self.metrics.record_prefix_lookup(
-                    int(sl["n_cached"]),
-                    blocks_live=self._prefix.blocks_live,
-                    evictions=self._prefix.evictions)
-            self._slice = None
-            self._install_slot(sid, handle, None, sl["logits"], node,
-                               table_row=sl["table"],
-                               private=sl["private"],
-                               arow=sl.get("arow", 0), fsm=sl.get("fsm"))
-            return
-        node = None
+        node = sl["node"]
+        if int(sl["n_cached"]) > 0:
+            # Recorded at FINISH like the whole-prompt path, so a
+            # mid-slice unwind + replay can never double-count.
+            self.metrics.record_copy_avoided(
+                int(sl["n_cached"]) * self._kv_token_bytes)
         if not self._degraded:
-            match = self._prefix.match(
-                prompt, max_blocks=self._match_blocks(prompt))
-            node = self._donate_tail(prompt, self._row, match,
-                                     int(sl["n_cached"]))
+            # The start-time pin survived the interleaved ticks
+            # (flush_unpinned spares pinned chains), so donation
+            # descends from it directly. While degraded, the
+            # matched blocks stay pinned-but-undonated: the table
+            # references them in place, so the pin must outlive
+            # the slot either way.
+            node = self._donate_tail_paged(
+                prompt, node, sl["table"], sl["private"],
+                int(sl["n_cached"]) // self.prefix_block_size)
+            self.metrics.record_prefix_lookup(
+                int(sl["n_cached"]),
+                blocks_live=self._prefix.blocks_live,
+                evictions=self._prefix.evictions)
         self._slice = None
-        self._install_slot(sid, handle, self._row, sl["logits"], node,
-                           arow=sl.get("arow", 0), fsm=sl.get("fsm"))
+        self._install_slot(sid, handle, sl["logits"], node, sl["table"],
+                           sl["private"], arow=sl.get("arow", 0),
+                           fsm=sl.get("fsm"))
 
-    def _install_slot(self, sid: int, handle: RequestHandle, row, logits,
-                      node, table_row=None, private=None, arow=0,
+    def _install_slot(self, sid: int, handle: RequestHandle, logits,
+                      node, table_row, private, arow=0,
                       fsm=None) -> None:
-        """Make a fully-prefilled row live in slot ``sid``. Two shapes:
+        """Make a fully-prefilled prompt live in slot ``sid``. Two shapes:
         a FRESH request samples its first token from the prefill logits
         (that's TTFT); a REPLAYED one (``handle.tokens`` non-empty —
         fault recovery or drain/restore) rebuilt its KV from the
         prompt and re-feeds the emitted tokens through the coming
         ticks, so no token is ever re-sampled or double-streamed.
 
-        Paged mode passes ``table_row``/``private`` instead of ``row``:
-        the KV is already where it lives (the pool), so there is no
-        insert dispatch at all — installation is the host-side table
-        stamp.
+        The KV is already where it lives (the pool blocks
+        ``table_row`` names, ``private`` the ones this slot owns), so
+        there is no insert dispatch at all — installation is the
+        host-side table stamp.
 
         Tenant mode passes ``arow`` (the admission's pinned adapter
         pool row — ownership transfers to the slot here, or is
@@ -3273,15 +2701,12 @@ class ServeEngine:
                 # migration mirror): fail the REQUEST via the replay
                 # budget, never the engine.
                 self._release_adapter(arow)
-                if self._paged and private:
+                if private:
                     self._prefix.release(private)
                 if node is not None:
                     self._prefix.unpin(node)
                 raise _SlotStateLost("constraint_admit", e) from e
         try:
-            if not self._paged:
-                self._cache = self._device_call(
-                    "insert", self._insert_p, self._cache, row, sid, plen)
             if replay:
                 first = handle.tokens[0]
                 handle.replay_pending = list(handle.tokens[1:])
@@ -3293,15 +2718,14 @@ class ServeEngine:
                 with self._phase["first_token_wait"]:
                     first = int(tok[0])
         except _SlotStateLost:
-            if self._paged and private:
+            if private:
                 self._prefix.release(private)
             if node is not None:
                 self._prefix.unpin(node)
             self._release_adapter(arow)
             raise
-        if self._paged:
-            self._tables[sid] = table_row
-            self._private[sid] = list(private)
+        self._tables[sid] = table_row
+        self._private[sid] = list(private)
         self._slot_nodes[sid] = node
         if not replay:
             now = self._clock()
@@ -3525,18 +2949,11 @@ class ServeEngine:
                 caps[sid] = self._grammar_draft_walk(sid, fsm_entry,
                                                      block[sid, 1:])
             drafted_tick += int(caps[sid])
-        if self._paged:
-            self._cache, win, acc, self._rng = self._device_call(
-                "verify", self._verify_p, self._params, self._cache,
-                self._positions, self._tables, block, self._temps,
-                self._top_ks, self._top_ps, *self._verify_extra(),
-                caps, forced_n, self._rng)
-        else:
-            self._cache, win, acc, self._rng = self._device_call(
-                "verify", self._verify_p, self._params, self._cache,
-                self._positions, block, self._temps, self._top_ks,
-                self._top_ps, *self._verify_extra(), caps, forced_n,
-                self._rng)
+        self._cache, win, acc, self._rng = self._device_call(
+            "verify", self._verify_p, self._params, self._cache,
+            self._positions, self._tables, block, self._temps,
+            self._top_ks, self._top_ps, *self._verify_extra(),
+            caps, forced_n, self._rng)
         self.metrics.record_decode_tick()
         return win, acc, caps, drafted_tick
 
@@ -3674,9 +3091,8 @@ class ServeEngine:
             self._reap()
         with ph["admit"]:
             self._admit()
-        if self._paged:
-            with ph["append_blocks"]:
-                self._paged_append_blocks()
+        with ph["append_blocks"]:
+            self._paged_append_blocks()
         live = [i for i, s in enumerate(self._slots) if s is not None]
         new_tokens = 0
         if live and self._spec_on:
@@ -3685,7 +3101,7 @@ class ServeEngine:
             except _SlotStateLost:
                 # The verify window donates the resident tree exactly
                 # like the tick did (and a consumed draft tree shares
-                # the paged pool's fate): every live slot replays.
+                # the pool's fate): every live slot replays.
                 self._lose_live_slots()
         elif live:
             nxt = None
@@ -3705,9 +3121,8 @@ class ServeEngine:
         self.metrics.record_tick(
             now, self.scheduler.depth, len(live), self.max_slots,
             new_tokens, now - t0)
-        if self._paged:
-            self.metrics.record_paged_gauges(self.blocks_shared,
-                                             self.block_table_fill)
+        self.metrics.record_paged_gauges(self.blocks_shared,
+                                         self.block_table_fill)
         emitted = self.metrics.tokens_emitted - emitted_before
         phase_wall = dict(self._phase_wall)
         self.metrics.record_step(time.perf_counter() - t_step, phase_wall)
@@ -3838,21 +3253,20 @@ class ServeEngine:
                          key=lambda h: h.arrival_s)
         handles.extend(self._admitting)
         handles.extend(self.scheduler.drain())
-        # Paged engines record each running slot's block table in the
-        # v3 snapshot — postmortem context (which pool blocks the
+        # Each running slot's block table goes into the v3+
+        # snapshot — postmortem context (which pool blocks the
         # stream occupied, how much was shared), never a restore input:
         # pool storage dies with the process and the restore path
         # rebuilds KV via replay exactly like a v2 snapshot.
         tables = {}
-        if self._paged:
-            for sid, h in enumerate(self._slots):
-                if h is not None:
-                    row = self._tables[sid]
-                    tables[id(h)] = [int(b) for b in row[row != 0]]
+        for sid, h in enumerate(self._slots):
+            if h is not None:
+                row = self._tables[sid]
+                tables[id(h)] = [int(b) for b in row[row != 0]]
         self._snapshot = {
             "version": drain_io.SNAPSHOT_VERSION,
             "drained_unix_s": time.time(),
-            "paged": self._paged,
+            "paged": True,
             # v5: the drafting config the streams ran under — postmortem
             # context (restore replays token-exactly into ANY engine,
             # speculative or not; KV and FSM state are pure functions of
@@ -3917,14 +3331,13 @@ class ServeEngine:
         demotion uses), host-tier blocks extending it (already host
         arrays, no transfer) — as a `serve/drain.py` chain wire entry
         (:func:`~pddl_tpu.serve.drain.kv_chain_to_wire`). ``None``
-        when nothing is cached, the prefix machinery is off, the HOST
+        when nothing is cached, the HOST
         TIER is off (the D2H read rides the tier's jitted gather, and
         a tier-less replica could not receive a peer's chain either —
         exporting is a tiered-fleet feature), or the engine is
         degraded (exporting from a shed cache would race the flush).
         The matched chain is pinned for exactly the read."""
-        if (not self._prefix_on or self._host is None
-                or self._degraded or self._drained):
+        if self._host is None or self._degraded or self._drained:
             return None
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         cap = self._match_blocks(tokens)

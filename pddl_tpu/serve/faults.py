@@ -48,8 +48,7 @@ class FaultPlan(_BaseFaultPlan):
     consumed the donated draft tree), ``verify`` (the wide-window
     program that replaces ``tick`` on a ``spec_k > 0`` engine — same
     donated-tree recovery: full live-slot replay), and
-    ``draft_prefill`` (the draft model's admission chunk, paged
-    engines only).
+    ``draft_prefill`` (the draft model's admission chunk).
 
     The tiered-KV site (ISSUE 13): ``host_promote`` — the H2D scatter
     that promotes a host-tier chain into the pool on a ``host_tier``
@@ -57,10 +56,10 @@ class FaultPlan(_BaseFaultPlan):
     pin holds across retries); exhausted retries unwind the promotion
     (ids + pins released exactly) and charge the admission a replay; a
     REAL error may have consumed the donated pool tree and recovers
-    like donate/chunk (pool rebuild; paged: full live-slot replay).
+    like a chunk's (pool rebuild + full live-slot replay).
     Demotion is deliberately NOT a site: it is an eager opportunistic
     read whose failure degrades to the old free-and-recompute path."""
 
-    SITES = ("prefill", "gather", "chunk_prefill", "chunk_prefill_wide",
-             "donate", "insert", "tick", "sample_first", "adapter_load",
-             "draft", "verify", "draft_prefill", "host_promote")
+    SITES = ("chunk_prefill", "chunk_prefill_wide", "tick",
+             "sample_first", "adapter_load", "draft", "verify",
+             "draft_prefill", "host_promote")

@@ -87,8 +87,7 @@ def build_engine(config: Dict[str, object]):
     params = model.init(jax.random.key(int(config.get("param_seed", 0))),
                         dummy, train=False)["params"]
     aging = config.get("aging_s", 30.0)
-    # Multi-tenant passthrough (ISSUE 9, mirroring the r13 `paged`
-    # passthrough): a `tenant` sub-config builds the same registry on
+    # Multi-tenant passthrough (ISSUE 9): a `tenant` sub-config builds the same registry on
     # every process replica — adapters are (name, seed[, rank, scale])
     # pairs materialized via the registry's deterministic
     # `register_random`, so every replica (and the chaos oracle) holds
@@ -117,7 +116,7 @@ def build_engine(config: Dict[str, object]):
             token_strings=tenant_cfg.get("token_strings"),
             adapter_load_tokens=int(
                 tenant_cfg.get("adapter_load_tokens", 8)))
-    # Tiered KV cache (ISSUE 13, mirroring the paged/tenant/spec
+    # Tiered KV cache (ISSUE 13, mirroring the tenant/spec
     # passthroughs): a nonzero host_tier_bytes arms the host-RAM spill
     # tier on every process replica — which is also what makes the
     # router's chain pulls land somewhere. Absent keeps the untiered
@@ -143,17 +142,15 @@ def build_engine(config: Dict[str, object]):
         prefill_token_budget=config.get("prefill_token_budget"),
         aging_s=float(aging) if aging is not None else None,
         prefill_slice_tokens=config.get("prefill_slice_tokens"),
-        # Engine-parity default: absent means the auto-sized prefix
-        # pool, NOT off — the router's affinity shadow must point at
-        # caches that exist. Pass 0 explicitly to disable.
+        # Engine-parity default: absent means the auto-sized block
+        # pool (the pool is the KV cache; the engine refuses 0).
         prefix_cache_blocks=config.get("prefix_cache_blocks"),
-        # Paged attention (ISSUE 8): decode straight from the block
-        # pool through per-slot block tables; absent keeps the copy
-        # engine so existing bench configs stay comparable.
-        paged=bool(config.get("paged", False)),
+        # `main` refuses `paged: false` before it gets here; a direct
+        # caller's reaches the constructor, which raises.
+        paged=config.get("paged", True),
         tenant=tenant,
-        # Speculative serving (ISSUE 12, mirroring the paged/tenant
-        # passthroughs): every replica drafts with the same k/ngram, so
+        # Speculative serving (ISSUE 12, mirroring the tenant
+        # passthrough): every replica drafts with the same k/ngram, so
         # migrated speculative streams land on an engine that re-feeds
         # them through the identical verify machinery. Absent keeps the
         # classic tick so existing fleet configs stay comparable.
@@ -181,6 +178,17 @@ def main(argv=None) -> int:
     if role not in ROLES:
         print(f"invalid replica role {role!r}: must be one of {ROLES}",
               file=sys.stderr)
+        return 2
+
+    # A worker config comes from outside the process. `paged: true`
+    # or the key absent builds the engine; `paged: false` asks for the
+    # resident-row engine, which was removed in PR 34 — refused like a
+    # bad role, before the engine builds, never served silently as
+    # something else.
+    if config.get("paged", True) is not True:
+        print(f"invalid replica config paged={config['paged']!r}: the "
+              "resident-row engine was removed in PR 34; drop the key "
+              "or pass true", file=sys.stderr)
         return 2
 
     # Framed transport (ISSUE 14, `fleet/transport.py`): the parent

@@ -1,9 +1,8 @@
 """Prefix-aware KV reuse under the serving engine.
 
-`block_pool.py` is the DEVICE half: a resident pool of fixed-size
-token blocks per K/V cache leaf plus the tree-level gather (pool →
-slot prefix) and donate (slot prompt → pool blocks) assembly over the
-single-leaf primitives in :mod:`pddl_tpu.ops.attention`.
+`block_pool.py` is the DEVICE half: the resident pool of fixed-size
+token blocks that IS the engine's KV cache (one fused K/V leaf per
+attention layer, read and written in place through block tables).
 `radix.py` is the HOST half: a refcounted, LRU-evicted radix tree over
 token ids mapping prompt prefixes to stored block chains.
 `hosttier.py` is the SECOND tier under both (ISSUE 13): a
@@ -16,9 +15,6 @@ engine integration (`pddl_tpu/serve/engine.py`).
 """
 
 from pddl_tpu.serve.kvcache.block_pool import (
-    donate_prefix_blocks,
-    gather_prefix_into_row,
-    kv_block_pool,
     paged_decode_cache,
     pool_nbytes,
 )
@@ -29,9 +25,6 @@ __all__ = [
     "HostTierCache",
     "HostTierConfig",
     "RadixPrefixCache",
-    "donate_prefix_blocks",
-    "gather_prefix_into_row",
-    "kv_block_pool",
     "paged_decode_cache",
     "pool_nbytes",
 ]

@@ -145,13 +145,14 @@ class ServeMetrics:
         self.prefill_tokens_saved = 0
         self.prefix_evictions = 0
         self.prefix_blocks_live = 0  # gauge, engine-stamped per admission
-        # Paged-attention telemetry (all zero on a copy-mode engine):
-        # `copy_bytes_avoided` counts the pool->slot gather bytes a
-        # prefix hit did NOT copy (matched tokens x per-token KV
-        # bytes — the admission work paging deletes); `blocks_shared`
-        # is the live gauge of pool blocks referenced by >1 slot
-        # (each one a block the copy engine would hold once PER slot —
-        # the capacity-doubling number); `block_table_fill` is the
+        # Block-pool telemetry: `copy_bytes_avoided` counts the bytes
+        # a prefix hit references in place (matched tokens x per-token
+        # KV bytes — what a pool->slot gather would have copied; the
+        # path it measures against went in PR 34, ROADMAP names the
+        # counter a debt); `blocks_shared` is the live gauge of pool
+        # blocks referenced by >1 slot (each one a block a
+        # private-copy design would hold once PER slot — the
+        # capacity-doubling number); `block_table_fill` is the
         # mean occupied fraction of live slots' block tables.
         self.copy_bytes_avoided = 0
         self.blocks_shared = 0       # gauge, engine-stamped per tick

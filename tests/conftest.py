@@ -83,6 +83,35 @@ class FakeClock:
         return self.now
 
 
+def refcount_baseline(prefix) -> bool:
+    """(all refs zero, accounting exact) over the whole radix tree."""
+    stack = [prefix._root]
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children.values())
+        if node is not prefix._root and node.ref != 0:
+            return False
+    return (prefix.blocks_live + prefix.blocks_free
+            == prefix.num_blocks - 1)
+
+
+def assert_pool_idle(eng, cached_blocks=None):
+    """No live stream of a ``ServeEngine`` holds anything: every table
+    row all-scratch, no private block owned, the sharing gauges at
+    their idle values, every radix refcount zero, and every block
+    either cached or (once) on the free list."""
+    assert eng.live_slots == 0 and eng._slice is None
+    assert not eng._tables.any()
+    assert all(not ids for ids in eng._private)
+    assert all(node is None for node in eng._slot_nodes)
+    assert eng.blocks_shared == 0 and eng.block_table_fill == 0.0
+    idx = eng._prefix
+    assert refcount_baseline(idx), "leaked pin or lost block"
+    assert len(set(idx._free)) == idx.blocks_free, "block freed twice"
+    if cached_blocks is not None:
+        assert idx.blocks_live == cached_blocks
+
+
 @pytest.fixture()
 def pin_zero_recompiles():
     """THE fixed-shape contract as a reusable fixture: every resident

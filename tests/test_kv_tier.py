@@ -13,7 +13,7 @@ The contracts under test:
   injected OOM.
 - **Token-exactness**: a chain that round-trips the host tier (or
   crosses replicas over the chain wire format) yields bit-identical
-  streams to ``generate()`` — row and paged engines, GPT and Llama.
+  streams to ``generate()`` — GPT and Llama.
 - **Cold path unchanged**: byte budget 0 compiles the exact untiered
   program set and emits identical tokens.
 - **Budget charge**: promotions price ``promote_tokens_per_block`` per
@@ -81,25 +81,22 @@ def llama_setup():
     return model, {"params": params}
 
 
-def _prompts(n=4, length=24, seed=0):
+def _prompts(n=6, length=24, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, 32, size=length).astype(np.int32)
             for _ in range(n)]
 
 
-def _engine(model, variables, *, paged=False, host=1 << 24, **kw):
+def _engine(model, variables, *, host=1 << 24, **kw):
     """A tier-testable engine: the device pool is deliberately TINY
-    (row: 7 allocatable blocks; paged: floor + 1) so cycling a few
-    3-block prompts forces LRU eviction — the demotion trigger."""
+    (the engine's floor + 1) so cycling a few 3-block prompts forces
+    LRU eviction — the demotion trigger."""
     kw.setdefault("max_slots", 2)
     kw.setdefault("prefill_len", 32)
     kw.setdefault("prefix_block_size", BS)
     kw.setdefault("prefix_chunk", BS)
-    if paged:
-        kw.setdefault("prefix_cache_blocks", 2 * (64 // BS) + 1 + 1)
-    else:
-        kw.setdefault("prefix_cache_blocks", 8)
-    return ServeEngine(model, variables, paged=paged, host_tier=host,
+    kw.setdefault("prefix_cache_blocks", 2 * (64 // BS) + 1 + 1)
+    return ServeEngine(model, variables, host_tier=host,
                        **kw)
 
 
@@ -228,17 +225,16 @@ def test_radix_flush_bypasses_demotion_hook():
 
 
 # ------------------------------------------------- engine token-exact
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_demote_promote_token_exact(gpt_setup, paged,
+def test_demote_promote_token_exact(gpt_setup,
                                     pin_zero_recompiles):
     """Cycling more chains than the device pool holds forces demotion;
     revisiting them forces promotion — and every stream, cold or
     promoted, matches the one-shot ``generate()`` oracle exactly."""
     model, variables = gpt_setup
-    eng = pin_zero_recompiles(_engine(model, variables, paged=paged))
-    # 6 distinct 3-block chains: more than either mode's pool can keep
-    # (row: 7 allocatable; paged: floor 17 minus live usage).
-    prompts = _prompts(6)
+    eng = pin_zero_recompiles(_engine(model, variables))
+    # 6 distinct 3-block chains: more than the pool can keep (floor
+    # 17 minus live usage).
+    prompts = _prompts()
     refs = [ref_greedy(model, variables, p, 4) for p in prompts]
     for _ in range(3):
         outs = _serve_all(eng, prompts)
@@ -256,7 +252,7 @@ def test_demote_promote_token_exact(gpt_setup, paged,
 def test_llama_promotion_token_exact(llama_setup, pin_zero_recompiles):
     model, variables = llama_setup
     eng = pin_zero_recompiles(_engine(model, variables))
-    prompts = _prompts(4, seed=5)
+    prompts = _prompts(seed=5)
     refs = [ref_greedy(model, variables, p, 4) for p in prompts]
     for _ in range(2):
         assert _serve_all(eng, prompts) == refs
@@ -266,25 +262,23 @@ def test_llama_promotion_token_exact(llama_setup, pin_zero_recompiles):
 def test_budget_zero_is_bit_identical_to_untiered(gpt_setup):
     """The cold-path contract: byte budget 0 (or host_tier=None) is
     the untiered engine — same compiled-program SET (no host_promote
-    key), same tokens, in both engine modes."""
+    key), same tokens."""
     model, variables = gpt_setup
-    prompts = _prompts(4)
-    for paged in (False, True):
-        plain = _engine(model, variables, paged=paged, host=None)
-        zero = _engine(model, variables, paged=paged,
-                       host=HostTierConfig(byte_budget=0))
-        plain.warmup(), zero.warmup()
-        assert plain.compile_counts() == zero.compile_counts()
-        assert "host_promote" not in zero.compile_counts()
-        assert not zero.host_tier_enabled
-        outs_p = [_serve_all(plain, prompts) for _ in range(2)]
-        outs_z = [_serve_all(zero, prompts) for _ in range(2)]
-        assert outs_p == outs_z
+    prompts = _prompts()
+    plain = _engine(model, variables, host=None)
+    zero = _engine(model, variables, host=HostTierConfig(byte_budget=0))
+    plain.warmup(), zero.warmup()
+    assert plain.compile_counts() == zero.compile_counts()
+    assert "host_promote" not in zero.compile_counts()
+    assert not zero.host_tier_enabled
+    outs_p = [_serve_all(plain, prompts) for _ in range(2)]
+    outs_z = [_serve_all(zero, prompts) for _ in range(2)]
+    assert outs_p == outs_z
 
 
 def test_host_tier_requires_prefix_machinery(gpt_setup):
     model, variables = gpt_setup
-    with pytest.raises(ValueError, match="prefix-cache machinery"):
+    with pytest.raises(ValueError, match="IS the KV cache"):
         ServeEngine(model, variables, max_slots=2, prefill_len=32,
                     prefix_cache_blocks=0, host_tier=1 << 20)
 
@@ -298,7 +292,7 @@ def test_degraded_mode_touches_the_tier_in_neither_direction(gpt_setup):
     eng = _engine(model, variables, clock=clock,
                   backoff_sleep=_no_sleep, degraded_cooldown_s=100.0)
     eng.warmup()
-    prompts = _prompts(6)
+    prompts = _prompts()
     _serve_all(eng, prompts)          # populate pool + host tier
     _serve_all(eng, prompts)          # revisit: spills + promotions
     spills_before = eng.metrics.host_tier_spills
@@ -334,7 +328,7 @@ def test_promotion_budget_charge(gpt_setup):
         byte_budget=1 << 24, promote_tokens_per_block=3),
         prefill_token_budget=64)
     eng.warmup()
-    prompts = _prompts(4)
+    prompts = _prompts()
     _serve_all(eng, prompts)   # A's chain ends up demoted by the cycle
     target = prompts[0]
 
@@ -370,7 +364,7 @@ def test_min_chain_blocks_policy(gpt_setup):
         byte_budget=1 << 24, min_chain_blocks=3))
     eng.warmup()
     # 2-block prompts (16 tokens): every chain is below the floor.
-    prompts = _prompts(4, length=16, seed=3)
+    prompts = _prompts(12, length=16, seed=3)
     for _ in range(3):
         _serve_all(eng, prompts)
     assert eng.metrics.prefix_evictions > 0
@@ -385,7 +379,7 @@ def test_fault_storm_at_host_promote_replays_token_exact(
                      max_random_injections=4, sleep_fn=_no_sleep)
     eng = pin_zero_recompiles(_engine(model, variables, fault_plan=plan,
                                       backoff_sleep=_no_sleep))
-    prompts = _prompts(4)
+    prompts = _prompts()
     refs = [ref_greedy(model, variables, p, 4) for p in prompts]
     for _ in range(3):
         assert _serve_all(eng, prompts) == refs
@@ -395,8 +389,7 @@ def test_fault_storm_at_host_promote_replays_token_exact(
 
 @pytest.mark.chaos
 @pytest.mark.parametrize("seed", [0, 1, 2])
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_chaos_matrix_faults_at_host_promote(gpt_setup, seed, paged,
+def test_chaos_matrix_faults_at_host_promote(gpt_setup, seed,
                                              pin_zero_recompiles):
     """The ISSUE 13 chaos matrix: seeded transient storms aimed at the
     promotion site while chains cycle through the tier — every request
@@ -406,10 +399,10 @@ def test_chaos_matrix_faults_at_host_promote(gpt_setup, seed, paged,
     plan = FaultPlan(seed=seed, transient_rate=0.5,
                      sites=["host_promote"], max_random_injections=6,
                      sleep_fn=_no_sleep)
-    eng = pin_zero_recompiles(_engine(model, variables, paged=paged,
+    eng = pin_zero_recompiles(_engine(model, variables,
                                       fault_plan=plan,
                                       backoff_sleep=_no_sleep))
-    prompts = _prompts(4, seed=seed)
+    prompts = _prompts(seed=seed)
     refs = [ref_greedy(model, variables, p, 4) for p in prompts]
     for _ in range(3):
         assert _serve_all(eng, prompts) == refs
@@ -417,20 +410,19 @@ def test_chaos_matrix_faults_at_host_promote(gpt_setup, seed, paged,
 
 
 @pytest.mark.chaos
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_kill_mid_promotion_drain_restores_token_exact(gpt_setup, paged):
+def test_kill_mid_promotion_drain_restores_token_exact(gpt_setup):
     """A KILL at the host_promote site unwinds out of step() like a
     real crash while the tier is populated; the drain snapshot (taken
     on the dying engine) restores into a FRESH tiered engine
     token-exactly — the tier's contents die with the process and that
     must not matter."""
     model, variables = gpt_setup
-    prompts = _prompts(6)  # enough chains to overflow either pool
+    prompts = _prompts()  # enough chains to overflow either pool
     refs = [ref_greedy(model, variables, p, 6) for p in prompts]
     plan = FaultPlan(scheduled=[
         FaultSpec(step=s, site="host_promote", kind=FaultKind.KILL)
         for s in range(200)])
-    eng = _engine(model, variables, paged=paged, fault_plan=plan,
+    eng = _engine(model, variables, fault_plan=plan,
                   backoff_sleep=_no_sleep)
     eng.warmup()
     _serve_all(eng, prompts, n_new=6)  # cold pass: no promotions yet
@@ -447,7 +439,7 @@ def test_kill_mid_promotion_drain_restores_token_exact(gpt_setup, paged):
             break
     assert killed, "no promotion happened — the kill never fired"
     snapshot = eng.drain()
-    fresh = _engine(model, variables, paged=paged)
+    fresh = _engine(model, variables)
     fresh.warmup()
     restored = fresh.restore(snapshot)
     fresh.run(max_steps=5000)
@@ -463,7 +455,7 @@ def test_drain_restore_with_tier_populated(gpt_setup):
     fresh tiered engine token-exactly (KV is a pure function of the
     tokens; the tier is an optimization, never restore state)."""
     model, variables = gpt_setup
-    prompts = _prompts(4)
+    prompts = _prompts()
     refs = [ref_greedy(model, variables, p, 8) for p in prompts]
     eng = _engine(model, variables)
     eng.warmup()
@@ -488,7 +480,7 @@ def test_exposition_round_trips_host_tier_series(gpt_setup):
     model, variables = gpt_setup
     eng = _engine(model, variables)
     eng.warmup()
-    prompts = _prompts(4)
+    prompts = _prompts()
     _serve_all(eng, prompts)
     _serve_all(eng, prompts)
     text = serve_exposition(eng.metrics, eng)

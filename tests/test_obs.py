@@ -329,7 +329,7 @@ def test_jsonl_log_appends_whole_lines(tmp_path):
 def test_prometheus_render_parses_strict():
     snap = {"requests_finished": 3, "ttft_p50_s": 0.125,
             "maybe_none": None, "flag": True,
-            "compile_counts": {"tick": 1, "insert": 1}}
+            "compile_counts": {"tick": 1, "sample_first": 1}}
     text = render_prometheus(snap, prefix="pddl_serve",
                              counters=frozenset({"requests_finished"}))
     samples, types = parse_prometheus_text(text)
@@ -545,8 +545,6 @@ def test_tracer_hook_surface_matches_null():
 # The direct children of ``pddl.serve.step`` (``first_token_wait`` nests
 # in ``admit``, under the span-only ``admit_request``).
 TOP_LEVEL_PHASES = tuple(p for p in PHASES if p != "first_token_wait")
-ENGINE_KINDS = pytest.mark.parametrize("paged", [False, True],
-                                       ids=["copy", "paged"])
 
 
 class _Clock:
@@ -574,8 +572,7 @@ class _SlowAdmission(NullTracer):
         self.ticks.append(record)
 
 
-@ENGINE_KINDS
-def test_phase_counters_read_what_the_schedule_implies(gpt_setup, paged):
+def test_phase_counters_read_what_the_schedule_implies(gpt_setup):
     """Two requests through one slot on an injected clock: the
     scheduler's wait, the admission wall and the step/tick counts are
     exactly what the submits and steps imply; a replayed stream adds
@@ -589,8 +586,8 @@ def test_phase_counters_read_what_the_schedule_implies(gpt_setup, paged):
     plan = FaultPlan(scheduled=[FaultSpec(step=3, site="tick",
                                           kind=FaultKind.TRANSIENT)])
     eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
-                      paged=paged, clock=clock, tracer=tracer,
-                      fault_plan=plan, max_retries=0)
+                      clock=clock, tracer=tracer, fault_plan=plan,
+                      max_retries=0)
     eng.warmup()
     a = eng.submit((np.arange(6) * 3 + 1) % 32, 6)   # t = 100
     clock.t = 101.0
@@ -625,8 +622,7 @@ def test_phase_counters_read_what_the_schedule_implies(gpt_setup, paged):
     # Every step had a live slot; the failed tick was never dispatched.
     assert m["decode_ticks"] == steps - 1
     assert set(m["phase_wall_s"]) == set(PHASES)
-    assert all((w > 0) == (paged or phase != "append_blocks")
-               for phase, w in m["phase_wall_s"].items())
+    assert all(w > 0 for w in m["phase_wall_s"].values())
     top = sum(m["phase_wall_s"][p] for p in TOP_LEVEL_PHASES)
     assert 0 < top <= m["step_wall_s"]
     assert m["phase_wall_s"]["first_token_wait"] \
@@ -637,11 +633,10 @@ def test_phase_counters_read_what_the_schedule_implies(gpt_setup, paged):
     assert [r["step"] for r in tracer.ticks] == list(range(steps))
 
 
-@ENGINE_KINDS
-def test_ring_record_carries_phase_wall(gpt_setup, paged):
+def test_ring_record_carries_phase_wall(gpt_setup):
     model, variables = gpt_setup
     eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                      paged=paged, telemetry_capacity=64)
+                      telemetry_capacity=64)
     handles = [eng.submit((np.arange(6) + i) % 32, 3) for i in range(3)]
     eng.run(max_steps=50)
     assert all(h.done for h in handles)
@@ -709,16 +704,13 @@ def _host_spans(trace_dir):
     return out
 
 
-@ENGINE_KINDS
-def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path,
-                                                 paged):
+def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path):
     """A few steps under ``jax.profiler.start_trace`` (TraceMe spans
     only, as the benchmark's traced runs): one ``pddl.serve.step`` per
     engine step, numbered, with ``tick_wait`` and ``admit_request``
     nested inside and ``request_id`` on the latter."""
     model, variables = gpt_setup
-    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                      paged=paged)
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16)
     eng.warmup()
     handles = [eng.submit((np.arange(6) + i) % 32, 4) for i in range(2)]
     options = jax.profiler.ProfileOptions()
@@ -744,9 +736,6 @@ def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path,
         by_name.setdefault(s[0].removeprefix("pddl.serve."), []).append(s)
     assert set(by_name) - {"step", "admit_request"} <= set(PHASES)
     for phase in TOP_LEVEL_PHASES:
-        if phase == "append_blocks" and not paged:
-            assert phase not in by_name
-            continue
         assert by_name[phase], phase
         assert all(inside(s, steps) for s in by_name[phase]), phase
     assert len(by_name["tick_wait"]) == n_steps
@@ -760,16 +749,14 @@ def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path,
     assert all(inside(s, requests) for s in by_name["first_token_wait"])
 
 
-@ENGINE_KINDS
-def test_cancelled_mid_admission_is_a_pop_and_no_admission(gpt_setup,
-                                                           paged):
+def test_cancelled_mid_admission_is_a_pop_and_no_admission(gpt_setup):
     """A sliced prefill cancelled between its scheduler pop and its
     slot: the pop and its wait count, and neither side of
     ``admit_wall_s / admissions`` moves — both are taken at install."""
     model, variables = gpt_setup
     clock = _Clock()
     eng = ServeEngine(model, variables, max_slots=1, prefill_len=32,
-                      prefix_chunk=8, prefill_slice_tokens=8, paged=paged,
+                      prefix_chunk=8, prefill_slice_tokens=8,
                       clock=clock)
     eng.warmup()
     h = eng.submit((np.arange(31) * 3) % 32, 3)      # t = 100

@@ -333,7 +333,7 @@ def test_flightrec_storage_faults_degrade_counted(tmp_path):
 # --------------------------------------------------- SIGKILL postmortem
 _WORKER_CFG = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                    slots=4, prefill_len=16, max_queue_depth=64,
-                   param_seed=0, prefix_cache_blocks=0)
+                   param_seed=0)
 
 
 def test_sigkill_flight_harvest_and_postmortem(tmp_path):

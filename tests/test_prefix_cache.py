@@ -2,23 +2,18 @@
 
 The contracts under test:
 
-- **Token-exactness**: a prefix-HIT admission (gathered blocks + chunked
-  suffix prefill) emits exactly what a cold prefill emits, which itself
-  equals single-request ``generate()`` — for the GPT (scalar-MHA cache)
-  and Llama (GQA + RoPE) families, and composed with int8
-  ``param_transform``. Every exactness test also asserts the hit
-  actually happened (``prefix_hits``/``prefill_tokens_saved``), so a
-  silently-dead cache cannot pass vacuously.
+- **Token-exactness** of prefix-HIT admissions lives in
+  `tests/test_serve_paged.py` (``test_paged_token_exact_*``: GPT, Llama,
+  int8, each at the default pool and at the pool's floor).
 - **Suffix-priced admission**: the prefill-token budget charges the
   UNCACHED suffix, so shared-prefix requests co-admit where cold ones
   serialize.
 - **Refcount/eviction invariants**: property-tested over randomized op
   sequences on the radix index — block accounting exact, pinned chains
   never evicted, interior nodes outlive children, LRU order respected.
-- **Fixed-shape discipline**: the prefix-cache engine (seven resident
-  programs: insert/tick/sample plus gather, narrow+wide chunk-prefill,
-  donate) compiles nothing new after warmup across a hit/miss/evict
-  workload (`pin_zero_recompiles` fixture from conftest).
+- **Fixed-shape discipline**: the engine (tick, first-token sample,
+  narrow+wide chunk-prefill) compiles nothing new after warmup across a
+  hit/miss/evict workload (`pin_zero_recompiles` fixture from conftest).
 """
 
 import jax
@@ -42,107 +37,33 @@ def gpt_setup():
     return model, {"params": params}
 
 
-@pytest.fixture(scope="module")
-def llama_setup():
-    model = tiny_llama(vocab_size=32, max_len=64)
-    prompt = jnp.ones((1, 8), jnp.int32)
-    params = model.init(jax.random.key(1), prompt, train=False)["params"]
-    return model, {"params": params}
-
-
-def _exactness_workload(model, variables, ref_variables=None, **engine_kw):
-    """Cold admit, full-prefix re-hit, and partial-prefix hit — all
-    pinned token-exact against generate(); returns the engine so the
-    caller can inspect telemetry."""
-    ref_variables = ref_variables or variables
-    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                      **engine_kw)
-    base = (np.arange(12) * 5 + 1) % 32
-    sibling = np.concatenate([base[:8], (np.arange(6) + 17) % 32])
-    h_cold = eng.submit(base, 6)
-    eng.run(max_steps=100)
-    h_hit = eng.submit(base, 6)          # full-chain hit
-    h_part = eng.submit(sibling, 6)      # shares base's first block
-    eng.run(max_steps=100)
-    assert h_cold.tokens == _ref_greedy(model, ref_variables, base, 6)
-    assert h_hit.tokens == _ref_greedy(model, ref_variables, base, 6)
-    assert h_part.tokens == _ref_greedy(model, ref_variables, sibling, 6)
-    # Not vacuous: the hits really took the gather path.
-    assert eng.metrics.prefix_hits >= 2
-    assert eng.metrics.prefill_tokens_saved >= 2 * eng.prefix_block_size
-    return eng
-
-
-def test_prefix_hit_token_exact_gpt(gpt_setup):
-    model, variables = gpt_setup
-    eng = _exactness_workload(model, variables)
-    assert eng.prefix_cache_enabled
-
-
-def test_prefix_hit_token_exact_llama(llama_setup):
-    """The GQA + RoPE family: post-RoPE cached keys are position-
-    absolute, so gathered prefix blocks must be bit-valid in a new
-    request's row cache."""
-    model, variables = llama_setup
-    _exactness_workload(model, variables)
-
-
-@pytest.mark.parametrize("family", ["gpt", "llama"])
-def test_int8_prefix_hit_token_exact(family, gpt_setup, llama_setup):
-    """int8 param_transform composes: the pool stores K/V (which int8
-    weight storage never touches), dequant runs inside the chunked
-    suffix prefill like every other compiled program."""
-    from pddl_tpu.ops.quant import dequantize, quantize_int8
-
-    model, variables = gpt_setup if family == "gpt" else llama_setup
-    qparams = quantize_int8(variables["params"], min_elems=128)
-    dense = {"params": dequantize(qparams)}
-    _exactness_workload(model, {"params": qparams}, ref_variables=dense,
-                        param_transform=dequantize)
-
-
 def test_zero_recompiles_across_hit_miss_evict(gpt_setup,
                                                pin_zero_recompiles):
-    """Every resident program (seven with the prefix cache on) stays at
-    one executable through cold admissions, full and partial hits, and
-    pool-pressure evictions (a pool too small for the workload's
-    distinct prefixes)."""
+    """Every resident program stays at one executable through cold
+    admissions, full and partial hits, and pool-pressure evictions (a
+    pool at its floor, too small for the workload's distinct
+    prefixes)."""
     model, variables = gpt_setup
     eng = pin_zero_recompiles(
         ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                    prefix_cache_blocks=4))  # 3 usable blocks + scratch
-    for i in range(6):  # distinct prompts force eviction churn
-        p = (np.arange(14) * 7 + 11 * i) % 32
+                    prefix_cache_blocks=17))  # the floor: 16 + scratch
+    for i in range(12):  # 24 distinct full blocks: eviction churn
+        p = (np.arange(16) * 7 + 11 * i + i // 3) % 32
         h = eng.submit(p, 4)
         eng.run(max_steps=100)
         assert h.tokens == _ref_greedy(model, variables, p, 4)
-    assert eng.metrics.prefix_lookups == 6
+    h = eng.submit(p, 4)  # and one full-chain hit
+    eng.run(max_steps=100)
+    assert h.tokens == _ref_greedy(model, variables, p, 4)
+    assert eng.metrics.prefix_lookups == 13
+    assert eng.metrics.prefix_hits == 1
     assert eng.metrics.prefix_evictions > 0  # pressure actually happened
-
-
-def test_block_aligned_repeat_never_thrashes_a_full_pool(gpt_setup):
-    """Donation dedup: a block-aligned prompt's tail block can never be
-    GATHERED (the match cap leaves one suffix token) but it IS stored —
-    re-admitting the same prompt must descend the stored chain instead
-    of allocating a fresh block, or a full pool would LRU-evict a
-    useful block to supply an id the index hands straight back."""
-    model, variables = gpt_setup
-    eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
-                      prefix_block_size=8, prefix_cache_blocks=3)
-    p = (np.arange(16) * 3 + 5) % 32  # 2 blocks, exactly fills the pool
-    for _ in range(3):
-        h = eng.submit(p, 3)
-        eng.run(max_steps=50)
-        assert h.tokens == _ref_greedy(model, variables, p, 3)
-    assert eng.metrics.prefix_evictions == 0  # repeats allocate nothing
-    assert eng.metrics.prefix_blocks_live == 2
-    assert eng.metrics.prefix_hits == 2
 
 
 def test_suffix_priced_admission_budget(gpt_setup):
     """The budget charges the uncached suffix: two shared-prefix
     requests co-admit under a budget that would serialize them cold
-    (the prefix-off control engine proves the discrimination)."""
+    (the cold-index control engine proves the discrimination)."""
     model, variables = gpt_setup
     shared = (np.arange(8) * 3 + 2) % 32
 
@@ -161,10 +82,10 @@ def test_suffix_priced_admission_budget(gpt_setup):
     eng.step()
     assert len(a.tokens) >= 1 and len(b.tokens) >= 1  # both admitted
 
-    # Control: identical budget, prefix caching off — the second
+    # Control: identical budget, nothing cached yet — the second
     # request's full 10-token prompt exceeds the burst budget and waits.
     ctl = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                      prefill_token_budget=6, prefix_cache_blocks=0)
+                      prefill_token_budget=6)
     c, d = (ctl.submit(p, 4) for p in prompts())
     ctl.step()
     assert len(c.tokens) >= 1
@@ -175,7 +96,7 @@ def test_suffix_priced_admission_budget(gpt_setup):
 def test_gather_scatter_roundtrip():
     """cache_blocks_scatter then cache_blocks_gather reproduces the row
     tokens bit-exactly at block granularity (the device copy contract
-    both halves of the prefix cache rest on)."""
+    both directions of the host tier rest on)."""
     rng = np.random.default_rng(0)
     pool = jnp.zeros((6, 2, 4, 3), jnp.float32)  # [N, H, bs, D]
     row = jnp.asarray(rng.normal(size=(1, 2, 32, 3)), jnp.float32)
@@ -331,8 +252,7 @@ def test_engine_validation():
                                       train=False)["params"]}
     with pytest.raises(ValueError, match="cacheable block"):
         ServeEngine(model, variables, max_slots=1, prefill_len=8,
-                    prefix_block_size=8, prefix_cache_blocks=8)
+                    prefix_block_size=8)
     with pytest.raises(ValueError, match="prefix_chunk"):
         ServeEngine(model, variables, max_slots=1, prefill_len=32,
-                    prefix_block_size=8, prefix_chunk=48,
-                    prefix_cache_blocks=8)
+                    prefix_block_size=8, prefix_chunk=48)

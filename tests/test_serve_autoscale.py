@@ -72,7 +72,6 @@ def _engine_factory(model, variables, *, max_queue_depth=3):
     def make():
         return ServeEngine(model, variables, max_slots=2, prefill_len=16,
                            max_queue_depth=max_queue_depth,
-                           prefix_cache_blocks=0,
                            backoff_sleep=_no_sleep)
     return make
 
@@ -386,7 +385,7 @@ def test_router_scale_up_revives_orphans(gpt_setup):
 
     def make():
         return ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                           max_queue_depth=8, prefix_cache_blocks=0,
+                           max_queue_depth=8,
                            fault_plan=plan, backoff_sleep=_no_sleep)
 
     fleet = FleetRouter([LocalReplica(0, make)], respawn=True,

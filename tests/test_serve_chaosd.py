@@ -85,7 +85,6 @@ def _engine_factory(model, variables, plan=None):
     def make():
         return ServeEngine(model, variables, max_slots=2, prefill_len=16,
                            fault_plan=plan, max_queue_depth=64,
-                           prefix_cache_blocks=0,
                            backoff_sleep=_no_sleep)
     return make
 
@@ -622,7 +621,7 @@ def test_conductor_campaign_disagg_tier_fleet(gpt_setup, tmp_path):
 # cfg, so parent and child provably share params.
 _WORKER_CFG = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                    slots=4, prefill_len=16, max_queue_depth=64,
-                   param_seed=0, prefix_cache_blocks=0)
+                   param_seed=0)
 
 
 @pytest.mark.chaosd

@@ -76,17 +76,37 @@ def _no_sleep(_):
     pass
 
 
+class _StepWallClock:
+    """The router's ``gray_timer`` and, once `_make_gray` arms it, the
+    victim's injected-LATENCY sleep: this clock moves only when an
+    injected fault "sleeps", so the step wall the gray detector samples
+    is the schedule's (calls x ``latency_s`` on the victim, zero on its
+    sibling) whatever else the machine is running. On the wall clock a
+    2 ms excess sat inside a loaded worker's own jitter, which could
+    mask the victim or suspect the sibling (PERF.md, PR 34)."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
 def _local_fleet(model, variables, n, *, with_plans=False,
                  max_queue_depth=64, **router_kw):
     plans = [FaultPlan(sleep_fn=_no_sleep) if with_plans else None
              for _ in range(n)]
+    if "gray" in router_kw:
+        router_kw.setdefault("gray_timer", _StepWallClock())
 
     def factory(plan):
         def make():
             return ServeEngine(model, variables, max_slots=2,
                                prefill_len=16, fault_plan=plan,
                                max_queue_depth=max_queue_depth,
-                               prefix_cache_blocks=0,
                                backoff_sleep=_no_sleep)
         return make
 
@@ -100,7 +120,6 @@ def _fresh_replicas(model, variables, n):
     def factory():
         return ServeEngine(model, variables, max_slots=2,
                            prefill_len=16, max_queue_depth=64,
-                           prefix_cache_blocks=0,
                            backoff_sleep=_no_sleep)
     return [LocalReplica(i, factory) for i in range(n)]
 
@@ -521,7 +540,6 @@ def test_recover_mid_chain_pull(gpt_setup, tmp_path):
         # 24-token prompts.
         return ServeEngine(model, variables, max_slots=2,
                            prefill_len=32, max_queue_depth=64,
-                           prefix_cache_blocks=0,
                            backoff_sleep=_no_sleep)
 
     recovered, revived = FleetRouter.recover(
@@ -585,9 +603,9 @@ def test_gray_detector_suspects_drift_and_recovers():
 def _make_gray(fleet, plans, victim_id, *, latency_s):
     """Drive the fleet until the detector suspects ``victim_id``: a
     long-running stream keeps each engine ticking; after a clean
-    baseline window, the victim's every device call gains a real
-    latency injection, which the router's per-step wall sampling
-    sees."""
+    baseline window, the victim's every device call gains a latency
+    injection on the fleet's `_StepWallClock`, which the router's
+    per-step wall sampling sees."""
     det = fleet.gray
     # The median-of-``smooth`` prefilter (ISSUE 18 de-flake) consumes
     # ``smooth`` raw samples per window entry — scale the drive counts
@@ -597,7 +615,7 @@ def _make_gray(fleet, plans, victim_id, *, latency_s):
         fleet.step()
     plans[victim_id]._rates = (0.0, 0.0, 1.0)  # latency on every call
     plans[victim_id].latency_s = latency_s
-    plans[victim_id]._sleep = time.sleep
+    plans[victim_id]._sleep = fleet._gray_timer.sleep
     for _ in range(200 * det.smooth):
         fleet.step()
         # A gray_drain fleet acts on the suspicion INSIDE the same
@@ -693,13 +711,13 @@ def test_hedge_copy_failure_does_not_kill_the_stream(gpt_setup):
         def make():
             return ServeEngine(model, variables, max_slots=2,
                                prefill_len=16, fault_plan=plan,
-                               prefix_cache_blocks=0,
                                backoff_sleep=_no_sleep)
         return make
 
     fleet = FleetRouter(
         [FailsWhenArmed(i, factory(plans[i])) for i in range(2)],
         affinity_block_size=8, affinity_blocks=1, respawn=False,
+        gray_timer=_StepWallClock(),
         # smooth=3 (ISSUE 18 de-flake): median-of-3 prefilter kills
         # single-sample wall outliers; baseline=4 medians keeps the
         # same 12 RAW samples of baseline coverage as before.
@@ -765,7 +783,7 @@ def test_gray_drain_retires_suspect_via_live_migration(gpt_setup):
 # --------------------------------------------------------- process fleet
 _WORKER_CFG = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                    slots=4, prefill_len=16, max_queue_depth=64,
-                   param_seed=0, prefix_cache_blocks=0)
+                   param_seed=0)
 
 
 @pytest.mark.chaos

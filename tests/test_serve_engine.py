@@ -14,16 +14,25 @@ The contracts under test:
   queue sheds load with the typed ``QueueFull``.
 - **Fixed-shape discipline**: after ``warmup()`` a mixed workload
   (different prompt lengths, sampling params, request sizes) compiles
-  NOTHING new — all four resident programs stay at exactly one
+  NOTHING new — every resident program stays at exactly one
   executable.
+- **One engine**: the default constructor IS the paged engine; the
+  site vocabulary, the donation map and ``compile_counts()`` agree for
+  every engine kind; whatever ends a stream hands its blocks back.
 """
+
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import ref_greedy as _ref_greedy, FakeClock as _FakeClock
+from conftest import (
+    FakeClock as _FakeClock,
+    assert_pool_idle as _assert_pool_idle,
+    ref_greedy as _ref_greedy,
+)
 from pddl_tpu.models.gpt import (
     batched_filtered_logits,
     filtered_logits,
@@ -33,12 +42,15 @@ from pddl_tpu.models.gpt import (
 )
 from pddl_tpu.models.llama import tiny_llama
 from pddl_tpu.serve import (
+    FaultPlan,
     FinishReason,
+    Priority,
     QueueFull,
     RequestState,
     SamplingParams,
     ServeEngine,
 )
+from pddl_tpu.serve.tenant import AdapterRegistry, TenantConfig
 
 
 @pytest.fixture(scope="module")
@@ -338,7 +350,8 @@ def test_deadline_expired_in_queue_never_pays_prefill(gpt_setup):
     assert doomed.state == RequestState.TIMED_OUT
     assert doomed.tokens == []  # never ran
     assert fine.state == RequestState.FINISHED
-    assert fine.tokens == _ref_greedy(model, variables, (np.arange(6) + 1) % 32, 3)
+    assert fine.tokens == _ref_greedy(model, variables,
+                                      (np.arange(6) + 1) % 32, 3)
 
 
 def test_queue_full_sheds_load_typed(gpt_setup):
@@ -416,9 +429,10 @@ def test_eos_finishes_early(gpt_setup):
     assert h.tokens == ref[:ref.index(eos) + 1]
 
 
-def test_submit_validation_and_ring_refusal(gpt_setup):
+def test_submit_validation(gpt_setup):
     model, variables = gpt_setup
-    eng = ServeEngine(model, variables, max_slots=1, prefill_len=8)
+    eng = ServeEngine(model, variables, max_slots=1, prefill_len=8,
+                      prefix_block_size=4)
     with pytest.raises(ValueError, match="prefill_len"):
         eng.submit(np.zeros(9, np.int32), 4)
     with pytest.raises(ValueError, match="max_len"):
@@ -427,6 +441,206 @@ def test_submit_validation_and_ring_refusal(gpt_setup):
         eng.submit(np.zeros(0, np.int32), 4)
     with pytest.raises(ValueError, match="top_k/top_p"):
         SamplingParams(top_k=4)
-    swa = tiny_llama(vocab_size=32, max_len=1024, sliding_window=64)
-    with pytest.raises(NotImplementedError, match="ring"):
-        ServeEngine(swa, variables, max_slots=1)
+
+
+# ------------------------------------------------------------ one engine
+_PAGED_SITES = {"sample_first", "chunk_prefill", "chunk_prefill_wide",
+                "tick"}
+
+
+def test_default_constructor_is_the_paged_engine(gpt_setup):
+    """No option selects an engine any more: ``ServeEngine(model,
+    variables)`` decodes from the block pool through block tables, and
+    its program set holds none of the resident-row engines' sites."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16)
+    eng.warmup()
+    assert eng.paged
+    assert set(eng.compile_counts()) == _PAGED_SITES
+    assert not {"insert", "gather", "donate", "prefill"} \
+        & (set(eng.compile_counts()) | set(FaultPlan.SITES))
+    assert sorted(eng.program_lowerings()) == [
+        "chunk_prefill", "chunk_prefill_wide", "tick"]
+    # The tick takes the [S, T] block tables: the pool is the cache.
+    assert eng._tables.shape == (2, 64 // 8)
+    assert "paged" in eng.tick_lowering().as_text()
+    assert eng.prefix_pool_nbytes > 0
+
+
+def test_paged_keyword_accepts_only_true(gpt_setup):
+    """``paged`` is what is left of the switch: ``True`` (what the
+    benchmark's system modules pass, with every keyword they pass
+    beside it) builds the same engine as leaving it out; ``False``
+    names an engine that is gone and raises, never serves as something
+    else."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, paged=True, max_slots=2,
+                      prefill_len=16, prefix_block_size=8,
+                      prefix_cache_blocks=2 * 8 + 1, prefix_chunk=8,
+                      prefill_slice_tokens=None, max_queue_depth=8,
+                      aging_s=30.0, rng=jax.random.key(1),
+                      telemetry_capacity=16, param_transform=None)
+    eng.warmup()
+    assert set(eng.compile_counts()) == _PAGED_SITES
+    for bad in (False, None, 0):
+        with pytest.raises(ValueError, match="removed in PR 34"):
+            ServeEngine(model, variables, paged=bad)
+
+
+def test_worker_refuses_paged_false_before_the_engine_builds(
+        monkeypatch, capsys):
+    """A fleet worker's config comes from outside the process:
+    ``paged: false`` exits 2 with a typed message, like a bad role,
+    before any engine is built."""
+    from pddl_tpu.serve.fleet import worker
+
+    def _never(config):
+        raise AssertionError("the engine was built")
+
+    monkeypatch.setattr(worker, "build_engine", _never)
+    cfg = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
+               slots=2, prefill_len=16, param_seed=0, paged=False)
+    assert worker.main(["--config-json", json.dumps(cfg)]) == 2
+    err = capsys.readouterr().err
+    assert "paged=False" in err and "removed in PR 34" in err
+    # `paged: true` and the key absent both pass the gate (and reach
+    # the engine build, which this test has stubbed out).
+    for ok in ({**cfg, "paged": True},
+               {k: v for k, v in cfg.items() if k != "paged"}):
+        with pytest.raises(AssertionError, match="engine was built"):
+            worker.main(["--config-json", json.dumps(ok)])
+
+
+def _engine_of_kind(kind, model, variables):
+    kw = dict(max_slots=2, prefill_len=16)
+    if kind == "narrow_only":       # chunk == prefill_len: no wide twin
+        kw.update(prefix_chunk=16)
+    elif kind == "spec_ngram":
+        kw.update(spec_k=3)
+    elif kind == "spec_draft_model":
+        draft = tiny_gpt(vocab_size=32, max_len=64, depth=1)
+        dvars = {"params": draft.init(
+            jax.random.key(9), jnp.ones((1, 8), jnp.int32),
+            train=False)["params"]}
+        kw.update(spec_k=2, spec_draft_model=draft,
+                  spec_draft_variables=dvars)
+    elif kind in ("tenant", "tenant_spec"):
+        reg = AdapterRegistry(model.embed_dim, model.vocab_size, rank=4)
+        reg.register_random("acme", seed=100, scale=0.1)
+        kw.update(tenant=TenantConfig(registry=reg))
+        if kind == "tenant_spec":
+            kw.update(spec_k=2)
+    elif kind == "host_tier":
+        kw.update(host_tier=1 << 20)
+    elif kind == "sliced":
+        kw.update(prefill_slice_tokens=4, prefix_chunk=4)
+    else:
+        assert kind == "plain"
+    return ServeEngine(model, variables, **kw)
+
+
+@pytest.mark.parametrize("kind", [
+    "plain", "narrow_only", "spec_ngram", "spec_draft_model", "tenant",
+    "tenant_spec", "host_tier", "sliced"])
+def test_sites_counts_and_donation_map_agree(gpt_setup, kind):
+    """One vocabulary, three readers: after ``warmup()`` every
+    ``compile_counts()`` key is a ``FaultPlan`` site and stands at one
+    executable, and every site the engine's donation map names is a
+    program this engine compiled (a speculative engine has no ``tick``
+    to lose, an n-gram one no ``draft_prefill``) that really does
+    donate the pool."""
+    model, variables = gpt_setup
+    eng = _engine_of_kind(kind, model, variables)
+    eng.warmup()
+    counts = eng.compile_counts()
+    assert set(counts) <= set(FaultPlan.SITES)
+    assert all(v == 1 for v in counts.values()), counts
+    assert set(eng._donated_by_site) <= set(counts)
+    assert set(eng._donated_by_site.values()) == {"pool"}
+    assert ("tick" in counts) != ("verify" in counts)
+    assert ("chunk_prefill_wide" in counts) == (kind != "narrow_only")
+    # Whatever donates nothing is not in the map; the rest all are.
+    quiet = {"sample_first", "adapter_load"}
+    if kind in ("spec_ngram", "tenant_spec"):
+        quiet.add("draft")      # the n-gram drafter reads, never owns
+    assert set(counts) - set(eng._donated_by_site) == quiet & set(counts)
+    # Warmup left no trace: nothing live, nothing cached, tables scratch.
+    _assert_pool_idle(eng, cached_blocks=0)
+
+
+@pytest.mark.parametrize("ending", [
+    "cancelled", "deadline", "eos", "length", "queue_full_shed",
+    "preempted_and_resumed", "cancelled_mid_slice"])
+def test_every_ending_hands_its_blocks_back(gpt_setup, ending):
+    """However a stream ends, its slot's table row goes all-scratch,
+    the private blocks it owned (its prompt's tail and everything it
+    generated) are back on the free list, and only its prompt's full
+    blocks stay, cached and unpinned."""
+    model, variables = gpt_setup
+    clock = _FakeClock()
+    p = (np.arange(12) * 5 + 1) % 32      # 1 full block + 4 tokens
+    ref = _ref_greedy(model, variables, p, 12)
+    kw = dict(max_slots=1, prefill_len=16, clock=clock, max_queue_depth=1)
+    if ending == "eos":
+        kw.update(eos_token=ref[9])
+    if ending == "cancelled_mid_slice":
+        kw.update(prefill_slice_tokens=4, prefix_chunk=4)
+    eng = ServeEngine(model, variables, **kw)
+    eng.warmup()
+    _assert_pool_idle(eng, cached_blocks=0)
+    prio = (Priority.BEST_EFFORT if ending == "preempted_and_resumed"
+            else Priority.INTERACTIVE)
+    h = eng.submit(p, 12, priority=prio,
+                   deadline_s=10.0 if ending == "deadline" else None)
+    eng.step()
+    if ending == "cancelled_mid_slice":
+        # One slice of three is in: blocks allocated, no slot yet.
+        assert not h.tokens and eng._slice is not None
+        held = list(eng._slice["private"])
+        assert held and not set(held) & set(eng._prefix._free)
+        h.cancel()
+        eng.step()
+        assert h.finish_reason is FinishReason.CANCELLED
+        assert set(held) <= set(eng._prefix._free)
+        _assert_pool_idle(eng, cached_blocks=0)
+        return
+    for _ in range(5):
+        eng.step()
+    assert h.state == RequestState.RUNNING
+    # Live: 18 positions written = 3 table entries, one of them the
+    # donated (cached, pinned) prompt block, two private.
+    held = list(eng._private[0])
+    assert len(held) == 2 and (eng._tables[0] != 0).sum() == 3
+    assert eng.block_table_fill == 3 / 8
+    assert not set(held) & set(eng._prefix._free)
+    if ending == "cancelled":
+        h.cancel()
+    elif ending == "deadline":
+        clock.now = 11.0
+    elif ending == "queue_full_shed":
+        eng.submit((np.arange(5) + 2) % 32, 2)        # fills the queue
+        with pytest.raises(QueueFull):
+            eng.submit((np.arange(6) + 3) % 32, 2)    # shed: holds nothing
+    elif ending == "preempted_and_resumed":
+        ia = eng.submit((np.arange(7) * 3 + 2) % 32, 3)
+        eng.step()  # parks h, admits the interactive request
+        assert h.state == RequestState.QUEUED and h.preemptions == 1
+        assert set(held) <= set(eng._prefix._free) | set(eng._private[0])
+    eng.run(max_steps=200)
+    want = {"cancelled": FinishReason.CANCELLED,
+            "deadline": FinishReason.TIMED_OUT,
+            "eos": FinishReason.EOS}.get(ending, FinishReason.LENGTH)
+    assert h.finish_reason is want
+    if want is FinishReason.LENGTH:
+        assert h.tokens == ref          # resumed or undisturbed: exact
+    elif ending == "eos":
+        assert h.tokens == ref[:ref.index(ref[9]) + 1]
+    else:
+        assert h.tokens == ref[:len(h.tokens)] and len(h.tokens) < 12
+    if ending == "preempted_and_resumed":
+        assert ia.tokens == _ref_greedy(
+            model, variables, (np.arange(7) * 3 + 2) % 32, 3)
+    assert set(held) <= set(eng._prefix._free)
+    # Only full prompt blocks stay cached: p's one (the shed and the
+    # interactive prompts are shorter than a block).
+    _assert_pool_idle(eng, cached_blocks=1)

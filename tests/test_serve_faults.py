@@ -50,7 +50,12 @@ from pddl_tpu.serve import (
 )
 from pddl_tpu.serve.scheduler import FCFSScheduler
 from pddl_tpu.serve.request import Request, RequestHandle
-from conftest import ref_greedy as _ref_greedy, FakeClock as _FakeClock
+from conftest import (
+    FakeClock as _FakeClock,
+    assert_pool_idle,
+    ref_greedy as _ref_greedy,
+    refcount_baseline as _refcount_baseline,
+)
 
 
 @pytest.fixture(scope="module")
@@ -284,48 +289,55 @@ def test_replay_budget_exhausted_fails_request_not_engine(gpt_setup):
 
 
 def test_oom_degrades_flushes_and_rearms(gpt_setup):
-    """RESOURCE_EXHAUSTED on the gather path: unpinned pool blocks are
-    flushed, donations stop, serving continues cold and token-exact,
-    and the prefix cache re-arms (hits resume) after the cool-down."""
+    """RESOURCE_EXHAUSTED in a prefix hit's suffix chunk: unpinned pool
+    blocks are flushed (the chain the faulted admission had pinned in
+    place is spared — its table referenced it), donations stop, serving
+    continues cold and token-exact, and the prefix cache re-arms (hits
+    resume) after the cool-down."""
     model, variables = gpt_setup
     clock = _FakeClock()
     p = (np.arange(12) * 5 + 1) % 32
+    q = (np.arange(11) * 7 + 3) % 32
     ref = _ref_greedy(model, variables, p, 4)
     plan = FaultPlan()
     eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
                       clock=clock, fault_plan=plan,
                       degraded_cooldown_s=5.0, backoff_sleep=_no_sleep)
-    assert eng.prefix_cache_enabled
-    h0 = eng.submit(p, 4)
-    eng.run(max_steps=50)
-    assert h0.tokens == ref
-    assert eng._prefix.blocks_live > 0
-    assert eng.prefix_pool_nbytes > 0  # the sheddable-HBM gauge
-    # The NEXT admission's gather (a prefix hit on p's chain) OOMs.
-    plan._sched[(_next_step(eng), "gather")] = [FaultKind.OOM]
+    for prompt in (p, q):
+        h0 = eng.submit(prompt, 4)
+        eng.run(max_steps=50)
+        assert h0.tokens == _ref_greedy(model, variables, prompt, 4)
+    assert eng._prefix.blocks_live == 2  # p's block and q's
+    assert eng.prefix_pool_nbytes > 0  # the pool's HBM gauge
+    # The NEXT admission's chunk (the suffix of a hit on p's chain) OOMs.
+    plan._sched[(_next_step(eng), "chunk_prefill")] = [FaultKind.OOM]
     h1 = eng.submit(p, 4)
     eng.run(max_steps=50)
     assert h1.state == RequestState.FINISHED
     assert h1.tokens == ref  # replayed cold, still exact
     assert h1.replays == 1
     assert eng.degraded
-    assert eng._prefix.blocks_live == 0  # flushed (nothing was pinned)
+    # q's unpinned block was flushed; p's was pinned under h1 when the
+    # OOM fired and survives, unpinned again by the unwind.
+    assert eng._prefix.blocks_live == 1
+    assert eng._prefix.match(p, max_blocks=1).n_blocks == 1
+    assert eng._prefix.match(q, max_blocks=1).n_blocks == 0
     assert eng.metrics.degraded_entries == 1
     # While degraded: no lookups, no donations, still exact.
     lookups_during = eng.metrics.prefix_lookups
-    h2 = eng.submit(p, 4)
+    h2 = eng.submit(q, 4)
     eng.run(max_steps=50)
-    assert h2.tokens == ref
+    assert h2.tokens == _ref_greedy(model, variables, q, 4)
     assert eng.metrics.prefix_lookups == lookups_during
-    assert eng._prefix.blocks_live == 0
+    assert eng._prefix.blocks_live == 1
     # Past the cool-down the cache re-arms: donation resumes, then hits.
     clock.now += 6.0
-    h3 = eng.submit(p, 4)
+    h3 = eng.submit(q, 4)
     eng.run(max_steps=50)
     assert not eng.degraded
     assert eng.metrics.degraded_time_s > 0
-    assert h3.tokens == ref
-    assert eng._prefix.blocks_live > 0  # donated again
+    assert h3.tokens == _ref_greedy(model, variables, q, 4)
+    assert eng._prefix.blocks_live == 2  # q donated again
     hits_before = eng.metrics.prefix_hits
     h4 = eng.submit(p, 4)
     eng.run(max_steps=50)
@@ -359,9 +371,10 @@ def test_classify_real_runtime_errors(message, kind):
 def test_real_error_on_donated_program_never_redispatches(gpt_setup):
     """A REAL device error (not an injected pre-dispatch fault) from a
     donated-buffer program may have consumed its input, so the engine
-    must escalate immediately — rebuild the slot pool and replay —
-    instead of retrying into a deleted array. Simulated by raising the
-    installed jax's own runtime error class from the insert program."""
+    must escalate immediately — rebuild the block pool and replay
+    every live slot — instead of retrying into a deleted array.
+    Simulated by raising the installed jax's own runtime error class
+    from an admission's chunk program."""
     model, variables = gpt_setup
     reqs = [((np.arange(6) * 3 + 2) % 32, 6), ((np.arange(9) + 5) % 32, 5)]
     refs = [_ref_greedy(model, variables, p, n) for p, n in reqs]
@@ -371,29 +384,93 @@ def test_real_error_on_donated_program_never_redispatches(gpt_setup):
     h0 = eng.submit(*reqs[0])
     eng.step()
     assert h0.state == RequestState.RUNNING
-    real_insert, calls = eng._insert_p, {"n": 0}
+    real_chunk, calls = eng._chunk_p, {"n": 0}
 
-    def flaky_insert(*args):
+    def flaky_chunk(*args):
         calls["n"] += 1
         if calls["n"] == 1:
             raise jax.errors.JaxRuntimeError(
                 "INTERNAL: interconnect hiccup mid-dispatch")
-        return real_insert(*args)
+        return real_chunk(*args)
 
-    eng._insert_p = flaky_insert
+    eng._chunk_p = flaky_chunk
     try:
         h1 = eng.submit(*reqs[1])
         eng.run(max_steps=100)
     finally:
-        eng._insert_p = real_insert
+        eng._chunk_p = real_chunk
     for h, ref in zip((h0, h1), refs):
         assert h.state == RequestState.FINISHED
         assert h.tokens == ref
     # Escalated, not retried: the failing dispatch was never re-issued
-    # (call 2 is the replay admission's fresh insert), the mid-stream
-    # neighbor was replayed off the rebuilt pool cache too.
+    # (call 2 is a replay admission's fresh chunk), the mid-stream
+    # neighbor was replayed off the rebuilt pool too.
     assert eng.metrics.retries == 0
     assert h0.replays == 1 and h1.replays == 1
+    assert _refcount_baseline(eng._prefix)
+
+
+# ---------------------------------------------- the matrix, site by site
+# `test_chaos_matrix` draws its sites at random; this holds every
+# surviving site to every recoverable fault kind by name. The workload
+# reaches all four: a 14-token prompt takes the wide chunk, the others
+# two narrow ones; every fresh admission samples a first token.
+_SITE_KINDS = {
+    "transient_past_retries": dict(transient_rate=1.0,
+                                   max_random_injections=2),
+    "oom": dict(oom_rate=1.0, max_random_injections=1),
+    "latency": dict(latency_rate=1.0, latency_s=1e-4,
+                    max_random_injections=2),
+}
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("kind", sorted(_SITE_KINDS))
+@pytest.mark.parametrize("site", ["chunk_prefill", "chunk_prefill_wide",
+                                  "tick", "sample_first"])
+def test_fault_matrix_per_site(gpt_setup, workload_refs,
+                               pin_zero_recompiles, site, kind):
+    """One fault kind aimed at one site from the first dispatch on:
+    a transient burst one past ``max_retries`` (the touched state is
+    lost and replays), an OOM (DEGRADED, replay on the cold path,
+    re-arm), a latency spike (nothing lost). Every stream ends
+    token-exact against ``generate()``, nothing recompiles, and the
+    pool ends with every pin released and every block cached or
+    free."""
+    model, variables = gpt_setup
+    clock = _FakeClock()
+    plan = FaultPlan(seed=0, sites=(site,), sleep_fn=_no_sleep,
+                     **_SITE_KINDS[kind])
+    eng = pin_zero_recompiles(ServeEngine(
+        model, variables, max_slots=2, prefill_len=16, clock=clock,
+        fault_plan=plan, max_retries=1, degraded_cooldown_s=5.0,
+        backoff_sleep=_no_sleep))
+    handles = [eng.submit(p, n) for p, n in _WORKLOAD]
+    eng.run(max_steps=300)
+    assert not eng.has_work
+    for h, ref in zip(handles, workload_refs):
+        assert h.state == RequestState.FINISHED
+        assert h.tokens == ref
+    assert plan.total_injected \
+        == _SITE_KINDS[kind]["max_random_injections"]
+    m = eng.metrics
+    if kind == "transient_past_retries":
+        # One retry, then the budget is spent: lost, replayed.
+        assert m.retries == 1 and m.retry_sites == {site: 1}
+        assert m.replays >= 1 and m.degraded_entries == 0
+    elif kind == "oom":
+        assert m.retries == 0  # never blind-retried
+        assert m.replays >= 1 and m.degraded_entries == 1
+        assert eng.degraded
+        clock.now += 6.0
+        again = eng.submit(*_WORKLOAD[0])
+        eng.run(max_steps=100)
+        assert again.tokens == workload_refs[0]
+        assert not eng.degraded  # re-armed
+    else:
+        assert (m.retries, m.replays, m.degraded_entries) == (0, 0, 0)
+    assert max(h.replays for h in handles) <= 1
+    assert_pool_idle(eng)
 
 
 # -------------------------------------------------------- drain / restore
@@ -699,18 +776,6 @@ def test_deadline_shed_at_pop_time(gpt_setup):
 
 
 # -------------------------------------------------------- refcount hygiene
-def _refcount_baseline(prefix):
-    """(all refs zero, accounting exact) over the whole radix tree."""
-    stack = [prefix._root]
-    while stack:
-        node = stack.pop()
-        stack.extend(node.children.values())
-        if node is not prefix._root and node.ref != 0:
-            return False
-    return (prefix.blocks_live + prefix.blocks_free
-            == prefix.num_blocks - 1)
-
-
 @pytest.mark.chaos
 def test_cancel_storm_refcounts_return_to_baseline(gpt_setup,
                                                    pin_zero_recompiles):
@@ -726,7 +791,7 @@ def test_cancel_storm_refcounts_return_to_baseline(gpt_setup,
                      max_random_injections=25, sleep_fn=_no_sleep)
     eng = pin_zero_recompiles(ServeEngine(
         model, variables, max_slots=2, prefill_len=16, clock=clock,
-        prefix_cache_blocks=6, max_queue_depth=64, fault_plan=plan,
+        prefix_cache_blocks=17, max_queue_depth=64, fault_plan=plan,
         degraded_cooldown_s=3.0, backoff_sleep=_no_sleep))
     shared = (np.arange(8) * 3 + 2) % 32
     handles = []

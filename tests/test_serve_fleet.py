@@ -79,7 +79,6 @@ def _local_fleet(model, variables, n, *, with_plans=False, clock=None,
             return ServeEngine(model, variables, max_slots=2,
                                prefill_len=16, fault_plan=plan,
                                max_queue_depth=max_queue_depth,
-                               prefix_cache_blocks=0,
                                backoff_sleep=_no_sleep)
         return make
 
@@ -171,7 +170,7 @@ def test_cascading_death_mid_restore_stays_token_exact(gpt_setup):
 
     def factory():
         return ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                           max_queue_depth=64, prefix_cache_blocks=0,
+                           max_queue_depth=64,
                            backoff_sleep=_no_sleep)
 
     class DiesMidRestore(LocalReplica):
@@ -414,8 +413,7 @@ def test_process_fleet_sigkill_migration_token_exact():
     from pddl_tpu.serve.fleet.worker import build_engine
 
     cfg = dict(vocab=64, max_len=128, embed_dim=64, depth=2, heads=2,
-               slots=4, prefill_len=32, max_queue_depth=64, param_seed=0,
-               prefix_cache_blocks=0)  # 4-program engine: exact pin set
+               slots=4, prefill_len=32, max_queue_depth=64, param_seed=0)
     reps = [ProcessReplica(i, {**cfg, "replica_id": i},
                            python=sys.executable) for i in range(2)]
     fleet = FleetRouter(reps, affinity_block_size=8, affinity_blocks=1,
@@ -467,7 +465,7 @@ def test_sigkill_after_finish_settles_from_pipe_buffer():
 
     cfg = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                slots=2, prefill_len=16, max_queue_depth=8, param_seed=0,
-               prefix_cache_blocks=0, replica_id=0)
+               replica_id=0)
     rep = ProcessReplica(0, cfg, python=sys.executable)
     fleet = FleetRouter([rep], affinity_block_size=8, affinity_blocks=1,
                         respawn=False)
@@ -508,7 +506,7 @@ def test_worker_rejects_bad_restore_entry_and_stays_alive():
 
     cfg = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                slots=2, prefill_len=16, max_queue_depth=8, param_seed=0,
-               prefix_cache_blocks=0, replica_id=0)
+               replica_id=0)
     rep = ProcessReplica(0, cfg, python=sys.executable)
     try:
         rep.restore([(7, {"tokens": [1, 2]})])  # no prompt: undecodable
@@ -565,7 +563,7 @@ def test_router_idle_gap_is_not_heartbeat_silence():
 
     cfg = dict(vocab=32, max_len=64, embed_dim=32, depth=1, heads=2,
                slots=2, prefill_len=16, max_queue_depth=8, param_seed=0,
-               prefix_cache_blocks=0, replica_id=0)
+               replica_id=0)
     clock = _FakeClock(1000.0)
     rep = ProcessReplica(0, cfg, python=sys.executable, clock=clock)
     try:
@@ -601,8 +599,7 @@ def test_fleet_drain_includes_snapshot_absent_assigned(gpt_setup):
             return super().drain_entries(now_s)[1:]  # "unread" request
 
     def factory():
-        return ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                           prefix_cache_blocks=0)
+        return ServeEngine(model, variables, max_slots=2, prefill_len=16)
 
     fleet = FleetRouter([Forgetful(0, factory)], respawn=False)
     reqs = [(list(range(1, 9)), 5), (list(range(3, 10)), 4)]
@@ -633,7 +630,7 @@ def test_local_drain_entries_encode_on_engine_clock(gpt_setup):
     eng_clock = _FakeClock(100.0)
     rep = LocalReplica(0, lambda: ServeEngine(
         gpt_setup[0], gpt_setup[1], max_slots=2, prefill_len=16,
-        prefix_cache_blocks=0, clock=eng_clock))
+        clock=eng_clock))
     rep.submit(3, list(range(1, 9)), 4, None, None)
     eng_clock.now = 103.0
     (rid, entry), = rep.drain_entries(5.0)  # router epoch: meaningless

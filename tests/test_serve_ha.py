@@ -77,7 +77,6 @@ def _local_fleet(model, variables, n, **router_kw):
     def factory():
         return ServeEngine(model, variables, max_slots=2,
                            prefill_len=16, max_queue_depth=64,
-                           prefix_cache_blocks=0,
                            backoff_sleep=_no_sleep)
     replicas = [LocalReplica(i, factory) for i in range(n)]
     return FleetRouter(replicas, affinity_block_size=8,

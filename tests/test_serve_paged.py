@@ -1,4 +1,4 @@
-"""True paged attention (`ops/attention.paged_*` + ``ServeEngine(paged=True)``).
+"""Paged attention (`ops/attention.paged_*`) and the engine's block pool.
 
 The contracts under test:
 
@@ -10,12 +10,14 @@ The contracts under test:
 - **Write discipline**: `paged_cache_insert` lands each token in its
   table-mapped block; padding junk beyond the table deflects to the
   scratch sink and can never corrupt a real block.
-- **Engine token-exactness**: the paged engine — no resident slot
-  cache, prefix hits PINNED in place, suffix blocks appended in place,
-  donation a pure refcount hand-off — emits exactly what the
-  resident-row engine and one-shot ``generate()`` emit, across
-  GPT/Llama/int8 and across cold, prefix-hit, preempted, and replayed
-  streams.
+- **Engine token-exactness**: the engine — prefix hits PINNED in
+  place, suffix blocks appended in place, donation a pure refcount
+  hand-off — emits exactly what one-shot ``generate()`` emits, across
+  GPT/Llama/int8, at the default pool and at the pool's floor, and
+  across cold, prefix-hit, preempted, and replayed streams. Every
+  exactness test also asserts the hit actually happened
+  (``prefix_hits``/``prefill_tokens_saved``), so a silently-dead cache
+  cannot pass vacuously.
 - **Sharing with zero copies**: concurrent shared-prefix streams
   reference the SAME pool blocks (``blocks_shared`` > 0), admission
   records the gather bytes it no longer pays (``copy_bytes_avoided``),
@@ -23,7 +25,7 @@ The contracts under test:
   growing the pool.
 - **Resilience parity**: the 3-seed chaos matrix, drain/restore (v3
   snapshots carry block tables; v2 snapshots restore through the same
-  replay path), and the zero-recompile pin all hold in paged mode.
+  replay path), and the zero-recompile pin all hold.
 """
 
 import jax
@@ -276,13 +278,21 @@ def test_paged_cache_insert_and_scratch_deflection():
 def _paged_engine(model, variables, **kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("prefill_len", 16)
-    return ServeEngine(model, variables, paged=True, **kw)
+    return ServeEngine(model, variables, **kw)
+
+
+# The exactness workload's pool: the constructor's default (live worst
+# case + two prompts a slot of cache) and the least the engine takes
+# (2 slots x ceil(64 / 8) + scratch), where cached chains compete with
+# live streams for every block.
+_POOLS = {"auto": None, "floor": 2 * (64 // 8) + 1}
+POOLS = pytest.mark.parametrize("pool", sorted(_POOLS))
 
 
 def _exactness_workload(model, variables, ref_variables=None, **engine_kw):
-    """Cold admit, full-chain re-hit, partial hit — the paged twin of
-    `test_prefix_cache._exactness_workload`, pinned against the same
-    generate() oracle."""
+    """Cold admit, full-chain re-hit, partial hit — all pinned
+    token-exact against generate(); returns the engine so the caller
+    can inspect telemetry."""
     ref_variables = ref_variables or variables
     eng = _paged_engine(model, variables, **engine_kw)
     base = (np.arange(12) * 5 + 1) % 32
@@ -297,6 +307,7 @@ def _exactness_workload(model, variables, ref_variables=None, **engine_kw):
     assert h_part.tokens == _ref_greedy(model, ref_variables, sibling, 6)
     # Not vacuous: the hits referenced cached blocks in place.
     assert eng.metrics.prefix_hits >= 2
+    assert eng.metrics.prefill_tokens_saved >= 2 * eng.prefix_block_size
     assert eng.metrics.copy_bytes_avoided > 0
     return eng
 
@@ -311,24 +322,31 @@ def exact_gpt(gpt_setup):
     return _exactness_workload(model, variables)
 
 
-def test_paged_token_exact_gpt(exact_gpt, pin_zero_recompiles):
-    eng = pin_zero_recompiles(exact_gpt)
+@POOLS
+def test_paged_token_exact_gpt(gpt_setup, exact_gpt, pin_zero_recompiles,
+                               pool):
+    eng = pin_zero_recompiles(
+        exact_gpt if pool == "auto" else _exactness_workload(
+            *gpt_setup, prefix_cache_blocks=_POOLS[pool]))
     assert eng.paged
-    # The paged program set: no gather, no insert, no donate scatter.
-    assert set(eng.compile_counts()) <= {
+    # The program set: no gather, no insert, no donate scatter.
+    assert set(eng.compile_counts()) == {
         "tick", "sample_first", "chunk_prefill", "chunk_prefill_wide"}
 
 
-def test_paged_token_exact_llama(llama_setup):
+@POOLS
+def test_paged_token_exact_llama(llama_setup, pool):
     """GQA + RoPE: post-RoPE keys are position-absolute, so a SHARED
     pool block read through two different slots' tables is bit-valid
     for both."""
     model, variables = llama_setup
-    _exactness_workload(model, variables)
+    _exactness_workload(model, variables,
+                        prefix_cache_blocks=_POOLS[pool])
 
 
+@POOLS
 @pytest.mark.parametrize("family", ["gpt", "llama"])
-def test_paged_int8_token_exact(family, gpt_setup, llama_setup):
+def test_paged_int8_token_exact(family, pool, gpt_setup, llama_setup):
     """int8 param_transform composes: what the pool stores is K/V,
     which int8 weight storage never touches; dequant runs inside the
     paged chunk/tick programs."""
@@ -338,29 +356,14 @@ def test_paged_int8_token_exact(family, gpt_setup, llama_setup):
     qparams = quantize_int8(variables["params"], min_elems=128)
     dense = {"params": dequantize(qparams)}
     _exactness_workload(model, {"params": qparams}, ref_variables=dense,
-                        param_transform=dequantize)
-
-
-def test_paged_equals_resident_row_engine(gpt_setup):
-    """THE oracle pin the ISSUE names: the same mixed workload through
-    a paged and a resident-row engine, stream-for-stream identical."""
-    model, variables = gpt_setup
-    prompts = [((np.arange(9 + i) * 3 + 5 * i + 1) % 32) for i in range(5)]
-    prompts.append(prompts[0].copy())  # a full-chain re-hit
-    streams = {}
-    for mode in ("paged", "row"):
-        eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                          paged=(mode == "paged"))
-        hs = [eng.submit(p, 5) for p in prompts]
-        eng.run(max_steps=300)
-        streams[mode] = [h.tokens for h in hs]
-    assert streams["paged"] == streams["row"]
+                        param_transform=dequantize,
+                        prefix_cache_blocks=_POOLS[pool])
 
 
 def test_concurrent_shared_prefix_blocks_shared_in_place(gpt_setup):
     """Many live slots on one warm prefix: the matched blocks exist
     ONCE (blocks_shared counts them), table occupancy is reported, and
-    every stream is token-exact — the capacity story of paged mode as
+    every stream is token-exact — the capacity story of paging as
     an observable, not a slogan."""
     model, variables = gpt_setup
     eng = _paged_engine(model, variables, max_slots=4)
@@ -384,13 +387,18 @@ def test_concurrent_shared_prefix_blocks_shared_in_place(gpt_setup):
     assert eng.metrics.copy_bytes_avoided > 0
 
 
-def test_block_aligned_repeat_never_grows_a_paged_pool(gpt_setup):
-    """The paged twin of the donation-dedup pin: re-admitting a
-    block-aligned prompt swaps the slot's table onto the stored chain
-    and RELEASES the duplicate private blocks, so repeats hold the
-    pool at its deduplicated size (no eviction churn, live == 2)."""
+@pytest.mark.parametrize("pool", [None, 64 // 8 + 1], ids=["auto", "floor"])
+def test_block_aligned_repeat_never_grows_a_paged_pool(gpt_setup, pool):
+    """Donation dedup: a block-aligned prompt's tail block can never be
+    MATCHED (the match cap leaves one suffix token) but it IS stored —
+    re-admitting the same prompt swaps the slot's table onto the stored
+    chain and RELEASES the duplicate private blocks, so repeats hold
+    the pool at its deduplicated size (no eviction churn, live == 2),
+    at the default pool and at the floor, where a leaked duplicate
+    would LRU-evict a useful block."""
     model, variables = gpt_setup
-    eng = _paged_engine(model, variables, max_slots=1)
+    eng = _paged_engine(model, variables, max_slots=1,
+                        prefix_cache_blocks=pool)
     p = (np.arange(16) * 3 + 5) % 32  # 2 full blocks at bs=8
     for _ in range(3):
         h = eng.submit(p, 3)
@@ -435,15 +443,15 @@ def test_paged_sliced_admission_token_exact(gpt_setup, pin_zero_recompiles):
 
 
 def test_paged_pool_size_validation(gpt_setup):
-    """paged without the pool machinery, or with a pool the live
-    streams could starve, fails LOUDLY at construction."""
+    """No pool at all, or a pool the live streams could starve, fails
+    LOUDLY at construction."""
     model, variables = gpt_setup
-    with pytest.raises(ValueError, match="paged=True needs"):
+    with pytest.raises(ValueError, match="IS the KV cache"):
         ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                    paged=True, prefix_cache_blocks=0)
+                    prefix_cache_blocks=0)
     with pytest.raises(ValueError, match="starve"):
         ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                    paged=True, prefix_cache_blocks=4)
+                    prefix_cache_blocks=4)
 
 
 # ----------------------------------------------------------- resilience

@@ -399,7 +399,7 @@ def test_interactive_preempts_best_effort_token_exact(
     model, variables = gpt_setup
     eng = pin_zero_recompiles(ServeEngine(
         model, variables, max_slots=2, prefill_len=16,
-        prefix_cache_blocks=0, preempt_cap=2))
+        preempt_cap=2))
     be_p = [(np.arange(7) + i) % 32 for i in range(2)]
     be = [eng.submit(p, 20, priority=Priority.BEST_EFFORT) for p in be_p]
     eng.step()
@@ -421,7 +421,7 @@ def test_interactive_preempts_best_effort_token_exact(
 def test_preempt_cap_zero_disables_preemption(gpt_setup):
     model, variables = gpt_setup
     eng = ServeEngine(model, variables, max_slots=1, prefill_len=16,
-                      prefix_cache_blocks=0, preempt_cap=0)
+                      preempt_cap=0)
     be = eng.submit(np.arange(6) % 32, 10, priority=Priority.BEST_EFFORT)
     eng.step()
     eng.submit((np.arange(5) + 2) % 32, 2,
@@ -538,7 +538,7 @@ def _slo_fleet(model, variables, n, *, clock, admission,
                max_queue_depth=4, slots=2):
     def factory():
         return ServeEngine(model, variables, max_slots=slots,
-                           prefill_len=16, prefix_cache_blocks=0,
+                           prefill_len=16,
                            max_queue_depth=max_queue_depth,
                            backoff_sleep=_no_sleep)
     replicas = [LocalReplica(i, factory) for i in range(n)]

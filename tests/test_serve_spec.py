@@ -101,11 +101,11 @@ def workload_refs(gpt_setup):
     return [_ref_greedy(model, variables, p, n) for p, n in _WORKLOAD]
 
 
-def _spec_engine(model, variables, *, paged=False, **kw):
+def _spec_engine(model, variables, **kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("prefill_len", 16)
     kw.setdefault("spec_k", 3)
-    return ServeEngine(model, variables, paged=paged, **kw)
+    return ServeEngine(model, variables, **kw)
 
 
 # ------------------------------------------------------- shared drafter
@@ -135,16 +135,15 @@ def test_ngram_drafts_one_definition_and_equivalence():
 
 
 # ----------------------------------------------------- token exactness
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
 def test_spec_token_exact_gpt(gpt_setup, workload_refs,
-                              pin_zero_recompiles, paged):
+                              pin_zero_recompiles):
     """Cold + shared-prefix admissions through the speculative engine:
     every greedy stream identical to generate(), more than one token
     per verify window actually accepted, zero recompiles over the
     mixed accept counts."""
     model, variables = gpt_setup
     eng = pin_zero_recompiles(
-        _spec_engine(model, variables, paged=paged, max_slots=3))
+        _spec_engine(model, variables, max_slots=3))
     handles = [eng.submit(p, n) for p, n in _WORKLOAD]
     eng.run(max_steps=400)
     for h, ref in zip(handles, workload_refs):
@@ -160,12 +159,11 @@ def test_spec_token_exact_gpt(gpt_setup, workload_refs,
     assert eng.metrics.tokens_emitted == total
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_spec_token_exact_llama(llama_setup, pin_zero_recompiles, paged):
+def test_spec_token_exact_llama(llama_setup, pin_zero_recompiles):
     model, variables = llama_setup
     refs = [_ref_greedy(model, variables, p, n) for p, n in _WORKLOAD[:3]]
     eng = pin_zero_recompiles(
-        _spec_engine(model, variables, paged=paged, max_slots=3))
+        _spec_engine(model, variables, max_slots=3))
     handles = [eng.submit(p, n) for p, n in _WORKLOAD[:3]]
     eng.run(max_steps=400)
     for h, ref in zip(handles, refs):
@@ -200,7 +198,7 @@ def test_spec_draft_model_token_exact(gpt_setup, draft_setup,
     dmodel, dvars = draft_setup
     refs = [_ref_greedy(model, variables, p, n) for p, n in _WORKLOAD[:3]]
     eng = pin_zero_recompiles(
-        _spec_engine(model, variables, paged=True, max_slots=3,
+        _spec_engine(model, variables, max_slots=3,
                      spec_draft_model=dmodel, spec_draft_variables=dvars))
     assert eng.spec_draft_model_enabled
     handles = [eng.submit(p, n) for p, n in _WORKLOAD[:3]]
@@ -233,8 +231,7 @@ def test_eos_mid_window_truncates_exactly(gpt_setup):
     assert h1.finish_reason == h0.finish_reason == FinishReason.EOS
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_sampled_constrained_stream_stays_mask_legal(gpt_setup, paged):
+def test_sampled_constrained_stream_stays_mask_legal(gpt_setup):
     """A SAMPLED grammar-constrained stream on a speculative engine
     draws its one token per window under its FSM mask (review-found:
     an unmasked draw could emit an illegal token and crash the host
@@ -247,7 +244,7 @@ def test_sampled_constrained_stream_stays_mask_legal(gpt_setup, paged):
                                                model.vocab_size, rank=4),
                       token_strings=VOCAB32)
     eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
-                      tenant=tc, spec_k=3, paged=paged)
+                      tenant=tc, spec_k=3)
     spec = {"kind": "regex", "pattern": r"-?\d+(\.\d+)?"}
     h = eng.submit(_WORKLOAD[0][0], 10, constraint=spec,
                    sampling=SamplingParams(temperature=1.0, top_k=8))
@@ -282,9 +279,8 @@ def test_sampled_rows_do_not_speculate(gpt_setup):
 
 # -------------------------------------------- mixed batches, recompiles
 @pytest.mark.parametrize("family", ["gpt", "llama"])
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
 def test_mixed_batch_zero_recompiles(gpt_setup, llama_setup,
-                                     pin_zero_recompiles, paged, family):
+                                     pin_zero_recompiles, family):
     """The acceptance-criteria batch: speculative-greedy + sampled +
     grammar-constrained + two adapters live in ONE tick with mixed
     accept counts — zero recompiles in both engine modes for BOTH
@@ -302,7 +298,7 @@ def test_mixed_batch_zero_recompiles(gpt_setup, llama_setup,
     def run(spec_k):
         tc = TenantConfig(registry=reg, token_strings=VOCAB32)
         eng = ServeEngine(model, variables, max_slots=4, prefill_len=16,
-                          tenant=tc, spec_k=spec_k, paged=paged)
+                          tenant=tc, spec_k=spec_k)
         eng.warmup()
         hs = [eng.submit(prompts[0], 10, constraint=constraint),
               eng.submit(prompts[1], 10, adapter="acme"),
@@ -328,9 +324,8 @@ def test_mixed_batch_zero_recompiles(gpt_setup, llama_setup,
 # ----------------------------------------------------------- resilience
 @pytest.mark.chaos
 @pytest.mark.parametrize("seed", [0, 1, 2])
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
 def test_spec_chaos_matrix(gpt_setup, workload_refs, pin_zero_recompiles,
-                           seed, paged):
+                           seed):
     """Seeded mixed chaos (transients, OOM, latency — the rate draws
     now also land on draft/verify/draft_prefill): no crash, every
     request terminal, survivors token-exact, zero recompiles across
@@ -341,7 +336,7 @@ def test_spec_chaos_matrix(gpt_setup, workload_refs, pin_zero_recompiles,
                      max_random_injections=20)
     tracer = RequestTracer()
     eng = pin_zero_recompiles(
-        _spec_engine(model, variables, paged=paged, fault_plan=plan,
+        _spec_engine(model, variables, fault_plan=plan,
                      backoff_sleep=_no_sleep, tracer=tracer))
     handles = [eng.submit(p, n) for p, n in _WORKLOAD]
     eng.run(max_steps=600)
@@ -450,14 +445,13 @@ def test_preempt_mid_speculation_token_exact(gpt_setup):
 
 
 # ------------------------------------------------------ drain & compat
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_drain_restore_v5_round_trip(gpt_setup, paged):
+def test_drain_restore_v5_round_trip(gpt_setup):
     """Mid-flight drain: v5 snapshot carries the per-stream speculative
     accounting; restore is token-exact into a speculative engine of
     EITHER mode and into a classic (spec_k=0) engine."""
     model, variables = gpt_setup
     refs = [_ref_greedy(model, variables, p, n) for p, n in _WORKLOAD[:3]]
-    eng = _spec_engine(model, variables, paged=paged)
+    eng = _spec_engine(model, variables)
     handles = [eng.submit(p, n) for p, n in _WORKLOAD[:3]]
     eng.step()  # one window each for the two slotted streams
     assert not any(h.done for h in handles)
@@ -471,7 +465,7 @@ def test_drain_restore_v5_round_trip(gpt_setup, paged):
         == eng.metrics.spec_drafted_tokens
     for spec_k in (3, 0):
         eng2 = ServeEngine(model, variables, max_slots=2,
-                           prefill_len=16, spec_k=spec_k, paged=paged)
+                           prefill_len=16, spec_k=spec_k)
         restored = eng2.restore(snapshot)
         eng2.run(max_steps=300)
         done = {(tuple(h.request.prompt), h.request.max_new_tokens): h
@@ -531,7 +525,6 @@ def test_fleet_migration_mid_speculation_token_exact(gpt_setup,
     def factory(plan):
         def make():
             return _spec_engine(model, variables, fault_plan=plan,
-                                prefix_cache_blocks=0,
                                 backoff_sleep=_no_sleep)
         return make
 
@@ -618,16 +611,13 @@ def test_spec_validation(gpt_setup, draft_setup):
     dmodel, dvars = draft_setup
     with pytest.raises(ValueError, match="spec_k"):
         ServeEngine(model, variables, spec_k=-1)
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(model, variables, spec_k=2,
-                    spec_draft_model=dmodel, spec_draft_variables=dvars)
     with pytest.raises(ValueError, match="spec_k >= 1"):
-        ServeEngine(model, variables, paged=True,
+        ServeEngine(model, variables,
                     spec_draft_model=dmodel, spec_draft_variables=dvars)
     with pytest.raises(ValueError, match="spec_draft_variables"):
-        ServeEngine(model, variables, paged=True, spec_k=2,
+        ServeEngine(model, variables, spec_k=2,
                     spec_draft_model=dmodel)
     big = tiny_gpt(vocab_size=64, max_len=64)
     with pytest.raises(ValueError, match="vocab"):
-        ServeEngine(model, variables, paged=True, spec_k=2,
+        ServeEngine(model, variables, spec_k=2,
                     spec_draft_model=big, spec_draft_variables=dvars)

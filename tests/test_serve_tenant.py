@@ -14,7 +14,7 @@ The contracts under test (ISSUE 9 acceptance criteria):
   stream's output is accepted by its grammar/schema.
 - **Zero recompiles over a mixed batch**: ≥3 distinct adapters +
   constrained + unconstrained + no-adapter slots in ONE tick, in both
-  ``paged=True`` and resident-row modes, GPT and Llama, int8 composing.
+  GPT and Llama, int8 composing.
 - **Resilience parity**: 3-seed chaos matrix with tenant requests
   (token-exact survivors, zero recompiles), preemption resume,
   drain/restore v4 + v1-v3 back-compat ("no adapter, unconstrained"
@@ -239,9 +239,8 @@ def test_adapter_pool_lru_pins_and_exhaustion():
 
 
 # ------------------------------------------------- correctness oracles
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
 def test_mixed_batch_token_exact_zero_recompiles_gpt(
-        gpt_setup, pin_zero_recompiles, paged):
+        gpt_setup, pin_zero_recompiles):
     """THE acceptance pin: one engine, ≥3 distinct adapters +
     constrained + unconstrained + no-adapter slots mixed through the
     same fused ticks — every stream token-exact against its own oracle
@@ -250,7 +249,7 @@ def test_mixed_batch_token_exact_zero_recompiles_gpt(
     model, variables = gpt_setup
     reg = _registry(model)
     eng = pin_zero_recompiles(_tenant_engine(
-        model, variables, reg=reg, max_slots=6, paged=paged))
+        model, variables, reg=reg, max_slots=6))
     base = (np.arange(12) * 5 + 1) % 32
     spec = {"kind": "regex", "pattern": "[0-9][0-9][0-9][0-9]"}
     hs = {
@@ -281,15 +280,13 @@ def test_mixed_batch_token_exact_zero_recompiles_gpt(
     assert eng.metrics.requests_grammar_complete == 2
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_mixed_batch_token_exact_llama(llama_setup, pin_zero_recompiles,
-                                       paged):
+def test_mixed_batch_token_exact_llama(llama_setup, pin_zero_recompiles):
     """GQA + RoPE + bias-free head: the external-head tenant programs
     are token-exact on the Llama family too, both modes."""
     model, variables = llama_setup
     reg = _registry(model)
     eng = pin_zero_recompiles(_tenant_engine(
-        model, variables, reg=reg, max_slots=3, paged=paged))
+        model, variables, reg=reg, max_slots=3))
     base = (np.arange(11) * 3 + 2) % 32
     spec = {"kind": "regex", "pattern": "[0-9][0-9][0-9]"}
     h0 = eng.submit(base, 5)
@@ -473,7 +470,7 @@ def test_install_fault_after_single_step_slice_releases_pin_once(
 @pytest.mark.chaos
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_tenant_chaos_matrix(gpt_setup, pin_zero_recompiles, seed):
-    """The mixed chaos profile with tenant requests (paged engine):
+    """The mixed chaos profile with tenant requests:
     every request terminal, finished streams token-exact against their
     own oracles (merged weights / grammar referee), zero recompiles
     across retry / replay / degraded / pool-rebuild transitions — the
@@ -485,7 +482,7 @@ def test_tenant_chaos_matrix(gpt_setup, pin_zero_recompiles, seed):
                      oom_rate=0.02, latency_rate=0.1, latency_s=1e-4,
                      max_random_injections=20)
     eng = pin_zero_recompiles(_tenant_engine(
-        model, variables, reg=reg, max_slots=2, paged=True,
+        model, variables, reg=reg, max_slots=2,
         fault_plan=plan, backoff_sleep=_no_sleep))
     spec = {"kind": "regex", "pattern": "[0-9][0-9][0-9][0-9]"}
     fsm = compile_constraint(spec, VOCAB32)
@@ -512,8 +509,7 @@ def test_tenant_chaos_matrix(gpt_setup, pin_zero_recompiles, seed):
                 model, _merged(model, variables, reg, adapter), p, 5)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_drain_restore_v4_round_trip(gpt_setup, paged):
+def test_drain_restore_v4_round_trip(gpt_setup):
     """v4 snapshot carries adapter + constraint; restore into a fresh
     tenant engine (same registry config) resumes adapted streams on the
     right weights and constrained streams under the same automaton,
@@ -521,8 +517,7 @@ def test_drain_restore_v4_round_trip(gpt_setup, paged):
     model, variables = gpt_setup
     reg = _registry(model)
     spec = {"kind": "regex", "pattern": "[0-9]" * 8}
-    eng1 = _tenant_engine(model, variables, reg=reg, max_slots=2,
-                          paged=paged)
+    eng1 = _tenant_engine(model, variables, reg=reg, max_slots=2)
     p1 = (np.arange(11) * 5 + 2) % 32
     p2 = (np.arange(9) * 7 + 3) % 32
     eng1.submit(p1, 8, adapter="acme")
@@ -535,8 +530,7 @@ def test_drain_restore_v4_round_trip(gpt_setup, paged):
     assert entries[11]["adapter"] == "acme"
     assert entries[9]["constraint"] == spec
 
-    eng2 = _tenant_engine(model, variables, reg=reg, max_slots=2,
-                          paged=paged)
+    eng2 = _tenant_engine(model, variables, reg=reg, max_slots=2)
     rh = eng2.restore(snap)
     eng2.run(max_steps=400)
     assert rh[0].tokens == _ref_greedy(
@@ -545,11 +539,9 @@ def test_drain_restore_v4_round_trip(gpt_setup, paged):
     assert fsm.accepts(rh[1].tokens) or len(rh[1].tokens) == 8
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["row", "paged"])
-def test_old_snapshots_restore_with_tenant_defaults(gpt_setup, tmp_path,
-                                                    paged):
+def test_old_snapshots_restore_with_tenant_defaults(gpt_setup, tmp_path):
     """The back-compat pin: v1/v2/v3 snapshots — no adapter/constraint
-    keys anywhere — restore into a tenant-capable engine in BOTH modes
+    keys anywhere — restore into a tenant-capable engine
     with "no adapter, unconstrained" defaults, token-exactly; future
     versions still refuse."""
     import pddl_tpu.serve.drain as drain_io
@@ -574,12 +566,12 @@ def test_old_snapshots_restore_with_tenant_defaults(gpt_setup, tmp_path,
             snap["paged"] = False
         path = tmp_path / f"v{version}.json"
         path.write_text(json.dumps(snap))
-        eng = _tenant_engine(model, variables, max_slots=1, paged=paged)
+        eng = _tenant_engine(model, variables, max_slots=1)
         (restored,) = eng.restore(str(path))
         assert restored.request.adapter is None
         assert restored.request.constraint is None
         eng.run(max_steps=200)
-        assert restored.tokens == ref, (version, paged)
+        assert restored.tokens == ref, version
     bad = tmp_path / "v99.json"
     bad.write_text(json.dumps({"version": 99, "requests": []}))
     with pytest.raises(ValueError, match="version"):

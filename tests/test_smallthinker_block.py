@@ -169,7 +169,7 @@ def test_expert_tile_bounds_the_padding():
 # ------------------------------------------------------------ the engine
 def engine_for(weights, **kw):
     kw.setdefault("max_slots", 3)
-    return ServeEngine(tiny_smallthinker(), weights, paged=True,
+    return ServeEngine(tiny_smallthinker(), weights,
                        prefill_len=64, prefix_block_size=4, prefix_chunk=16,
                        **kw)
 
@@ -220,15 +220,17 @@ def test_engine_counts_prefill_and_expert_load(weights):
 
 
 def test_window_model_is_paged_not_row_cached(weights):
-    """``ring_len(8, 256)`` is 128: the row engine would allocate a rolling
-    ring and goes on refusing; the paged engine gives window layers the
-    full-length paged cache."""
+    """``ring_len(8, 256)`` is 128: ``generate()`` would allocate a
+    rolling ring; the engine gives window layers the full-length pool,
+    one ``[N, Hkv, bs, 2D]`` leaf a layer like any other."""
     model = tiny_smallthinker(max_len=256)
     assert model.uses_ring_cache
-    with pytest.raises(NotImplementedError, match="paged=True"):
-        ServeEngine(model, weights, max_slots=2, prefill_len=64)
-    assert ServeEngine(model, weights, paged=True, max_slots=2,
-                       prefill_len=64, prefix_block_size=4).paged
+    eng = ServeEngine(model, weights, max_slots=2, prefill_len=64,
+                      prefix_block_size=4)
+    assert eng.paged
+    pools = [leaf for leaf in jax.tree.leaves(eng._cache) if leaf.ndim == 4]
+    assert len(pools) == model.depth
+    assert {leaf.shape[0] for leaf in pools} == {eng._prefix.num_blocks}
 
 
 def test_no_wide_program_beside_a_wide_chunk(weights):
@@ -236,10 +238,10 @@ def test_no_wide_program_beside_a_wide_chunk(weights):
     no ``prefill_len``-wide second program is built (at 12,288 wide its
     temporaries did not fit the chip)."""
     model = tiny_smallthinker(max_len=4096)
-    wide = ServeEngine(model, weights, paged=True, max_slots=1,
+    wide = ServeEngine(model, weights, max_slots=1,
                        prefill_len=2048, prefix_block_size=16,
                        prefix_chunk=512)
-    none = ServeEngine(model, weights, paged=True, max_slots=1,
+    none = ServeEngine(model, weights, max_slots=1,
                        prefill_len=2048, prefix_block_size=16,
                        prefix_chunk=1024)
     assert wide._has_wide and not none._has_wide
