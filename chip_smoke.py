@@ -50,6 +50,18 @@ import numpy as np
 # refused in the paged kernel.
 HEAD_SHAPES = ((12, 12, 64), (12, 4, 64))
 
+# The paged decode kernel as the benchmark's cells call it: (slots, query
+# heads, kv heads, head dim, context, window, shallowest and deepest
+# row). `gpt2l_chat_*`: 48 rows of 20 x 64 at mixed depths over the
+# 1,024-token context (block 16: a 64-wide table); `st21b_longdoc_steady`:
+# 8 rows of 28 q / 4 kv heads of 128 at 1k-12k of a 16,384-token context
+# (a 1,024-wide table), on a NoPE-global layer and on a window layer.
+CELL_SHAPES = (
+    (48, 20, 20, 64, 1024, None, 0, 1023),
+    (8, 28, 4, 128, 16384, None, 1024, 12288),
+    (8, 28, 4, 128, 16384, 4096, 1024, 12288),
+)
+
 # Greedy-consistency bound for untrained bf16 GPT-small — the one
 # tests_tpu/ uses. Its logits are bf16 (spacing 2^-7 relative, ~0.016
 # near the top logits of ~2.4) and two compiled programs for the same
@@ -258,10 +270,12 @@ def _max_err(got, want) -> float:
 
 
 def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
-                  block_sizes=(8, 16), context: int = 1024) -> dict:
+                  block_sizes=(8, 16), context: int = 1024,
+                  cell_shapes=CELL_SHAPES) -> dict:
     """Flash fwd + fused bwd and the paged decode kernel vs their jnp
-    oracles. Compiled (``interpret=False``) on a TPU; interpreted only
-    where there is no TPU to compile for."""
+    oracles, the paged kernel also at the benchmark cells' own shapes
+    (``cell_shapes``, block 16). Compiled (``interpret=False``) on a
+    TPU; interpreted only where there is no TPU to compile for."""
     from pddl_tpu.ops.attention import (
         attention_reference,
         flash_attention,
@@ -271,6 +285,20 @@ def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
 
     interpret = jax.devices()[0].platform != "tpu"
     worst = {"flash_fwd": 0.0, "flash_bwd": 0.0, "paged": 0.0}
+
+    def paged_err(q1, pool, slots, t, index, window=None, seed=0):
+        """Kernel against the jnp path over a scattered table; entries
+        past a row's depth are scratch, as the engine leaves them."""
+        bs = pool.shape[2]
+        table = np.random.RandomState(seed).permutation(
+            np.arange(1, slots * t + 1)).reshape(slots, t).astype(np.int32)
+        table[np.arange(t) > np.asarray(index)[:, None] // bs] = 0
+        got = jax.jit(lambda *a: paged_decode_attention_kernel(
+            *a, window=window, interpret=interpret))(q1, pool, table, index)
+        want = jax.jit(lambda *a: paged_decode_attention(
+            *a, window=window, kernel=False))(q1, pool, table, index)
+        return _max_err(got, want)
+
     for h, hkv, d in head_shapes:
         ks = jax.random.split(jax.random.key(h * hkv), 5)
         q = jax.random.normal(ks[0], (2, h, seq, d), jnp.bfloat16)
@@ -297,16 +325,21 @@ def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
             slots, t = 8, context // bs
             n = slots * t + 1  # block 0 is the scratch sink
             pool = jax.random.normal(ks[4], (n, hkv, bs, 2 * d), jnp.bfloat16)
-            table = jnp.asarray(np.random.RandomState(bs).permutation(
-                np.arange(1, n)).reshape(slots, t), jnp.int32)
             # Depths from empty to the last position, unaligned included.
             index = jnp.asarray(np.linspace(0, context - 1, slots), jnp.int32)
             q1 = q[:1, :, :slots].transpose(2, 1, 0, 3)  # [slots, h, 1, d]
-            got = jax.jit(lambda *a: paged_decode_attention_kernel(
-                *a, interpret=interpret))(q1, pool, table, index)
-            want = jax.jit(lambda *a: paged_decode_attention(
-                *a, kernel=False))(q1, pool, table, index)
-            worst["paged"] = max(worst["paged"], _max_err(got, want))
+            worst["paged"] = max(worst["paged"], paged_err(
+                q1, pool, slots, t, index, seed=bs))
+    for slots, h, hkv, d, ctx, window, lo, hi in cell_shapes:
+        bs, t = 16, ctx // 16
+        ks = jax.random.split(jax.random.key(slots * h), 2)
+        q1 = jax.random.normal(ks[0], (slots, h, 1, d), jnp.bfloat16)
+        pool = jax.random.normal(ks[1], (slots * t + 1, hkv, bs, 2 * d),
+                                 jnp.bfloat16)
+        index = jnp.asarray(np.random.RandomState(slots).permutation(
+            np.linspace(lo, hi, slots)), jnp.int32)
+        worst["paged"] = max(worst["paged"], paged_err(
+            q1, pool, slots, t, index, window=window, seed=h))
     # bf16 in and out, f32 accumulation inside: the bounds
     # tests_tpu/test_on_chip_numerics.py holds the same kernels to.
     bounds = {"flash_fwd": 2e-2, "flash_bwd": 5e-2, "paged": 2e-2}
@@ -315,6 +348,7 @@ def kernels_phase(head_shapes=HEAD_SHAPES, seq: int = 1024,
             raise AssertionError(f"{name}: max abs error {err} > "
                                  f"{bounds[name]} vs the jnp oracle")
     return {"interpret": interpret, "head_shapes": list(head_shapes),
+            "cell_shapes": [list(c) for c in cell_shapes],
             "max_abs_err": {k: round(v, 5) for k, v in worst.items()}}
 
 

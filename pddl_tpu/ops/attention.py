@@ -1004,9 +1004,13 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
 #   traffic bounded by the deepest live slot exactly like
 #   :func:`decode_attention`) is the CPU/tier-1 numerics ORACLE; the
 #   Pallas path (:func:`paged_decode_attention_kernel`) is the TPU
-#   hot-path kernel — the block table rides in SMEM via scalar
-#   prefetch and drives the pool BlockSpec index map, so each grid
-#   step DMAs exactly one pool block.
+#   hot-path kernel — the block table and the depths ride in SMEM via
+#   scalar prefetch, the pool stays in HBM, and each slot's grid step
+#   walks the table entries the slot's depth (and window) reaches, K
+#   pool blocks a loop step: K block copies into one of two VMEM
+#   buffers, the next group in flight under the current one's online
+#   softmax. A table entry no key of which the slot can see is neither
+#   visited nor fetched.
 #
 # THE POOL LEAF. One leaf per layer, K and V side by side in the last
 # dimension: ``[num_blocks, kv_heads, block_size, 2 * head_dim]``, K in
@@ -1270,19 +1274,47 @@ def paged_decode_attention(
     return jnp.moveaxis(out, 0, 3).reshape(b, h, s, d)
 
 
-def _paged_decode_kernel(table_ref, index_ref, q_ref, kv_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float, bs: int,
-                         num_t: int, window: Optional[int]):
-    """One (slot, table-entry) grid step of paged decode attention.
+# Bytes of pool blocks one group of the paged decode kernel fetches (and
+# one of its two VMEM buffers holds). The kernel pays its per-step fixed
+# cost — the loop's bookkeeping, the copies' descriptors, the matmuls'
+# set-up — once a group, so a group wants to be large; two buffers of it
+# want to sit well inside VMEM and a short row wants few dead keys in
+# its last group, so not too large. Chosen from readings on the chip at
+# both benchmark configurations' shapes (PERF.md §6, PR 35).
+PAGED_GROUP_BYTES = 1 << 20
 
-    ``table_ref``/``index_ref`` are scalar-prefetched (SMEM): the table
-    drove this step's pool BlockSpec index map (the DMA fetched pool
-    block ``table[b, j]``, K and V in one transfer), and the per-slot
-    depth gates the compute — blocks past the slot's live prefix are
-    skipped entirely, so the sweep costs what the slot's depth costs,
-    exactly like the chunked jnp path. Running max / denominator /
-    accumulator persist in VMEM scratch across the (sequential,
-    innermost) table sweep.
+
+def paged_blocks_per_group(pool_shape, itemsize: int, table_width: int) -> int:
+    """K, the pool blocks the paged decode kernel takes a loop step:
+    the power of two whose bytes come nearest :data:`PAGED_GROUP_BYTES`
+    (a power of two so that ``K * block_size`` keys fill whole 128-lane
+    tiles of the score block), at most the whole table."""
+    _, hkv, bs, d2 = pool_shape
+    k = 2 ** round(math.log2(max(
+        1.0, PAGED_GROUP_BYTES / (hkv * bs * d2 * itemsize))))
+    return max(1, min(int(k), int(table_width)))
+
+
+def _paged_decode_kernel(table_ref, index_ref, q_ref, pool_ref, o_ref,
+                         buf_ref, sem_ref, side_ref, m_ref, l_ref, acc_ref,
+                         *, scale: float, window: Optional[int]):
+    """One slot's grid step of paged decode attention: the whole sweep
+    over the slot's live pool blocks, ``K`` of them a loop step.
+
+    ``table_ref``/``index_ref`` are scalar-prefetched (SMEM); the pool
+    leaf ``pool_ref`` stays in HBM. The slot's depth (and, on a window
+    layer, its band) says which table entries hold keys it can see:
+    ``first .. last``. The loop walks them in groups of ``K``,
+    each group ``K`` block copies (K and V in one transfer) through the
+    table into one of two VMEM buffers ``[H_kv, K * bs, 2D]``; the NEXT
+    group's copies — at a slot's last group, the next slot's first —
+    are started before the current one is waited for, so the fetch runs
+    under the compute. A table entry past ``last`` is neither
+    copied nor waited for: its stretch of the buffer keeps what an
+    earlier group left there (finite pool data; zeros before the first)
+    and the mask drops it, so a parked row costs one block and a block
+    under a window's band nothing. Running max / denominator /
+    accumulator live in VMEM scratch across the loop.
 
     Everything in here is ``2D`` lanes wide and nothing is sliced or
     reshaped (Mosaic cannot re-tile a 64-lane minor dim): ``q_ref``
@@ -1291,30 +1323,80 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, kv_ref, o_ref,
     ``p . [k|v]`` accumulates ``p . v`` in lanes ``[D, 2D)``, which the
     wrapper slices off in HBM.
     """
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    num_t = table_ref.shape[1]
+    _, _, span, _ = buf_ref.shape
+    bs = pool_ref.shape[2]
+    k_blocks = span // bs
     bq = pl.program_id(0)
-    j = pl.program_id(1)
+    last_row = pl.num_programs(0) - 1
+
+    def reach(depth):
+        """(first, last) table entry holding a key a row at ``depth``
+        attends to; ``first <= last`` inside the table whatever the
+        depth, so every row has a group and the chain of prefetches
+        from row to row never breaks."""
+        last = jnp.clip(depth // bs, 0, num_t - 1)
+        if window is None:
+            return 0, last
+        return jnp.clip((depth - (window - 1)) // bs, 0, last), last
+
+    def group_copies(row, j0, last, side, start: bool):
+        """Start, or wait for, the copies of ``row``'s group that opens
+        at table entry ``j0`` into buffer ``side``: one a live entry."""
+        def one(i, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[table_ref[row, j0 + i]],
+                buf_ref.at[side, :, pl.ds(pl.multiple_of(i * bs, bs), bs)],
+                sem_ref.at[side])
+            copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(last - j0 + 1, 0, k_blocks), one, None)
+
+    @pl.when(bq == 0)
+    def _first_slot():
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+        side_ref[0] = 0
+        first0, last0 = reach(index_ref[0])
+        group_copies(0, first0, last0, 0, start=True)
+
     depth = index_ref[bq]  # tokens in the virtual cache before this step
+    first, last = reach(depth)
+    groups = (last - first) // k_blocks + 1
+    # The row whose first group follows this row's last one.
+    next_row = jnp.minimum(bq + 1, last_row)
+    next_first, next_last = reach(index_ref[next_row])
+    side0 = side_ref[0]
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def body(g, carry):
+        side = (side0 + g) % 2
+        j0 = first + g * k_blocks
+        # The next group of this slot or, at its last, the next slot's
+        # first: in flight while this group is computed on.
+        more = g + 1 < groups
 
-    run = j * bs <= depth  # block intersects [0, depth] (current token incl.)
-    if window is not None:
-        run = jnp.logical_and(run, (j + 1) * bs - 1 > depth - window)
+        @pl.when(jnp.logical_or(more, bq < last_row))
+        def _prefetch():
+            group_copies(jnp.where(more, bq, next_row),
+                         jnp.where(more, j0 + k_blocks, next_first),
+                         jnp.where(more, last, next_last),
+                         1 - side, start=True)
 
-    @pl.when(run)
-    def _compute():
-        # [Hkv, rep, 2D] x [Hkv, bs, 2D] -> [Hkv, rep, bs], batched on the
-        # kv-head dim, f32 accumulation on the MXU. q, the output and the
-        # scratches all carry the [Hkv, rep, ...] grouping (the wrapper
+        group_copies(bq, j0, last, side, start=False)
+        kv = buf_ref[side]
+        # [Hkv, rep, 2D] x [Hkv, K*bs, 2D] -> [Hkv, rep, K*bs], batched on
+        # the kv-head dim, f32 accumulation on the MXU. q, the output and
+        # the scratches all carry the [Hkv, rep, ...] grouping (the wrapper
         # reshapes in HBM, where it is free).
         sb = jax.lax.dot_general(
-            q_ref[0], kv_ref[0], (((2,), (2,)), ((0,), (0,))),
+            q_ref[0], kv, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, sb.shape, 2)
+        pos = j0 * bs + jax.lax.broadcasted_iota(jnp.int32, sb.shape, 2)
         mask = pos <= depth
         if window is not None:
             mask = jnp.logical_and(mask, pos > depth - window)
@@ -1325,18 +1407,18 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, kv_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sb - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(  # [Hkv, rep, bs] x [Hkv, bs, 2D]
-            p.astype(kv_ref.dtype), kv_ref[0],
-            (((2,), (1,)), ((0,), (0,))),
+        pv = jax.lax.dot_general(  # [Hkv, rep, K*bs] x [Hkv, K*bs, 2D]
+            p.astype(kv.dtype), kv, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * alpha + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
 
-    @pl.when(j == num_t - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, groups, body, None)
+    side_ref[0] = (side0 + groups) % 2
+    l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel(
@@ -1346,56 +1428,77 @@ def paged_decode_attention_kernel(
 ) -> jnp.ndarray:
     """The Pallas paged decode kernel (single-token steps).
 
-    Grid ``(B, T)`` with the table sweep innermost (sequential TPU grid
-    order, like the flash kernels): the scalar-prefetched block table
-    steers each step's pool BlockSpec at block ``block_table[b, j]`` of
-    the fused leaf — indirection happens in the DMA index map, never as
-    a gathered copy in HBM — and the per-slot depth (also prefetched)
-    skips dead blocks, so a parked slot costs one skipped sweep and a
-    live one exactly its prefix. Numerics match the jnp reference path
-    of :func:`paged_decode_attention` (same masking and online softmax;
-    pinned by `tests/test_serve_paged.py`).
+    Grid ``(B,)``, one step a slot, the fused leaf left in HBM: inside
+    its step a slot loops over the groups of ``K`` pool blocks that its
+    depth (and its window's band) reaches and copies each through the
+    scalar-prefetched block table into one of two VMEM buffers, the
+    next group's copies in flight under the current group's online
+    softmax (:func:`_paged_decode_kernel`) — indirection happens in the
+    copies' source addresses, never as a gathered copy in HBM. A table
+    entry that holds no key the slot can see has no loop step and no
+    copy: a live slot costs its prefix (its band, on a window layer), a
+    parked one a single block. ``K`` follows from the leaf's shape
+    (:func:`paged_blocks_per_group`). Numerics match the jnp reference
+    path of :func:`paged_decode_attention` (same masking and online
+    softmax; pinned by `tests/test_serve_paged.py`).
     """
     b, h, s, d = q.shape
     if s != 1:
         raise ValueError(f"decode kernel takes single-token steps, got s={s}")
     _check_fused_pool(kv_pool, d)
-    n, hkv, bs, d2 = kv_pool.shape
+    hkv = kv_pool.shape[1]
     rep = _gqa_rep(q, kv_pool)
-    t = jnp.asarray(block_table, jnp.int32).shape[1]
+    block_table = jnp.asarray(block_table, jnp.int32)
+    k_blocks = paged_blocks_per_group(
+        kv_pool.shape, kv_pool.dtype.itemsize, block_table.shape[1])
     scale_v = (1.0 / math.sqrt(d)) if scale is None else scale
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
-    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
     # kv-head grouping and the zero V-half of q, both done in HBM.
     qg = jnp.pad(q.reshape(b, hkv, rep, d), ((0, 0),) * 3 + ((0, d),))
+    out = _paged_decode_call(
+        block_table, index, qg, kv_pool, k_blocks=k_blocks,
+        scale=float(scale_v), window=window, interpret=bool(interpret))
+    return out[..., d:].reshape(b, h, 1, d)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "k_blocks", "scale", "window", "interpret"))
+def _paged_decode_call(block_table, index, qg, kv_pool, *, k_blocks: int,
+                       scale: float, window: Optional[int], interpret: bool):
+    """The ``pallas_call`` of :func:`paged_decode_attention_kernel`. An
+    inlined ``jit`` only so that jax keeps the traced kernel: a tick
+    calls this once a layer with the same shapes, and without it every
+    layer traces the kernel's body anew in every run's set-up."""
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    b, hkv, rep, d2 = qg.shape
+    bs = kv_pool.shape[2]
     q_spec = pl.BlockSpec((1, hkv, rep, d2),
-                          lambda bq, j, tbl, idx: (bq, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, hkv, bs, d2),
-                           lambda bq, j, tbl, idx: (tbl[bq, j], 0, 0, 0))
+                          lambda bq, tbl, idx: (bq, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, t),
-        in_specs=[q_spec, kv_spec],
+        grid=(b,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, hkv, k_blocks * bs, d2), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),           # one a buffer
+            pltpu.SMEM((1,), jnp.int32),  # buffer of a slot's first group
             pltpu.VMEM((hkv, rep, LANES), jnp.float32),  # running max
             pltpu.VMEM((hkv, rep, LANES), jnp.float32),  # running denom
             pltpu.VMEM((hkv, rep, d2), jnp.float32),     # [junk | output]
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale_v, bs=bs,
-                          num_t=t, window=window),
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d2), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=bool(interpret),
-    )(jnp.asarray(block_table, jnp.int32), index, qg, kv_pool)
-    return out[..., d:].reshape(b, h, 1, d)
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(block_table, index, qg, kv_pool)
 
 
 def decode_attention(

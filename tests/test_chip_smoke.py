@@ -64,8 +64,10 @@ def test_serve_phase_rehearsal():
 def test_kernels_phase_rehearsal():
     facts = chip_smoke.kernels_phase(
         head_shapes=((4, 4, 8), (4, 2, 8)), seq=32, block_sizes=(4,),
-        context=32)
+        context=32, cell_shapes=((6, 4, 4, 8, 64, None, 0, 63),
+                                 (3, 6, 2, 8, 128, 40, 30, 127)))
     assert facts["interpret"] is True
+    assert len(facts["cell_shapes"]) == 2
 
 
 def test_data_parallel_phase_rehearsal(eight_devices):

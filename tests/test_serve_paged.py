@@ -177,15 +177,155 @@ def test_paged_paths_match_attention_reference(path, hq, hkv, window):
     else:
         got = paged_decode_attention(q, pool, table, index, window=window,
                                      kernel=False, blocks_per_chunk=2)
-    for i, depth in enumerate(index):
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_row_oracle(q, kc, vc, index, window)),
+        rtol=1e-5, atol=1e-5)
+
+
+def _row_oracle(q, kc, vc, index, window):
+    """Each row through the plain ``attention_reference`` over its
+    virtual cache cut to its depth (and its window's band)."""
+    rows = []
+    for i, depth in enumerate(np.asarray(index)):
         # The query sits at position `depth`, the last of depth + 1 keys:
         # k_offset 0 with one query row means causal is "all keys".
         lo = 0 if window is None else max(0, depth + 1 - window)
-        ref = attention_reference(
+        rows.append(attention_reference(
             q[i:i + 1], kc[i:i + 1, :, lo:depth + 1],
-            vc[i:i + 1, :, lo:depth + 1], causal=False)
-        np.testing.assert_allclose(np.asarray(got[i:i + 1]),
-                                   np.asarray(ref), rtol=1e-5, atol=1e-5)
+            vc[i:i + 1, :, lo:depth + 1], causal=False))
+    return jnp.concatenate(rows, axis=0)
+
+
+# The group loop of the kernel, K blocks a step. At these sizes a block
+# weighs hkv * 4 * 16 * 4 bytes, so the test names K by the bytes it sets
+# `PAGED_GROUP_BYTES` to (K is computed from shapes; nothing takes it as
+# an argument). bs = 4 throughout: K = 4 is a 16-key group.
+_GROUP_CASES = {
+    # K does not divide the table: T = 6, K = 4, the second group's tail
+    # reads the table clamped at its last entry.
+    "k_does_not_divide_table": dict(k=4, t=6, index=[23, 15, 16]),
+    # Table narrower than K: the whole table is one group (K clamps to T).
+    "table_smaller_than_k": dict(k=8, t=3, index=[11, 0, 5], want_k=3),
+    # Depth ON a group's last key (15, 31) and on a group's first (16, 32).
+    "depth_on_group_edges": dict(k=4, t=12, index=[15, 16, 31, 32]),
+    # Band starts inside a group: depth 29, window 6 -> keys 24..29, first
+    # block 6, groups of two blocks from there.
+    "window_starts_inside_group": dict(k=2, t=12, index=[29, 26, 7],
+                                       window=6),
+    # Band skips whole groups: depth 47, window 5 -> blocks 10 and 11
+    # only; ten blocks under the band are never fetched.
+    "window_skips_whole_groups": dict(k=2, t=12, index=[47, 40, 3],
+                                      window=5),
+    # A window wider than some rows' depth beside rows it cuts.
+    "window_wider_than_depth": dict(k=4, t=12, index=[5, 44, 20], window=24),
+    # Parked rows (depth 0, every table entry scratch) between live ones,
+    # first and last rows of the grid among them.
+    "parked_rows_between_live": dict(k=2, t=8, index=[0, 30, 0, 0, 17, 0],
+                                     parked=[0, 2, 3, 5]),
+    # One block a group: the loop's smallest step.
+    "one_block_groups": dict(k=1, t=6, index=[23, 0, 9]),
+}
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (6, 1)])
+@pytest.mark.parametrize("case", sorted(_GROUP_CASES))
+def test_paged_kernel_group_loop(case, hq, hkv, monkeypatch):
+    """The kernel's loop over groups of K blocks (interpret mode)
+    against the jnp oracle and the plain reference, at the edges the
+    one-block kernel never had: see `_GROUP_CASES`. rep 1, 2 and 6."""
+    from pddl_tpu.ops import attention as attn
+
+    spec = _GROUP_CASES[case]
+    bs, d, t, window = 4, 8, spec["t"], spec.get("window")
+    index = np.asarray(spec["index"], np.int32)
+    b = len(index)
+    rng = np.random.RandomState(len(case) + hq + hkv)
+    pool, table, _, _ = _random_paged(rng, b, hkv, bs, t, d)
+    for row in spec.get("parked", ()):
+        table[row] = 0                       # all scratch, as the engine parks
+    for row, depth in enumerate(index):      # entries past the depth: scratch
+        table[row, depth // bs + 1:] = 0
+    dense = np.asarray(pool)[table].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, t * bs, 2 * d)
+    kc, vc = jnp.asarray(dense[..., :d]), jnp.asarray(dense[..., d:])
+    q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
+    monkeypatch.setattr(attn, "PAGED_GROUP_BYTES",
+                        spec["k"] * hkv * bs * 2 * d * 4)
+    assert attn.paged_blocks_per_group(pool.shape, 4, t) \
+        == spec.get("want_k", spec["k"])
+    got = paged_decode_attention_kernel(q, pool, table, index,
+                                        window=window, interpret=True)
+    ref = paged_decode_attention(q, pool, table, index, window=window,
+                                 kernel=False, blocks_per_chunk=3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_row_oracle(q, kc, vc, index, window)),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_paged_kernel_never_reads_past_a_rows_reach(monkeypatch):
+    """A table entry the row cannot see — past its depth, or under its
+    window's band — is neither fetched nor attended: poison (NaN) in
+    every such block, and in scratch, leaves the output finite and equal
+    to the clean pool's."""
+    from pddl_tpu.ops import attention as attn
+
+    rng = np.random.RandomState(11)
+    b, hkv, bs, t, d, window = 3, 2, 4, 12, 8, 6
+    pool, table, _, _ = _random_paged(rng, b, hkv, bs, t, d)
+    q = jnp.asarray(rng.randn(b, 4, 1, d), jnp.float32)
+    index = np.array([29, 47, 9], np.int32)
+    seen = np.zeros(pool.shape[0], bool)
+    for row, depth in enumerate(index):
+        first = max(0, depth + 1 - window) // bs
+        seen[table[row, first:depth // bs + 1]] = True
+    poisoned = jnp.where(seen[:, None, None, None], pool, jnp.nan)
+    monkeypatch.setattr(attn, "PAGED_GROUP_BYTES", 2 * hkv * bs * 2 * d * 4)
+    clean = paged_decode_attention_kernel(q, pool, table, index,
+                                          window=window, interpret=True)
+    got = paged_decode_attention_kernel(q, poisoned, table, index,
+                                        window=window, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_paged_kernel_row_past_its_table_keeps_the_chain(window, monkeypatch):
+    """A row whose counter lies past its table (outside the contract:
+    the engine never sends one) still takes one group, so the rows
+    after it find their first group's copies started: they come out
+    right and the call returns."""
+    from pddl_tpu.ops import attention as attn
+
+    rng = np.random.RandomState(12)
+    b, hkv, bs, t, d = 4, 2, 4, 6, 8
+    pool, table, kc, vc = _random_paged(rng, b, hkv, bs, t, d)
+    q = jnp.asarray(rng.randn(b, 4, 1, d), jnp.float32)
+    index = np.array([9, t * bs + 40, 23, 0], np.int32)
+    monkeypatch.setattr(attn, "PAGED_GROUP_BYTES", 2 * hkv * bs * 2 * d * 4)
+    got = paged_decode_attention_kernel(q, pool, table, index,
+                                        window=window, interpret=True)
+    want = _row_oracle(q, kc, vc, index, window)
+    keep = np.array([0, 2, 3])
+    np.testing.assert_allclose(np.asarray(got)[keep], np.asarray(want)[keep],
+                               rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("shape,itemsize,t,want", [
+    ((3073, 20, 16, 128), 2, 64, 16),     # GPT-2-large's leaf: 80 KB a block
+    ((8193, 4, 16, 256), 2, 1024, 32),    # SmallThinker's: 32 KB a block
+    ((97, 12, 8, 128), 2, 128, 32),       # GPT-small, block 8: 24 KB
+    ((19, 2, 4, 16), 4, 6, 6),            # a test's toy pool: the whole table
+    ((9, 64, 64, 256), 2, 8, 1),          # a block over the target: one
+])
+def test_paged_blocks_per_group_follows_the_leaf(shape, itemsize, t, want):
+    """K is a function of the leaf's shape and the table's width alone:
+    the power of two nearest `PAGED_GROUP_BYTES`, at most the table."""
+    from pddl_tpu.ops.attention import paged_blocks_per_group
+
+    assert paged_blocks_per_group(shape, itemsize, t) == want
 
 
 def _scatter_insert(pool, k, v, table, index):

@@ -64,6 +64,21 @@ def _shapes_on(chip, *args):
             else np.asarray(x).dtype, sharding=chip), args)
 
 
+def _mosaic_calls(text):
+    """The Mosaic kernel calls of a compiled module's text."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _makes(text, shape):
+    """The instructions of a compiled module whose RESULT has ``shape``
+    (``bf16[3073,20,16,128]``), parameters apart: a pool-shaped one is a
+    copy of the pool, whatever its name."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= \(?{re.escape(shape)}", line)
+            and " parameter(" not in line]
+
+
 def _compile_with_kernel(fn, *shapes, kernel=True):
     """Compile for the described chip; the Mosaic kernel must be in it
     (or, ``kernel=False``, the documented jnp fallback must be)."""
@@ -71,31 +86,40 @@ def _compile_with_kernel(fn, *shapes, kernel=True):
     assert ("tpu_custom_call" in text) == kernel
 
 
-@pytest.mark.parametrize("heads,kv_heads,head_dim,block_size", [
-    (12, 12, 64, 8),    # GPT-small, the engine's default block size
-    (12, 12, 64, 16),
-    (12, 4, 64, 8),     # Llama-small (GQA)
-    (12, 4, 64, 16),
-    (16, 16, 128, 8),   # 128-wide heads: a 256-lane fused leaf
-    (16, 16, 128, 16),
-    (20, 20, 64, 16),   # GPT-2-large, the benchmark's configuration
+@pytest.mark.parametrize("heads,kv_heads,head_dim,block_size,context,window", [
+    (12, 12, 64, 8, 1024, None),    # GPT-small, the engine's default block
+    (12, 12, 64, 16, 1024, None),
+    (12, 4, 64, 8, 1024, None),     # Llama-small (GQA)
+    (12, 4, 64, 16, 1024, None),
+    (16, 16, 128, 8, 1024, None),   # 128-wide heads: a 256-lane fused leaf
+    (16, 16, 128, 16, 1024, None),
+    (20, 20, 64, 16, 1024, None),   # GPT-2-large, the benchmark's configuration
+    (28, 4, 128, 16, 16384, None),  # SmallThinker: 7 q heads a kv head over
+    (28, 4, 128, 16, 16384, 4096),  # a 1,024-wide table, NoPE-global and window
 ])
 def test_paged_decode_kernel_compiles_for_v5e(v5e_chip, heads, kv_heads,
-                                              head_dim, block_size):
-    """Eight slots over a 1024-token context, bf16, over the fused pool
-    leaf ``[N, Hkv, bs, 2D]``, as the engine's paged tick calls it."""
-    slots, table = 8, 1024 // block_size
+                                              head_dim, block_size, context,
+                                              window):
+    """Eight slots over the context, bf16, over the fused pool leaf
+    ``[N, Hkv, bs, 2D]``, as the engine's paged tick calls it: ONE
+    Mosaic call for the whole sweep (the slots' loops are inside it) that
+    takes the leaf where it lies — no instruction makes or copies an
+    array of the leaf's shape."""
+    slots, table = 8, context // block_size
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
     pool = sds((slots * table + 1, kv_heads, block_size, 2 * head_dim),
                jnp.bfloat16)
-    _compile_with_kernel(
+    text = jax.jit(
         lambda q, kv, t, i: paged_decode_attention_kernel(
-            q, kv, t, i, interpret=False),
+            q, kv, t, i, window=window, interpret=False)).lower(
         sds((slots, heads, 1, head_dim), jnp.bfloat16), pool,
-        sds((slots, table), jnp.int32), sds((slots,), jnp.int32))
+        sds((slots, table), jnp.int32), sds((slots,), jnp.int32)
+    ).compile().as_text()
+    assert len(_mosaic_calls(text)) == 1
+    assert not _makes(text, "bf16[" + ",".join(map(str, pool.shape)) + "]")
 
 
 # ----------------------------------------------- the engine's own programs
@@ -193,8 +217,11 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(v5e_chip, family,
                         | {r for r in results if r.startswith(pool_shape)})
         assert len(pool_layouts) == 1, pool_layouts
         assert pool_layouts.pop().startswith(pool_shape + "{3,2,1,0:T(8,128)")
-        # (4) The Mosaic kernel serves the tick; chunks take the jnp path.
-        assert ("tpu_custom_call" in text) == (name == "_tick_paged")
+        # (4) The Mosaic kernel serves the tick, ONE call a layer (each
+        # slot's walk over its blocks is inside it); chunks take the jnp
+        # path.
+        assert len(_mosaic_calls(text)) == \
+            (len(leaves) if name == "_tick_paged" else 0)
         # (5) No padding: what the program holds of the pool is what the
         # pool's data weighs (a 64-lane leaf pinned row-major is 2x).
         held = memory.argument_size_in_bytes
@@ -295,7 +322,8 @@ def test_smallthinker_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         text = compiled.as_text()
         header = text.split("\n", 1)[0]
         assert f"jit_{name}" in header
-        assert ("tpu_custom_call" in text) == (name == "_tick_paged")
+        # One Mosaic call a layer in the tick (window and NoPE-global).
+        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
         shape = r"\(?" + re.escape(pool_shape)
         moved = [line.strip()[:160] for line in text.splitlines()
                  if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",

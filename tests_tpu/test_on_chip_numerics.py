@@ -7,6 +7,8 @@ determinism. Tolerances are bf16/f32-mixed: the kernel accumulates in
 f32 but inputs/outputs are bf16 (the TPU training configuration).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ from pddl_tpu.ops.attention import (
     attention_reference,
     decode_attention,
     flash_attention,
+    paged_blocks_per_group,
     paged_cache_insert,
     paged_decode_attention,
     paged_decode_attention_kernel,
@@ -193,6 +196,54 @@ def test_paged_window_kernel_at_smallthinker_shapes_on_chip(window):
         np.testing.assert_allclose(
             np.asarray(chunk[:, :, r:r + 1], np.float32),
             np.asarray(one, np.float32), atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("slots,heads,kv_heads,d,context,window,lo,hi", [
+    (48, 20, 20, 64, 1024, None, 0, 1023),       # gpt2l_chat_*
+    (8, 28, 4, 128, 16384, None, 1024, 12288),   # st21b_longdoc_steady,
+    (8, 28, 4, 128, 16384, 4096, 1024, 12288),   # NoPE-global and window
+])
+def test_paged_decode_kernel_at_the_cells_shapes_on_chip(
+        slots, heads, kv_heads, d, context, window, lo, hi):
+    """The kernel as the benchmark's cells call it — 48 rows of 20 x 64
+    at mixed depths 0-1,023 (a fresh row, a group's last and first key
+    and the context's last position among them, parked rows between),
+    and 8 rows of 28 q / 4 kv heads of 128 at 1k-12k of context with the
+    4,096 window and without — against the jnp path and against dense
+    ``decode_attention`` over the virtual cache the table spells. Table
+    entries past a row's depth are scratch, as the engine leaves them:
+    the kernel's group loop copies none of them."""
+    bs, t = 16, context // 16
+    n = slots * t + 1
+    ks = jax.random.split(jax.random.key(slots + heads), 2)
+    q = jax.random.normal(ks[0], (slots, heads, 1, d), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (n, kv_heads, bs, 2 * d), jnp.bfloat16)
+    rng = np.random.RandomState(slots)
+    index = rng.randint(lo, hi + 1, size=slots).astype(np.int32)
+    span = paged_blocks_per_group(pool.shape, 2, t) * bs
+    a = lo // span + 1               # depths ON a group's last and first key
+    index[:6] = [lo, hi, a * span - 1, a * span,
+                 (a + 1) * span - 1, (a + 1) * span]
+    table = rng.permutation(np.arange(1, n)).reshape(slots, t).astype(
+        np.int32)
+    if lo == 0:
+        index[8::5] = 0              # parked rows: depth 0, all scratch
+        table[8::5] = 0
+    table[np.arange(t) > index[:, None] // bs] = 0
+    got = jax.jit(lambda *a: paged_decode_attention_kernel(
+        *a, window=window, interpret=False))(q, pool, table, index)
+    want = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window, kernel=False))(q, pool, table, index)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2, rtol=2e-2)
+    kc, vc = paged_kv_split(jnp.moveaxis(pool[table], 1, 2).reshape(
+        slots, kv_heads, context, 2 * d))
+    dense = jax.jit(functools.partial(decode_attention, window=window))(
+        q, kc, vc, index)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(dense, np.float32),
+        atol=2e-2, rtol=2e-2)
 
 
 @pytest.mark.parametrize("tokens", [8, 2048])
