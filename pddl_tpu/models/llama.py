@@ -38,6 +38,15 @@ Llama/Mistral/Qwen lineage — on the same substrate:
   experts (``moe_act="reglu"``), and ``moe_router_input="attn"``: the
   router reads the ATTENTION's normed input, so a runtime can fetch the
   chosen experts' weights while attention runs.
+- **Latent attention** (the DeepSeek-V2/V3 lineage's MLA;
+  :class:`LatentAttention`, ``kv_lora_rank > 0``, declared on the block
+  like a window or a RoPE flag): queries and keys/values through low-rank
+  projections, RoPE on ``qk_rope_head_dim`` of a head's dimensions, and
+  ONE cache entry a token for all heads, ``[c_kv | k_rope]``. Beside it
+  the lineage's expert layer: ``moe_layout`` (which layers route; a
+  leading dense layer), ``moe_intermediate_dim`` (an expert width apart
+  from the dense MLP's), sigmoid scores with a selection-only bias, a
+  gate scale and shared experts (:class:`pddl_tpu.ops.moe.SwitchFFN`).
 
 Everything else — flash/ring attention, Megatron TP (use
 ``LLAMA_TP_RULES`` from :mod:`pddl_tpu.parallel.tensor_parallel`),
@@ -62,9 +71,11 @@ from pddl_tpu.models.vit import (
     remat_block,
 )
 from pddl_tpu.ops.attention import (
+    LANES,
     attention_reference,
     decode_attention,
     flash_attention,
+    paged_kv_fuse,
 )
 from pddl_tpu.ops.rope import apply_rope_qk
 
@@ -264,7 +275,7 @@ class LlamaAttention(nn.Module):
             # projected, which is position-pure a fortiori).
             with jax.named_scope("attn_window" if self.sliding_window
                                  else "attn_global"):
-                o = paged_decode_step(self, index, q, k, v,
+                o = paged_decode_step(self, index, q, paged_kv_fuse(k, v),
                                       window=self.sliding_window)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, s, self.num_heads * head_dim)
@@ -351,13 +362,183 @@ class LlamaAttention(nn.Module):
         return dense(features=e, name="out")(o)
 
 
+class LatentAttention(nn.Module):
+    """Causal multi-head LATENT attention (MLA, DeepSeek-V2/V3 lineage).
+
+    With ``x`` the block's normed input at position ``t``:
+
+    - queries: ``c_q = RMSNorm(x W_dq)`` (``q_lora_rank``); ``[q_nope |
+      q_rope] = c_q W_uq``, ``num_heads`` heads of ``qk_nope_head_dim +
+      qk_rope_head_dim``; ``q_rope`` rotated at ``t``;
+    - the cache entry, one a token, shared by all heads: ``[c_kv |
+      k_rope] = x W_dkv`` (``kv_lora_rank + qk_rope_head_dim``); ``c_kv
+      = RMSNorm(c_kv)``; ``k_rope`` rotated at ``t``;
+    - keys and values: ``[k_nope | v]_h = c_kv W_ukv,h``; ``k_h = [k_nope,h
+      | k_rope]``; scores ``q_h . k_h`` scaled by ``(qk_nope_head_dim +
+      qk_rope_head_dim)^-0.5``; output ``concat(o_h) W_o``.
+
+    Training, eval and a chunk of prefill use that PER-HEAD form. A decode
+    step uses the same mathematics in the latent space (the ABSORBED
+    form): ``q~_h = q_nope,h W_uk,h^T``, score ``[q~_h | q_rope,h] .
+    [c_kv | k_rope]``, ``o~_h = sum p c_kv``, ``o_h = o~_h W_uv,h`` — the
+    cached entry is the key of every head as it lies and its first
+    ``kv_lora_rank`` lanes are the value, so the paged kernel reads each
+    entry once for all heads and nothing a head wide is ever cached.
+    An entry is STORED in whole 128-lane tiles, zeros after ``k_rope``
+    (GLM-4.7-Flash's 576 values in 640 lanes): the chip lays a 576-wide
+    minor dimension out in 640 lanes at rest whatever the shape says,
+    and Mosaic copies whole tiles only (it refused the 576-wide leaf,
+    `tests/test_tpu_aot_compile.py`), so the leaf says what is there.
+
+    A chunk of prefill through the paged cache (``s > 1``) writes its
+    entries first and then attends over the pool in the per-head form,
+    the gathered entries expanded to ``k_nope`` / ``v`` a sweep step at a
+    time (scope ``mla_expand``): per cached token that costs
+    ``2 kv_lora_rank H (nope + v)`` FLOPs again in every later chunk,
+    where the absorbed form would widen every score from ``nope + rope``
+    to ``kv_lora_rank + rope`` lanes and every value from ``v`` to
+    ``kv_lora_rank`` — at GLM-4.7-Flash's widths 2.1 x the attention
+    FLOPs of a chunk against a re-expansion a fifth of them.
+
+    The flash kernel takes keys and values of one width: a model whose
+    ``v_head_dim`` differs from ``qk_nope_head_dim + qk_rope_head_dim``
+    trains with ``attention="reference"``.
+    """
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    attention: str = "flash"  # "flash" | "reference"
+    rms_eps: float = 1e-5
+    decode: bool = False
+    max_decode_len: int = 1024
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, e = x.shape
+        h, rank = self.num_heads, self.kv_lora_rank
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        dense = functools.partial(
+            nn.DenseGeneral, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype)
+        norm = lambda name, t: _rms_norm(
+            self.rms_eps, self.param_dtype, name)(t).astype(self.dtype)
+        c_q = norm("q_norm", dense(features=self.q_lora_rank,
+                                   name="q_down")(x))
+        q = dense(features=(h, nope + rope), name="q_up")(c_q)
+        q = q.transpose(0, 2, 1, 3)                      # [B, H, S, nope+rope]
+        down = dense(features=rank + rope, name="kv_down")(x)
+        c_kv = norm("kv_norm", down[..., :rank])         # [B, S, rank]
+        k_rope = down[..., None, :, rank:]               # [B, 1, S, rope]
+        # W_ukv [rank, H, nope + v]: a head's W_uk beside its W_uv.
+        w_ukv = self.param(
+            "kv_up", nn.initializers.lecun_normal(), (rank, h, nope + vd),
+            self.param_dtype).astype(self.dtype)
+        out = dense(features=e, name="out")
+        lanes = -(-(rank + rope) // LANES) * LANES   # an entry as stored
+        index = self.variable(
+            "cache", "cache_index",
+            lambda: jnp.zeros((), jnp.int32)) if self.decode else None
+        positions = jnp.arange(s) if index is None \
+            else index.value[..., None] + jnp.arange(s)
+        q_rope, k_rope = apply_rope_qk(q[..., nope:], k_rope, positions,
+                                       theta=self.rope_theta)
+        q_nope = q[..., :nope]
+        scale = (nope + rope) ** -0.5
+
+        def expand(entries):
+            """Cache entries ``[B, 1, K, lanes]`` as every head's key and
+            value, ``[B, H, K, nope + rope]`` and ``[B, H, K, v]``."""
+            c, kr = entries[:, 0, :, :rank], entries[:, :, :, rank:rank + rope]
+            with jax.named_scope("mla_expand"):
+                kv = jnp.einsum("bkc,chd->bhkd", c, w_ukv)
+                k = jnp.concatenate(
+                    [kv[..., :nope],
+                     jnp.broadcast_to(kr, kv.shape[:3] + (rope,))], axis=-1)
+            return k, kv[..., nope:]
+
+        if index is None:
+            k, v = expand(jnp.concatenate([c_kv[:, None], k_rope], axis=-1))
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            if self.attention == "flash":
+                o = flash_attention(q, k, v, causal=True, scale=scale)
+            elif self.attention == "reference":
+                o = attention_reference(q, k, v, causal=True, scale=scale)
+            else:
+                raise ValueError(
+                    f"latent attention runs attention='flash' or "
+                    f"'reference', got {self.attention!r}")
+            return out(o.transpose(0, 2, 1, 3).reshape(b, s, h * vd))
+
+        entry = jnp.concatenate(
+            [c_kv[:, None], k_rope,
+             jnp.zeros((b, 1, s, lanes - rank - rope), self.dtype)], axis=-1)
+
+        def absorbed(attend):
+            """A step in the latent space: ``attend(q~)`` over entries
+            that are key and (their first ``rank`` lanes) value at once."""
+            with jax.named_scope("mla_absorb"):
+                q_lat = jnp.concatenate(
+                    [jnp.einsum("bhsd,chd->bhsc", q_nope,
+                                w_ukv[..., :nope]), q_rope], axis=-1)
+            o_lat = attend(q_lat)                        # [B, H, s, rank]
+            with jax.named_scope("mla_absorb"):
+                return jnp.einsum("bhsc,chd->bhsd", o_lat, w_ukv[..., nope:])
+
+        if self.has_variable("cache", BLOCK_TABLE_KEY):
+            # PAGED serving: the pool leaf is [N, 1, block, lanes], the
+            # entry as it is; the tick goes through the paged kernel
+            # in the absorbed form, a chunk in the per-head form over
+            # entries expanded inside the sweep.
+            with jax.named_scope("attn_latent"):
+                if s == 1:
+                    o = absorbed(lambda q_lat: paged_decode_step(
+                        self, index, q_lat, entry, scale=scale,
+                        value_lanes=(0, rank)))
+                else:
+                    o = paged_decode_step(
+                        self, index, jnp.concatenate([q_nope, q_rope], -1),
+                        entry, scale=scale, expand=expand)
+            return out(o.transpose(0, 2, 1, 3).reshape(b, s, h * vd))
+
+        # The row cache of `generate()`: full length, absorbed throughout.
+        initialized = self.has_variable("cache", "cached_latent")
+        cached = self.variable(
+            "cache", "cached_latent", jnp.zeros,
+            (b, 1, self.max_decode_len, lanes), self.dtype)
+        i = index.value
+        if initialized:
+            if i.ndim:  # per-row positions: scatter, out of range dropped
+                cached.value = cached.value.at[
+                    jnp.arange(b)[:, None], :, i[:, None] + jnp.arange(s)
+                ].set(jnp.moveaxis(entry, 1, 2))
+            else:
+                cached.value = jax.lax.dynamic_update_slice(
+                    cached.value, entry, (0, 0, i, 0))
+            index.value = i + s
+        o = absorbed(lambda q_lat: decode_attention(
+            q_lat, cached.value[..., :rank + rope],
+            cached.value[..., :rank], i, scale=scale,
+            chunk=128 if s > 1 else 512))
+        return out(o.transpose(0, 2, 1, 3).reshape(b, s, h * vd))
+
+
 class LlamaBlock(nn.Module):
     """Pre-RMSNorm residual block: attention then a SwiGLU MLP — dense,
     or routed over ``moe_experts`` gated experts (the Mixtral block:
     ``block_sparse_moe`` with top-``moe_top_k`` routing).
     ``moe_router_input="attn"`` moves the router in front of the
     attention: logits from the attention's normed input (a bias-free
-    ``router`` of the block's own), experts applied to the MLP's."""
+    ``router`` of the block's own), experts applied to the MLP's.
+    ``kv_lora_rank > 0`` makes the attention :class:`LatentAttention`;
+    ``moe_intermediate_dim`` gives the experts a width of their own."""
 
     num_heads: int
     num_kv_heads: int
@@ -377,6 +558,16 @@ class LlamaBlock(nn.Module):
     moe_eval_dropless: bool = True  # eval/serving is dropless
     moe_act: str = "swiglu"  # "swiglu" | "reglu"
     moe_router_input: str = "mlp"  # "mlp" | "attn"
+    moe_intermediate_dim: Optional[int] = None  # None: intermediate_dim
+    moe_router_score: str = "softmax"  # "softmax" | "sigmoid"
+    moe_select_bias: bool = False
+    moe_gate_scale: float = 1.0
+    moe_shared_experts: int = 0
+    kv_lora_rank: int = 0  # > 0: latent attention (the five below)
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     rms_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -398,16 +589,26 @@ class LlamaBlock(nn.Module):
                 router_logits = nn.Dense(
                     self.moe_experts, use_bias=False, dtype=jnp.float32,
                     param_dtype=self.param_dtype, name="router")(h)
-        h = LlamaAttention(
-            num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
-            head_dim=self.head_dim, rope=self.rope,
-            rope_theta=self.rope_theta, attention=self.attention,
-            sliding_window=self.sliding_window, qkv_bias=self.qkv_bias,
-            mesh=self.mesh, decode=self.decode,
-            max_decode_len=self.max_decode_len, dtype=self.dtype,
-            param_dtype=self.param_dtype, name="attn",
-        )(h.astype(self.dtype))
-        x = x + h
+        if self.kv_lora_rank:
+            attn = LatentAttention(
+                num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+                attention=self.attention, rms_eps=self.rms_eps,
+                decode=self.decode, max_decode_len=self.max_decode_len,
+                dtype=self.dtype, param_dtype=self.param_dtype, name="attn")
+        else:
+            attn = LlamaAttention(
+                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim, rope=self.rope,
+                rope_theta=self.rope_theta, attention=self.attention,
+                sliding_window=self.sliding_window, qkv_bias=self.qkv_bias,
+                mesh=self.mesh, decode=self.decode,
+                max_decode_len=self.max_decode_len, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="attn")
+        x = x + attn(h.astype(self.dtype))
 
         h = _rms_norm(self.rms_eps, self.param_dtype, "ln2")(x)
         h = h.astype(self.dtype)
@@ -416,10 +617,15 @@ class LlamaBlock(nn.Module):
 
             h = SwitchFFN(
                 num_experts=self.moe_experts,
-                hidden_dim=self.intermediate_dim, top_k=self.moe_top_k,
+                hidden_dim=self.moe_intermediate_dim
+                or self.intermediate_dim, top_k=self.moe_top_k,
                 capacity_factor=self.moe_capacity_factor,
                 eval_dropless=self.moe_eval_dropless,
-                expert_act=self.moe_act, dtype=self.dtype,
+                expert_act=self.moe_act,
+                router_score=self.moe_router_score,
+                select_bias=self.moe_select_bias,
+                gate_scale=self.moe_gate_scale,
+                shared_experts=self.moe_shared_experts, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="moe",
             )(h, train, router_logits=router_logits)
             return x + h
@@ -477,9 +683,33 @@ class Llama(nn.Module):
     moe_eval_dropless: bool = True  # eval/serving is dropless
     moe_act: str = "swiglu"  # "swiglu" | "reglu" (ReLU-gated experts)
     moe_router_input: str = "mlp"  # "attn": router before attention
+    # A 0/1 entry a layer: which layers route (None: `moe_every`'s
+    # rule). The DeepSeek lineage's `first_k_dense_replace` is zeros
+    # first; `intermediate_dim` is then the dense layers' width and
+    # `moe_intermediate_dim` an expert's (None: the same).
+    moe_layout: Optional[tuple] = None
+    moe_intermediate_dim: Optional[int] = None
+    moe_router_score: str = "softmax"  # "sigmoid": per-expert scores
+    moe_select_bias: bool = False  # a bias on the choice of experts only
+    moe_gate_scale: float = 1.0  # routed_scaling_factor
+    moe_shared_experts: int = 0  # always-on experts beside the routed
+    # Latent attention (`LatentAttention`): `kv_lora_rank > 0` turns it
+    # on in every layer (each block carries the declaration; a layout
+    # comes with the first configuration that mixes layer kinds).
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     rms_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+
+    @property
+    def latent_layers(self) -> int:
+        """How many layers a paged chunk program re-expands cached
+        entries for (`serve/metrics.py` ``latent_expanded_tokens``)."""
+        return self.depth if self.kv_lora_rank else 0
 
     def layer_window(self, i: int) -> Optional[int]:
         """Layer ``i``'s attention window (None: full attention)."""
@@ -492,10 +722,14 @@ class Llama(nn.Module):
         return self.rope_layout is None or bool(self.rope_layout[i])
 
     def moe_layer(self, i: int) -> bool:
-        """Interleaved MoE blocks: every ``moe_every``-th, counted from
-        the back like ViT (Mixtral's ``moe_every=1``: every block)."""
-        return bool(self.moe_experts) \
-            and (self.depth - 1 - i) % self.moe_every == 0
+        """Whether layer ``i`` routes: ``moe_layout``'s entry, else every
+        ``moe_every``-th block counted from the back like ViT (Mixtral's
+        ``moe_every=1``: every block)."""
+        if not self.moe_experts:
+            return False
+        if self.moe_layout is not None:
+            return bool(self.moe_layout[i])
+        return (self.depth - 1 - i) % self.moe_every == 0
 
     @property
     def uses_ring_cache(self) -> bool:
@@ -539,7 +773,8 @@ class Llama(nn.Module):
 
         block_cls = (LlamaBlock if self.decode
                      else remat_block(LlamaBlock, self.remat))
-        for layout in (self.sliding_window_layout, self.rope_layout):
+        for layout in (self.sliding_window_layout, self.rope_layout,
+                       self.moe_layout):
             if layout is not None and len(layout) != self.depth:
                 raise ValueError(
                     f"a per-layer layout needs {self.depth} entries, got "
@@ -559,6 +794,16 @@ class Llama(nn.Module):
                 moe_eval_dropless=self.moe_eval_dropless,
                 moe_act=self.moe_act,
                 moe_router_input=self.moe_router_input,
+                moe_intermediate_dim=self.moe_intermediate_dim,
+                moe_router_score=self.moe_router_score,
+                moe_select_bias=self.moe_select_bias,
+                moe_gate_scale=self.moe_gate_scale,
+                moe_shared_experts=self.moe_shared_experts,
+                kv_lora_rank=self.kv_lora_rank,
+                q_lora_rank=self.q_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim,
                 rms_eps=self.rms_eps, dtype=self.dtype,
                 param_dtype=self.param_dtype, name=f"block{i}",
             )(x, train)
@@ -644,6 +889,44 @@ def tiny_smallthinker(vocab_size: int = 64, **kwargs) -> Llama:
         sliding_window=8, sliding_window_layout=layout, rope_layout=layout,
         moe_experts=8, moe_top_k=2, moe_act="reglu",
         moe_router_input="attn", rms_eps=1e-6, attention="reference")
+    return Llama(vocab_size=vocab_size, **{**defaults, **kwargs})
+
+
+# GLM-4.7-Flash (zai-org, HF config.json, `glm4_moe_lite`): 47 layers x
+# 2048; 20 heads of latent attention (q rank 768, kv rank 512, 192 + 64
+# rope dims a key, 256 a value, theta 1e6); layer 0 a dense SwiGLU MLP of
+# 10,240, layers 1-46 64 SwiGLU experts of 1,536 top-4 chosen by sigmoid
+# scores plus a selection-only bias, gates renormalised and scaled by
+# 1.8, beside one shared expert; RMSNorm 1e-5; untied head over 154,880
+# tokens. Its multi-token-prediction block is not built. `depth` and
+# `max_len` are the caller's (202,752 positions published). Served at
+# random weights only.
+_GLM_FLASH = dict(
+    num_heads=20, kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6, moe_experts=64,
+    moe_top_k=4, moe_router_score="sigmoid", moe_select_bias=True,
+    moe_gate_scale=1.8, moe_shared_experts=1, rms_eps=1e-5)
+
+
+def GLM_4_7_Flash(depth: int = 47, **kwargs) -> Llama:
+    defaults = dict(
+        _GLM_FLASH, vocab_size=154880, embed_dim=2048,
+        intermediate_dim=10240, moe_intermediate_dim=1536,
+        moe_layout=(0,) + (1,) * (depth - 1))
+    return Llama(depth=depth, **{**defaults, **kwargs})
+
+
+def tiny_glm_flash(vocab_size: int = 64, **kwargs) -> Llama:
+    """The same block at test size: a dense layer then routed ones, 8
+    experts top-2 beside a shared one, 4 latent heads of 12 + 4 rope
+    dims a key and 16 a value over a cache entry of 24 + 4 values."""
+    depth = kwargs.get("depth", 3)
+    defaults = dict(
+        _GLM_FLASH, depth=depth, max_len=128, embed_dim=32, num_heads=4,
+        kv_lora_rank=24, q_lora_rank=20, qk_nope_head_dim=12,
+        qk_rope_head_dim=4, v_head_dim=16, intermediate_dim=48,
+        moe_intermediate_dim=16, moe_layout=(0,) + (1,) * (depth - 1),
+        moe_experts=8, moe_top_k=2, attention="reference")
     return Llama(vocab_size=vocab_size, **{**defaults, **kwargs})
 
 

@@ -38,6 +38,7 @@ from pddl_tpu.ops.attention import (
     flash_attention,
     paged_cache_insert,
     paged_decode_attention,
+    paged_kv_fuse,
 )
 
 # The paged-serving cache leaf names (`ops/attention.paged_*`,
@@ -51,25 +52,29 @@ BLOCK_TABLE_KEY = "block_table"
 PAGED_KV_KEY = "cached_kv"
 
 
-def paged_decode_step(module: nn.Module, index, q, k, v, *,
-                      window: Optional[int] = None):
+def paged_decode_step(module: nn.Module, index, q, entry, **attend):
     """The paged branch of an attention module's decode step, shared by
-    the MHA below and `llama.LlamaAttention`: write this call's K/V
-    ``[B, H_kv, s, D]`` (cache dtype, post-RoPE) into the pool through
-    the slot's block table, advance the counter ``index`` (the module's
-    own ``cache_index`` variable), attend over the pool. The paged
-    cache collection holds exactly three leaves per module — the fused
-    pool ``[N, H_kv, block_size, 2D]``, the counter and the table —
+    the MHA below, `llama.LlamaAttention` and `llama.LatentAttention`:
+    write this call's cache entries ``[B, H_c, s, lanes]`` (cache dtype,
+    post-RoPE: a K/V layer's ``paged_kv_fuse(k, v)``, a latent layer's
+    ``[c_kv | k_rope]``) into the pool through the slot's block table,
+    advance the counter ``index`` (the module's own ``cache_index``
+    variable), attend over the pool. ``attend`` is what the layer
+    declares of its entries and its mask to
+    :func:`~pddl_tpu.ops.attention.paged_decode_attention`
+    (``window``, ``scale``, ``value_lanes``, ``expand``). The paged
+    cache collection holds exactly three leaves per module — the pool
+    ``[N, H_c, block_size, lanes]``, the counter and the table —
     DECLARED (not just read) so the mutated cache keeps them and the
-    donated tree's structure stays stable. Returns ``[B, H, s, D]``."""
+    donated tree's structure stays stable. Returns ``[B, H, s, Dv]``."""
     pool = module.variable("cache", PAGED_KV_KEY, lambda: None)
     table = module.variable(
         "cache", BLOCK_TABLE_KEY,
         lambda: jnp.zeros((1, 1), jnp.int32)).value
     i = index.value
-    pool.value = paged_cache_insert(pool.value, k, v, table, i)
+    pool.value = paged_cache_insert(pool.value, entry, table, i)
     index.value = i + q.shape[2]
-    return paged_decode_attention(q, pool.value, table, i, window=window)
+    return paged_decode_attention(q, pool.value, table, i, **attend)
 
 
 class MultiHeadAttention(nn.Module):
@@ -162,8 +167,9 @@ class MultiHeadAttention(nn.Module):
             # scratch sink for parked slots / padding junk) by the
             # engine's table discipline; reads sweep the table with the
             # same masking as the row path below.
-            o = paged_decode_step(self, index, q, k.astype(self.dtype),
-                                  v.astype(self.dtype))
+            o = paged_decode_step(
+                self, index, q,
+                paged_kv_fuse(k.astype(self.dtype), v.astype(self.dtype)))
             o = o.transpose(0, 2, 1, 3).reshape(b, s, h * head_dim)
             return dense(features=h * head_dim, name="out")(o)
         # During init() the cache variables don't exist yet: create them

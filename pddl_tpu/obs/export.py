@@ -135,7 +135,7 @@ SERVE_COUNTER_KEYS = frozenset({
     "queue_pops", "queue_wait_s", "admissions", "admit_wall_s",
     # What prefill cost in tokens: prompt tokens installed, and chunk
     # programs dispatched by compiled width (a labeled counter).
-    "prefill_tokens", "prefill_chunks",
+    "prefill_tokens", "prefill_chunks", "latent_expanded_tokens",
 })
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")

@@ -1012,10 +1012,18 @@ def cache_blocks_scatter(pool: jnp.ndarray, row: jnp.ndarray, block_ids,
 #   softmax. A table entry no key of which the slot can see is neither
 #   visited nor fetched.
 #
-# THE POOL LEAF. One leaf per layer, K and V side by side in the last
-# dimension: ``[num_blocks, kv_heads, block_size, 2 * head_dim]``, K in
-# lanes ``[0, D)`` and V in ``[D, 2D)`` (:func:`paged_kv_fuse` /
-# :func:`paged_kv_split`). The reason is the layout the leaf has AT
+# THE POOL LEAF. One leaf per layer, ``[num_blocks, cache_heads,
+# block_size, lanes]``: one ENTRY of ``lanes`` values a token and a cache
+# head, as the layer declares it. The ops here know two things of an
+# entry: its KEY is lanes ``[0, Dk)``, ``Dk`` the width of the query it
+# is scored against, and its VALUE is the lanes the caller names
+# (``value_lanes``). A K/V layer stores K and V side by side, ``lanes =
+# 2 * head_dim``, K in ``[0, D)`` and V in ``[D, 2D)``
+# (:func:`paged_kv_fuse`, the default). A latent-attention layer stores
+# ONE entry for all its heads, ``[c_kv | k_rope]``: the whole entry is
+# the key of the absorbed query and its first ``kv_lora_rank`` lanes are
+# the value (`models/llama.LatentAttention`). Side by side, and not two
+# leaves, because of the layout the leaf has AT
 # REST, between programs, which nothing in the engine chooses: a jitted
 # program receives and hands back every argument in the chip's default
 # layout for its shape. For separate 64-wide leaves
@@ -1062,24 +1070,25 @@ def paged_kv_fuse(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([k, v], axis=-1)
 
 
-def paged_kv_split(kv: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The K and V halves of a fused ``[..., 2D]`` array."""
-    d = kv.shape[-1] // 2
-    return kv[..., :d], kv[..., d:]
-
-
-def _check_fused_pool(pool: jnp.ndarray, d: int) -> None:
-    if pool.ndim != 4 or pool.shape[-1] != 2 * d:
+def _entry_lanes(pool: jnp.ndarray, dk: int, value_lanes):
+    """``(v0, v1)``, the value lanes of a pool leaf's entries whose key
+    is lanes ``[0, dk)``; ``None`` is the K/V leaf's ``[dk, 2 dk)``."""
+    v0, v1 = (dk, 2 * dk) if value_lanes is None else map(int, value_lanes)
+    lanes = pool.shape[-1]
+    if pool.ndim != 4 or not (dk <= lanes and 0 <= v0 < v1 <= lanes):
         raise ValueError(
-            f"pool leaf must be [N, H_kv, block_size, 2*D={2 * d}], got "
+            f"pool leaf must be [N, H_c, block_size, lanes] with the key "
+            f"in lanes [0, {dk}) and the value in [{v0}, {v1}), got "
             f"{pool.shape}")
+    return v0, v1
 
 
-def paged_cache_insert(pool: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+def paged_cache_insert(pool: jnp.ndarray, entry: jnp.ndarray,
                        block_table, index) -> jnp.ndarray:
-    """Write ``k``/``v`` ``[B, H_kv, s, D]`` at global positions
-    ``index (+ arange(s))`` into pool blocks resolved through
-    ``block_table [B, T]`` (``pool [N, H_kv, block_size, 2D]``).
+    """Write the entries ``entry [B, H_c, s, lanes]`` (a K/V layer's
+    :func:`paged_kv_fuse`, a latent layer's ``[c_kv | k_rope]``) at
+    global positions ``index (+ arange(s))`` into pool blocks resolved
+    through ``block_table [B, T]`` (``pool [N, H_c, block_size, lanes]``).
 
     ``index`` is a scalar (batch-1 chunk prefill at a traced offset) or
     a per-row ``[B]`` vector (the serving tick: every slot writes one
@@ -1097,16 +1106,20 @@ def paged_cache_insert(pool: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     tokens in, scatter whole blocks back on dimension 0. A block of the
     span the tokens do not reach is written back as it was read.
     """
+    if pool.ndim != 4 or entry.ndim != 4 \
+            or entry.shape[1::2] != pool.shape[1::2]:
+        raise ValueError(
+            f"entries {entry.shape} do not fit the pool leaf {pool.shape} "
+            "([N, H_c, block_size, lanes])")
     n, hkv, bs, d2 = pool.shape
-    b, _, s, d = k.shape
-    _check_fused_pool(pool, d)
+    b, _, s, _ = entry.shape
     block_table = jnp.asarray(block_table, jnp.int32)
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
             f"block_table must be [B={b}, T], got {block_table.shape}")
     t = block_table.shape[1]
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
-    kv = paged_kv_fuse(k, v).astype(pool.dtype)
+    kv = entry.astype(pool.dtype)
     n_span = (bs + s - 2) // bs + 1     # most blocks s tokens can touch
     span = (index // bs)[:, None] + jnp.arange(n_span)         # [B, n_span]
     ids = jnp.where(
@@ -1135,6 +1148,7 @@ def paged_decode_attention(
     block_table, index, *, window: Optional[int] = None,
     scale: Optional[float] = None, blocks_per_chunk: Optional[int] = None,
     kernel: Optional[bool] = None, interpret: Optional[bool] = None,
+    value_lanes: Optional[tuple] = None, expand=None,
 ):
     """Attention over a paged KV pool through a per-slot block table.
 
@@ -1145,12 +1159,20 @@ def paged_decode_attention(
     storage and the table is the only per-slot state.
 
     Args:
-      q: ``[B, H, s, D]`` post-RoPE queries (``s == 1`` on the decode
+      q: ``[B, H, s, Dk]`` post-RoPE queries (``s == 1`` on the decode
         tick; ``s > 1`` for chunked prefill continuing at ``index``).
-      kv_pool: the fused ``[N, H_kv, block_size, 2D]`` pool leaf (K in
-        lanes ``[0, D)``, V in ``[D, 2D)``); the current tokens must
+      kv_pool: the ``[N, H_c, block_size, lanes]`` pool leaf (an
+        entry's key in lanes ``[0, Dk)``); the current tokens must
         already be written (:func:`paged_cache_insert` runs first, like
         the row path).
+      value_lanes: ``(start, stop)``, the lanes of an entry that are its
+        value; ``None`` is the K/V leaf's ``[Dk, 2 Dk)``. The result is
+        ``stop - start`` wide.
+      expand: jnp path only. ``expand(entries [B, H_c, keys, lanes]) ->
+        (k [B, H, keys, Dk], v [B, H, keys, Dv])`` turns a gathered
+        stretch of entries into per-head keys and values inside the
+        sweep (a latent layer's chunk prefill: ``q`` is then the
+        per-head query and ``value_lanes`` is not read).
       block_table: ``[B, T]`` int32 pool block ids; entries beyond a
         slot's depth are scratch (never read — masked).
       index: tokens in the (virtual) cache before this call; scalar or
@@ -1168,12 +1190,21 @@ def paged_decode_attention(
         picks the kernel on TPU and the reference elsewhere.
       interpret: Pallas interpret mode (defaults to non-TPU backends).
 
-    Returns ``[B, H, s, D]`` in q's dtype.
+    Returns ``[B, H, s, Dv]`` in q's dtype.
     """
     b, h, s, d = q.shape
-    _check_fused_pool(kv_pool, d)
+    if kv_pool.ndim != 4:
+        raise ValueError(
+            f"pool leaf must be [N, H_c, block_size, lanes], got "
+            f"{kv_pool.shape}")
     n, hkv, bs, d2 = kv_pool.shape
-    rep = _gqa_rep(q, kv_pool)
+    if expand is None:
+        v0, v1 = _entry_lanes(kv_pool, d, value_lanes)
+        groups, rep, dv = hkv, _gqa_rep(q, kv_pool), v1 - v0
+    else:  # expanded keys and values are per q head: no grouping is left
+        groups, rep, dv = h, 1, jax.eval_shape(
+            expand, jax.ShapeDtypeStruct((b, hkv, bs, d2), kv_pool.dtype)
+        )[1].shape[-1]
     block_table = jnp.asarray(block_table, jnp.int32)
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
@@ -1194,9 +1225,12 @@ def paged_decode_attention(
                 "the paged Pallas kernel serves single-token decode "
                 f"steps only (got a {s}-token block); multi-token "
                 "prefill takes the jnp path (kernel=False)")
+        if expand is not None:
+            raise ValueError("the paged Pallas kernel reads entries as "
+                             "they are stored (expand= is the jnp path's)")
         return paged_decode_attention_kernel(
             q, kv_pool, block_table, index, scale=scale_v,
-            window=window, interpret=interpret)
+            window=window, interpret=interpret, value_lanes=value_lanes)
 
     # ---- jnp reference path (the tier-1 oracle) ----
     if blocks_per_chunk is None:
@@ -1224,7 +1258,8 @@ def paged_decode_attention(
             # [B*cb, Hkv, bs, 2D] -> [B, Hkv, cb*bs, 2D]
             kvc = jnp.moveaxis(kvc.reshape(b, cb, hkv, bs, d2), 1, 2) \
                 .reshape(b, hkv, chunk, d2)
-            kc, vc = paged_kv_split(kvc)
+            kc, vc = (kvc[..., :d], kvc[..., v0:v1]) if expand is None \
+                else expand(kvc)
             sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(kv_pool.dtype),
                             kc, preferred_element_type=jnp.float32) * scale_v
             pos = start_blk * bs + jnp.arange(chunk)
@@ -1250,28 +1285,28 @@ def paged_decode_attention(
         # two thirds of it).
         first = 0 if window is None else \
             jnp.maximum(jnp.min(index) - (window - 1), 0) // chunk
-        m0 = jnp.full((b, hkv, rep, sq, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, hkv, rep, sq, 1), jnp.float32)
-        acc0 = jnp.zeros((b, hkv, rep, sq, d), jnp.float32)
+        m0 = jnp.full((b, groups, rep, sq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((b, groups, rep, sq, 1), jnp.float32)
+        acc0 = jnp.zeros((b, groups, rep, sq, dv), jnp.float32)
         if n_chunks == 1:
             m, l, acc = body(0, (m0, l0, acc0))
         else:
             m, l, acc = jax.lax.fori_loop(first, live, body, (m0, l0, acc0))
         return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
-    qg = q.reshape(b, hkv, rep, s, d)
+    qg = q.reshape(b, groups, rep, s, d)
     sq = _largest_dividing_block(s, PAGED_SWEEP_MAX_ROWS)
     if sq == s:
-        return sweep(qg, index).reshape(b, h, s, d)
+        return sweep(qg, index).reshape(b, h, s, dv)
     # A chunk wider than PAGED_SWEEP_MAX_ROWS goes a stretch of rows at a
     # time: each stretch sweeps to ITS causal edge and from ITS band's
     # start (half the multiplies of the whole square, less for a window
     # layer), and the score block stays the stretch's size.
-    tiles = jnp.moveaxis(qg.reshape(b, hkv, rep, s // sq, sq, d), 3, 0)
+    tiles = jnp.moveaxis(qg.reshape(b, groups, rep, s // sq, sq, d), 3, 0)
     out = jax.lax.map(
         lambda a: sweep(a[0], index + a[1] * sq),
         (tiles, jnp.arange(s // sq, dtype=jnp.int32)))
-    return jnp.moveaxis(out, 0, 3).reshape(b, h, s, d)
+    return jnp.moveaxis(out, 0, 3).reshape(b, h, s, dv)
 
 
 # Bytes of pool blocks one group of the paged decode kernel fetches (and
@@ -1316,12 +1351,15 @@ def _paged_decode_kernel(table_ref, index_ref, q_ref, pool_ref, o_ref,
     under a window's band nothing. Running max / denominator /
     accumulator live in VMEM scratch across the loop.
 
-    Everything in here is ``2D`` lanes wide and nothing is sliced or
-    reshaped (Mosaic cannot re-tile a 64-lane minor dim): ``q_ref``
-    carries zeros in lanes ``[D, 2D)``, so ``q . [k|v]`` is ``q . k``
-    exactly (the V half adds exact zeros to the f32 sum), and
-    ``p . [k|v]`` accumulates ``p . v`` in lanes ``[D, 2D)``, which the
-    wrapper slices off in HBM.
+    Everything in here is one entry (``lanes``) wide and nothing is
+    sliced or reshaped (Mosaic cannot re-tile a 64-lane minor dim):
+    ``q_ref`` carries zeros in every lane past the key's ``[0, Dk)``, so
+    ``q . entry`` is ``q . key`` exactly (the other lanes add exact
+    zeros to the f32 sum), and ``p . entry`` accumulates ``p . value``
+    in the value's lanes, which the wrapper slices off in HBM. A K/V
+    leaf's entry is ``[k | v]``; a latent leaf's is key throughout and
+    value in its leading lanes, which is the absorbed form of latent
+    attention with no line of this body changed.
     """
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
@@ -1425,10 +1463,11 @@ def paged_decode_attention_kernel(
     q: jnp.ndarray, kv_pool: jnp.ndarray,
     block_table, index, *, scale: Optional[float] = None,
     window: Optional[int] = None, interpret: Optional[bool] = None,
+    value_lanes: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """The Pallas paged decode kernel (single-token steps).
 
-    Grid ``(B,)``, one step a slot, the fused leaf left in HBM: inside
+    Grid ``(B,)``, one step a slot, the pool leaf left in HBM: inside
     its step a slot loops over the groups of ``K`` pool blocks that its
     depth (and its window's band) reaches and copies each through the
     scalar-prefetched block table into one of two VMEM buffers, the
@@ -1445,8 +1484,8 @@ def paged_decode_attention_kernel(
     b, h, s, d = q.shape
     if s != 1:
         raise ValueError(f"decode kernel takes single-token steps, got s={s}")
-    _check_fused_pool(kv_pool, d)
-    hkv = kv_pool.shape[1]
+    v0, v1 = _entry_lanes(kv_pool, d, value_lanes)
+    hkv, lanes = kv_pool.shape[1], kv_pool.shape[3]
     rep = _gqa_rep(q, kv_pool)
     block_table = jnp.asarray(block_table, jnp.int32)
     k_blocks = paged_blocks_per_group(
@@ -1455,12 +1494,13 @@ def paged_decode_attention_kernel(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
-    # kv-head grouping and the zero V-half of q, both done in HBM.
-    qg = jnp.pad(q.reshape(b, hkv, rep, d), ((0, 0),) * 3 + ((0, d),))
+    # Cache-head grouping and q's zeros past the key, both done in HBM.
+    qg = jnp.pad(q.reshape(b, hkv, rep, d),
+                 ((0, 0),) * 3 + ((0, lanes - d),))
     out = _paged_decode_call(
         block_table, index, qg, kv_pool, k_blocks=k_blocks,
         scale=float(scale_v), window=window, interpret=bool(interpret))
-    return out[..., d:].reshape(b, h, 1, d)
+    return out[..., v0:v1].reshape(b, h, 1, v1 - v0)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
@@ -1563,6 +1603,7 @@ def decode_attention(
     """
     b, h, s, d = q.shape
     hkv, cache_len = k_cache.shape[1], k_cache.shape[2]
+    dv = v_cache.shape[-1]  # a latent cache's value is narrower than its key
     rep = _gqa_rep(q, k_cache)
     index = jnp.asarray(index, jnp.int32)
     if index.ndim > 1 or (index.ndim == 1 and index.shape[0] != b):
@@ -1618,7 +1659,7 @@ def decode_attention(
         kc = jax.lax.dynamic_slice(
             k_cache, (0, 0, start, 0), (b, hkv, chunk, d))
         vc = jax.lax.dynamic_slice(
-            v_cache, (0, 0, start, 0), (b, hkv, chunk, d))
+            v_cache, (0, 0, start, 0), (b, hkv, chunk, dv))
         sb = jnp.einsum("bgrqd,bgkd->bgrqk", qg.astype(k_cache.dtype), kc,
                         preferred_element_type=jnp.float32) * scale_v
         slot = start + jnp.arange(chunk)
@@ -1662,7 +1703,7 @@ def decode_attention(
     live = jnp.minimum((jnp.max(total) + chunk - 1) // chunk, n_chunks)
     m0 = jnp.full((b, hkv, rep, s, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, rep, s, 1), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, rep, s, d), jnp.float32)
+    acc0 = jnp.zeros((b, hkv, rep, s, dv), jnp.float32)
     if n_chunks == 1:
         # Whole cache in one pass — no while loop in the program at all.
         m, l, acc = body(0, (m0, l0, acc0))
@@ -1681,7 +1722,7 @@ def decode_attention(
         m = jnp.where(keep, m, m0)
         l = jnp.where(keep, l, l0)
         acc = jnp.where(keep, acc, acc0)
-    out = (acc / jnp.maximum(l, 1e-30)).reshape(b, h, s, d).astype(q.dtype)
+    out = (acc / jnp.maximum(l, 1e-30)).reshape(b, h, s, dv).astype(q.dtype)
     if return_lse:
         # Rows with nothing attended (empty history) keep lse ~ -inf so
         # a logsumexp-space merge gives them zero weight.

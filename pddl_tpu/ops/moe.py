@@ -174,6 +174,16 @@ class SwitchFFN(nn.Module):
     outside the layer — a block that routes from its attention's normed
     input, so that expert weights can be fetched while attention runs,
     hands them in and the layer declares no router of its own.
+
+    Routing of the DeepSeek-V3 lineage (``noaux_tc``), every piece its
+    own attribute: ``router_score="sigmoid"`` scores each expert by
+    ``sigmoid(logit)`` (a bias-free router: this lineage's only bias is
+    the next one); ``select_bias`` adds a learned per-expert ``[N]``
+    float32 bias to the scores FOR THE CHOICE OF EXPERTS ONLY — the
+    gates are the unbiased scores of the chosen ones; ``gate_scale``
+    multiplies the (renormalised) gates; ``shared_experts`` adds, after
+    the combine, a plain gated MLP of ``shared_experts * hidden`` that
+    every token goes through (scope ``moe_shared``).
     """
 
     num_experts: int
@@ -183,6 +193,10 @@ class SwitchFFN(nn.Module):
     capacity_factor: float = 1.25
     expert_act: str = "gelu"  # "gelu" | "swiglu" (Mixtral) | "reglu"
     normalize_gates: bool = True  # top_k >= 2: g_j / sum_j g_j
+    router_score: str = "softmax"  # "softmax" | "sigmoid"
+    select_bias: bool = False  # a bias on the choice of experts only
+    gate_scale: float = 1.0  # routed_scaling_factor
+    shared_experts: int = 0  # always-on experts beside the routed ones
     aux_loss_weight: float = 0.01
     # Eval/serving (train=False) uses capacity == seq — enough for the
     # worst case: the k choices per token are DISTINCT experts (each
@@ -207,7 +221,12 @@ class SwitchFFN(nn.Module):
                 f"top_k={self.top_k} must be in [1, num_experts={n}]")
         if self.expert_act not in EXPERT_ACTS:
             raise ValueError(f"unknown expert_act {self.expert_act!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
         gated = self.expert_act != "gelu"
+        if self.shared_experts and not gated:
+            raise ValueError("shared_experts are gated MLPs: expert_act "
+                             f"{self.expert_act!r} has no gate")
         # Batch rows are the dispatch groups (the Switch/Mesh-TF "group"
         # dim): capacity is per group, so dispatch/combine are
         # [B, S, N, C] — linear in batch, never quadratic in total tokens.
@@ -217,13 +236,20 @@ class SwitchFFN(nn.Module):
             else d * self.mlp_ratio
 
         # Router (f32 for a stable softmax regardless of compute dtype).
+        sigmoid = self.router_score == "sigmoid"
         with jax.named_scope("moe_router"):
             if router_logits is None:
                 router_logits = nn.Dense(
-                    n, dtype=jnp.float32,
+                    n, use_bias=not sigmoid, dtype=jnp.float32,
                     param_dtype=self.param_dtype, name="router"
                 )(x.astype(jnp.float32))
-            probs = nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+            router_logits = router_logits.astype(jnp.float32)
+            probs = nn.sigmoid(router_logits) if sigmoid \
+                else nn.softmax(router_logits, axis=-1)
+            # What the experts are CHOSEN by; the gates stay `probs`.
+            select = probs + self.param(
+                "select_bias", nn.initializers.zeros, (n,), jnp.float32
+            ) if self.select_bias else probs
 
         # Expert-major parameters: dim 0 shards over the `expert` mesh axis.
         # batch_axis=(0,): the expert dim must not count toward fan-in, or
@@ -238,24 +264,27 @@ class SwitchFFN(nn.Module):
         b2 = None if gated else param("b2", nn.initializers.zeros, n, d)
 
         if not train and self.eval_dropless:
-            return self._serve(x, probs, w1, w3, b1, w2, b2)
+            return self._plus_shared(
+                self._serve(x, probs, select, w1, w3, b1, w2, b2), x, hidden)
 
         # k sequential choices (k is tiny and static — an unrolled Python
         # loop of MXU-friendly one-hot ops, no sorting network needed).
         # Choice j's queue positions start after the KEPT tokens of
         # choices < j (mesh-tf top-2 convention), so second choices never
         # displace first choices from an expert's capacity.
-        remaining = probs
+        remaining = select
         offset = jnp.zeros((b, n), probs.dtype)     # kept tokens per expert
         gates, dispatches = [], []
         first_choice_onehot = None
         for _ in range(self.top_k):
-            gate = jnp.max(remaining, axis=-1)                # (B, S)
             raw_onehot = nn.one_hot(
                 jnp.argmax(remaining, axis=-1), n)            # (B, S, N)
+            gate = jnp.sum(probs * raw_onehot, axis=-1)       # (B, S)
             if first_choice_onehot is None:
                 first_choice_onehot = raw_onehot
-            remaining = remaining * (1.0 - raw_onehot)
+            # A biased score may be negative: a chosen expert leaves
+            # the race at -inf, not at 0.
+            remaining = jnp.where(raw_onehot > 0, -jnp.inf, remaining)
             position = (jnp.cumsum(raw_onehot, axis=1)
                         + offset[:, None, :]) * raw_onehot    # 1-based
             onehot = raw_onehot * (position <= capacity)
@@ -269,6 +298,8 @@ class SwitchFFN(nn.Module):
         if self.top_k > 1 and self.normalize_gates:
             denom = sum(gates) + 1e-9
             gates = [g / denom for g in gates]
+        if self.gate_scale != 1.0:
+            gates = [g * self.gate_scale for g in gates]
 
         dispatch = sum(dispatches)
         # Dropped tokens have an all-zero dispatch row, so gating needs no
@@ -309,20 +340,44 @@ class SwitchFFN(nn.Module):
             h = nn.gelu(jnp.einsum("bncd,ndh->bnch", expert_in, w1)
                         + b1[:, None, :])
             expert_out = jnp.einsum("bnch,nhd->bncd", h, w2) + b2[:, None, :]
-        return jnp.einsum("bsnc,bncd->bsd", combine, expert_out)
+        return self._plus_shared(
+            jnp.einsum("bsnc,bncd->bsd", combine, expert_out), xc, hidden)
 
-    def _serve(self, x, probs, w1, w3, b1, w2, b2):
+    def _plus_shared(self, y, x, hidden: int):
+        """``y`` plus what the shared experts make of ``x``: one gated
+        MLP of ``shared_experts * hidden``, bias-free, no routing."""
+        if not self.shared_experts:
+            return y
+        dense = lambda width, name: nn.Dense(
+            width, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name)
+        width = self.shared_experts * hidden
+        with jax.named_scope("moe_shared"):
+            x = x.astype(self.dtype)
+            hid = EXPERT_ACTS[self.expert_act](
+                dense(width, "shared_gate")(x)) * dense(width, "shared_up")(x)
+            return y + dense(x.shape[-1], "shared_down")(hid)
+
+    def _serve(self, x, probs, select, w1, w3, b1, w2, b2):
         """Eval and serving: dropless, token-major. The k choices and
-        their gates are the training path's (k largest probabilities,
-        ties to the lower expert; renormalised over the kept ones, which
-        is a softmax over the selected logits); what differs is that no
+        their gates are the training path's (the k largest of ``select``,
+        ties to the lower expert; gates the chosen experts' ``probs``,
+        renormalised over the kept ones — for softmax scores a softmax
+        over the selected logits — then scaled); what differs is that no
         expert has a capacity."""
         b, s, d = x.shape
         n, k = self.num_experts, self.top_k
         with jax.named_scope("moe_router"):
-            gates, index = jax.lax.top_k(probs.reshape(b * s, n), k)
+            if select is probs:
+                gates, index = jax.lax.top_k(probs.reshape(b * s, n), k)
+            else:
+                _, index = jax.lax.top_k(select.reshape(b * s, n), k)
+                gates = jnp.take_along_axis(probs.reshape(b * s, n), index,
+                                            axis=-1)
             if k > 1 and self.normalize_gates:
                 gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-9)
+            if self.gate_scale != 1.0:
+                gates = gates * self.gate_scale
             if s > 1 and self.has_variable("cache", EXPERT_LOAD_KEY):
                 # Serving statistics (module docstring of the keys):
                 # pairs of the chunk's real tokens only. The tick's rows

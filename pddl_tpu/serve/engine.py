@@ -562,6 +562,10 @@ class ServeEngine:
                 "clamp its positions")
         self.prefix_block_size = bs
         self._chunk = chunk
+        # A model with latent attention layers re-expands, in every
+        # chunk program, the cached entries the chunk attends over
+        # (`metrics.latent_expanded_tokens`).
+        self._reexpands = bool(getattr(model, "latent_layers", 0))
 
         # Chunked-prefill fairness: at most `prefill_slice_tokens` of
         # prompt prefill per step(), the decode tick interleaved
@@ -2145,7 +2149,8 @@ class ServeEngine:
             chunk_toks = np.zeros((1, width), np.int32)
             chunk_toks[0, :w] = prompt[off:off + w]
             logits = dispatch(site, prog, chunk_toks, w, off)
-            self.metrics.record_prefill_chunk(width)
+            self.metrics.record_prefill_chunk(
+                width, off if self._reexpands else 0)
             self._tracer.on_prefill_chunk(handle, site, off, w,
                                           self._last_wall_s)
             off += w
@@ -2626,7 +2631,8 @@ class ServeEngine:
                     "draft_prefill", self._dchunk_p, self._dparams,
                     self._dcache, chunk_toks, np.int32(w),
                     np.int32(off), sl["table"][None])
-            self.metrics.record_prefill_chunk(self._chunk)
+            self.metrics.record_prefill_chunk(
+                self._chunk, off if self._reexpands else 0)
             self._tracer.on_prefill_chunk(handle, "chunk_prefill", off, w,
                                           self._last_wall_s)
             sl["off"] = off + w

@@ -1,10 +1,12 @@
 """Device-resident KV block pool: the serving engine's KV cache.
 
 The engine has no per-request cache rows: its cache tree IS a pool
-(:func:`paged_decode_cache`), one fused leaf per attention layer
-``[N, kv_heads, block_size, 2*D]`` with K and V side by side — the shape
-whose layout at rest every program uses in place (`ops/attention.py`,
-paged section). Live streams and cached prompt prefixes alike live
+(:func:`paged_decode_cache`), one leaf per attention layer
+``[N, cache_heads, block_size, lanes]`` whose entries are what the layer
+declares it caches of a token — K and V side by side (``2*D`` lanes), or
+a latent layer's one ``[c_kv | k_rope]`` — the shape whose layout at
+rest every program uses in place (`ops/attention.py`, paged section).
+Live streams and cached prompt prefixes alike live
 here at fixed-size token-block granularity, reached through per-slot
 block tables.
 
@@ -45,11 +47,16 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
     """The serving cache tree: the pool IS the cache.
 
     Builds the cache tree the engine hands straight to
-    ``dec.apply``: each attention module's K and V become ONE fused
-    block pool ``[num_blocks, kv_heads, block_size, 2 * head_dim]``
-    (K in lanes ``[0, D)``, V in ``[D, 2D)`` — the one leaf shape whose
+    ``dec.apply``: what an attention module declares as its row cache
+    (``[1, cache_heads, max_len, width]`` leaves: ``cached_key`` and
+    ``cached_value``, or a latent layer's one ``cached_latent``) becomes
+    ONE block pool ``[num_blocks, cache_heads, block_size, lanes]``,
+    ``lanes`` the widths side by side (K in lanes ``[0, D)``, V in
+    ``[D, 2D)``; a latent entry as it is) — the one leaf shape whose
     layout at rest every paged program reads and writes in place;
-    `ops/attention.py`'s paged section says why), position counters
+    `ops/attention.py`'s paged section says why. Which lanes are key
+    and which are value is the module's to say where it attends
+    (`models/vit.paged_decode_step`). Position counters
     and per-slot block tables are CANONICAL PLACEHOLDERS (scalar 0 /
     ``[1, 1]``) that every paged program re-stamps from engine-owned
     host state on entry and restores on exit — one tree structure
@@ -82,14 +89,16 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
             else:
                 kv[name] = val
         if kv:
-            k, v = kv.pop("cached_key"), kv.pop("cached_value")
-            if kv or k.shape != v.shape or k.dtype != v.dtype:
+            rows = list(kv.values())
+            if any(r.ndim != 4 or r.shape[:3] != rows[0].shape[:3]
+                   or r.dtype != rows[0].dtype for r in rows):
                 raise ValueError(
-                    f"cannot fuse K/V leaves {k.shape} {v.shape} "
-                    f"{sorted(kv)} into one paged pool leaf")
-            _, hkv, _, d = k.shape
+                    "cannot lay the row-cache leaves "
+                    f"{ {k: v.shape for k, v in kv.items()} } side by "
+                    "side in one paged pool leaf")
             out[PAGED_KV_KEY] = jnp.zeros(
-                (num_blocks, hkv, block_size, 2 * d), k.dtype)
+                (num_blocks, rows[0].shape[1], block_size,
+                 sum(r.shape[3] for r in rows)), rows[0].dtype)
             out[BLOCK_TABLE_KEY] = jnp.zeros((1, 1), jnp.int32)
         return out
 

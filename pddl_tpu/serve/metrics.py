@@ -227,9 +227,15 @@ class ServeMetrics:
         # `admissions`), and the chunk programs dispatched, by their
         # compiled width (counted at the dispatch, replays and sliced
         # admissions included). sum(width * chunks) / prefill_tokens is
-        # the padding the fixed widths cost.
+        # the padding the fixed widths cost. Of a model with latent
+        # attention layers, whose chunk programs re-expand every cached
+        # entry they attend over: the cached tokens so re-expanded (a
+        # chunk's offset, counted at its dispatch, once a chunk whatever
+        # the number of such layers); over prefill_tokens, how many
+        # times a prompt token is expanded again after its own chunk.
         self.prefill_tokens = 0
         self.prefill_chunks: Dict[str, int] = {}
+        self.latent_expanded_tokens = 0
         # Recent admission timestamps: the QueueFull retry_after_s
         # estimator (a short window so the hint tracks CURRENT service
         # rate, not the all-time average).
@@ -347,10 +353,14 @@ class ServeMetrics:
         self.admit_wall_s += admit_wall_s
         self.prefill_tokens += int(prompt_tokens)
 
-    def record_prefill_chunk(self, width: int) -> None:
-        """One chunk-prefill program of compiled ``width`` dispatched."""
+    def record_prefill_chunk(self, width: int,
+                             latent_expanded: int = 0) -> None:
+        """One chunk-prefill program of compiled ``width`` dispatched,
+        re-expanding ``latent_expanded`` cached tokens (0 for a model
+        without latent layers)."""
         key = str(int(width))
         self.prefill_chunks[key] = self.prefill_chunks.get(key, 0) + 1
+        self.latent_expanded_tokens += int(latent_expanded)
 
     def recent_admission_interval_s(self) -> Optional[float]:
         """Mean gap between recent admissions, or ``None`` before two
@@ -540,6 +550,7 @@ class ServeMetrics:
             "prefill_tokens": self.prefill_tokens,
             # Labeled series, one sample per compiled chunk width.
             "prefill_chunks": dict(self.prefill_chunks),
+            "latent_expanded_tokens": self.latent_expanded_tokens,
             # Per-priority splits: mappings render as labeled series
             # (one sample per class) through `obs/export.py`, so the
             # SLO runbook reads shed/finish/TTFT per class off one

@@ -44,7 +44,6 @@ from pddl_tpu.ops.attention import (
     paged_decode_attention,
     paged_decode_attention_kernel,
     paged_kv_fuse,
-    paged_kv_split,
 )
 from pddl_tpu.serve import ServeEngine
 from pddl_tpu.serve.faults import FaultPlan
@@ -85,8 +84,8 @@ def _random_paged(rng, b, hkv, bs, t, d):
     return pool, table, jnp.asarray(dense[..., :d]), jnp.asarray(dense[..., d:])
 
 
-def test_paged_kv_fuse_and_split_are_inverse():
-    """K in lanes [0, D), V in [D, 2D): the one definition of the pool
+def test_paged_kv_fuse_lays_k_then_v():
+    """K in lanes [0, D), V in [D, 2D): the one definition of a K/V pool
     leaf's last dimension."""
     rng = np.random.RandomState(9)
     k = jnp.asarray(rng.randn(2, 3, 4, 8), jnp.float32)
@@ -94,12 +93,10 @@ def test_paged_kv_fuse_and_split_are_inverse():
     kv = paged_kv_fuse(k, v)
     assert kv.shape == (2, 3, 4, 16)
     np.testing.assert_array_equal(np.asarray(kv[..., :8]), np.asarray(k))
-    k2, v2 = paged_kv_split(kv)
-    np.testing.assert_array_equal(np.asarray(k2), np.asarray(k))
-    np.testing.assert_array_equal(np.asarray(v2), np.asarray(v))
+    np.testing.assert_array_equal(np.asarray(kv[..., 8:]), np.asarray(v))
     with pytest.raises(ValueError, match="differ"):
         paged_kv_fuse(k, v[:, :2])
-    with pytest.raises(ValueError, match=r"2\*D"):
+    with pytest.raises(ValueError, match="value in"):
         paged_decode_attention(k[:, :, :1], kv[..., :8],
                                np.zeros((2, 1), np.int32), np.int32(0))
 
@@ -363,7 +360,7 @@ def test_paged_insert_matches_per_token_scatter(s):
     index = np.array([4, 9, 7, t * bs - 1, 2, t * bs], np.int32)
     k = jnp.asarray(rng.randn(b, hkv, s, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, hkv, s, d), jnp.float32)
-    got = paged_cache_insert(pool, k, v, table, index)
+    got = paged_cache_insert(pool, paged_kv_fuse(k, v), table, index)
     want = _scatter_insert(pool, k, v, table, index)
     np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(want[1:]))
     # Row 0's first token really is where the table says.
@@ -382,7 +379,7 @@ def test_paged_cache_insert_and_scratch_deflection():
     index = np.array([5, 17, 0], np.int32)
     k = jnp.asarray(rng.randn(b, hkv, 1, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, hkv, 1, d), jnp.float32)
-    out = paged_cache_insert(pool, k, v, table, index)
+    out = paged_cache_insert(pool, paged_kv_fuse(k, v), table, index)
     for i in range(b):
         got = np.asarray(out[table[i, index[i] // bs], :, index[i] % bs])
         np.testing.assert_array_equal(got[:, :d], np.asarray(k[i, :, 0]))
@@ -393,7 +390,8 @@ def test_paged_cache_insert_and_scratch_deflection():
     v2 = jnp.asarray(rng.randn(1, hkv, 10, d), jnp.float32)
     kv2 = paged_kv_fuse(k2, v2)
     start = 9  # mid-block start, spans blocks 2..4
-    out2 = paged_cache_insert(pool, k2, v2, table[:1], np.int32(start))
+    out2 = paged_cache_insert(pool, paged_kv_fuse(k2, v2), table[:1],
+                              np.int32(start))
     for j in range(10):
         pos = start + j
         got = np.asarray(out2[table[0, pos // bs], :, pos % bs])
@@ -404,7 +402,8 @@ def test_paged_cache_insert_and_scratch_deflection():
         np.asarray(pool[table[0, start // bs], :, : start % bs]))
     # ...and a write running off the table's end deflects to scratch:
     # no real block outside row 0's own table changes.
-    out3 = paged_cache_insert(pool, k2, v2, table[:1], np.int32(t * bs - 3))
+    out3 = paged_cache_insert(pool, paged_kv_fuse(k2, v2), table[:1],
+                              np.int32(t * bs - 3))
     np.testing.assert_array_equal(np.asarray(out3[1 + t:]),
                                   np.asarray(pool[1 + t:]))
     # The in-table tail tokens still landed.

@@ -96,6 +96,8 @@ def _compile_with_kernel(fn, *shapes, kernel=True):
     (20, 20, 64, 16, 1024, None),   # GPT-2-large, the benchmark's configuration
     (28, 4, 128, 16, 16384, None),  # SmallThinker: 7 q heads a kv head over
     (28, 4, 128, 16, 16384, 4096),  # a 1,024-wide table, NoPE-global and window
+    (20, 1, 576, 16, 20480, None),  # GLM-4.7-Flash's latent leaf: 20 absorbed
+                                    # q heads over ONE 640-lane cache head
 ])
 def test_paged_decode_kernel_compiles_for_v5e(v5e_chip, heads, kv_heads,
                                               head_dim, block_size, context,
@@ -110,11 +112,17 @@ def test_paged_decode_kernel_compiles_for_v5e(v5e_chip, heads, kv_heads,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-    pool = sds((slots * table + 1, kv_heads, block_size, 2 * head_dim),
+    latent = kv_heads == 1 and head_dim == 576
+    # The latent entry is key in all of its 576 values and value in the
+    # first 512, stored in 640 lanes (a 576-lane leaf is refused: Mosaic
+    # copies whole 128-lane tiles).
+    lanes, value_lanes = (640, (0, 512)) if latent else (2 * head_dim, None)
+    pool = sds((slots * table + 1, kv_heads, block_size, lanes),
                jnp.bfloat16)
     text = jax.jit(
         lambda q, kv, t, i: paged_decode_attention_kernel(
-            q, kv, t, i, window=window, interpret=False)).lower(
+            q, kv, t, i, window=window, interpret=False,
+            value_lanes=value_lanes)).lower(
         sds((slots, heads, 1, head_dim), jnp.bfloat16), pool,
         sds((slots, table), jnp.int32), sds((slots,), jnp.int32)
     ).compile().as_text()
@@ -348,6 +356,66 @@ def test_smallthinker_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
             for dims in re.findall(r"= \(?\w+\[([\d,]*)\]", text)
             if "8193" not in dims and dims not in weight_dims)
         assert largest < 64 * 2048 * 2048 // 8, (name, tokens, largest)
+
+
+def test_glm_flash_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
+    """The benchmark cell `glm47f_agent_saturated`'s own `_tick_paged` and
+    `_chunk_paged` at the published widths (2048, 20 latent heads over a
+    512 + 64-value entry, 64 SwiGLU experts of 1,536 top-4 beside a shared
+    one, a dense first layer of 10,240, vocabulary 154,880; 40 slots of
+    20,480 positions, block 16, 2,048-wide chunks), two layers (the dense
+    one and a routed one), weights as shapes. The ONE paged Mosaic kernel
+    is in the tick, a call a layer, on the leaf `bf16[51201,1,16,640]`;
+    every leaf is aliased and never copied; no chunk-wide second program
+    is built."""
+    from pddl_tpu.models.llama import GLM_4_7_Flash
+    from pddl_tpu.serve import ServeEngine
+
+    model = GLM_4_7_Flash(depth=2, max_len=20480, dtype=jnp.bfloat16,
+                          param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.ones((1, 8), jnp.int32), train=False))[
+            "params"]
+    eng = ServeEngine(model, {"params": params}, paged=True, max_slots=40,
+                      prefill_len=16384, prefix_block_size=16,
+                      prefix_cache_blocks=40 * 1280 + 1, prefix_chunk=2048)
+    assert not eng._has_wide
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    shapes = functools.partial(_shapes_on, v5e_chip)
+    pool_shape = "bf16[51201,1,16,640]"
+    leaves = [leaf for leaf in jax.tree.leaves(eng._cache) if leaf.ndim == 4]
+    assert [f"bf16[{','.join(map(str, x.shape))}]" for x in leaves] == \
+        [pool_shape] * 2
+    programs = {
+        "_tick_paged": (eng._tick_p, eng._tick_args()),
+        "_chunk_paged": (eng._chunk_p, eng._chunk_args_paged(eng._chunk)),
+    }
+    for name, (prog, args) in programs.items():
+        compiled = prog.lower(*shapes(*args)).compile()
+        text = compiled.as_text()
+        header = text.split("\n", 1)[0]
+        assert f"jit_{name}" in header
+        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
+        shape = r"\(?" + re.escape(pool_shape)
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
+                              line)]
+        assert not moved, f"{name}: pool-sized copies\n" + "\n".join(moved)
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < leaves[0].size * 2, name
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                            header).group(1)
+        entry = re.search(
+            r"entry_computation_layout=\{\((.*)\)->\((.*)\)\}", header)
+        params_in = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", entry.group(1))
+        pool_params = {i for i, p in enumerate(params_in)
+                       if p.startswith(pool_shape)}
+        assert len(pool_params) == 2
+        assert all(params_in[i].startswith(pool_shape + "{3,2,1,0:T(8,128)")
+                   for i in pool_params)
+        aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
+        assert pool_params <= aliased, (name, aliases)
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])

@@ -23,7 +23,6 @@ from pddl_tpu.ops.attention import (
     paged_cache_insert,
     paged_decode_attention,
     paged_decode_attention_kernel,
-    paged_kv_split,
 )
 from pddl_tpu.ops.augment import standard_augment
 from pddl_tpu.ops.large_vocab import chunked_cross_entropy
@@ -154,9 +153,9 @@ def test_paged_decode_kernel_matches_oracle_on_chip(heads, kv_heads, d,
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=2e-2, rtol=2e-2)
     # The virtual cache the table spells, attended densely.
-    kc, vc = paged_kv_split(jnp.moveaxis(pool[table], 1, 2).reshape(
-        slots, kv_heads, 1024, 2 * d))
-    dense = jax.jit(decode_attention)(q, kc, vc, index)
+    kv = jnp.moveaxis(pool[table], 1, 2).reshape(slots, kv_heads, 1024,
+                                                 2 * d)
+    dense = jax.jit(decode_attention)(q, kv[..., :d], kv[..., d:], index)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(dense, np.float32),
         atol=2e-2, rtol=2e-2)
@@ -237,10 +236,52 @@ def test_paged_decode_kernel_at_the_cells_shapes_on_chip(
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=2e-2, rtol=2e-2)
-    kc, vc = paged_kv_split(jnp.moveaxis(pool[table], 1, 2).reshape(
-        slots, kv_heads, context, 2 * d))
+    kv = jnp.moveaxis(pool[table], 1, 2).reshape(slots, kv_heads, context,
+                                                 2 * d)
     dense = jax.jit(functools.partial(decode_attention, window=window))(
-        q, kc, vc, index)
+        q, kv[..., :d], kv[..., d:], index)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(dense, np.float32),
+        atol=2e-2, rtol=2e-2)
+
+
+def test_paged_kernel_on_a_latent_leaf_at_the_glm_cells_shape_on_chip():
+    """The same kernel as the GLM-4.7-Flash cell calls it: 20 absorbed q
+    heads of 576 over ONE cache head whose entries are key in their first
+    576 lanes and value in their first 512, stored in 640; 48 rows over a
+    20,480-token context (table width 1,280, K 64) at depths from a fresh
+    row to 18k, parked rows between; against the jnp path and against
+    dense ``decode_attention`` over the virtual cache the table spells."""
+    slots, heads, dk, dv, lanes, bs, context = 48, 20, 576, 512, 640, 16, 20480
+    t = context // bs
+    n = slots * t + 1
+    ks = jax.random.split(jax.random.key(576), 2)
+    q = jax.random.normal(ks[0], (slots, heads, 1, dk), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (n, 1, bs, lanes), jnp.bfloat16)
+    pool = pool.at[..., dk:].set(0)       # as the layer stores an entry
+    rng = np.random.RandomState(7)
+    index = rng.randint(1024, 18432, size=slots).astype(np.int32)
+    span = paged_blocks_per_group(pool.shape, 2, t) * bs
+    assert span == 1024
+    index[:6] = [0, 18431, 2 * span - 1, 2 * span, 3 * span - 1, 20479]
+    table = rng.permutation(np.arange(1, n)).reshape(slots, t).astype(
+        np.int32)
+    index[8::5] = 0                       # parked rows: all scratch
+    table[8::5] = 0
+    table[np.arange(t) > index[:, None] // bs] = 0
+    kw = dict(scale=256 ** -0.5, value_lanes=(0, dv))
+    got = jax.jit(lambda *a: paged_decode_attention_kernel(
+        *a, interpret=False, **kw))(q, pool, table, index)
+    want = jax.jit(lambda *a: paged_decode_attention(
+        *a, kernel=False, **kw))(q, pool, table, index)
+    assert got.shape == (slots, heads, 1, dv)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2, rtol=2e-2)
+    virtual = jnp.moveaxis(pool[table], 1, 2).reshape(slots, 1, context,
+                                                      lanes)
+    dense = jax.jit(functools.partial(decode_attention, scale=256 ** -0.5))(
+        q, virtual[..., :dk], virtual[..., :dv], index)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(dense, np.float32),
         atol=2e-2, rtol=2e-2)
@@ -300,7 +341,7 @@ def test_paged_tick_write_in_place_on_chip():
     index = np.asarray([0, 15, 3, 16, 511, 9, 1023, 1024], np.int32)
     before = np.asarray(pool, np.float32)
     out = jax.jit(paged_cache_insert, donate_argnums=(0,))(
-        pool, k, v, table, index)
+        pool, jnp.concatenate([k, v], -1), table, index)
     out = np.asarray(out, np.float32)
     want = before.copy()
     new = np.asarray(jnp.concatenate([k, v], -1)[:, :, 0], np.float32)
