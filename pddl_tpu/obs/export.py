@@ -109,7 +109,8 @@ SERVE_COUNTER_KEYS = frozenset({
     "requests_finished", "requests_rejected", "requests_timed_out",
     "requests_cancelled", "requests_failed", "requests_deadline_shed",
     "tokens_emitted", "prefix_lookups", "prefix_hits",
-    "prefill_tokens_saved", "prefix_evictions", "retries", "replays",
+    "prefill_tokens_saved", "prefix_evictions", "prefix_reclaims",
+    "prefix_reclaim_visited", "retries", "replays",
     "preemptions", "degraded_entries", "degraded_time_s",
     "copy_bytes_avoided",
     # Multi-tenant counters (`serve/tenant/`): adapter pool traffic and

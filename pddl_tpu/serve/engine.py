@@ -2274,6 +2274,7 @@ class ServeEngine:
             self.metrics.record_prefix_lookup(
                 n_cached, blocks_live=self._prefix.blocks_live,
                 evictions=self._prefix.evictions)
+            self._record_prefix_reclaims()
         return logits, node, table_row, private
 
     def _donate_tail_paged(self, prompt: np.ndarray, node, table_row,
@@ -2442,6 +2443,13 @@ class ServeEngine:
             for blk, bid in zip(need, ids):
                 self._tables[sid, blk] = bid
                 self._private[sid].append(bid)
+        self._record_prefix_reclaims()
+
+    def _record_prefix_reclaims(self) -> None:
+        self.metrics.record_prefix_reclaims(
+            evictions=self._prefix.evictions,
+            reclaims=self._prefix.reclaims,
+            visited=self._prefix.reclaim_visited)
 
     def _preempt_for_interactive(self) -> List[int]:
         """Every slot is busy and ``interactive`` work is queued: park
@@ -2666,6 +2674,7 @@ class ServeEngine:
                 int(sl["n_cached"]),
                 blocks_live=self._prefix.blocks_live,
                 evictions=self._prefix.evictions)
+            self._record_prefix_reclaims()
         self._slice = None
         self._install_slot(sid, handle, sl["logits"], node, sl["table"],
                            sl["private"], arow=sl.get("arow", 0),

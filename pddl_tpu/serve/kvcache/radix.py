@@ -20,6 +20,9 @@ Invariants (property-tested in ``tests/test_prefix_cache.py``):
   recently accessed first — an interior node always outlives its
   children, so a stored chain can never lose an ancestor block while a
   descendant (or a pinned user) remains.
+- **Reclaim cost**: the evictable leaves are kept in LRU order as the
+  index changes (:class:`RadixPrefixCache` says how), so handing out a
+  block under pressure costs what it frees, not the size of the index.
 
 Single-threaded by design, like the engine that drives it: the engine
 is caller-driven (``step()``), so no locking — and because the device
@@ -31,6 +34,7 @@ decoding from its private copy.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import itertools
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
@@ -43,7 +47,7 @@ class _Node:
     its pool row. The root is a sentinel (no key, no block)."""
 
     __slots__ = ("key", "block_id", "parent", "children", "ref",
-                 "last_access")
+                 "last_access", "queued")
 
     def __init__(self, key: Optional[tuple], block_id: Optional[int],
                  parent: Optional["_Node"]):
@@ -53,6 +57,7 @@ class _Node:
         self.children: Dict[tuple, "_Node"] = {}
         self.ref = 0
         self.last_access = 0
+        self.queued = False  # has an entry in the index's LRU heap
 
 
 @dataclasses.dataclass
@@ -70,6 +75,34 @@ class PrefixMatch:
 
 class RadixPrefixCache:
     """Refcounted, LRU-evicted radix index over a block pool.
+
+    **How a reclaim finds its victims.** The evictable nodes (not the
+    root, ``ref == 0``, no child left) sit in a binary heap of
+    ``(stamp, seq, node)`` entries, validated when popped — a lazy heap
+    rather than an ordered dict or a linked list, because a node that
+    BECOMES evictable (an unpin, a parent whose last child was evicted)
+    carries an old stamp and belongs in the middle of the order, where
+    only a heap inserts in O(log n). A node has at most one entry
+    (``_Node.queued``), pushed when it becomes evictable and left alone
+    when that ends (a pin, a new child) or when ``match`` / ``descend``
+    / ``extend`` restamp it: stamps only grow, so an entry's stamp is
+    never later than its node's, it surfaces no later than it is due,
+    and a pop that finds its node unevictable drops the entry, one that
+    finds it restamped pushes it back under the new stamp. The heap
+    never holds more entries than the index holds nodes; nothing is
+    ever scanned or compacted.
+
+    No tie-break orders the victims: a stamp belongs to one call and a
+    call stamps one root path, so two nodes share a stamp only as
+    ancestor and descendant, and an ancestor is no leaf while its
+    descendant is in the index. ``seq`` only keeps the heap from
+    comparing nodes (and the visit counts deterministic).
+
+    ``reclaims`` counts the allocations that met a short free list and
+    ``reclaim_visited`` the heap entries popped plus the parents those
+    evictions exposed — visited per eviction is the witness that a
+    reclaim costs what it frees (about 1–2; the walk this replaced
+    visited the whole index per pass).
 
     Args:
       block_size: tokens per block (the pool's token granularity).
@@ -89,7 +122,11 @@ class RadixPrefixCache:
         self._free: Deque[int] = deque(range(1, num_blocks))
         self._root = _Node(None, None, None)
         self._clock = itertools.count(1)
+        self._lru: List[Tuple[int, int, _Node]] = []
+        self._seq = itertools.count()
         self.evictions = 0
+        self.reclaims = 0
+        self.reclaim_visited = 0
         # Demotion hook (ISSUE 13, `kvcache/hosttier.py`): called once
         # per reclaim pass with the LIST of victims BEFORE their block
         # ids are freed — each node still attached (parent chain
@@ -202,6 +239,7 @@ class RadixPrefixCache:
             node = node.parent
 
     def unpin(self, node: _Node) -> None:
+        tip = node
         while node is not self._root:
             if node.ref <= 0:
                 raise RuntimeError(
@@ -209,6 +247,9 @@ class RadixPrefixCache:
                     "an engine slot released its prefix chain twice")
             node.ref -= 1
             node = node.parent
+        # ``ref`` moved along the whole path, but every node above the
+        # tip has a child: only the tip can have become evictable.
+        self._queue_if_evictable(tip)
 
     def flush_unpinned(self) -> int:
         """Degraded-mode flush: evict EVERY unpinned block (the chains
@@ -245,17 +286,33 @@ class RadixPrefixCache:
         needed. May return FEWER than asked (everything else is pinned)
         — the caller donates a shorter chain prefix, never fails."""
         if len(self._free) < n:
+            self.reclaims += 1
             self._reclaim(n - len(self._free))
         take = min(n, len(self._free))
         return [self._free.popleft() for _ in range(take)]
 
+    def _queue_if_evictable(self, node: _Node) -> None:
+        """Give ``node`` its entry in the LRU heap if it is evictable
+        and has none. Called wherever a node can BECOME evictable: the
+        tip ``extend`` leaves, the tip ``unpin`` releases, a parent
+        whose last child a reclaim took."""
+        if (node is not self._root and node.ref == 0
+                and not node.children and not node.queued):
+            self._push(node)
+
+    def _push(self, node: _Node) -> None:
+        node.queued = True
+        heapq.heappush(self._lru,
+                       (node.last_access, next(self._seq), node))
+
     def _reclaim(self, need: int, demote: bool = True) -> None:
         """Evict up to ``need`` unpinned LEAVES, least recently accessed
-        first. One DFS collects the whole evictable set per pass (not
-        one full-tree scan PER block — allocation bursts sit on the
-        admission/TTFT path); evicting a leaf can expose its parent as
-        a new evictable leaf, so passes repeat until satisfied or
-        nothing is evictable.
+        first, in passes: a pass takes up to ``need`` of the leaves
+        evictable at its start; evicting a leaf can expose its parent,
+        which becomes a candidate only in the NEXT pass, so passes
+        repeat until satisfied or nothing is evictable. Victims come
+        off the LRU heap (the class docstring has its rules); nothing
+        but victims, stale entries and exposed parents is looked at.
 
         With a demotion hook installed (``on_evict``), eviction is a
         POLICY DECISION rather than a free: the WHOLE reclaim's victim
@@ -266,39 +323,57 @@ class RadixPrefixCache:
         batched transfer per allocation shortfall rather than one per
         pass (passes often take 1-2 leaves each, and the hook's
         device round trip sits on the admission path). Victims are
-        marked, not freed, between passes, so exposing a parent as the
-        next pass's leaf needs no tree mutation before the hook runs.
+        counted off their parents, not detached, between passes
+        (``left``), so exposing a parent as the next pass's leaf needs
+        no tree mutation before the hook runs.
         ``demote=False`` (the degraded flush) skips the hook
         unconditionally."""
         call_hook = demote and self.on_evict is not None
+        heap = self._lru
         all_taken: List[_Node] = []
-        marked = set()
+        left: Dict[_Node, int] = {}  # parent -> children not yet taken
         while need > 0:
-            victims = []
-            stack = [self._root]
-            while stack:
-                node = stack.pop()
-                stack.extend(node.children.values())
-                if (node is not self._root and node.ref == 0
-                        and id(node) not in marked
-                        and all(id(c) in marked
-                                for c in node.children.values())):
-                    victims.append(node)
-            if not victims:
+            took = 0
+            exposed: List[_Node] = []
+            while took < need and heap:
+                stamp, _, node = heapq.heappop(heap)
+                self.reclaim_visited += 1
+                node.queued = False
+                if node.ref or left.get(node, len(node.children)):
+                    continue  # pinned or extended since it was queued
+                if stamp != node.last_access:
+                    self._push(node)  # restamped since it was queued
+                    continue
+                all_taken.append(node)
+                took += 1
+                parent = node.parent
+                n = left.get(parent, len(parent.children)) - 1
+                left[parent] = n
+                if n == 0 and parent is not self._root and parent.ref == 0:
+                    exposed.append(parent)
+            # An exposed parent cannot already be queued: an entry from
+            # its own days as a leaf is older than any child it has had
+            # since and was popped before them.
+            self.reclaim_visited += len(exposed)
+            for parent in exposed:
+                self._push(parent)
+            if took == 0:
                 break
-            victims.sort(key=lambda v: v.last_access)
-            taken = victims[:need]
-            all_taken.extend(taken)
-            marked.update(id(v) for v in taken)
-            need -= min(need, len(victims))
+            need -= took
         if not all_taken:
             return
-        if call_hook:
-            self.on_evict(all_taken)
-        for victim in all_taken:
-            del victim.parent.children[victim.key]
-            self._free.append(victim.block_id)
-            self.evictions += 1
+        try:
+            if call_hook:
+                self.on_evict(all_taken)
+        finally:
+            # The victims are off the heap and their parents counted
+            # down: a hook that raises must not leave them half-taken
+            # (in the index, evictable, and never found again), so they
+            # are freed all the same and the exception goes on up.
+            for victim in all_taken:
+                del victim.parent.children[victim.key]
+                self._free.append(victim.block_id)
+                self.evictions += 1
 
     # --------------------------------------------------------- insertion
     def extend(self, node: _Node, tokens: Sequence[int],
@@ -310,24 +385,34 @@ class RadixPrefixCache:
         donation when the allocator ran dry); extra chunks are simply
         not stored."""
         now = next(self._clock)
-        for j, bid in enumerate(block_ids):
-            if bid == SCRATCH_BLOCK:
-                raise ValueError("the scratch block cannot join the index")
-            key = tuple(int(t) for t in
-                        tokens[j * self.block_size:(j + 1) * self.block_size])
-            if len(key) != self.block_size:
-                raise ValueError(
-                    f"chunk {j} has {len(key)} tokens, need a full "
-                    f"{self.block_size}-token block")
-            if key in node.children:
-                # A concurrent admission in the same tick already stored
-                # this chunk: keep the existing node, return the id to
-                # the free list (ours was never written into the tree).
-                self._free.append(bid)
-                node = node.children[key]
-            else:
-                child = _Node(key, bid, node)
-                node.children[key] = child
-                node = child
-            node.last_access = now
+        try:
+            for j, bid in enumerate(block_ids):
+                if bid == SCRATCH_BLOCK:
+                    raise ValueError(
+                        "the scratch block cannot join the index")
+                key = tuple(int(t) for t in
+                            tokens[j * self.block_size:
+                                   (j + 1) * self.block_size])
+                if len(key) != self.block_size:
+                    raise ValueError(
+                        f"chunk {j} has {len(key)} tokens, need a full "
+                        f"{self.block_size}-token block")
+                if key in node.children:
+                    # A concurrent admission in the same tick already
+                    # stored this chunk: keep the existing node, return
+                    # the id to the free list (ours was never written
+                    # into the tree).
+                    self._free.append(bid)
+                    node = node.children[key]
+                else:
+                    child = _Node(key, bid, node)
+                    node.children[key] = child
+                    node = child
+                node.last_access = now
+        finally:
+            # The parent stopped being a leaf (its heap entry, if any,
+            # is dropped when popped); the tip reached — the new one,
+            # an existing one on a dedup, the last attached if a chunk
+            # was refused — may be one.
+            self._queue_if_evictable(node)
         return node

@@ -144,6 +144,11 @@ class ServeMetrics:
         self.prefix_hits = 0
         self.prefill_tokens_saved = 0
         self.prefix_evictions = 0
+        # Allocations that met a short free list, and the index nodes
+        # those reclaims examined: visited per eviction is what a block
+        # costs under pressure (about 1-2; it was the index's size).
+        self.prefix_reclaims = 0
+        self.prefix_reclaim_visited = 0
         self.prefix_blocks_live = 0  # gauge, engine-stamped per admission
         # Block-pool telemetry: `copy_bytes_avoided` counts the bytes
         # a prefix hit references in place (matched tokens x per-token
@@ -393,6 +398,16 @@ class ServeMetrics:
         self.prefix_blocks_live = int(blocks_live)
         self.prefix_evictions = int(evictions)
 
+    def record_prefix_reclaims(self, *, evictions: int, reclaims: int,
+                               visited: int) -> None:
+        """The index's own running totals of allocation under pressure
+        (`RadixPrefixCache.evictions` / ``reclaims`` /
+        ``reclaim_visited``), stamped after every admission and once a
+        step after the block tables grow."""
+        self.prefix_evictions = int(evictions)
+        self.prefix_reclaims = int(reclaims)
+        self.prefix_reclaim_visited = int(visited)
+
     def record_copy_avoided(self, nbytes: int) -> None:
         """One paged prefix hit referenced ``nbytes`` of matched KV in
         place instead of gathering it into a slot row."""
@@ -498,6 +513,8 @@ class ServeMetrics:
             "prefill_tokens_saved": self.prefill_tokens_saved,
             "prefix_blocks_live": self.prefix_blocks_live,
             "prefix_evictions": self.prefix_evictions,
+            "prefix_reclaims": self.prefix_reclaims,
+            "prefix_reclaim_visited": self.prefix_reclaim_visited,
             "copy_bytes_avoided": self.copy_bytes_avoided,
             "blocks_shared": self.blocks_shared,
             "block_table_fill": round(self.block_table_fill, 6),

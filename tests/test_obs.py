@@ -778,3 +778,68 @@ def test_cancelled_mid_admission_is_a_pop_and_no_admission(gpt_setup):
     m = eng.metrics.snapshot()
     assert (m["queue_pops"], m["admissions"], m["admit_wall_s"]) \
         == (2, 1, 0.0)
+
+
+# ------------------------------------------- the block index under pressure
+@pytest.fixture(scope="module")
+def pressed_engine(gpt_setup):
+    """An engine whose pool sits at its floor (16 blocks + scratch) after
+    24 distinct full blocks went through it: the free list is empty and
+    every further block is a reclaim. Left mid-stream: one request
+    admitted, its answer still to decode across block boundaries."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
+                      prefix_cache_blocks=17)
+    for i in range(12):
+        eng.submit((np.arange(16) * 7 + 11 * i + i // 3) % 32, 4)
+        eng.run(max_steps=100)
+    h = eng.submit((np.arange(16) * 5 + 3) % 32, 40)
+    while not h.tokens:
+        eng.step()
+    return eng, h
+
+
+@pytest.mark.parametrize("key", ["prefix_reclaims",
+                                 "prefix_reclaim_visited"])
+def test_reclaim_counters_in_snapshot_and_exposition(pressed_engine, key):
+    eng, _ = pressed_engine
+    snap = eng.metrics.snapshot()
+    own = {"prefix_reclaims": eng._prefix.reclaims,
+           "prefix_reclaim_visited": eng._prefix.reclaim_visited}
+    assert snap[key] == own[key] > 0
+    assert key in SERVE_COUNTER_KEYS
+    samples, types = parse_prometheus_text(
+        serve_exposition(eng.metrics, eng))
+    assert types[f"pddl_serve_{key}_total"] == "counter"
+    assert samples[(f"pddl_serve_{key}_total", ())] == float(snap[key])
+
+
+def test_reclaims_cost_what_they_free_and_decode_feeds_them(pressed_engine):
+    """Visited per eviction is the witness (the walk this replaced read
+    the index's size there), and the counters move on a step that
+    admits nothing: a live stream crossing a block boundary."""
+    eng, h = pressed_engine
+    before = eng.metrics.snapshot()
+    eng.run(max_steps=100)
+    assert h.done and len(h.tokens) == 40
+    snap = eng.metrics.snapshot()
+    assert snap["prefix_lookups"] == before["prefix_lookups"]
+    assert snap["prefix_reclaims"] > before["prefix_reclaims"]
+    assert snap["prefix_evictions"] > before["prefix_evictions"]
+    assert snap["prefix_evictions"] == eng._prefix.evictions
+    assert (snap["prefix_evictions"] <= snap["prefix_reclaim_visited"]
+            <= 3 * snap["prefix_evictions"])
+
+
+def test_reclaim_counters_start_at_zero_and_take_the_index_totals():
+    m = ServeMetrics()
+    snap = m.snapshot()
+    assert snap["prefix_reclaims"] == snap["prefix_reclaim_visited"] == 0
+    m.record_prefix_reclaims(evictions=5, reclaims=3, visited=7)
+    snap = m.snapshot()
+    assert (snap["prefix_evictions"], snap["prefix_reclaims"],
+            snap["prefix_reclaim_visited"]) == (5, 3, 7)
+    # A lookup restamps evictions and leaves the reclaim totals alone.
+    m.record_prefix_lookup(0, blocks_live=4, evictions=6)
+    snap = m.snapshot()
+    assert (snap["prefix_evictions"], snap["prefix_reclaims"]) == (6, 3)

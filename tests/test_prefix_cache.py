@@ -256,3 +256,267 @@ def test_engine_validation():
     with pytest.raises(ValueError, match="prefix_chunk"):
         ServeEngine(model, variables, max_slots=1, prefill_len=32,
                     prefix_block_size=8, prefix_chunk=48)
+
+
+# ------------------------------------------- reclaim: the LRU heap vs the walk
+class _WalkOracle(RadixPrefixCache):
+    """The index as it reclaimed before it kept its evictable leaves in
+    LRU order: ``_reclaim`` below is that version's body, copied — a
+    depth-first walk of the whole index per pass, a sort of the
+    evictable leaves — and lives on only here, as the reference the
+    heap is held to. (The heap bookkeeping of the base class still runs
+    underneath and is never read.)"""
+
+    def _reclaim(self, need, demote=True):
+        call_hook = demote and self.on_evict is not None
+        all_taken = []
+        marked = set()
+        while need > 0:
+            victims = []
+            stack = [self._root]
+            while stack:
+                node = stack.pop()
+                stack.extend(node.children.values())
+                if (node is not self._root and node.ref == 0
+                        and id(node) not in marked
+                        and all(id(c) in marked
+                                for c in node.children.values())):
+                    victims.append(node)
+            if not victims:
+                break
+            victims.sort(key=lambda v: v.last_access)
+            taken = victims[:need]
+            all_taken.extend(taken)
+            marked.update(id(v) for v in taken)
+            need -= min(need, len(victims))
+        if not all_taken:
+            return
+        if call_hook:
+            self.on_evict(all_taken)
+        for victim in all_taken:
+            del victim.parent.children[victim.key]
+            self._free.append(victim.block_id)
+            self.evictions += 1
+
+
+def _nodes(idx):
+    out, stack = [], [idx._root]
+    while stack:
+        n = stack.pop()
+        stack.extend(n.children.values())
+        if n is not idx._root:
+            out.append(n)
+    return out
+
+
+def _recording_hook(idx, log):
+    """An ``on_evict`` that writes down what it is given — each victim's
+    chain and block id, one list a call — and checks the contract: every
+    victim still attached all the way to the root, its id not yet on
+    the free list."""
+    def hook(victims):
+        free = set(idx._free)
+        for v in victims:
+            walk = v
+            while walk is not idx._root:
+                assert walk.parent.children[walk.key] is walk
+                walk = walk.parent
+            assert v.block_id not in free
+        log.append([(tuple(idx.chain_tokens(v)), v.block_id)
+                    for v in victims])
+    return hook
+
+
+def _check_heap(idx):
+    """What the heap has to hold for a reclaim to find every victim:
+    every evictable leaf queued, no node queued twice, no entry later
+    than its node's stamp, never more entries than nodes — and no two
+    evictable leaves under one stamp, so LRU order needs no tie-break."""
+    nodes = _nodes(idx)
+    evictable = [n for n in nodes if n.ref == 0 and not n.children]
+    stamps = [n.last_access for n in evictable]
+    assert len(stamps) == len(set(stamps)), "two evictable leaves share a stamp"
+    queued = [node for _, _, node in idx._lru]
+    assert len(queued) == len({id(n) for n in queued}), "node queued twice"
+    assert all(n.queued for n in queued)
+    assert {id(n) for n in evictable} <= {id(n) for n in queued}
+    assert {id(n) for n in queued} <= {id(n) for n in nodes}
+    assert all(stamp <= node.last_access for stamp, _, node in idx._lru)
+    assert len(idx._lru) <= idx.blocks_live
+
+
+@pytest.mark.parametrize("num_blocks,bs,vocab,steps",
+                         [(24, 2, 3, 400), (161, 4, 2, 900)],
+                         ids=["23blocks", "160blocks"])
+@pytest.mark.parametrize("seed", range(8))
+def test_reclaim_evicts_what_the_walk_evicted(seed, num_blocks, bs, vocab,
+                                              steps):
+    """Seeded random histories of all eight public operations, driven
+    through the index and through the walk it replaced side by side:
+    equal victim lists in order (all passes of a reclaim, one hook call),
+    equal free lists, equal ``evictions``, after every operation."""
+    rng = np.random.default_rng(10_000 * num_blocks + seed)
+    new, old = RadixPrefixCache(bs, num_blocks), _WalkOracle(bs, num_blocks)
+    logs = ([], [])
+    hooks = tuple(_recording_hook(idx, log)
+                  for idx, log in zip((new, old), logs))
+    for idx, hook in zip((new, old), hooks):
+        idx.on_evict = hook
+    pins = []       # (node in new, node in old), pinned once each
+    held = []       # ids allocated and not yet attached or released
+
+    def prompt():
+        return rng.integers(0, vocab,
+                            size=bs * int(rng.integers(1, 9))).tolist()
+
+    def both(fn):
+        return fn(new), fn(old)
+
+    def same_place(a, b):
+        assert new.chain_tokens(a) == old.chain_tokens(b)
+
+    for _ in range(steps):
+        op = int(rng.integers(0, 13))
+        if op <= 1:     # match, capped or not (restamps a root path)
+            toks = prompt()
+            cap = [None, 0, 1, 3][int(rng.integers(0, 4))]
+            a, b = both(lambda i: i.match(toks, max_blocks=cap))
+            assert a.block_ids == b.block_ids
+            same_place(a.node, b.node)
+        elif op in (2, 10, 11, 12):   # admit: match, pin, allocate, descend, extend
+            toks = prompt()
+            a, b = both(lambda i: i.match(toks, max_blocks=2))
+            m = a.n_blocks
+            new.pin(a.node), old.pin(b.node)
+            want = len(toks) // bs - m
+            ids_a, ids_b = both(lambda i: i.allocate(want))
+            assert ids_a == ids_b
+            (da, sa), (db, sb) = (new.descend(a.node, toks, m),
+                                  old.descend(b.node, toks, m))
+            assert sa == sb
+            same_place(da, db)
+            dup = min(sa - m, len(ids_a))       # already stored: hand back
+            new.release(ids_a[:dup]), old.release(ids_b[:dup])
+            rest = ids_a[dup:]
+            if rng.integers(0, 4) == 0 and len(rest) > 1:
+                cut = int(rng.integers(1, len(rest)))   # partial donation
+                new.release(rest[cut:]), old.release(rest[cut:])
+                rest = rest[:cut]
+            chunk = toks[sa * bs:(sa + len(rest)) * bs]
+            ta, tb = new.extend(da, chunk, rest), old.extend(db, chunk, rest)
+            same_place(ta, tb)
+            new.unpin(a.node), old.unpin(b.node)
+            if rng.integers(0, 2):              # a live slot keeps the tip
+                new.pin(ta), old.pin(tb)
+                pins.append((ta, tb))
+        elif op == 3:   # the same chunk stored twice: extend's dedup
+            toks = prompt()[:bs]
+            ids_a, ids_b = both(lambda i: i.allocate(1))
+            assert ids_a == ids_b
+            ta = new.extend(new._root, toks, ids_a)
+            tb = old.extend(old._root, toks, ids_b)
+            same_place(ta, tb)
+        elif op == 4 and pins:   # a slot ends
+            a, b = pins.pop(int(rng.integers(len(pins))))
+            new.unpin(a), old.unpin(b)
+        elif op == 5:   # pin wherever a match ends, leaf or not
+            toks = prompt()
+            a, b = both(lambda i: i.match(toks))
+            if a.node is not new._root:
+                new.pin(a.node), old.pin(b.node)
+                pins.append((a.node, b.node))
+        elif op in (6, 7):  # pressure: 1 .. more than is evictable
+            top = 4 if op == 6 else 16
+            if rng.integers(0, num_blocks // 4) == 0:
+                top = num_blocks + 4
+            n = int(rng.integers(1, top))
+            ids_a, ids_b = both(lambda i: i.allocate(n))
+            assert ids_a == ids_b
+            if rng.integers(0, 2):
+                held.extend(ids_a)              # a slot's private blocks
+            else:
+                new.release(ids_a), old.release(ids_b)
+        elif op == 8 and held:   # private blocks come back
+            k = int(rng.integers(1, len(held) + 1))
+            new.release(held[:k]), old.release(held[:k])
+            del held[:k]
+        elif op == 9 and rng.integers(0, num_blocks // 3) == 0:   # degraded flush
+            calls = len(logs[0])
+            assert new.flush_unpinned() == old.flush_unpinned()
+            assert len(logs[0]) == calls, "the flush called the hook"
+        assert logs[0] == logs[1]
+        assert list(new._free) == list(old._free)
+        assert new.evictions == old.evictions
+        assert new.blocks_live == len(_nodes(new)) + len(held)
+        _check_heap(new)
+    # The history met pressure: reclaims that evicted, some of them in
+    # more than one pass (a victim's parent among the same call's).
+    multi_pass = sum(any(c[:-bs] in {d for d, _ in call} for c, _ in call)
+                     for call in logs[0])
+    assert len(logs[0]) >= 15 and multi_pass >= 2, (len(logs[0]), multi_pass)
+    assert new.reclaims >= len(logs[0])
+    # Every pin released, everything is evictable: both drain alike.
+    for a, b in pins:
+        new.unpin(a), old.unpin(b)
+    new.release(held), old.release(held)
+    assert new.allocate(num_blocks) == old.allocate(num_blocks)
+    assert logs[0] == logs[1]
+    assert not new._root.children and not new._lru
+    assert new.blocks_free == 0
+
+
+def _filled(n_chains, depth=4, bs=2):
+    """An index whose every block sits in one of ``n_chains`` distinct
+    unpinned chains of ``depth``; the free list empty."""
+    idx = RadixPrefixCache(bs, n_chains * depth + 1)
+    for c in range(n_chains):
+        toks = [c] * bs + [j for j in range(1, depth) for _ in range(bs)]
+        idx.extend(idx._root, toks, idx.allocate(depth))
+    assert idx.blocks_free == 0 and idx.reclaims == 0
+    return idx
+
+
+def test_reclaim_cost_does_not_grow_with_the_index():
+    """No clock: ``reclaim_visited`` counts what a reclaim examined. A
+    block handed out at a shortfall costs the same small constant in an
+    index of 200 blocks and of 20,000; ``k`` blocks cost O(k + parents
+    exposed); a leaf matched since it was queued costs one visit more,
+    once."""
+    seen = {}
+    for n_chains in (50, 5_000):
+        idx = _filled(n_chains)
+        one = []
+        for _ in range(3):      # a chain's tip, its parent exposed; ...
+            before = idx.reclaim_visited
+            idx.release(idx.allocate(1))
+            idx.allocate(1)     # (take the freed one back: short again)
+            one.append(idx.reclaim_visited - before)
+        before = idx.reclaim_visited
+        got = idx.allocate(40)
+        many = idx.reclaim_visited - before
+        assert len(got) == 40
+        idx.match([7] * 2 + [1] * 2)    # restamps chain 7's path
+        before = idx.reclaim_visited
+        idx.allocate(4)
+        after_match = idx.reclaim_visited - before
+        seen[n_chains] = (one, many, after_match, idx.reclaims,
+                          idx.evictions)
+        assert idx.evictions == 3 + 40 + 4
+    assert seen[50] == seen[5_000]
+    one, many, after_match, reclaims, _ = seen[50]
+    assert max(one) <= 2 and many <= 2 * 40 and after_match <= 2 * 4 + 1
+    assert reclaims == 3 + 1 + 1
+
+
+def test_evicting_hook_that_raises_leaves_no_half_taken_victim():
+    idx = _filled(3)
+
+    def boom(victims):
+        raise RuntimeError("spill failed")
+    idx.on_evict = boom
+    with pytest.raises(RuntimeError, match="spill failed"):
+        idx.allocate(2)
+    idx.on_evict = None
+    _check_heap(idx)
+    assert idx.blocks_live == len(_nodes(idx)) == 10
+    assert len(idx.allocate(12)) == 12 and not idx._root.children
