@@ -27,6 +27,9 @@ from flax import linen as nn
 from pddl_tpu.models.gpipe import GPipeModel
 from pddl_tpu.models.vit import (
     BLOCK_TABLE_KEY,
+    PAGED_KV_KEY,
+    SLOT_STATE_KEY,
+    STATE_SLOT_KEY,
     TransformerBlock,
     remat_block,
 )
@@ -547,16 +550,47 @@ def _decode_fns(dec, temperature, top_k, top_p, max_new_tokens,
 CACHE_INDEX_KEYS = frozenset({"pos_index", "cache_index"})
 
 
+def _leaf_key(path) -> str:
+    return str(getattr(path[-1], "key", path[-1])) if path else ""
+
+
+def _stamp(cache, is_leaf, value):
+    """``cache`` with ``value`` in place of every leaf whose key path
+    ``is_leaf`` names; a tree without such leaves is returned as it is."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: value if is_leaf(path) else leaf, cache)
+
+
 def is_cache_index_path(path) -> bool:
     """True when a cache-tree key path names a position counter leaf."""
-    return bool(path) and (
-        str(getattr(path[-1], "key", path[-1])) in CACHE_INDEX_KEYS)
+    return _leaf_key(path) in CACHE_INDEX_KEYS
 
 
 def is_block_table_path(path) -> bool:
     """True when a cache-tree key path names a paged block-table leaf."""
-    return bool(path) and (
-        str(getattr(path[-1], "key", path[-1])) == BLOCK_TABLE_KEY)
+    return _leaf_key(path) == BLOCK_TABLE_KEY
+
+
+def is_paged_pool_path(path) -> bool:
+    """True when a cache-tree key path names a block pool leaf
+    (``[N, H_c, block_size, lanes]``): the leaves whose blocks the host
+    tier moves, a prefix chain exports and ``kv_token_bytes`` weighs. By
+    key, never by rank: a per-slot state leaf has three dimensions too."""
+    return _leaf_key(path) == PAGED_KV_KEY
+
+
+def is_slot_state_path(path) -> bool:
+    """True when a cache-tree key path names a per-slot state leaf
+    (``[slots, ...]``, `vit.SLOT_STATE_KEY`)."""
+    return _leaf_key(path) == SLOT_STATE_KEY
+
+
+def set_cache_state_slot(cache, slot):
+    """Stamp every ``state_slot`` leaf of a PAGED cache with the slot a
+    batch-1 chunk program fills (a scalar; the canonical placeholder is
+    0). A tree without per-slot state is returned as it is."""
+    return _stamp(cache, lambda path: _leaf_key(path) == STATE_SLOT_KEY,
+                  slot)
 
 
 def set_cache_positions(cache, positions):
@@ -564,9 +598,7 @@ def set_cache_positions(cache, positions):
     ``positions`` (``[slots]`` for the fused tick, a scalar for a
     batch-1 chunk; the engine owns the authoritative per-slot
     positions and every program stamps them in before its apply)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: positions if is_cache_index_path(path) else leaf,
-        cache)
+    return _stamp(cache, is_cache_index_path, positions)
 
 
 def set_cache_block_tables(cache, tables):
@@ -578,9 +610,7 @@ def set_cache_block_tables(cache, tables):
     placeholder on exit, so the resident donated tree keeps ONE
     structure across the whole program set (shape-stable donation =
     zero recompiles)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: tables if is_block_table_path(path) else leaf,
-        cache)
+    return _stamp(cache, is_block_table_path, tables)
 
 
 def set_cache_valid_len(cache, length):
@@ -590,10 +620,8 @@ def set_cache_valid_len(cache, length):
     it is."""
     from pddl_tpu.ops.moe import VALID_LEN_KEY
 
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: length if path and str(
-            getattr(path[-1], "key", path[-1])) == VALID_LEN_KEY else leaf,
-        cache)
+    return _stamp(cache, lambda path: _leaf_key(path) == VALID_LEN_KEY,
+                  length)
 
 
 def prefill_row_from(dec, params, prompt, length, row_cache, start, *,
@@ -682,6 +710,7 @@ def prefill_row_features(dec, params, prompt, length, row_cache, start, *,
     pt = param_transform or (lambda p: p)
     p2 = pt(params)
     cache = set_cache_positions(row_cache, jnp.asarray(start, jnp.int32))
+    cache = set_cache_valid_len(cache, jnp.asarray(length, jnp.int32))
     feats, mutated = dec.apply(
         {"params": p2, "cache": cache}, prompt,
         train=False, mutable=["cache"], features_only=True)

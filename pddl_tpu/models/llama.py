@@ -47,6 +47,12 @@ Llama/Mistral/Qwen lineage — on the same substrate:
   leading dense layer), ``moe_intermediate_dim`` (an expert width apart
   from the dense MLP's), sigmoid scores with a selection-only bias, a
   gate scale and shared experts (:class:`pddl_tpu.ops.moe.SwitchFFN`).
+- **Gated short convolution** (the LFM2 lineage; :class:`ShortConv`,
+  ``layer_types[i] == "conv"``): an operator with NO per-token cache
+  entry, whose whole memory is the last ``conv_kernel - 1`` gated inputs
+  — a fixed state a serving slot, beside the paged K/V of the attention
+  layers it alternates with. ``layer_types`` (the published key) is the
+  one per-layer declaration of which operator a block has.
 
 Everything else — flash/ring attention, Megatron TP (use
 ``LLAMA_TP_RULES`` from :mod:`pddl_tpu.parallel.tensor_parallel`),
@@ -67,6 +73,8 @@ from flax import linen as nn
 from pddl_tpu.models.gpipe import GPipeModel
 from pddl_tpu.models.vit import (
     BLOCK_TABLE_KEY,
+    SLOT_STATE_KEY,
+    STATE_SLOT_KEY,
     paged_decode_step,
     remat_block,
 )
@@ -131,6 +139,10 @@ class LlamaAttention(nn.Module):
     attention: str = "flash"  # "flash" | "reference" | "ring" | "ring_flash"
     sliding_window: Optional[int] = None  # Mistral-style SWA width
     qkv_bias: bool = False  # Qwen2-style q/k/v projection biases
+    # RMSNorm of every q and k head over its own dimensions, one learned
+    # scale each shared by the heads, BEFORE RoPE (the LFM2 lineage).
+    qk_norm: bool = False
+    rms_eps: float = 1e-5
     mesh: Optional[Any] = None
     decode: bool = False
     max_decode_len: int = 1024
@@ -168,6 +180,11 @@ class LlamaAttention(nn.Module):
         k = qkv(features=(self.num_kv_heads, head_dim), name="key")(x)
         v = qkv(features=(self.num_kv_heads, head_dim), name="value")(x)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, S, D]
+        if self.qk_norm:
+            q = _rms_norm(self.rms_eps, self.param_dtype, "q_norm")(
+                q).astype(self.dtype)
+            k = _rms_norm(self.rms_eps, self.param_dtype, "k_norm")(
+                k).astype(self.dtype)
 
         if self.decode:
             return self._decode_step(q, k, v, b, s, e, head_dim, dense)
@@ -530,6 +547,143 @@ class LatentAttention(nn.Module):
         return out(o.transpose(0, 2, 1, 3).reshape(b, s, h * vd))
 
 
+# What a block mixes tokens with (`LlamaBlock.operator`); `Llama` derives
+# it a layer from `layer_types` and the latent widths.
+OPERATORS = ("attention", "latent", "conv")
+# The published `layer_types` entries, by the operator they name.
+LAYER_TYPES = {"full_attention": "attention", "conv": "conv"}
+
+
+class ShortConv(nn.Module):
+    """Gated short convolution (the LFM2 lineage's ``conv`` operator).
+
+    With ``u`` the block's normed input at position ``t``: ``[B | C | X] =
+    u W_in`` (three ``E``-wide parts, in that order, no bias); ``z = B *
+    X``; ``c_t = sum_j taps[j] * z_{t - (K-1) + j}`` — a causal depthwise
+    convolution, one ``K``-tap kernel a channel, ``taps[K-1]`` on the
+    current token, ``z_t = 0`` before the sequence; the operator is ``(C *
+    c) W_out``. Nothing is cached per token: after token ``t`` the
+    operator's whole memory is ``(z_{t-K+2} .. z_t)``, ``K - 1`` rows of
+    ``E`` values whatever the context.
+
+    ``decode=True`` keeps that state in the ``cache`` collection under
+    ``SLOT_STATE_KEY``, ``[rows, K-1, E]`` in the compute dtype, beside a
+    position counter (``cache_index``, stamped like every attention's):
+
+    - a block whose position is 0 starts from zeros whatever the row
+      held (a slot's previous stream never leaks into the next);
+    - per-row positions ``[B]`` (the serving tick): batch row ``i`` IS
+      state row ``i``, one token a row; a row at position 0 is a parked
+      slot, and leaves its state row as it was (a prompt being prefilled
+      in slices into that row is not overwritten by the ticks between);
+    - a scalar position: one block of ``s`` tokens for every row, of
+      which the first ``valid_len`` are real (the engine's right-padded
+      chunk; all of them outside a paged engine) — the state left is
+      that of the last ``K - 1`` REAL positions, the state handed in
+      shifted along where the block holds fewer. In a paged engine the
+      batch-1 chunk reaches its row through the stamped slot index
+      (``STATE_SLOT_KEY``; `kvcache.paged_decode_cache` puts it and the
+      ``valid_len`` leaf beside the state).
+
+    Three taps over ``E`` channels are an elementwise fusion beside two
+    matmuls: no kernel of its own (``shortconv_roofline_pct`` in the
+    benchmark is the witness)."""
+
+    kernel_size: int = 3  # conv_L_cache
+    decode: bool = False
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope("shortconv"):
+            return self._apply(x)
+
+    @staticmethod
+    def _split_in(projected):
+        """``[B | C | X]`` of the in projection, in that order."""
+        return jnp.split(projected, 3, axis=-1)
+
+    @staticmethod
+    def _history(held, position):
+        """The rows a block starts behind: what the state held, or zeros
+        where the block starts a sequence (position 0)."""
+        fresh = (position == 0)[..., None, None]
+        return jnp.where(fresh, jnp.zeros_like(held), held)
+
+    def _state_after(self, zp, valid):
+        """The state ``valid`` real tokens leave: ``zp [B, K-1+s, E]`` is
+        the block behind its history, so row ``valid + j`` holds ``z`` of
+        block position ``valid - (K-1) + j``."""
+        return jax.lax.dynamic_slice_in_dim(zp, valid, self.kernel_size - 1,
+                                            axis=1)
+
+    def _apply(self, x):
+        from pddl_tpu.ops.moe import VALID_LEN_KEY
+
+        b, s, e = x.shape
+        k = self.kernel_size
+        if k < 2:
+            raise ValueError(f"a short convolution needs >= 2 taps, got {k}")
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
+        gate_in, gate_out, val = self._split_in(
+            dense(3 * e, name="in_proj")(x))
+        z = (gate_in * val).astype(self.dtype)
+        taps = self.param("taps", nn.initializers.lecun_normal(), (k, e),
+                          self.param_dtype)
+        out = dense(e, name="out_proj")
+
+        def convolve(zp):
+            """Float32 sums, like the family's norms."""
+            c = sum(taps[j].astype(jnp.float32)
+                    * zp[:, j:j + s].astype(jnp.float32) for j in range(k))
+            return out(gate_out * c.astype(self.dtype))
+
+        if not self.decode:
+            return convolve(jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0))))
+
+        initialized = self.has_variable("cache", SLOT_STATE_KEY)
+        state = self.variable("cache", SLOT_STATE_KEY, jnp.zeros,
+                              (b, k - 1, e), self.dtype)
+        index = self.variable("cache", "cache_index",
+                              lambda: jnp.zeros((), jnp.int32))
+        i = index.value
+        if i.ndim and s != 1:
+            # The speculative verify block: a rejected draft would have
+            # to be taken back out of the state, which no counter stamp
+            # does (`Llama.unrewindable_cache`).
+            raise ValueError(
+                "per-row positions over a short-convolution state support "
+                f"single-token steps only (got a {s}-token block)")
+        # A paged engine's batch-1 chunk: its row and its real length
+        # are stamped beside the state.
+        paged = self.has_variable("cache", STATE_SLOT_KEY)
+        chunk = paged and not i.ndim
+        valid = s
+        if chunk:
+            slot = self.variable("cache", STATE_SLOT_KEY, lambda: None).value
+            valid = self.variable("cache", VALID_LEN_KEY, lambda: None).value
+            held = jax.lax.dynamic_slice_in_dim(state.value, slot, b, axis=0)
+        else:
+            held = state.value
+        zp = jnp.concatenate([self._history(held, i), z], axis=1)
+        if initialized:
+            left = self._state_after(zp, valid)
+            if chunk:
+                state.value = jax.lax.dynamic_update_slice_in_dim(
+                    state.value, left, slot, axis=0)
+            elif paged:
+                # The tick: a row at position 0 is a parked slot, and
+                # its state row may be a prompt's, mid-way through a
+                # sliced prefill.
+                state.value = jnp.where((i == 0)[:, None, None], held, left)
+            else:
+                state.value = left
+            index.value = i + s
+        return convolve(zp)
+
+
 class LlamaBlock(nn.Module):
     """Pre-RMSNorm residual block: attention then a SwiGLU MLP — dense,
     or routed over ``moe_experts`` gated experts (the Mixtral block:
@@ -537,7 +691,9 @@ class LlamaBlock(nn.Module):
     ``moe_router_input="attn"`` moves the router in front of the
     attention: logits from the attention's normed input (a bias-free
     ``router`` of the block's own), experts applied to the MLP's.
-    ``kv_lora_rank > 0`` makes the attention :class:`LatentAttention`;
+    ``operator`` says what the block mixes tokens with — ``"attention"``
+    (:class:`LlamaAttention`), ``"latent"`` (:class:`LatentAttention`, at
+    the five latent widths) or ``"conv"`` (:class:`ShortConv`);
     ``moe_intermediate_dim`` gives the experts a width of their own."""
 
     num_heads: int
@@ -563,11 +719,14 @@ class LlamaBlock(nn.Module):
     moe_select_bias: bool = False
     moe_gate_scale: float = 1.0
     moe_shared_experts: int = 0
-    kv_lora_rank: int = 0  # > 0: latent attention (the five below)
+    operator: str = "attention"  # one of OPERATORS
+    kv_lora_rank: int = 0  # the five widths of operator="latent"
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    qk_norm: bool = False
+    conv_kernel: int = 3  # operator="conv": taps (conv_L_cache)
     rms_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -589,7 +748,14 @@ class LlamaBlock(nn.Module):
                 router_logits = nn.Dense(
                     self.moe_experts, use_bias=False, dtype=jnp.float32,
                     param_dtype=self.param_dtype, name="router")(h)
-        if self.kv_lora_rank:
+        if self.operator not in OPERATORS:
+            raise ValueError(f"unknown block operator {self.operator!r} "
+                             f"(one of {OPERATORS})")
+        if self.operator == "conv":
+            attn = ShortConv(
+                kernel_size=self.conv_kernel, decode=self.decode,
+                dtype=self.dtype, param_dtype=self.param_dtype, name="conv")
+        elif self.operator == "latent":
             attn = LatentAttention(
                 num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
                 kv_lora_rank=self.kv_lora_rank,
@@ -605,6 +771,7 @@ class LlamaBlock(nn.Module):
                 head_dim=self.head_dim, rope=self.rope,
                 rope_theta=self.rope_theta, attention=self.attention,
                 sliding_window=self.sliding_window, qkv_bias=self.qkv_bias,
+                qk_norm=self.qk_norm, rms_eps=self.rms_eps,
                 mesh=self.mesh, decode=self.decode,
                 max_decode_len=self.max_decode_len, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="attn")
@@ -693,9 +860,13 @@ class Llama(nn.Module):
     moe_select_bias: bool = False  # a bias on the choice of experts only
     moe_gate_scale: float = 1.0  # routed_scaling_factor
     moe_shared_experts: int = 0  # always-on experts beside the routed
-    # Latent attention (`LatentAttention`): `kv_lora_rank > 0` turns it
-    # on in every layer (each block carries the declaration; a layout
-    # comes with the first configuration that mixes layer kinds).
+    # The operator a layer has, by the published key: "conv"
+    # (`ShortConv`) or "full_attention" (None: attention everywhere).
+    # An attention layer is `LatentAttention` when `kv_lora_rank > 0`,
+    # else `LlamaAttention`; `layer_operator` is the ONE reading of both.
+    layer_types: Optional[tuple] = None
+    conv_kernel: int = 3  # conv_L_cache: the short convolution's taps
+    qk_norm: bool = False  # per-head RMSNorm of q and k before RoPE
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -705,11 +876,45 @@ class Llama(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
+    def layer_operator(self, i: int) -> str:
+        """Layer ``i``'s operator, one of :data:`OPERATORS`."""
+        if self.layer_types is not None:
+            kind = self.layer_types[i]
+            if kind not in LAYER_TYPES:
+                raise ValueError(
+                    f"layer_types[{i}] = {kind!r}: not one of "
+                    f"{sorted(LAYER_TYPES)}")
+            if LAYER_TYPES[kind] == "conv":
+                return "conv"
+        return "latent" if self.kv_lora_rank else "attention"
+
+    def _layers_of(self, operator: str) -> int:
+        return sum(self.layer_operator(i) == operator
+                   for i in range(self.depth))
+
     @property
     def latent_layers(self) -> int:
         """How many layers a paged chunk program re-expands cached
         entries for (`serve/metrics.py` ``latent_expanded_tokens``)."""
-        return self.depth if self.kv_lora_rank else 0
+        return self._layers_of("latent")
+
+    @property
+    def slot_state_layers(self) -> int:
+        """How many layers keep a fixed state a serving slot and no
+        per-token cache entry (the short convolutions). A prefix hit
+        restores an attention layer and not these, so a paged engine
+        neither matches nor donates prefixes for such a model
+        (`serve/engine.py`, "slot state")."""
+        return self._layers_of("conv")
+
+    @property
+    def unrewindable_cache(self) -> bool:
+        """True when stamping a position counter back does NOT take a
+        rejected block out of the cache: a rolling ring cache has
+        recycled the slots (:attr:`uses_ring_cache`), a short
+        convolution's state has moved on. THE property speculative
+        decoding and the serving engine read before they draft."""
+        return self.uses_ring_cache or self.slot_state_layers > 0
 
     def layer_window(self, i: int) -> Optional[int]:
         """Layer ``i``'s attention window (None: full attention)."""
@@ -740,7 +945,8 @@ class Llama(nn.Module):
         :func:`ring_len` over the blocks' ``max_decode_len`` (=
         ``max_len``, line where the blocks are built), for any layer."""
         return any(ring_len(self.layer_window(i), self.max_len) is not None
-                   for i in range(self.depth))
+                   for i in range(self.depth)
+                   if self.layer_operator(i) == "attention")
 
     def paged_cache_extras(self) -> dict:
         """Cache leaves a PAGED serving engine adds beside each
@@ -774,7 +980,7 @@ class Llama(nn.Module):
         block_cls = (LlamaBlock if self.decode
                      else remat_block(LlamaBlock, self.remat))
         for layout in (self.sliding_window_layout, self.rope_layout,
-                       self.moe_layout):
+                       self.moe_layout, self.layer_types):
             if layout is not None and len(layout) != self.depth:
                 raise ValueError(
                     f"a per-layer layout needs {self.depth} entries, got "
@@ -799,11 +1005,13 @@ class Llama(nn.Module):
                 moe_select_bias=self.moe_select_bias,
                 moe_gate_scale=self.moe_gate_scale,
                 moe_shared_experts=self.moe_shared_experts,
+                operator=self.layer_operator(i),
                 kv_lora_rank=self.kv_lora_rank,
                 q_lora_rank=self.q_lora_rank,
                 qk_nope_head_dim=self.qk_nope_head_dim,
                 qk_rope_head_dim=self.qk_rope_head_dim,
-                v_head_dim=self.v_head_dim,
+                v_head_dim=self.v_head_dim, qk_norm=self.qk_norm,
+                conv_kernel=self.conv_kernel,
                 rms_eps=self.rms_eps, dtype=self.dtype,
                 param_dtype=self.param_dtype, name=f"block{i}",
             )(x, train)
@@ -930,6 +1138,60 @@ def tiny_glm_flash(vocab_size: int = 64, **kwargs) -> Llama:
     return Llama(vocab_size=vocab_size, **{**defaults, **kwargs})
 
 
+# LFM2-24B-A2B (LiquidAI, HF config.json, `lfm2_moe`): 40 layers x 2048;
+# `layer_types` conv, conv, then [full_attention, conv, conv, conv] ten
+# times less the last two: 30 gated short convolutions (3 taps, no bias)
+# and 10 attention layers (32 q / 8 kv heads of 64, q and k RMS-normed a
+# head before RoPE at theta 1e6); layers 0-1 a dense SwiGLU MLP of 11,776,
+# the other 38 64 SwiGLU experts of 1,536 top-4 by sigmoid scores plus a
+# selection-only bias, gates renormalised, scale 1, no shared expert;
+# RMSNorm 1e-5; head over 65,536 tokens (untied here like the family's
+# other two); 128,000 positions. A `depth` under 40 is a cut for one
+# chip: the leading dense layers counted once, then the pattern from
+# layer 2 on. Served at random weights only.
+LFM2_DENSE_LAYERS = 2
+
+
+def lfm2_layer_types(depth: int = 40) -> tuple:
+    return tuple("full_attention" if i % 4 == 2 else "conv"
+                 for i in range(depth))
+
+
+_LFM2 = dict(
+    qk_norm=True, conv_kernel=3, rope_theta=1e6, moe_top_k=4,
+    moe_router_score="sigmoid", moe_select_bias=True, moe_gate_scale=1.0,
+    rms_eps=1e-5)
+
+
+def _lfm2_layouts(depth: int, published: int = 40) -> dict:
+    types, dense = lfm2_layer_types(published), LFM2_DENSE_LAYERS
+    if depth < published:
+        types, dense = types[:1] + types[dense:dense + depth - 1], 1
+    return dict(layer_types=types,
+                moe_layout=(0,) * dense + (1,) * (depth - dense))
+
+
+def LFM2_24B_A2B(depth: int = 40, **kwargs) -> Llama:
+    defaults = dict(
+        _LFM2, vocab_size=65536, max_len=128000, embed_dim=2048,
+        num_heads=32, num_kv_heads=8, head_dim=64, intermediate_dim=11776,
+        moe_intermediate_dim=1536, moe_experts=64, **_lfm2_layouts(depth))
+    return Llama(depth=depth, **{**defaults, **kwargs})
+
+
+def tiny_lfm2(vocab_size: int = 64, **kwargs) -> Llama:
+    """The same block at test size: a dense convolution layer, then one
+    whole period [attention, 3 x convolution] routed over 8 experts
+    top-4; 4 q heads over 2 kv heads of 8, q/k norm, 3 taps."""
+    depth = kwargs.get("depth", 5)
+    defaults = dict(
+        _LFM2, depth=depth, max_len=128, embed_dim=32, num_heads=4,
+        num_kv_heads=2, head_dim=8, intermediate_dim=48,
+        moe_intermediate_dim=16, moe_experts=8, attention="reference",
+        **_lfm2_layouts(depth))
+    return Llama(vocab_size=vocab_size, **{**defaults, **kwargs})
+
+
 class _LlamaEmbed(nn.Module):
     """Token embedding (the pre-pipeline Llama stem; RoPE needs no
     positional parameters — positions enter inside each block)."""
@@ -1019,8 +1281,13 @@ class GPipeLlama(GPipeModel):
                  intermediate_dim: Optional[int] = None,
                  rope_theta: float = 10000.0,
                  attention: str = "reference", rms_eps: float = 1e-5,
-                 remat_stages: bool = False,
+                 remat_stages: bool = False, layer_types=None,
                  dtype: Any = jnp.float32, param_dtype: Any = jnp.float32):
+        if layer_types is not None:
+            raise NotImplementedError(
+                "GPipeLlama stacks identical attention blocks: a model "
+                "that mixes operators by layer (layer_types) has no "
+                "pipeline stage yet")
         kv = num_kv_heads or num_heads
         if intermediate_dim is None:
             intermediate_dim = _default_intermediate_dim(embed_dim)

@@ -434,17 +434,24 @@ def generate_speculative(
             f"prompt + new tokens + draft_len {total + draft_len} exceed "
             f"max_len {model.max_len} (speculative blocks write "
             f"draft_len={draft_len} positions of lookahead)")
-    if getattr(model, "uses_ring_cache", False):
-        # Ring cache: block writes reuse slots of positions that rolled
-        # out of the window — after a partial rejection those slots are
+    if getattr(model, "unrewindable_cache", False):
+        # A cache `_rewind_index`'s counter stamp cannot rewind. Ring
+        # cache: block writes reuse slots of positions that rolled out
+        # of the window — after a partial rejection those slots are
         # back INSIDE the rewound position's window, and their history
-        # is gone. Not recoverable; refuse rather than silently corrupt.
-        # (The decision comes from the model — llama.ring_len, the same
-        # function that sizes the cache — so this gate cannot drift.)
+        # is gone. Per-slot state (a short convolution's): it has moved
+        # on past the rejected tokens. Not recoverable; refuse rather
+        # than silently corrupt. (The decision comes from the model —
+        # `Llama.unrewindable_cache`, which reads llama.ring_len, the
+        # function that sizes the cache, and the layers' operators — so
+        # this gate cannot drift.)
         raise NotImplementedError(
-            "speculative decoding needs a full-length KV cache; "
-            f"sliding_window={model.sliding_window} uses a ring cache "
-            "whose slots cannot be rewound")
+            "speculative decoding needs a cache that a position-counter "
+            "stamp rewinds: a full-length KV cache in every layer. "
+            f"This model (sliding_window={model.sliding_window}, "
+            f"{getattr(model, 'slot_state_layers', 0)} layers with "
+            "per-slot state) uses a ring cache whose slots cannot be "
+            "rewound, or a state that cannot")
 
     dec = model.clone(decode=True)
     params = variables["params"]

@@ -50,6 +50,15 @@ from pddl_tpu.ops.attention import (
 # by shape duck typing.
 BLOCK_TABLE_KEY = "block_table"
 PAGED_KV_KEY = "cached_kv"
+# A layer that keeps a FIXED state a serving slot and no per-token entry
+# (`llama.ShortConv`) declares it under SLOT_STATE_KEY, ``[rows, ...]``:
+# rows by slots, never by blocks — the pool builder sizes it by the slot
+# count and builds no pool and no table for such a layer. STATE_SLOT_KEY
+# is the slot index a batch-1 chunk program reaches its row through
+# (stamped like the tables, canonical placeholder scalar 0). Every walker
+# of a cache tree tells a pool from a state leaf by these keys.
+SLOT_STATE_KEY = "slot_state"
+STATE_SLOT_KEY = "state_slot"
 
 
 def paged_decode_step(module: nn.Module, index, q, entry, **attend):

@@ -137,6 +137,10 @@ SERVE_COUNTER_KEYS = frozenset({
     # What prefill cost in tokens: prompt tokens installed, and chunk
     # programs dispatched by compiled width (a labeled counter).
     "prefill_tokens", "prefill_chunks", "latent_expanded_tokens",
+    # Slot state: admissions that started a state row from zeros, and
+    # admissions that skipped the prefix index for a model with such
+    # state (state_bytes_resident beside them stays a gauge).
+    "state_rows_started", "prefix_skipped_stateful",
 })
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")

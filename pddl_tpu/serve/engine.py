@@ -160,12 +160,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from pddl_tpu.models.gpt import (
+    is_paged_pool_path,
     lm_head_logits,
     prefill_row_features,
     prefill_row_from,
     sample_logits_batched,
     set_cache_block_tables,
     set_cache_positions,
+    set_cache_state_slot,
 )
 from pddl_tpu.models.speculative import ngram_drafts
 from pddl_tpu.obs.ring import TelemetryRing
@@ -184,6 +186,7 @@ from pddl_tpu.serve.kvcache import (
     RadixPrefixCache,
     paged_decode_cache,
     pool_nbytes,
+    slot_state_nbytes,
 )
 from pddl_tpu.serve.metrics import PHASES, ServeMetrics
 from pddl_tpu.serve.request import (
@@ -566,6 +569,11 @@ class ServeEngine:
         # chunk program, the cached entries the chunk attends over
         # (`metrics.latent_expanded_tokens`).
         self._reexpands = bool(getattr(model, "latent_layers", 0))
+        # Slot state (module docstring): a model with layers that keep a
+        # fixed state a SLOT and no per-token entry (`llama.ShortConv`).
+        # Derived from the model's own declaration; it turns prefix
+        # reuse off and refuses what cannot carry such state.
+        self._stateful = bool(getattr(model, "slot_state_layers", 0))
 
         # Chunked-prefill fairness: at most `prefill_slice_tokens` of
         # prompt prefill per step(), the decode tick interleaved
@@ -591,6 +599,12 @@ class ServeEngine:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self._spec_k = int(spec_k)
         self._spec_on = self._spec_k > 0
+        if self._spec_on and self._stateful:
+            raise NotImplementedError(
+                "speculative serving needs a cache a counter stamp can "
+                "rewind; this model's per-slot state (short-convolution "
+                "layers) has moved on past a rejected draft and cannot "
+                "be")
         self._spec_ngram = int(spec_ngram)
         self._draft_on = spec_draft_model is not None
         if self._spec_on:
@@ -621,10 +635,12 @@ class ServeEngine:
                     f"draft model max_len {spec_draft_model.max_len} < "
                     f"target max_len {model.max_len}: the draft cache "
                     "must cover every position a stream can reach")
-            if getattr(spec_draft_model, "uses_ring_cache", False):
+            if getattr(spec_draft_model, "unrewindable_cache", False):
                 raise NotImplementedError(
                     "draft models with sliding-window layers are not "
-                    "supported (the draft tree gets no window masking)")
+                    "supported (the draft tree gets no window masking), "
+                    "nor ones with per-slot state: a counter stamp "
+                    "rewinds neither cache")
             self._ddec = spec_draft_model.clone(decode=True)
             self._dparams = spec_draft_variables["params"]
         elif spec_draft_variables is not None:
@@ -730,8 +746,17 @@ class ServeEngine:
         # recompiles.
         def _canon_paged(cache):
             cache = set_cache_positions(cache, jnp.zeros((), jnp.int32))
+            cache = set_cache_state_slot(cache, jnp.zeros((), jnp.int32))
             return set_cache_block_tables(cache,
                                           jnp.zeros((1, 1), jnp.int32))
+
+        # A batch-1 chunk does not know which slot it fills except by
+        # the stamped ``slot``: a model with per-slot state reads and
+        # leaves its state row there; any other model has no such leaf
+        # and the argument is pruned from the compiled program.
+        def _stamp_chunk(cache, table, slot):
+            cache = set_cache_block_tables(cache, table)
+            return set_cache_state_slot(cache, slot)
 
         def _tick_paged(params, cache, positions, tables, tokens, temps,
                         top_ks, top_ps, rng):
@@ -747,18 +772,20 @@ class ServeEngine:
                 top_p=top_ps)
             return _canon_paged(mutated["cache"]), nxt, rng
 
-        def _chunk_paged(params, cache, tokens, length, start, table):
-            cache = set_cache_block_tables(cache, table)
+        def _chunk_paged(params, cache, tokens, length, start, table,
+                         slot):
+            cache = _stamp_chunk(cache, table, slot)
             cache, logits = prefill_row_from(dec, params, tokens, length,
                                              cache, start,
                                              param_transform=pt)
             return _canon_paged(cache), logits
 
-        def _chunk_paged_wide(params, cache, tokens, length, start, table):
+        def _chunk_paged_wide(params, cache, tokens, length, start, table,
+                              slot):
             # The same computation at the wide width — a DISTINCT
             # function object, so its jit cache (and compile_counts
             # entry) never shares entries with the narrow program's.
-            cache = set_cache_block_tables(cache, table)
+            cache = _stamp_chunk(cache, table, slot)
             cache, logits = prefill_row_from(dec, params, tokens, length,
                                              cache, start,
                                              param_transform=pt)
@@ -821,8 +848,8 @@ class ServeEngine:
                     jnp.full((1,), aid, jnp.int32))
 
             def _chunk_paged_t(params, cache, tokens, length, start,
-                               table, aid, pool_a, pool_b):
-                cache = set_cache_block_tables(cache, table)
+                               table, slot, aid, pool_a, pool_b):
+                cache = _stamp_chunk(cache, table, slot)
                 cache, last, lf = prefill_row_features(
                     dec, params, tokens, length, cache, start,
                     param_transform=pt)
@@ -830,9 +857,9 @@ class ServeEngine:
                                                    pool_b, aid)
 
             def _chunk_paged_wide_t(params, cache, tokens, length, start,
-                                    table, aid, pool_a, pool_b):
+                                    table, slot, aid, pool_a, pool_b):
                 # Distinct function object (wide-program discipline).
-                cache = set_cache_block_tables(cache, table)
+                cache = _stamp_chunk(cache, table, slot)
                 cache, last, lf = prefill_row_features(
                     dec, params, tokens, length, cache, start,
                     param_transform=pt)
@@ -1001,7 +1028,9 @@ class ServeEngine:
                                       donate_argnums=(1,))
                               if self._has_wide else None)
         self._prefix = RadixPrefixCache(bs, pool_blocks)
-        self._cache = paged_decode_cache(dec, pool_blocks, bs)
+        self._cache = paged_decode_cache(dec, pool_blocks, bs,
+                                         self.max_slots)
+        self.metrics.state_bytes_resident = slot_state_nbytes(self._cache)
         # Host-authoritative per-slot block tables (scratch-filled
         # for parked slots) and the private (not-yet-shared) block
         # ids each slot owns.
@@ -1011,12 +1040,7 @@ class ServeEngine:
             [] for _ in range(self.max_slots)]
         # KV bytes one token occupies across every leaf — what one
         # avoided gather copy is worth (`copy_bytes_avoided`).
-        kv_bytes = sum(
-            int(leaf.size) * leaf.dtype.itemsize
-            for path, leaf in jax.tree_util.tree_leaves_with_path(
-                self._cache)
-            if leaf.ndim > 2)
-        self._kv_token_bytes = kv_bytes // (pool_blocks * bs)
+        self._kv_token_bytes = pool_nbytes(self._cache) // (pool_blocks * bs)
         self._verify_p = self._draft_p = self._dchunk_p = None
         self._draft_model_p = None
         self._dcache = None
@@ -1035,8 +1059,8 @@ class ServeEngine:
                 # block-id space, one table, two KV trees (target +
                 # draft) — sharing, dedup, flush, and reset all act
                 # on both through the same ids.
-                self._dcache = paged_decode_cache(self._ddec,
-                                                  pool_blocks, bs)
+                self._dcache = paged_decode_cache(
+                    self._ddec, pool_blocks, bs, self.max_slots)
             else:
                 self._draft_p = jax.jit(_draft_ngram)
         self._init_host_tier(host_tier)
@@ -1086,6 +1110,13 @@ class ServeEngine:
                else HostTierConfig(byte_budget=int(host_tier)))
         if cfg.byte_budget == 0:
             return
+        if self._stateful:
+            raise NotImplementedError(
+                "host_tier with a model that keeps per-slot state "
+                "(short-convolution layers) is not supported: a promoted "
+                "block restores the attention layers' K/V and not the "
+                "state, and this engine matches no prefix for such a "
+                "model (ROADMAP M5)")
         if self._draft_on:
             raise NotImplementedError(
                 "host_tier with spec_draft_model is not supported yet: "
@@ -1094,7 +1125,7 @@ class ServeEngine:
                 "second cache tree through the tier is follow-on work")
         spec = {}
         for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache):
-            if leaf.ndim < 3:
+            if not is_paged_pool_path(path):
                 continue
             spec[jax.tree_util.keystr(path)] = (
                 (1,) + tuple(leaf.shape[1:-2])
@@ -1110,10 +1141,11 @@ class ServeEngine:
             # (`ops.attention.cache_blocks_scatter`): one scatter per
             # KV leaf over the donated pool tree, no model compute;
             # padded ids land their junk in the scratch sink, and
-            # non-KV leaves (counters, tables) pass through untouched
-            # so the tree keeps its canonical placeholders.
+            # non-pool leaves (counters, tables — told by KEY, not by
+            # rank) pass through untouched so the tree keeps its
+            # canonical placeholders.
             def _s(path, pool_leaf, row_leaf):
-                if pool_leaf.ndim < 3:
+                if not is_paged_pool_path(path):
                     return pool_leaf
                 return cache_blocks_scatter(pool_leaf, row_leaf, ids, 0)
             return jax.tree_util.tree_map_with_path(_s, pool, rows)
@@ -1136,7 +1168,7 @@ class ServeEngine:
             # the old free-and-recompute path in `_demote_blocks`.
             out = {}
             for path, leaf in jax.tree_util.tree_leaves_with_path(pool):
-                if leaf.ndim < 3:
+                if not is_paged_pool_path(path):
                     continue
                 out[jax.tree_util.keystr(path)] = cache_blocks_gather(
                     leaf, ids)
@@ -1309,7 +1341,7 @@ class ServeEngine:
         and :meth:`program_lowerings` lowers."""
         t1 = np.zeros((1, self._table_width), np.int32)
         return (self._params, self._cache, np.zeros((1, width), np.int32),
-                np.int32(1), np.int32(0), t1, *self._chunk_extra(0))
+                np.int32(1), np.int32(0), t1, *self._chunk_extra(0, 0))
 
     def tick_lowering(self):
         """The one-token tick LOWERED at its serving shapes, for
@@ -1580,11 +1612,12 @@ class ServeEngine:
                 if req.adapter is not None else 0)
         return arow, fsm
 
-    def _chunk_extra(self, aid):
-        """Extra prefill-program args in tenant mode (adapter id +
-        factor pools); empty on a plain engine."""
-        return ((np.int32(aid), self._apool_a, self._apool_b)
-                if self._tenant_on else ())
+    def _chunk_extra(self, aid, sid):
+        """A chunk program's trailing args: the slot the chunk fills
+        (where a model with per-slot state keeps its row) and, in
+        tenant mode, adapter id + factor pools."""
+        return ((np.int32(sid), np.int32(aid), self._apool_a, self._apool_b)
+                if self._tenant_on else (np.int32(sid),))
 
     def _tick_extra(self):
         """Extra fused-tick args in tenant mode (grammar masks + factor
@@ -1780,13 +1813,15 @@ class ServeEngine:
         all private ownership dropped. Callers park/replay the live
         slots FIRST — their KV lived here."""
         self._cache = paged_decode_cache(self._dec, self._prefix.num_blocks,
-                                         self.prefix_block_size)
+                                         self.prefix_block_size,
+                                         self.max_slots)
         if self._draft_on:
             # The draft tree shares the block-id space: a pool reset
             # retires its storage too (replay rebuilds both trees).
             self._dcache = paged_decode_cache(self._ddec,
                                               self._prefix.num_blocks,
-                                              self.prefix_block_size)
+                                              self.prefix_block_size,
+                                              self.max_slots)
         self._prefix = RadixPrefixCache(self.prefix_block_size,
                                         self._prefix.num_blocks)
         self._tables[:] = 0
@@ -1917,6 +1952,20 @@ class ServeEngine:
                 self._evict(sid, RequestState.TIMED_OUT,
                             FinishReason.TIMED_OUT)
 
+    @property
+    def _prefix_off(self) -> bool:
+        """True when admissions neither match nor donate prefixes: the
+        post-OOM cool-down, and always for a model with per-slot state
+        (a shared block restores K/V, not a convolution's state —
+        snapshots of it at radix nodes are ROADMAP M5's)."""
+        return self._degraded or self._stateful
+
+    def _record_state_start(self, off: int) -> None:
+        """One chunk at ``off`` was dispatched: at offset 0 a model with
+        per-slot state started its row from zeros."""
+        if self._stateful and off == 0:
+            self.metrics.state_rows_started += 1
+
     def _match_blocks(self, prompt) -> int:
         """Cap on the matchable chain for one prompt (blocks): leave at
         least one suffix token, never exceed ``match_cap``."""
@@ -1931,7 +1980,7 @@ class ServeEngine:
         ``FCFSScheduler.admit``). Degraded mode charges the full prompt
         (the cache is not consulted on the cold path)."""
         prompt = handle.request.prompt
-        if self._degraded:
+        if self._prefix_off:
             cost = len(prompt)
         else:
             match = self._prefix.match(
@@ -2044,7 +2093,7 @@ class ServeEngine:
         bs = self.prefix_block_size
 
         def _leaf(path, leaf):
-            if leaf.ndim < 3:
+            if not is_paged_pool_path(path):
                 return np.zeros((), np.int32)
             row = np.zeros((1,) + tuple(leaf.shape[1:-2])
                            + (self._match_cap * bs, leaf.shape[-1]),
@@ -2186,7 +2235,10 @@ class ServeEngine:
         no private copy, an eviction stealing a matched block
         mid-admission would reach under this very request) and a
         shortfall must unwind pin + ids exactly. Degraded mode skips
-        the index entirely (all blocks private). Returns
+        the index entirely (all blocks private), and so does every
+        admission of a model with per-slot state (``_prefix_off``: a
+        hit would restore its attention layers and not its state).
+        Returns
         ``(pinned_node_or_None, n_matched_blocks, table_row [T],
         private_ids)``; raises :class:`_SlotStateLost` unwound on
         shortfall."""
@@ -2200,7 +2252,9 @@ class ServeEngine:
             # (self-unwinding; a promotion fault escalates exactly like
             # any admission dispatch).
             self._promote_host_chain(prompt, handle)
-        if not self._degraded:
+        if self._stateful:
+            self.metrics.prefix_skipped_stateful += 1
+        elif not self._degraded:
             match = self._prefix.match(
                 prompt, max_blocks=self._match_blocks(prompt))
             m = match.n_blocks
@@ -2225,7 +2279,8 @@ class ServeEngine:
         table_row[m:m + len(private)] = private
         return node, m, table_row, private
 
-    def _prefill_paged(self, prompt: np.ndarray, handle=None, aid=0):
+    def _prefill_paged(self, prompt: np.ndarray, handle=None, aid=0,
+                       sid=0):
         """Prefill one prompt, reusing any cached prefix: a prefix hit
         PINS the matched chain and points the slot's block table at it
         in place (no gather copy), private blocks are allocated for the
@@ -2233,7 +2288,8 @@ class ServeEngine:
         pool blocks; then the prompt's full blocks are donated.
         ``handle`` is the admission's request (tracing only — each
         dispatch lands on its span); ``aid`` the tenant adapter pool
-        row (0 = base model, ignored on a plain engine). Degraded mode
+        row (0 = base model, ignored on a plain engine); ``sid`` the
+        slot being filled (where per-slot state lands). Degraded mode
         (post-OOM cool-down) neither consults nor grows the cache — a
         pure cold chunked prefill, so serving continues while the pool
         stays shed. Returns
@@ -2243,13 +2299,15 @@ class ServeEngine:
         node, m, table_row, private = self._paged_match_and_allocate(
             prompt, handle)
         n_cached = m * self.prefix_block_size
-        use_prefix = not self._degraded
+        use_prefix = not self._prefix_off
         t1 = table_row[None]  # [1, T] — the chunk programs' view
 
         def _dispatch(site, prog, chunk_toks, w, off):
             self._cache, lg = self._device_call(
                 site, prog, self._params, self._cache, chunk_toks,
-                np.int32(w), np.int32(off), t1, *self._chunk_extra(aid))
+                np.int32(w), np.int32(off), t1,
+                *self._chunk_extra(aid, sid))
+            self._record_state_start(off)
             return lg
 
         try:
@@ -2522,7 +2580,7 @@ class ServeEngine:
         arow, fsm = self._tenant_admit(handle)
         try:
             logits, node, table_row, private = self._prefill_paged(
-                prompt, handle, arow)
+                prompt, handle, arow, sid)
         except _SlotStateLost:
             self._release_adapter(arow)
             raise
@@ -2626,11 +2684,12 @@ class ServeEngine:
             w = min(self._chunk, plen - off)
             chunk_toks = np.zeros((1, self._chunk), np.int32)
             chunk_toks[0, :w] = prompt[off:off + w]
-            extra = self._chunk_extra(sl.get("arow", 0))
+            extra = self._chunk_extra(sl.get("arow", 0), sl["sid"])
             self._cache, sl["logits"] = self._device_call(
                 "chunk_prefill", self._chunk_p, self._params,
                 self._cache, chunk_toks, np.int32(w), np.int32(off),
                 sl["table"][None], *extra)
+            self._record_state_start(off)
             if self._draft_on:
                 # The draft tree advances in lockstep with the
                 # slices (same chunk, same blocks), so fairness and
@@ -2660,7 +2719,7 @@ class ServeEngine:
             # mid-slice unwind + replay can never double-count.
             self.metrics.record_copy_avoided(
                 int(sl["n_cached"]) * self._kv_token_bytes)
-        if not self._degraded:
+        if not self._prefix_off:
             # The start-time pin survived the interleaved ticks
             # (flush_unpinned spares pinned chains), so donation
             # descends from it directly. While degraded, the
@@ -3352,6 +3411,7 @@ class ServeEngine:
         exporting is a tiered-fleet feature), or the engine is
         degraded (exporting from a shed cache would race the flush).
         The matched chain is pinned for exactly the read."""
+        self._refuse_chain_transfer()
         if self._host is None or self._degraded or self._drained:
             return None
         tokens = np.asarray(tokens, np.int32).reshape(-1)
@@ -3389,6 +3449,14 @@ class ServeEngine:
         return drain_io.kv_chain_to_wire(
             [int(t) for t in tokens[:len(blocks) * bs]], blocks)
 
+    def _refuse_chain_transfer(self) -> None:
+        if self._stateful:
+            raise NotImplementedError(
+                "a prefix chain carries K/V blocks only: a model that "
+                "keeps per-slot state (short-convolution layers) cannot "
+                "be resumed from one, and this engine caches no prefix "
+                "for it (ROADMAP M5)")
+
     def import_prefix_chain(self, entry) -> int:
         """The transfer IMPORT: decoded chain blocks enter the HOST
         TIER (no device work on the routing path — the next admission
@@ -3398,6 +3466,7 @@ class ServeEngine:
         failing this engine's leaf spec are refused block-by-block
         (`HostTierCache.store` validates). Returns blocks stored;
         0 with the tier disabled."""
+        self._refuse_chain_transfer()
         if self._host is None:
             return 0
         tokens, blocks = drain_io.kv_chain_from_wire(entry)

@@ -17,6 +17,7 @@ engine integration (`pddl_tpu/serve/engine.py`).
 from pddl_tpu.serve.kvcache.block_pool import (
     paged_decode_cache,
     pool_nbytes,
+    slot_state_nbytes,
 )
 from pddl_tpu.serve.kvcache.hosttier import HostTierCache, HostTierConfig
 from pddl_tpu.serve.kvcache.radix import RadixPrefixCache
@@ -27,4 +28,5 @@ __all__ = [
     "RadixPrefixCache",
     "paged_decode_cache",
     "pool_nbytes",
+    "slot_state_nbytes",
 ]

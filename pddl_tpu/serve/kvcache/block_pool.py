@@ -1,7 +1,9 @@
 """Device-resident KV block pool: the serving engine's KV cache.
 
 The engine has no per-request cache rows: its cache tree IS a pool
-(:func:`paged_decode_cache`), one leaf per attention layer
+(:func:`paged_decode_cache`), one leaf per attention layer (a layer that
+keeps a fixed state a SLOT instead — `llama.ShortConv` — gets a
+``[max_slots, ...]`` state leaf and no pool; see the builder)
 ``[N, cache_heads, block_size, lanes]`` whose entries are what the layer
 declares it caches of a token — K and V side by side (``2*D`` lanes), or
 a latent layer's one ``[c_kv | k_rope]`` — the shape whose layout at
@@ -36,14 +38,22 @@ from pddl_tpu.models.gpt import (
     BLOCK_TABLE_KEY,
     CACHE_INDEX_KEYS,
     _decode_cache_shapes,
+    is_paged_pool_path,
+    is_slot_state_path,
 )
-from pddl_tpu.models.vit import PAGED_KV_KEY
+from pddl_tpu.models.vit import (
+    PAGED_KV_KEY,
+    SLOT_STATE_KEY,
+    STATE_SLOT_KEY,
+)
+from pddl_tpu.ops.moe import VALID_LEN_KEY
 
 # The reserved write-sink block id (see module docstring).
 SCRATCH_BLOCK = 0
 
 
-def paged_decode_cache(dec, num_blocks: int, block_size: int):
+def paged_decode_cache(dec, num_blocks: int, block_size: int,
+                       max_slots: int = 1):
     """The serving cache tree: the pool IS the cache.
 
     Builds the cache tree the engine hands straight to
@@ -65,6 +75,16 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
     what keeps the donated resident buffers shape-stable and the
     program set at zero recompiles.
 
+    A layer declares what it keeps by its leaves' KEYS: a per-token
+    entry (the row-cache leaves above), or — ``SLOT_STATE_KEY``, ``[1,
+    ...]`` a row — a fixed state a slot. The second kind becomes a
+    ``[max_slots, ...]`` leaf, sized by slots and not by blocks, with no
+    pool and no table: the tick sees it row for row (batch row ``i`` IS
+    slot ``i``), a batch-1 chunk reaches its row through the scalar
+    ``STATE_SLOT_KEY`` placeholder beside it, stamped and restored like
+    the tables, and reads how many of its tokens are real from a
+    ``valid_len`` leaf (`gpt.set_cache_valid_len`).
+
     Block 0 stays the reserved scratch sink: parked slots' table rows
     are all scratch, so their fixed-shape tick writes land on junk the
     radix index never references.
@@ -75,6 +95,8 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
             f"sink), got {num_blocks}")
     if block_size < 1:
         raise ValueError(f"block_size must be >= 1, got {block_size}")
+    if max_slots < 1:
+        raise ValueError(f"max_slots must be >= 1, got {max_slots}")
     row = _decode_cache_shapes(dec, 1)
 
     def _build(tree):
@@ -86,6 +108,11 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
                 out[name] = _build(val)
             elif name in CACHE_INDEX_KEYS:
                 out[name] = jnp.zeros((), jnp.int32)
+            elif name == SLOT_STATE_KEY:
+                out[name] = jnp.zeros((max_slots,) + val.shape[1:],
+                                      val.dtype)
+                out[STATE_SLOT_KEY] = jnp.zeros((), jnp.int32)
+                out[VALID_LEN_KEY] = jnp.zeros((), jnp.int32)
             else:
                 kv[name] = val
         if kv:
@@ -119,10 +146,21 @@ def paged_decode_cache(dec, num_blocks: int, block_size: int):
     return cache
 
 
-def pool_nbytes(pool) -> int:
-    """Device bytes a block pool's leaves occupy — the HBM the engine's
-    degraded mode can shed (the number the failure-modes runbook in
-    `docs/OPERATIONS.md` reasons about when sizing pools against OOM
-    headroom)."""
+def _nbytes(cache, is_kind) -> int:
     return sum(int(leaf.size) * leaf.dtype.itemsize
-               for leaf in jax.tree.leaves(pool))
+               for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+               if is_kind(path))
+
+
+def pool_nbytes(cache) -> int:
+    """Device bytes a cache tree's block POOL leaves occupy — the HBM
+    the engine's degraded mode can shed (the number the failure-modes
+    runbook in `docs/OPERATIONS.md` reasons about when sizing pools
+    against OOM headroom)."""
+    return _nbytes(cache, is_paged_pool_path)
+
+
+def slot_state_nbytes(cache) -> int:
+    """Device bytes a cache tree's per-slot state leaves occupy (0 for a
+    model whose every layer caches per token)."""
+    return _nbytes(cache, is_slot_state_path)

@@ -241,6 +241,15 @@ class ServeMetrics:
         self.prefill_tokens = 0
         self.prefill_chunks: Dict[str, int] = {}
         self.latent_expanded_tokens = 0
+        # Slot state (a model with layers that keep a fixed state a
+        # slot, `llama.ShortConv`): admissions whose first chunk started
+        # a state row from zeros, admissions that skipped the prefix
+        # index because a hit would not restore that state, and the
+        # device bytes the state leaves hold (a gauge, set once: the
+        # leaves are sized by slots, not by load).
+        self.state_rows_started = 0
+        self.prefix_skipped_stateful = 0
+        self.state_bytes_resident = 0
         # Recent admission timestamps: the QueueFull retry_after_s
         # estimator (a short window so the hint tracks CURRENT service
         # rate, not the all-time average).
@@ -568,6 +577,9 @@ class ServeMetrics:
             # Labeled series, one sample per compiled chunk width.
             "prefill_chunks": dict(self.prefill_chunks),
             "latent_expanded_tokens": self.latent_expanded_tokens,
+            "state_rows_started": self.state_rows_started,
+            "prefix_skipped_stateful": self.prefix_skipped_stateful,
+            "state_bytes_resident": self.state_bytes_resident,
             # Per-priority splits: mappings render as labeled series
             # (one sample per class) through `obs/export.py`, so the
             # SLO runbook reads shed/finish/TTFT per class off one

@@ -713,3 +713,62 @@ def test_paged_metrics_reach_the_exposition(exact_gpt):
     assert flat["pddl_serve_engine_paged"] == 1
     assert "pddl_serve_engine_blocks_shared" in flat
     assert "pddl_serve_engine_block_table_fill" in flat
+
+
+def test_cache_tree_walkers_know_a_pool_by_its_key(gpt_setup, monkeypatch):
+    """A cache tree that holds, beside an attention layer's pool, a
+    3-dimensional leaf that is NOT a pool (what a layer with per-slot
+    state keeps, `vit.SLOT_STATE_KEY`): `_kv_token_bytes`, the host
+    tier's leaf spec, demotion (the whole-tree gather) and promotion (the
+    whole-tree scatter) take the pool by its KEY and leave the other leaf
+    alone — by rank they would have read a `[slots, 2, E]` state as a
+    pool of `slots` blocks."""
+    import pddl_tpu.serve.engine as engine_module
+    from pddl_tpu.models.vit import PAGED_KV_KEY, SLOT_STATE_KEY
+
+    model, variables = gpt_setup
+    build = engine_module.paged_decode_cache
+
+    def with_a_state_leaf(*args):
+        cache = build(*args)
+
+        def plant(tree):
+            if PAGED_KV_KEY in tree:
+                tree[SLOT_STATE_KEY] = jnp.full((2, 2, 8), 7.0)
+                return True
+            return any(plant(v) for v in tree.values()
+                       if hasattr(v, "items"))
+
+        assert plant(cache)
+        return cache
+
+    monkeypatch.setattr(engine_module, "paged_decode_cache",
+                        with_a_state_leaf)
+    bs = 8
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=32,
+                      prefix_block_size=bs, prefix_chunk=bs,
+                      prefix_cache_blocks=2 * (64 // bs) + 2,
+                      host_tier=1 << 24)
+    pools = [leaf for path, leaf in
+             jax.tree_util.tree_leaves_with_path(eng._cache)
+             if path[-1].key == PAGED_KV_KEY]
+    per_token = sum(p.nbytes for p in pools) // (pools[0].shape[0] * bs)
+    assert eng._kv_token_bytes == per_token
+    assert eng.prefix_pool_nbytes == sum(p.nbytes for p in pools)
+    assert eng.metrics.snapshot()["state_bytes_resident"] == 2 * 2 * 8 * 4
+    assert all(PAGED_KV_KEY in key for key in eng._host.leaf_spec)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 32, size=24).astype(np.int32)
+               for _ in range(6)]
+    refs = [_ref_greedy(model, variables, p, 4) for p in prompts]
+    for _ in range(3):   # more chains than the pool keeps: demote, promote
+        for p, ref in zip(prompts, refs):
+            h = eng.submit(p, 4)
+            eng.run(max_steps=5000)
+            assert h.tokens == ref
+    snap = eng.metrics.snapshot()
+    assert snap["host_tier_spills"] > 0 and snap["host_tier_promotions"] > 0
+    planted = [leaf for path, leaf in
+               jax.tree_util.tree_leaves_with_path(eng._cache)
+               if path[-1].key == SLOT_STATE_KEY]
+    assert planted and all(np.all(np.asarray(x) == 7.0) for x in planted)

@@ -173,12 +173,9 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(v5e_chip, family,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     shapes = functools.partial(_shapes_on, v5e_chip)
-    t1 = np.zeros((1, eng._table_width), np.int32)
 
     def chunk_args(width):
-        return shapes(eng._params, eng._cache,
-                      np.zeros((1, width), np.int32), np.int32(1),
-                      np.int32(0), t1)
+        return shapes(*eng._chunk_args_paged(width))
 
     programs = {
         "_tick_paged": (eng._tick_p, shapes(*eng._tick_args())),
@@ -416,6 +413,93 @@ def test_glm_flash_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
                    for i in pool_params)
         aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
         assert pool_params <= aliased, (name, aliases)
+
+
+def test_lfm2_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
+    """The benchmark cell `lfm2_extract_saturated`'s own `_tick_paged` and
+    `_chunk_paged` at the cell's whole size: the published widths (2048,
+    32 q / 8 kv heads of 64 with q/k norm, 3-tap short convolutions, 64
+    SwiGLU experts of 1,536 top-4, a dense first layer of 11,776,
+    vocabulary 65,536), all 9 layers of the cut, 48 slots of 18,432
+    positions, block 16, 2,048-wide chunks; weights and the cache tree as
+    shapes (the pool is 3.6 GB: nothing is allocated). The paged Mosaic
+    kernel is in the tick, a call an ATTENTION layer, on the leaf
+    `bf16[55297,8,16,128]` (K and V in exactly 128 lanes); a convolution
+    layer has no pool and no kernel, its state `bf16[48,2,2048]` a slot;
+    every pool and state leaf is aliased, no pool is copied; the
+    arguments and temporaries are what `reckoned_bytes` states and fit
+    the chip's 16.9 GB `bytes_limit`."""
+    import json
+    import pathlib
+
+    import pddl_tpu.serve.engine as engine_module
+    from pddl_tpu.models.llama import LFM2_24B_A2B
+    from pddl_tpu.serve import ServeEngine
+
+    cfg = json.loads((pathlib.Path(__file__).parent.parent / "chipbench"
+                      / "configs" / "lfm2-24b-a2b.json").read_text())
+    eng_cfg = cfg["engine"]
+    model = LFM2_24B_A2B(depth=cfg["num_hidden_layers"],
+                         max_len=eng_cfg["max_len"], dtype=jnp.bfloat16,
+                         param_dtype=jnp.bfloat16)
+    assert list(model.layer_types) == cfg["layer_types"]
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.ones((1, 8), jnp.int32), train=False))[
+            "params"]
+    build = engine_module.paged_decode_cache
+    monkeypatch.setattr(engine_module, "paged_decode_cache",
+                        lambda *a: jax.eval_shape(lambda: build(*a)))
+    eng = ServeEngine(model, {"params": params}, paged=True,
+                      max_slots=eng_cfg["max_slots"],
+                      prefill_len=eng_cfg["prefill_len"],
+                      prefix_block_size=eng_cfg["block_size"],
+                      prefix_cache_blocks=eng_cfg["pool_blocks"],
+                      prefix_chunk=eng_cfg["prefill_chunk"])
+    assert not eng._has_wide
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    shapes = functools.partial(_shapes_on, v5e_chip)
+    pool_shape, state_shape = "bf16[55297,8,16,128]", "bf16[48,2,2048]"
+    by_key = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(eng._cache):
+        by_key.setdefault(str(path[-1].key), []).append(
+            f"bf16[{','.join(map(str, leaf.shape))}]")
+    assert by_key["cached_kv"] == [pool_shape] * 2
+    assert by_key["slot_state"] == [state_shape] * 7
+    stated = lambda key: [int(x.replace(",", "")) for x in re.findall(
+        r"\d{1,3}(?:,\d{3}){2,}", cfg["reckoned_bytes"][key])]
+    programs = {
+        "_tick_paged": (eng._tick_p, eng._tick_args(), 0),
+        "_chunk_paged": (eng._chunk_p, eng._chunk_args_paged(eng._chunk), 1),
+    }
+    for name, (prog, args, which) in programs.items():
+        compiled = prog.lower(*shapes(*args)).compile()
+        text = compiled.as_text()
+        header = text.split("\n", 1)[0]
+        assert f"jit_{name}" in header
+        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
+        shape = r"\(?" + re.escape(pool_shape)
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
+                              line)]
+        assert not moved, f"{name}: pool-sized copies\n" + "\n".join(moved)
+        memory = compiled.memory_analysis()
+        args_b, temp_b = (memory.argument_size_in_bytes,
+                          memory.temp_size_in_bytes)
+        assert args_b == pytest.approx(stated("arguments")[which], rel=0.02)
+        assert temp_b == pytest.approx(stated("temporaries")[1 - which],
+                                       rel=0.1)
+        assert args_b + temp_b < 16.9e9, (name, args_b, temp_b)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                            header).group(1)
+        entry = re.search(
+            r"entry_computation_layout=\{\((.*)\)->\((.*)\)\}", header)
+        params_in = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", entry.group(1))
+        held = {i for i, p in enumerate(params_in)
+                if p.startswith((pool_shape, state_shape))}
+        assert len(held) == 9
+        aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
+        assert held <= aliased, (name, aliases)
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])

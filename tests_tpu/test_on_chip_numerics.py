@@ -514,3 +514,56 @@ def test_int8_serving_hook_on_chip():
         assert tokens.shape == (1, 88)
         gap = greedy_gap(model, dequantized, tokens, prompt.shape[1])
         assert np.all(gap < _TIE), float(gap.max())
+
+
+def test_lfm2_tick_and_chunk_against_the_reference_on_chip():
+    """LFM2-24B-A2B at its published widths (2048, 32 q / 8 kv heads of 64
+    with q/k norm, 3 taps, 64 experts of 1,536 top-4, a dense MLP of
+    11,776, vocabulary 65,536), two layers — a dense short-convolution
+    layer and a routed attention layer — through a paged ``ServeEngine``
+    in bf16: a 2,050-token prompt in two 2,048-wide chunks (the
+    convolution's state carried into a chunk of two real tokens), a
+    1-token prompt into the slot the long stream left, then the tick.
+    Every served greedy token against the benchmark's plain float32
+    reference over the whole sequence: bf16 costs a mean gap of a few
+    hundredths of a logit (the cell reads 0.01-0.03, PERF.md); a lost
+    state or a wrong tap moves the first tokens by whole logits."""
+    import sys
+    import pathlib
+
+    root = str(pathlib.Path(__file__).parent.parent)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.reference import lfm2 as reference
+    from chipbench.weights_lfm2 import make_weights
+    from pddl_tpu.models.llama import LFM2_24B_A2B
+    from pddl_tpu.serve import ServeEngine
+
+    types = ["conv", "full_attention"]
+    cfg = {"num_hidden_layers": 2, "layer_types": types,
+           "num_dense_layers": 1, "hidden_size": 2048,
+           "num_attention_heads": 32, "num_key_value_heads": 8,
+           "conv_L_cache": 3, "intermediate_size": 11776,
+           "num_experts": 64, "num_experts_per_tok": 4,
+           "moe_intermediate_size": 1536, "routed_scaling_factor": 1,
+           "vocab_size": 65536, "norm_eps": 1e-5,
+           "rope_parameters": {"rope_theta": 1000000}}
+    model = LFM2_24B_A2B(depth=2, max_len=6144, layer_types=tuple(types),
+                         moe_layout=(0, 1), dtype=jnp.bfloat16,
+                         param_dtype=jnp.bfloat16)
+    variables = make_weights(cfg, 5)
+    engine = ServeEngine(model, variables, max_slots=1, prefill_len=4096,
+                         prefix_block_size=16, prefix_chunk=2048)
+    rng = np.random.RandomState(0)
+    gaps = []
+    for plen in (2050, 1):
+        prompt = rng.randint(0, 65536, size=plen).astype(np.int32)
+        handle = engine.submit(prompt, 8)
+        engine.run()
+        assert len(handle.tokens) == 8
+        gaps.append(reference.served_gaps(variables["params"], cfg, prompt,
+                                          handle.tokens, 8)["gaps"])
+    gaps = np.concatenate(gaps)
+    assert gaps.max() < 1.0 and gaps.mean() < 0.1, gaps
+    assert set(engine.compile_counts().values()) == {1}
+    assert "tpu_custom_call" in engine.tick_lowering().as_text()
