@@ -232,7 +232,8 @@ def sample_logits(rng, logits, *, temperature: float = 1.0,
     top-k truncates, then nucleus (top-p) keeps the smallest set reaching
     ``top_p`` of the *warped* mass, then one categorical draw. Static
     shapes throughout — ``top_k`` uses ``lax.top_k``'s threshold,
-    ``top_p`` masks on the sorted CDF — so the whole step stays jittable.
+    ``top_p`` a searched cut (:func:`_nucleus_keep`) — so the whole step
+    stays jittable.
     """
     # Validate every CONCRETE value (Python, NumPy, or device scalar); a
     # TRACED top_p under jit stays dynamic and skips the check rather
@@ -252,40 +253,98 @@ def sample_logits(rng, logits, *, temperature: float = 1.0,
                              top_k=top_k, top_p=top_p), axis=-1)
 
 
-def _sort_descending(logits):
-    """One stable descending sort over the last axis that carries its
-    values: ``(sorted values, their vocabulary ids)``. Equal values keep
-    ascending-id order, exactly as ``jnp.argsort(-logits)`` orders them,
-    and no gather is needed to fetch the sorted values afterwards."""
-    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    neg, ids = jax.lax.sort((-logits, ids), dimension=logits.ndim - 1,
-                            is_stable=True, num_keys=1)
-    return -neg, ids
+# Bits of a threshold search's answer that one pass settles: a pass sums
+# the row against ``2**_SEARCH_BITS - 1`` thresholds, sibling reductions
+# that XLA fuses into one read of the row. 2 timed fastest on the v5e at
+# all four cells' [rows, V] (1, 3 and 4 slower: PERF.md, PR 39).
+_SEARCH_BITS = 2
+# The order key of -inf, as uint32. A search's coordinate ``u`` names the
+# float whose key is this plus ``u``: keys are the f32 bits with the low
+# 31 flipped on negatives, so integer order is float order; past +inf
+# ``u`` wraps through the NaNs, which no logit exceeds or equals.
+_KEY_NEG_INF = 0x807FFFFF
 
 
-def _nucleus_keep(logits, sorted_logits, sorted_ids, top_p):
+def _value_at(u):
+    """The f32 whose order key is -inf's plus ``u`` (uint32)."""
+    k = jax.lax.bitcast_convert_type(u + jnp.uint32(_KEY_NEG_INF), jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        jnp.where(k < 0, k ^ 0x7FFFFFFF, k), jnp.float32)
+
+
+def _search(pred, rows: int, bits: int):
+    """Per row the smallest ``u`` in ``[0, 2**bits)`` with ``pred`` true,
+    for a ``pred`` that is false then true along ``u`` and true at the
+    top: ``[rows, 1]`` uint32. ``pred`` maps ``[rows, 1]`` candidates to
+    ``[rows, 1]`` booleans; a pass tests the ends of ``2**_SEARCH_BITS``
+    equal sub-ranges and keeps the first whose end holds. A fixed number
+    of passes, no sort, no gather, and nothing to overflow: every end
+    lies inside the range before it."""
+    u = jnp.zeros((rows, 1), jnp.uint32)
+    left = bits
+    while left:
+        b = min(_SEARCH_BITS, left)
+        left -= b
+        fails = sum((~pred(u + jnp.uint32((j << left) - 1))).astype(jnp.uint32)
+                    for j in range(1, 2 ** b))
+        u = u + (fails << left)
+    return u
+
+
+def _count(mask):
+    return jnp.sum(mask, axis=-1, keepdims=True, dtype=jnp.int32)
+
+
+def _kth_value(w, k):
+    """Per row of ``w [rows, V]`` the ``k``-th largest value, ties counted
+    (``lax.top_k``'s ``[..., k - 1]``): the smallest value ``t`` with
+    fewer than ``k`` entries above it. ``k >= V`` gives the least."""
+    u = _search(lambda t: _count(w > _value_at(t)) < k[:, None],
+                w.shape[0], 32)
+    return _value_at(u)
+
+
+def _nucleus_keep(logits, top_p):
     """The nucleus (top-p) rule both filter pipelines share: a boolean
-    mask in VOCABULARY order of the smallest set of ``logits`` whose
-    softmax mass reaches ``top_p``.
+    mask of the smallest set of ``logits`` whose softmax mass reaches
+    ``top_p``, which broadcasts against ``logits.shape[:-1]``;
+    ``top_p >= 1`` keeps the whole row.
 
-    ``sorted_logits`` / ``sorted_ids`` are ``logits`` in stable
-    descending order (:func:`_sort_descending`); ``top_p`` broadcasts
-    against ``[..., 1]``. An entry is kept while the CDF *before* it is
-    ``< top_p`` (the first always is), and the CDF is monotone, so the
-    kept entries are a prefix of the sorted row and its length names
-    them. A bare value threshold would keep EVERY token tied with the
-    boundary logit and exceed the nucleus; the stable sort put ties in
-    ascending-id order, so the prefix ends at one (value, id) pair and,
-    back in vocabulary order, holds exactly what is larger than that
-    value, or equal to it with an id no higher: an elementwise test, no
-    inverse permutation and no gather over the vocabulary."""
-    cdf = jnp.cumsum(jax.nn.softmax(sorted_logits, axis=-1), axis=-1)
-    last = jnp.sum(cdf[..., :-1] < top_p, axis=-1, keepdims=True,
-                   dtype=jnp.int32)              # n_keep - 1
-    v_last = jnp.take_along_axis(sorted_logits, last, axis=-1)
-    id_last = jnp.take_along_axis(sorted_ids, last, axis=-1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    return (logits > v_last) | ((logits == v_last) & (ids <= id_last))
+    The set is a prefix of the row in descending order, ties in
+    ascending-id order (a stable sort's): an entry is kept while the
+    mass before it is ``< top_p``, the first always. It is found without
+    the sort. ``v_last``, the value of the last kept entry, is the
+    smallest value ``t`` whose mass strictly above, ``S(t)``, is
+    ``< top_p`` — a step function of the row's own values, searched over
+    their order keys. Of the ``c`` entries tied at ``v_last``, each of
+    probability ``q``, the first ``n = #{j < c : S + j q < top_p}`` (at
+    least one) are kept, and ``id_last`` is the ``n``-th smallest id
+    among them, by the same search counting ties. Back in vocabulary
+    order the set is what lies above ``v_last`` or equals it with an id
+    no higher than ``id_last``: an elementwise test."""
+    shape = logits.shape
+    w = logits.reshape(-1, shape[-1])
+    rows, v = w.shape
+    pp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32),
+                          shape[:-1]).reshape(rows, 1)
+    p = jax.nn.softmax(w, axis=-1)
+    mass_above = lambda t: jnp.sum(jnp.where(w > t, p, 0), axis=-1,
+                                   keepdims=True)
+    v_last = _value_at(_search(lambda t: mass_above(_value_at(t)) < pp,
+                               rows, 32))
+    above, tied = w > v_last, w == v_last
+    s, c = mass_above(v_last), _count(tied)
+    q = jnp.max(jnp.where(tied, p, 0), axis=-1, keepdims=True)
+    bits = max(1, (v - 1).bit_length())
+    n = 1 + _search(lambda j: ((j + 1).astype(jnp.int32) >= c)
+                    | (s + (j + 1).astype(jnp.float32) * q >= pp),
+                    rows, bits).astype(jnp.int32)
+    ids = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    id_last = _search(
+        lambda t: _count(tied & (ids <= t.astype(jnp.int32))) >= n,
+        rows, bits).astype(jnp.int32)
+    keep = above | (tied & (ids <= id_last)) | (pp >= 1.0)
+    return keep.reshape(shape)
 
 
 def filtered_logits(logits, *, temperature: float,
@@ -301,8 +360,7 @@ def filtered_logits(logits, *, temperature: float,
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -jnp.inf, logits)
     if top_p is not None:
-        keep = _nucleus_keep(logits, *_sort_descending(logits), top_p)
-        logits = jnp.where(keep, logits, -jnp.inf)
+        logits = jnp.where(_nucleus_keep(logits, top_p), logits, -jnp.inf)
     return logits
 
 
@@ -320,33 +378,27 @@ def batched_filtered_logits(logits, *, temperature, top_k, top_p):
     ignores the filtered row entirely.
 
     Row-by-row this matches ``filtered_logits`` exactly for enabled
-    filters: the top-k threshold is the k-th sorted value (ties at the
+    filters: the top-k threshold is the k-th largest value (ties at the
     boundary kept, like ``lax.top_k``'s), and the nucleus keep-set is
-    the same rule (:func:`_nucleus_keep`). With k as data, the top-k
-    threshold comes from ONE full sort that carries its values and that
-    the nucleus pass shares; every other step is elementwise or a
-    reduction over the row, so nothing gathers over ``[B, V]``.
+    the same rule (:func:`_nucleus_keep`). With k as data, the threshold
+    is searched (:func:`_kth_value`) inside a ``lax.cond`` that a batch
+    with no top-k row skips on the device. Nothing sorts or gathers over
+    ``[B, V]``: every step is elementwise or a reduction over the row.
     """
     logits = logits.astype(jnp.float32)
-    b, v = logits.shape
+    b = logits.shape[0]
     t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (b,))
     kk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (b,))
     pp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (b,))
     warped = logits / jnp.where(t > 0, t, 1.0)[:, None]
-    sorted_l, sorted_ids = _sort_descending(warped)
-    # Top-k: per-row k-th sorted value as the threshold (same
-    # keep-boundary-ties rule as lax.top_k in filtered_logits). The
-    # masked entries are exactly the tail of the descending order, so
-    # the same comparison masks the sorted row and the one sort stays
-    # valid after masking — no re-sort.
-    kth = jnp.take_along_axis(
-        sorted_l, (jnp.clip(kk, 1, v) - 1)[:, None], axis=-1)
-    no_topk = kk[:, None] <= 0
-    warped = jnp.where(no_topk | (warped >= kth), warped, -jnp.inf)
-    sorted_l = jnp.where(no_topk | (sorted_l >= kth), sorted_l, -jnp.inf)
-    keep = (_nucleus_keep(warped, sorted_l, sorted_ids, pp[:, None])
-            | (pp[:, None] >= 1.0))
-    return jnp.where(keep, warped, -jnp.inf)
+    # Only the threshold leaves the cond: a [B, V] result would be one
+    # more buffer and a copy of the row every tick.
+    kth = jax.lax.cond(
+        jnp.any(kk > 0),
+        lambda: jnp.where(kk[:, None] > 0, _kth_value(warped, kk), -jnp.inf),
+        lambda: jnp.full((b, 1), -jnp.inf))
+    warped = jnp.where(warped >= kth, warped, -jnp.inf)
+    return jnp.where(_nucleus_keep(warped, pp), warped, -jnp.inf)
 
 
 def sample_logits_batched(rng, logits, *, temperature, top_k, top_p):

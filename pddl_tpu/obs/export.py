@@ -110,7 +110,8 @@ SERVE_COUNTER_KEYS = frozenset({
     "requests_cancelled", "requests_failed", "requests_deadline_shed",
     "tokens_emitted", "prefix_lookups", "prefix_hits",
     "prefill_tokens_saved", "prefix_evictions", "prefix_reclaims",
-    "prefix_reclaim_visited", "retries", "replays",
+    "prefix_reclaim_visited", "nucleus_rows_searched", "topk_search_ticks",
+    "retries", "replays",
     "preemptions", "degraded_entries", "degraded_time_s",
     "copy_bytes_avoided",
     # Multi-tenant counters (`serve/tenant/`): adapter pool traffic and

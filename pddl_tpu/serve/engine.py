@@ -2789,6 +2789,7 @@ class ServeEngine:
                     "sample_first", self._sample_first_p, logits,
                     *self._first_mask_args(fsm),
                     np.float32(t), np.int32(k), np.float32(p), self._rng)
+                self.metrics.record_sampled(t, k, p, tick=False)
                 with self._phase["first_token_wait"]:
                     first = int(tok[0])
         except _SlotStateLost:
@@ -3029,6 +3030,8 @@ class ServeEngine:
             self._top_ks, self._top_ps, *self._verify_extra(),
             caps, forced_n, self._rng)
         self.metrics.record_decode_tick()
+        self.metrics.record_sampled(self._temps, self._top_ks, self._top_ps,
+                                    tick=True)
         return win, acc, caps, drafted_tick
 
     def _spec_emit(self, cur: int, live, win, acc, caps, forced_n):
@@ -3184,6 +3187,8 @@ class ServeEngine:
                     self._cache, nxt, self._rng = self._device_call(
                         "tick", self._tick_p, *self._tick_args())
                 self.metrics.record_decode_tick()
+                self.metrics.record_sampled(self._temps, self._top_ks,
+                                            self._top_ps, tick=True)
             except _SlotStateLost:
                 self._lose_live_slots()
             if nxt is not None:

@@ -149,6 +149,13 @@ class ServeMetrics:
         # costs under pressure (about 1-2; it was the index's size).
         self.prefix_reclaims = 0
         self.prefix_reclaim_visited = 0
+        # The sampler's searches, counted on the host from the arrays
+        # each tick and first-token draw ships (no device cost): the
+        # sampled rows that search a nucleus cut (`top_p < 1`), and the
+        # ticks in which some row asked for top-k (any other tick skips
+        # that search on the device).
+        self.nucleus_rows_searched = 0
+        self.topk_search_ticks = 0
         self.prefix_blocks_live = 0  # gauge, engine-stamped per admission
         # Block-pool telemetry: `copy_bytes_avoided` counts the bytes
         # a prefix hit references in place (matched tokens x per-token
@@ -287,6 +294,17 @@ class ServeMetrics:
     def record_decode_tick(self) -> None:
         """One decode tick (or speculative verify window) dispatched."""
         self.decode_ticks += 1
+
+    def record_sampled(self, temps, top_ks, top_ps, *,
+                       tick: bool) -> None:
+        """One sampler dispatch's per-row parameters as shipped (the
+        engine's sentinels: ``top_p >= 1`` no nucleus, ``top_k <= 0`` no
+        top-k); ``tick`` for a tick or verify window, not a first-token
+        draw."""
+        self.nucleus_rows_searched += int(np.count_nonzero(
+            (np.asarray(temps) > 0) & (np.asarray(top_ps) < 1.0)))
+        if tick and np.any(np.asarray(top_ks) > 0):
+            self.topk_search_ticks += 1
 
     def record_queue_pop(self, queue_wait_s: float) -> None:
         """One FRESH request popped by the scheduler, ``queue_wait_s``
@@ -524,6 +542,8 @@ class ServeMetrics:
             "prefix_evictions": self.prefix_evictions,
             "prefix_reclaims": self.prefix_reclaims,
             "prefix_reclaim_visited": self.prefix_reclaim_visited,
+            "nucleus_rows_searched": self.nucleus_rows_searched,
+            "topk_search_ticks": self.topk_search_ticks,
             "copy_bytes_avoided": self.copy_bytes_avoided,
             "blocks_shared": self.blocks_shared,
             "block_table_fill": round(self.block_table_fill, 6),

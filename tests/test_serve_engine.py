@@ -122,6 +122,56 @@ def test_per_slot_sampling_isolation(gpt_setup):
     assert all(0 <= t < 32 for t in hc.tokens) and len(hc.tokens) == 6
 
 
+def test_sampler_search_counters_follow_the_shipped_arrays(gpt_setup):
+    """``nucleus_rows_searched`` and ``topk_search_ticks`` (PR 39) count,
+    on the host, what the sampler's arrays ask of the device: per tick
+    and first-token draw the sampled rows with ``top_p < 1``, and the
+    ticks in which some row has ``top_k > 0`` (the others skip that
+    search). Held to counts taken from the arrays each dispatch shipped,
+    over greedy, nucleus-only and top-k requests, the top-k one ending
+    first so that some ticks have no top-k row."""
+    from pddl_tpu.obs import (SERVE_COUNTER_KEYS, parse_prometheus_text,
+                              serve_exposition)
+
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=4, prefill_len=16,
+                      rng=jax.random.key(3))
+    shipped = []
+    real = eng._device_call
+
+    def spy(site, fn, *args):
+        if site == "tick":          # (..., temps, top_ks, top_ps, ..., rng)
+            shipped.append(("tick", *(np.array(a) for a in args[5:8])))
+        elif site == "sample_first":  # (logits, ..., t, k, p, rng)
+            shipped.append(("first", *(np.array(a) for a in args[-4:-1])))
+        return real(site, fn, *args)
+
+    eng._device_call = spy
+    eng.submit(np.arange(5) % 32, 8)
+    eng.submit((np.arange(6) * 3) % 32, 8,
+               sampling=SamplingParams(temperature=0.8, top_p=0.9))
+    eng.submit((np.arange(4) + 5) % 32, 3,
+               sampling=SamplingParams(temperature=1.0, top_k=4))
+    eng.submit((np.arange(7) * 5) % 32, 6,
+               sampling=SamplingParams(temperature=0.7, top_k=3, top_p=0.8))
+    eng.submit((np.arange(3) + 9) % 32, 5,
+               sampling=SamplingParams(temperature=0.9, top_p=0.5))
+    eng.run(max_steps=100)
+    nucleus = sum(int(np.sum((t > 0) & (p < 1.0))) for _, t, _, p in shipped)
+    topk = sum(bool(np.any(k > 0)) for site, _, k, _ in shipped
+               if site == "tick")
+    ticks = sum(site == "tick" for site, *_ in shipped)
+    snap = eng.metrics.snapshot()
+    assert (snap["nucleus_rows_searched"], snap["topk_search_ticks"]) == \
+        (nucleus, topk)
+    assert nucleus > 0 and 0 < topk < ticks == snap["decode_ticks"]
+    samples, types = parse_prometheus_text(serve_exposition(eng.metrics, eng))
+    for key in ("nucleus_rows_searched", "topk_search_ticks"):
+        assert key in SERVE_COUNTER_KEYS
+        assert types[f"pddl_serve_{key}_total"] == "counter"
+        assert samples[(f"pddl_serve_{key}_total", ())] == float(snap[key])
+
+
 def _oracle_filtered_row(row, t, k, p):
     """The filter rule in plain NumPy, one row: warp, top-k by the k-th
     sorted value (boundary ties kept), then the smallest set of the

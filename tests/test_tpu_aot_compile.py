@@ -256,17 +256,20 @@ def _vocab_wide_gathers_and_sorts(text, rows, vocab):
 @pytest.mark.parametrize("program,rows,vocab", [
     ("sampler", 48, 50257),     # gpt2l_chat_*: 48 slots x GPT-2's vocabulary
     ("sampler", 8, 151936),     # st21b_longdoc_steady: 8 x SmallThinker's
+    ("sampler", 40, 154880),    # glm47f_agent_saturated: 40 x GLM's
+    ("sampler", 48, 65536),     # lfm2_extract_saturated: 48 x LFM2's
     ("_tick_paged", _SLOTS, 512),
 ])
-def test_sampler_sorts_once_and_gathers_nothing_vocab_wide_on_v5e(
+def test_sampler_sorts_nothing_and_gathers_nothing_vocab_wide_on_v5e(
         v5e_chip, monkeypatch, program, rows, vocab):
     """``sample_logits_batched`` at the benchmark cells' shapes, and the
     GPT-2-large ``_tick_paged`` this module builds, compiled for the
-    described chip: ONE sort over ``[rows, V]`` and no gather that yields
+    described chip: no sort over ``[rows, V]`` and no gather that yields
     ``rows x V`` elements. The parent of PR 31 held two sorts and three
     such gathers (``take_along_axis`` by the sort's permutation and by
-    its inverse): 72 ms of a 105 ms tick in the saturated cell, against
-    5 ms for the sorts."""
+    its inverse): 72 ms of a 105 ms tick in the saturated cell; PR 31
+    left one sort, 9 ms of glm-4.7-flash's 25.6 ms tick, which PR 39's
+    threshold search replaced."""
     shapes = functools.partial(_shapes_on, v5e_chip)
     if program == "sampler":
         from pddl_tpu.models.gpt import sample_logits_batched
@@ -286,7 +289,7 @@ def test_sampler_sorts_once_and_gathers_nothing_vocab_wide_on_v5e(
     gathers, sorts = _vocab_wide_gathers_and_sorts(
         lowered.compile().as_text(), rows, vocab)
     assert not gathers, "vocabulary-wide gathers\n" + "\n".join(gathers)
-    assert len(sorts) == 1, "sorts over [rows, V]\n" + "\n".join(sorts)
+    assert not sorts, "sorts over [rows, V]\n" + "\n".join(sorts)
 
 
 def test_smallthinker_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
@@ -487,8 +490,14 @@ def test_lfm2_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         args_b, temp_b = (memory.argument_size_in_bytes,
                           memory.temp_size_in_bytes)
         assert args_b == pytest.approx(stated("arguments")[which], rel=0.02)
-        assert temp_b == pytest.approx(stated("temporaries")[1 - which],
-                                       rel=0.1)
+        # `reckoned_bytes` is PR 38's compile (a benchmark file: only a
+        # `benchmark` PR restates it). Since PR 39 the tick's sampler
+        # holds one float32 [slots, vocabulary] row more, the
+        # probabilities its threshold search reads pass after pass.
+        sampler_row = (eng_cfg["max_slots"] * cfg["vocab_size"] * 4
+                       if name == "_tick_paged" else 0)
+        assert temp_b == pytest.approx(
+            stated("temporaries")[1 - which] + sampler_row, rel=0.1)
         assert args_b + temp_b < 16.9e9, (name, args_b, temp_b)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
                             header).group(1)
