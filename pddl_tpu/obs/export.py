@@ -135,6 +135,13 @@ SERVE_COUNTER_KEYS = frozenset({
     # summed waits.
     "engine_steps", "step_wall_s", "phase_wall_s", "decode_ticks",
     "queue_pops", "queue_wait_s", "admissions", "admit_wall_s",
+    # Where the engine's set-up goes (a labeled counter, one sample per
+    # `serve/metrics.SETUP_STAGES` entry), and the backend compiles by
+    # the phase or stage that was open (`serve/metrics.COMPILE_PLACES`):
+    # executables compiled, their seconds, those of them the persistent
+    # compile cache took in, and those it held instead.
+    "setup_wall_s", "compiles", "compile_s", "compile_cache_misses",
+    "compile_cache_hits",
     # What prefill cost in tokens: prompt tokens installed, and chunk
     # programs dispatched by compiled width (a labeled counter).
     "prefill_tokens", "prefill_chunks", "latent_expanded_tokens",
@@ -346,6 +353,7 @@ def engine_gauges(engine) -> Dict[str, object]:
         # sharing. ``paged`` is a constant series since PR 34 (one
         # engine), kept for dashboards keyed on it.
         "paged": getattr(engine, "paged", False),
+        # Counted over the live tables here, when read.
         "blocks_shared": getattr(engine, "blocks_shared", 0),
         "block_table_fill": getattr(engine, "block_table_fill", 0.0),
         # Speculative-serving gauges (0 on a classic engine): the

@@ -6,7 +6,8 @@ site's dispatch wall time spike, did retries cluster — without an
 unbounded log. This ring is that window: the engine appends one record
 per ``step()`` (occupancy, queue depth, tokens emitted, per-site
 ``_device_call`` wall time, per-phase wall time of the step's span
-tree, retries, degraded flag), capacity is fixed
+tree, retries, backend compiles inside the step, degraded flag),
+capacity is fixed
 at construction, and the oldest record is overwritten in place.
 ``snapshot()`` hands benches and the drain path a stable oldest→newest
 copy; ``summary()`` collapses the window into the handful of gauges the

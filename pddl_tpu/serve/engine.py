@@ -150,7 +150,9 @@ bit-identical to the untiered one — same programs, same tokens.
 
 from __future__ import annotations
 
+import functools
 import signal
+import threading
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
@@ -261,32 +263,119 @@ _DONATED_BY_SITE = {
 _SPAN_PREFIX = "pddl.serve."
 
 
+class _OpenPhase(threading.local):
+    """The engine phase or set-up stage open on this thread, where a
+    backend compile on it is counted (``None``: not the engine's)."""
+
+    phase = None
+
+
+_OPEN = _OpenPhase()
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"  # on a write
+_listening = False
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        phase = _OPEN.phase
+        if phase is not None:
+            phase.metrics.record_backend_compile(phase.name, seconds)
+
+
+def _on_event(event: str, **_) -> None:
+    phase = _OPEN.phase
+    if phase is None:
+        return
+    if event == _CACHE_HIT_EVENT:
+        phase.metrics.record_compile_cache_hit(phase.name)
+    elif event == _CACHE_MISS_EVENT:
+        phase.metrics.record_compile_cache_miss(phase.name)
+
+
+def _listen_for_compiles() -> None:
+    """Register the compile listeners with ``jax.monitoring``, once a
+    process (jax keeps a listener for the process's life)."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listening = True
+
+
 class _Phase:
-    """One phase boundary of ``step()``: entering opens a
-    ``jax.profiler.TraceAnnotation`` (a TraceMe — a flag test while no
-    profiler session runs, a host span on the device trace's own clock
-    while one does) and leaving adds the ``time.perf_counter``
-    duration to the engine's per-step ``phase_wall_s``. One
-    preallocated object per phase name; a phase never nests inside
-    itself."""
+    """One phase boundary of ``step()``, or one stage of the engine's
+    set-up: entering opens a ``jax.profiler.TraceAnnotation`` (a
+    TraceMe — a flag test while no profiler session runs, a host span on
+    the device trace's own clock while one does) and marks the phase
+    open on this thread (a backend compile meanwhile counts under its
+    name, `ServeMetrics.compiles`); leaving adds the
+    ``time.perf_counter`` duration to ``wall`` (the engine's per-step
+    ``phase_wall_s``, or ``ServeMetrics.setup_wall_s``) and hands the
+    thread back to ``outer``, the phase it nests in. One preallocated
+    object per step phase; a phase never nests inside itself."""
 
-    __slots__ = ("_name", "_label", "_wall", "_span", "_t0")
+    __slots__ = ("name", "metrics", "_label", "_wall", "_outer", "_span",
+                 "_t0")
 
-    def __init__(self, name: str, wall: Dict[str, float]):
-        self._name = name
-        self._label = _SPAN_PREFIX + name
+    def __init__(self, name: str, wall: Dict[str, float],
+                 metrics: ServeMetrics, *, label: Optional[str] = None,
+                 outer: Optional["_Phase"] = None):
+        self.name = name
+        self.metrics = metrics
+        self._label = label or _SPAN_PREFIX + name
         self._wall = wall
+        self._outer = outer
         self._span = None
         self._t0 = 0.0
 
     def __enter__(self) -> None:
         # The TraceMe's span starts at construction.
         self._span = jax.profiler.TraceAnnotation(self._label)
+        _OPEN.phase = self
         self._t0 = time.perf_counter()
 
     def __exit__(self, *exc) -> None:
-        self._wall[self._name] += time.perf_counter() - self._t0
+        self._wall[self.name] += time.perf_counter() - self._t0
+        _OPEN.phase = self._outer
         self._span.__exit__(*exc)
+
+
+def _setup_stage(name: str, metrics: ServeMetrics) -> _Phase:
+    """One stage of the engine's set-up (`metrics.SETUP_STAGES`): span
+    ``pddl.serve.setup.<name>``, wall into ``setup_wall_s``."""
+    return _Phase(name, metrics.setup_wall_s, metrics,
+                  label=_SPAN_PREFIX + "setup." + name)
+
+
+def _build_stage(init):
+    """``ServeEngine.__init__`` as the ``build`` set-up stage: the
+    engine's ``metrics`` is made first, so the stage counts its wall and
+    every compile of the body into it."""
+
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs) -> None:
+        _listen_for_compiles()
+        self.metrics = ServeMetrics()
+        with _setup_stage("build", self.metrics):
+            init(self, *args, **kwargs)
+
+    return __init__
+
+
+def _shared_block_count(slots, tables) -> int:
+    """Pool blocks referenced by MORE THAN ONE live slot's block table
+    (``ServeEngine.blocks_shared``)."""
+    live = [sid for sid, h in enumerate(slots) if h is not None]
+    if len(live) < 2:
+        return 0
+    # One vectorized pass: count ids that appear in more than one row.
+    # Within a row ids are unique by construction (each table entry is a
+    # distinct block or scratch), so a >1 total count means >1 slot.
+    rows = tables[live]
+    ids, counts = np.unique(rows[rows != 0], return_counts=True)
+    return int((counts > 1).sum())
 
 
 class ServeEngine:
@@ -427,6 +516,7 @@ class ServeEngine:
         the oldest record is overwritten, so memory is bounded forever.
     """
 
+    @_build_stage
     def __init__(self, model, variables, *, max_slots: int = 8,
                  prefill_len: Optional[int] = None,
                  max_queue_depth: int = 64,
@@ -475,7 +565,8 @@ class ServeEngine:
             max_queue_depth=max_queue_depth,
             prefill_token_budget=prefill_token_budget,
             aging_s=aging_s)
-        self.metrics = ServeMetrics()
+        # ``self.metrics`` (ServeMetrics) is made by `_build_stage`,
+        # before this body, which it times as the ``build`` stage.
 
         # Observability (`pddl_tpu/obs/`): the tracer defaults to the
         # shared no-op object, so a disabled engine pays one method
@@ -485,8 +576,14 @@ class ServeEngine:
         self.telemetry = TelemetryRing(telemetry_capacity)
         self._site_wall: Dict[str, float] = {}
         self._phase_wall: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
-        self._phase = {name: _Phase(name, self._phase_wall)
-                       for name in PHASES}
+        self._phase: Dict[str, _Phase] = {}
+        for name in PHASES:
+            # ``first_token_wait`` is the one phase that nests (in
+            # ``admit``): leaving it hands the thread back to ``admit``.
+            self._phase[name] = _Phase(
+                name, self._phase_wall, self.metrics,
+                outer=(self._phase["admit"] if name == "first_token_wait"
+                       else None))
         self._last_wall_s = 0.0
         self._cur_step = 0
 
@@ -1036,6 +1133,9 @@ class ServeEngine:
         # ids each slot owns.
         self._tables = np.zeros(
             (self.max_slots, self._table_width), np.int32)
+        # The sharing gauge is counted when read, off the step's path.
+        self.metrics.bind_blocks_shared(functools.partial(
+            _shared_block_count, self._slots, self._tables))
         self._private: List[List[int]] = [
             [] for _ in range(self.max_slots)]
         # KV bytes one token occupies across every leaf — what one
@@ -1289,42 +1389,54 @@ class ServeEngine:
         tables: every warmup write lands in the junk sink, the radix
         index stays empty, and every program traces once with its
         serving shapes). Implicit on the first ``step()`` if not
-        called."""
+        called. Each program's first call is a set-up stage of its own
+        (``warmup:<site>``), the closing wait another
+        (``warmup_wait``)."""
         if self._warm:
             return
+        stage = functools.partial(_setup_stage, metrics=self.metrics)
         first_mask = self._first_mask_args(None)  # () on a plain engine
         if self._tenant_on:
             # Warm the adapter-load program by writing zeros into the
             # identity row — content unchanged (row 0 IS the zero
             # adapter), program traced once.
-            self._apool_a, self._apool_b = self._adapter_load_p(
-                self._apool_a, self._apool_b, np.int32(0),
-                np.zeros((self.model.embed_dim, self._registry.rank),
-                         np.float32),
-                np.zeros((self._registry.rank, self.model.vocab_size),
-                         np.float32))
-        self._cache, logits = self._chunk_p(
-            *self._chunk_args_paged(self._chunk))
+            with stage("warmup:adapter_load"):
+                self._apool_a, self._apool_b = self._adapter_load_p(
+                    self._apool_a, self._apool_b, np.int32(0),
+                    np.zeros((self.model.embed_dim, self._registry.rank),
+                             np.float32),
+                    np.zeros((self._registry.rank, self.model.vocab_size),
+                             np.float32))
+        with stage("warmup:chunk_prefill"):
+            self._cache, logits = self._chunk_p(
+                *self._chunk_args_paged(self._chunk))
         if self._has_wide:
-            self._cache, logits = self._chunk_wide_p(
-                *self._chunk_args_paged(self.prefill_len))
-        tok, self._rng = self._sample_first_p(
-            logits, *first_mask, np.float32(0.0), np.int32(0),
-            np.float32(2.0), self._rng)
+            with stage("warmup:chunk_prefill_wide"):
+                self._cache, logits = self._chunk_wide_p(
+                    *self._chunk_args_paged(self.prefill_len))
+        with stage("warmup:sample_first"):
+            tok, self._rng = self._sample_first_p(
+                logits, *first_mask, np.float32(0.0), np.int32(0),
+                np.float32(2.0), self._rng)
         if self._host is not None:
             # All-scratch promote: junk lands in the sink, the host
             # tier stays empty, the program traces once — and the
             # demote gather's one program likewise.
-            self._cache = self._promote_p(
-                self._cache, self._assemble_promote_rows([]),
-                np.zeros(self._match_cap, np.int32))
-            self._demote_p(self._cache,
-                           np.zeros(self._match_cap, np.int32))
+            with stage("warmup:host_promote"):
+                self._cache = self._promote_p(
+                    self._cache, self._assemble_promote_rows([]),
+                    np.zeros(self._match_cap, np.int32))
+            with stage("warmup:host_demote"):
+                self._demote_p(self._cache,
+                               np.zeros(self._match_cap, np.int32))
         if self._spec_on:
-            nxt = self._warm_spec()
+            nxt = self._warm_spec(stage)
         else:
-            self._cache, nxt, self._rng = self._tick_p(*self._tick_args())
-        jax.block_until_ready((tok, nxt))
+            with stage("warmup:tick"):
+                self._cache, nxt, self._rng = self._tick_p(
+                    *self._tick_args())
+        with stage("warmup_wait"):
+            jax.block_until_ready((tok, nxt))
         self._warm = True
 
     def _tick_args(self) -> tuple:
@@ -1387,33 +1499,39 @@ class ServeEngine:
             if str(getattr(path[-1], "key", path[-1])) == EXPERT_LOAD_KEY}
         return {k: np.asarray(v) for k, v in jax.device_get(found).items()}
 
-    def _warm_spec(self):
+    def _warm_spec(self, stage):
         """Trace the draft/verify pair (and the draft model's admission
         chunk) with all-dead inputs: caps 0 + forced -1 accept nothing,
         junk writes land in the scratch sink (all-scratch tables), so
-        warmup leaves no trace in any live state. Returns the verify
-        window for the caller's block_until_ready."""
+        warmup leaves no trace in any live state. Each first call runs
+        in its set-up stage (``stage(name)``, as `warmup`'s). Returns
+        the verify window for the caller's block_until_ready."""
         s, k = self.max_slots, self._spec_k
         forced_tok = np.zeros((s, k), np.int32)
         forced_n = np.full(s, -1, np.int32)
         if self._draft_on:
             t1 = np.zeros((1, self._table_width), np.int32)
-            self._dcache = self._dchunk_p(
-                self._dparams, self._dcache,
-                np.zeros((1, self._chunk), np.int32), np.int32(1),
-                np.int32(0), t1)
-            self._dcache, drafts = self._draft_model_p(
-                self._dparams, self._dcache, self._positions,
-                self._tables, self._tokens, forced_tok, forced_n)
+            with stage("warmup:draft_prefill"):
+                self._dcache = self._dchunk_p(
+                    self._dparams, self._dcache,
+                    np.zeros((1, self._chunk), np.int32), np.int32(1),
+                    np.int32(0), t1)
+            with stage("warmup:draft"):
+                self._dcache, drafts = self._draft_model_p(
+                    self._dparams, self._dcache, self._positions,
+                    self._tables, self._tokens, forced_tok, forced_n)
         else:
-            drafts = self._draft_p(self._hist, self._positions)
+            with stage("warmup:draft"):
+                drafts = self._draft_p(self._hist, self._positions)
         block = np.zeros((s, k + 1), np.int32)
         caps = np.zeros(s, np.int32)
-        self._cache, w, acc, self._rng = self._verify_p(
-            self._params, self._cache, self._positions, self._tables,
-            block, self._temps, self._top_ks, self._top_ps,
-            *self._verify_extra(), caps, forced_n, self._rng)
-        jax.block_until_ready(drafts)
+        with stage("warmup:verify"):
+            self._cache, w, acc, self._rng = self._verify_p(
+                self._params, self._cache, self._positions, self._tables,
+                block, self._temps, self._top_ks, self._top_ps,
+                *self._verify_extra(), caps, forced_n, self._rng)
+        with stage("warmup_wait"):
+            jax.block_until_ready(drafts)
         return w
 
     def compile_counts(self) -> Dict[str, int]:
@@ -1660,16 +1778,7 @@ class ServeEngine:
         """Pool blocks referenced by MORE THAN ONE live slot's block
         table right now — each is one block of KV a private-copy
         design would have duplicated per referencing slot."""
-        live = [sid for sid, h in enumerate(self._slots) if h is not None]
-        if len(live) < 2:
-            return 0
-        # One vectorized pass (this gauge is stamped every tick): count
-        # ids that appear in more than one row. Within a row ids are
-        # unique by construction (each table entry is a distinct block
-        # or scratch), so a >1 total count means >1 slot.
-        rows = self._tables[live]
-        ids, counts = np.unique(rows[rows != 0], return_counts=True)
-        return int((counts > 1).sum())
+        return _shared_block_count(self._slots, self._tables)
 
     @property
     def block_table_fill(self) -> float:
@@ -3161,6 +3270,7 @@ class ServeEngine:
         phase."""
         ph = self._phase
         retries_before = self.metrics.retries
+        compiles_before = self.metrics.compiles_counted
         t0 = self._clock()
         emitted_before = self.metrics.tokens_emitted
         with ph["reap"]:
@@ -3196,28 +3306,31 @@ class ServeEngine:
                     nxt = np.asarray(nxt)  # per-tick host sync (streaming)
                 with ph["emit"]:
                     new_tokens = self._emit_tick(cur, live, nxt)
-        now = self._clock()
-        self.metrics.record_tick(
-            now, self.scheduler.depth, len(live), self.max_slots,
-            new_tokens, now - t0)
-        self.metrics.record_paged_gauges(self.blocks_shared,
-                                         self.block_table_fill)
-        emitted = self.metrics.tokens_emitted - emitted_before
+        with ph["account"]:
+            now = self._clock()
+            self.metrics.record_tick(
+                now, self.scheduler.depth, len(live), self.max_slots,
+                new_tokens, now - t0)
+            self.metrics.record_paged_gauges(self.block_table_fill)
+            emitted = self.metrics.tokens_emitted - emitted_before
+            # The one per-step record: the ring keeps it, the tracer is
+            # handed the same object (`RequestTracer(emit_ticks=True)`
+            # writes it to its sink).
+            record = {
+                "step": cur, "t_s": now,
+                "queue_depth": self.scheduler.depth,
+                "live_slots": len(live), "tokens": emitted,
+                "tick_wall_s": now - t0,
+                "retries": self.metrics.retries - retries_before,
+                "compiles": self.metrics.compiles_counted - compiles_before,
+                "degraded": self._degraded,
+                "site_wall_s": self._site_wall,
+            }
+        # The step's wall ends with ``account``, whose time the record
+        # carries: handing the record on is the step's last act.
         phase_wall = dict(self._phase_wall)
         self.metrics.record_step(time.perf_counter() - t_step, phase_wall)
-        # The one per-step record: the ring keeps it, the tracer is
-        # handed the same object (`RequestTracer(emit_ticks=True)`
-        # writes it to its sink).
-        record = {
-            "step": cur, "t_s": now,
-            "queue_depth": self.scheduler.depth,
-            "live_slots": len(live), "tokens": emitted,
-            "tick_wall_s": now - t0,
-            "retries": self.metrics.retries - retries_before,
-            "degraded": self._degraded,
-            "site_wall_s": self._site_wall,
-            "phase_wall_s": phase_wall,
-        }
+        record["phase_wall_s"] = phase_wall
         self.telemetry.append(record)
         self._tracer.on_tick(record)
         return emitted

@@ -45,9 +45,33 @@ PRIORITY_CLASSES = tuple(p.value for p in Priority)
 # the per-request ``admit_request`` span, which only the profiler's
 # trace carries: summed it would repeat ``admit``); the rest are direct
 # children of the step. The two ``*_wait`` phases are the step's only
-# host<-device reads.
+# host<-device reads. ``account`` is the step's own bookkeeping after
+# ``emit`` (these counters, the gauges, the ring's record), so the
+# direct children sum to the step's wall.
 PHASES = ("reap", "admit", "first_token_wait", "append_blocks",
-          "tick_dispatch", "tick_wait", "emit")
+          "tick_dispatch", "tick_wait", "emit", "account")
+
+# The engine's own set-up, stage by stage (``pddl.serve.setup.<stage>``
+# spans; the stable label set of ``setup_wall_s``): ``build`` is the
+# body of ``ServeEngine.__init__`` (the pool allocated, the programs
+# wrapped); ``warmup:<site>`` the first call of one resident program
+# from call to return — trace, lower, compile or persistent-cache load,
+# dispatch — under its site name (`engine.compile_counts()`; the host
+# tier's demotion gather is ``host_demote``); ``warmup_wait`` the closing
+# wait on the device, the programs' first execution. A stage the engine
+# does not have reads 0.
+SETUP_STAGES = ("build", "warmup:adapter_load", "warmup:chunk_prefill",
+                "warmup:chunk_prefill_wide", "warmup:sample_first",
+                "warmup:host_promote", "warmup:host_demote",
+                "warmup:draft_prefill", "warmup:draft", "warmup:verify",
+                "warmup:tick", "warmup_wait")
+
+# Where a compile can be counted: a step phase or a set-up stage (the
+# stable label set of ``compiles``, ``compile_s``,
+# ``compile_cache_misses`` and ``compile_cache_hits``). A compile while
+# none of them is open on the
+# compiling thread is not the engine's and is not counted.
+COMPILE_PLACES = PHASES + SETUP_STAGES
 
 
 def _pct(values, q: float) -> Optional[float]:
@@ -164,10 +188,12 @@ class ServeMetrics:
         # counter a debt); `blocks_shared` is the live gauge of pool
         # blocks referenced by >1 slot (each one a block a
         # private-copy design would hold once PER slot — the
-        # capacity-doubling number); `block_table_fill` is the
-        # mean occupied fraction of live slots' block tables.
+        # capacity-doubling number), counted when it is read from the
+        # source the engine binds (`bind_blocks_shared`);
+        # `block_table_fill` is the mean occupied fraction of live
+        # slots' block tables.
         self.copy_bytes_avoided = 0
-        self.blocks_shared = 0       # gauge, engine-stamped per tick
+        self._blocks_shared_fn = None
         self.block_table_fill = 0.0  # gauge, engine-stamped per tick
         # Tiered-KV-cache telemetry (`serve/kvcache/hosttier.py`; all
         # zero without a host tier): blocks demoted into the tier
@@ -229,6 +255,29 @@ class ServeMetrics:
         self.engine_steps = 0
         self.step_wall_s = 0.0
         self.phase_wall_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        # Where the engine's set-up goes (``SETUP_STAGES``, the same
+        # `_Phase` boundary as the step's), and every backend compile the
+        # engine saw, by the phase or stage open on the compiling thread
+        # (``COMPILE_PLACES``; jax's monitoring events): executables
+        # the backend compiled and their seconds; of those, the ones the
+        # persistent compile cache took in (compiled for longer than its
+        # floor, ``jax_persistent_cache_min_compile_time_secs``: what a
+        # warm cache spares the next process — the rest, small eager
+        # ops, compile in every process); and executables loaded from
+        # that cache instead (not in ``compiles``).
+        # sum(setup_wall_s) + step_wall_s before the first request is
+        # the engine's share of a process's start.
+        self.setup_wall_s: Dict[str, float] = dict.fromkeys(
+            SETUP_STAGES, 0.0)
+        self.compiles: Dict[str, int] = dict.fromkeys(COMPILE_PLACES, 0)
+        self.compile_s: Dict[str, float] = dict.fromkeys(
+            COMPILE_PLACES, 0.0)
+        self.compile_cache_misses: Dict[str, int] = dict.fromkeys(
+            COMPILE_PLACES, 0)
+        self.compile_cache_hits: Dict[str, int] = dict.fromkeys(
+            COMPILE_PLACES, 0)
+        self._compiles_counted = 0
+        self._cache_hit_pending = False
         self.decode_ticks = 0
         self.queue_pops = 0
         self.queue_wait_s = 0.0
@@ -290,6 +339,35 @@ class ServeMetrics:
         mine = self.phase_wall_s
         for phase, w in phase_wall_s.items():
             mine[phase] += w
+
+    def record_compile_cache_hit(self, where: str) -> None:
+        """The persistent compile cache held the executable the backend
+        was asked for under ``where``; the backend's own event for it
+        follows (`record_backend_compile`) and is not a compile."""
+        self.compile_cache_hits[where] += 1
+        self._cache_hit_pending = True
+
+    def record_compile_cache_miss(self, where: str) -> None:
+        """A compile under ``where`` went into the persistent compile
+        cache (it is also one of ``compiles``)."""
+        self.compile_cache_misses[where] += 1
+
+    def record_backend_compile(self, where: str, seconds: float) -> None:
+        """The backend produced one executable under ``where`` in
+        ``seconds``: compiled, or loaded from the persistent cache when
+        `record_compile_cache_hit` came first."""
+        if self._cache_hit_pending:
+            self._cache_hit_pending = False
+            return
+        self.compiles[where] += 1
+        self.compile_s[where] += seconds
+        self._compiles_counted += 1
+
+    @property
+    def compiles_counted(self) -> int:
+        """Every compile counted so far, wherever (the ring record's
+        ``compiles`` is its difference across one step)."""
+        return self._compiles_counted
 
     def record_decode_tick(self) -> None:
         """One decode tick (or speculative verify window) dispatched."""
@@ -440,11 +518,21 @@ class ServeMetrics:
         place instead of gathering it into a slot row."""
         self.copy_bytes_avoided += int(nbytes)
 
-    def record_paged_gauges(self, blocks_shared: int,
-                            block_table_fill: float) -> None:
-        """Per-tick paged sharing/occupancy gauges (engine-stamped)."""
-        self.blocks_shared = int(blocks_shared)
+    def record_paged_gauges(self, block_table_fill: float) -> None:
+        """The per-tick table occupancy gauge (engine-stamped)."""
         self.block_table_fill = float(block_table_fill)
+
+    def bind_blocks_shared(self, count) -> None:
+        """Where ``blocks_shared`` is counted when read: ``count()``
+        returns the blocks more than one live slot references now."""
+        self._blocks_shared_fn = count
+
+    @property
+    def blocks_shared(self) -> int:
+        """Pool blocks referenced by more than one live slot, counted
+        now (0 with no engine bound)."""
+        count = self._blocks_shared_fn
+        return 0 if count is None else int(count())
 
     # ----------------------------------------------------- tiered cache
     def record_host_spill(self, bytes_resident: int) -> None:
@@ -588,6 +676,13 @@ class ServeMetrics:
             "step_wall_s": self.step_wall_s,
             # Labeled series, one sample per phase (closed label set).
             "phase_wall_s": dict(self.phase_wall_s),
+            # Labeled series: one sample per set-up stage, and per phase
+            # or stage for the compiles (closed label sets).
+            "setup_wall_s": dict(self.setup_wall_s),
+            "compiles": dict(self.compiles),
+            "compile_s": dict(self.compile_s),
+            "compile_cache_misses": dict(self.compile_cache_misses),
+            "compile_cache_hits": dict(self.compile_cache_hits),
             "decode_ticks": self.decode_ticks,
             "queue_pops": self.queue_pops,
             "queue_wait_s": self.queue_wait_s,

@@ -26,9 +26,16 @@ The contracts under test:
   (exact against an injected clock), the ring record (handed to the
   tracer's ``on_tick`` as it is), and ``pddl.serve.*`` spans in a
   ``jax.profiler`` trace.
+- **Set-up stages and compiles**: the engine's own set-up
+  (`serve/metrics.SETUP_STAGES`) reaches ``setup_wall_s`` and
+  ``pddl.serve.setup.*`` spans and sums to the wall timed around it from
+  outside; a backend compile is counted under the phase or stage open
+  on its thread (and in the ring record of its step), a persistent-cache
+  load as a hit; the benchmark's readers of those counters.
 """
 
 import json
+import time
 import tracemalloc
 import urllib.request
 
@@ -57,8 +64,15 @@ from pddl_tpu.serve import (
     FaultSpec,
     FinishReason,
     ServeEngine,
+    TenantConfig,
 )
-from pddl_tpu.serve.metrics import PHASES, Reservoir, ServeMetrics
+from pddl_tpu.serve.metrics import (
+    COMPILE_PLACES,
+    PHASES,
+    SETUP_STAGES,
+    Reservoir,
+    ServeMetrics,
+)
 from pddl_tpu.utils.profiling import StepTimer
 from conftest import ref_greedy as _ref_greedy
 
@@ -747,6 +761,220 @@ def test_profiler_trace_holds_the_step_span_tree(gpt_setup, tmp_path):
     assert all(inside(s, by_name["admit"]) for s in requests)
     assert len(by_name["first_token_wait"]) == 2
     assert all(inside(s, requests) for s in by_name["first_token_wait"])
+
+
+# ----------------------------------------------- set-up stages and compiles
+# Engines with each kind of resident program: the plain tick and both
+# chunk widths; the speculative draft/verify pair; the tenant's adapter
+# load; the host tier's promotion and demotion programs.
+_ENGINE_KINDS = {
+    "plain": {},
+    "speculative": {"spec_k": 2},
+    "tenant": {"tenant": TenantConfig()},
+    "host_tier": {"host_tier": 1 << 20},
+}
+
+
+def _engine_stages(eng):
+    """The set-up stages an engine has: ``build``, one ``warmup:<site>``
+    per resident program (`compile_counts()` keys, and the host tier's
+    demotion gather beside its promotion), ``warmup_wait``."""
+    sites = set(eng.compile_counts())
+    if "host_promote" in sites:
+        sites.add("host_demote")
+    return {"build", "warmup_wait"} | {f"warmup:{s}" for s in sites}
+
+
+@pytest.mark.parametrize("kind", sorted(_ENGINE_KINDS))
+def test_setup_stages_sum_to_the_wall_around_them(gpt_setup, kind):
+    """Each stage of an engine's own set-up is timed, above 0, the stages
+    it does not have at 0, every label present; together they are the
+    wall timed from outside around ``ServeEngine(...)`` and ``warmup()``,
+    within 5 %."""
+    model, variables = gpt_setup
+    t = time.perf_counter()
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
+                      **_ENGINE_KINDS[kind])
+    eng.warmup()
+    outside = time.perf_counter() - t
+    wall = eng.metrics.snapshot()["setup_wall_s"]
+    assert tuple(wall) == SETUP_STAGES
+    mine = _engine_stages(eng)
+    assert {s for s, w in wall.items() if w > 0} == mine, wall
+    assert sum(wall.values()) == pytest.approx(outside, rel=0.05)
+    # Warmup runs once: a second call adds nothing.
+    eng.warmup()
+    assert eng.metrics.snapshot()["setup_wall_s"] == wall
+
+
+@pytest.fixture
+def persistent_cache_off():
+    """The persistent compile cache off for one test, so that a program
+    compiled again is a compile and not a load."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def test_a_compile_inside_a_step_lands_in_its_phase(gpt_setup,
+                                                    persistent_cache_off):
+    """``jax.clear_caches()`` between two steps makes the tick compile
+    again: the compile is counted under ``tick_dispatch`` and nowhere
+    else, and that step's ring record carries it; a step that compiles
+    nothing counts 0 there and moves no counter."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16,
+                      telemetry_capacity=64)
+    h = eng.submit(np.arange(6) % 32, 12)
+    for _ in range(3):
+        eng.step()
+    assert h.tokens and not h.done
+    before = eng.metrics.snapshot()
+    eng.step()
+    quiet = eng.metrics.snapshot()
+    assert eng.telemetry.last()["compiles"] == 0
+    for key in ("compiles", "compile_s", "compile_cache_hits"):
+        assert quiet[key] == before[key], key
+    jax.clear_caches()
+    eng.step()
+    after = eng.metrics.snapshot()
+    grew = {p: after["compiles"][p] - quiet["compiles"][p]
+            for p in COMPILE_PLACES
+            if after["compiles"][p] != quiet["compiles"][p]}
+    assert set(grew) == {"tick_dispatch"}, grew
+    assert eng.telemetry.last()["compiles"] == grew["tick_dispatch"] >= 1
+    assert after["compile_s"]["tick_dispatch"] \
+        > quiet["compile_s"]["tick_dispatch"]
+    assert after["compile_cache_hits"] == quiet["compile_cache_hits"]
+    eng.run(max_steps=30)
+    assert h.done and h.tokens == _ref_greedy(
+        model, variables, h.request.prompt, 12)
+
+
+def test_a_warm_cache_loads_what_a_cold_one_compiled(gpt_setup, tmp_path):
+    """Two engines of one shape through a persistent compile cache that
+    starts empty: the first compiles every resident program in its own
+    warmup stage, and the cache takes each in (a miss); the second loads
+    each from the cache (a hit) in the same stage and compiles nothing."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    model, variables = gpt_setup
+    keep = (jax.config.jax_enable_compilation_cache,
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()
+    try:
+        snaps = []
+        for _ in range(2):
+            eng = ServeEngine(model, variables, max_slots=2,
+                              prefill_len=16)
+            eng.warmup()
+            snaps.append(eng.metrics.snapshot())
+    finally:
+        for name, value in zip(("jax_enable_compilation_cache",
+                                "jax_compilation_cache_dir",
+                                "jax_persistent_cache_min_compile_time_secs"),
+                               keep):
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    programs = {f"warmup:{s}" for s in eng.compile_counts()}
+    cold, warm = snaps
+    for stage in programs:
+        assert cold["compiles"][stage] >= 1, stage
+        assert cold["compile_cache_misses"][stage] >= 1, stage
+        assert cold["compile_s"][stage] > 0, stage
+        assert warm["compile_cache_hits"][stage] >= 1, stage
+    assert sum(warm["compiles"].values()) == 0, warm["compiles"]
+    assert sum(warm["compile_cache_misses"].values()) == 0
+    assert sum(cold["compile_cache_hits"].values()) == 0
+
+
+def test_compiles_with_no_engine_phase_open_are_not_counted(gpt_setup,
+                                                            persistent_cache_off):
+    """A compile on the engine's thread between its steps (the caller's
+    own work) is not the engine's."""
+    model, variables = gpt_setup
+    eng = ServeEngine(model, variables, max_slots=2, prefill_len=16)
+    eng.warmup()
+    before = eng.metrics.snapshot()["compiles"]
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    eng.tick_lowering().compile()
+    assert eng.metrics.snapshot()["compiles"] == before
+
+
+def test_profiler_trace_holds_the_setup_spans(gpt_setup, tmp_path):
+    """An engine built and warmed under ``jax.profiler.start_trace``: one
+    ``pddl.serve.setup.<stage>`` span per stage the engine has, one after
+    another, each as long as its ``setup_wall_s``."""
+    model, variables = gpt_setup
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng = ServeEngine(model, variables, max_slots=2, prefill_len=16)
+        eng.warmup()
+    finally:
+        jax.profiler.stop_trace()
+    spans = sorted((s for s in _host_spans(tmp_path)
+                    if s[0].startswith("pddl.serve.setup.")),
+                   key=lambda s: s[1])
+    names = [s[0].removeprefix("pddl.serve.setup.") for s in spans]
+    assert sorted(names) == sorted(_engine_stages(eng))
+    assert names[0] == "build" and names[-1] == "warmup_wait"
+    assert all(a[2] <= b[1] for a, b in zip(spans, spans[1:]))
+    wall = eng.metrics.setup_wall_s
+    for name, (_, start, end, _) in zip(names, spans):
+        assert (end - start) / 1e9 == pytest.approx(wall[name], rel=0.05,
+                                                    abs=2e-4), name
+
+
+# The benchmark's readers of the set-up counters (`chipbench/metrics/`),
+# on a synthetic run: set-up 30 s, of it 12.5 s in the engine's stages and
+# 2.5 s in the steps before the open.
+_SETUP_OBS = {
+    "setup_s": 30.0,
+    "facts": {"counters_open": {
+        "step_wall_s": 2.5,
+        "setup_wall_s": {
+            "build": 1.5, "warmup:chunk_prefill": 4.0,
+            "warmup:sample_first": 1.0, "warmup:tick": 5.5,
+            "warmup_wait": 0.5, "warmup:verify": 0.0},
+        "compile_cache_misses": {"warmup:tick": 1, "warmup:chunk_prefill": 1,
+                                 "first_token_wait": 0, "admit": 1},
+    }},
+}
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("setup_engine_s", 15.0), ("setup_warmup_s", 11.0),
+    ("setup_other_s", 15.0), ("setup_compiles", 3)])
+def test_setup_readers_on_a_synthetic_obs(name, expected):
+    from chipbench.run import metric_reader
+
+    read = metric_reader(name)
+    assert read(_SETUP_OBS) == pytest.approx(expected)
+    # A program without the counters (the parent of this change) has
+    # nothing to read, set-up and steps or not.
+    older = {"setup_s": 30.0, "facts": {"counters_open": {
+        "step_wall_s": 2.5, "phase_wall_s": {"admit": 1.0}}}}
+    assert read(older) is None
+    assert read({"setup_s": 30.0, "facts": {}}) is None
+
+
+def test_setup_engine_and_other_sum_to_setup_s():
+    from chipbench.run import metric_reader
+
+    engine, other = (metric_reader(n)(_SETUP_OBS)
+                     for n in ("setup_engine_s", "setup_other_s"))
+    assert engine + other == _SETUP_OBS["setup_s"]
 
 
 def test_cancelled_mid_admission_is_a_pop_and_no_admission(gpt_setup):

@@ -518,11 +518,13 @@ def test_concurrent_shared_prefix_blocks_shared_in_place(gpt_setup):
         eng.step()
         shared_seen = max(shared_seen, eng.blocks_shared)
         fill_seen = max(fill_seen, eng.block_table_fill)
+        # The metrics' gauge is counted when read, from the same tables.
+        assert eng.metrics.snapshot()["blocks_shared"] == eng.blocks_shared
     for h, v in zip(hs, variants):
         assert h.tokens == _ref_greedy(model, variables, v, 6)
     assert shared_seen >= 1          # the warm block was referenced >1x
     assert 0.0 < fill_seen <= 1.0
-    assert eng.metrics.blocks_shared >= 0  # gauge stamped per tick
+    assert eng.metrics.blocks_shared == eng.blocks_shared == 0
     assert eng.metrics.copy_bytes_avoided > 0
 
 
