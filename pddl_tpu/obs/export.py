@@ -142,6 +142,10 @@ SERVE_COUNTER_KEYS = frozenset({
     # compile cache took in, and those it held instead.
     "setup_wall_s", "compiles", "compile_s", "compile_cache_misses",
     "compile_cache_hits",
+    # Routed expert layers traced, by the path their FFN took (the
+    # Pallas kernel or the loop) and the phase or stage open (a labeled
+    # counter over the same places as the compiles).
+    "moe_kernel_layers", "moe_loop_layers",
     # What prefill cost in tokens: prompt tokens installed, and chunk
     # programs dispatched by compiled width (a labeled counter).
     "prefill_tokens", "prefill_chunks", "latent_expanded_tokens",

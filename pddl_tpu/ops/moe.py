@@ -31,15 +31,20 @@ computes the routed pairs and at most ``num_experts`` tiles of padding
 over them, builds nothing ``[B, S, N, S]``-shaped (a 12k-token prefill
 chunk is a 74k-row sort, not a 9.6-billion-entry dispatch tensor), and a
 decode step reads the weights of the experts its rows hit and no others.
+On a TPU the gated experts' tiles run as one Pallas kernel a layer
+(:func:`_expert_ffn_body`), each hit expert's weights streamed through
+VMEM once while the MXU works on the blocks before them.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.experimental import pallas as pl
 
 # Cache-collection leaves a serving MoE layer accumulates into when the
 # paged engine put them there (`Llama.paged_cache_extras`): per-expert
@@ -50,21 +55,64 @@ VALID_LEN_KEY = "valid_len"
 
 EXPERT_ACTS = {"gelu": nn.gelu, "swiglu": nn.silu, "reglu": nn.relu}
 
+# What `grouped_expert_ffn` reports at trace time, one event a routed
+# layer, by the path its FFN took (`jax.monitoring`; the serving engine
+# counts them under the set-up stage or step phase open on the thread,
+# `ServeMetrics.moe_kernel_layers` / `moe_loop_layers`).
+KERNEL_LAYER_EVENT = "/pddl_tpu/moe/kernel_layer"
+LOOP_LAYER_EVENT = "/pddl_tpu/moe/loop_layer"
+
+# The expert kernel moves rows between HBM and VMEM in pieces of this
+# many, the height of a bf16 tile of the HBM layout: a copy must start
+# at a multiple of it.
+ROW_ALIGN = 16
+
 
 def expert_tile(pairs: int, num_experts: int) -> int:
-    """Rows per tile of :func:`grouped_expert_ffn`: about one expert's
-    even share of the pairs, a power of two in [16, 512]. One tile reads
-    its expert's weights once, so a tile should hold the expert's whole
-    run where it can (a prefill chunk is bound by those reads until a
-    run is some 256 rows long); 16 rows is the narrowest bf16 tile, which
-    is what a decode step's one or two rows per expert pad to."""
+    """Rows per tile of :func:`grouped_expert_ffn`'s loop: about one
+    expert's even share of the pairs, a power of two in [16, 512]. One
+    tile reads its expert's weights once, so a tile should hold the
+    expert's whole run where it can (a prefill chunk is bound by those
+    reads until a run is some 256 rows long); 16 rows is the narrowest
+    bf16 tile, which is what a decode step's one or two rows per expert
+    pad to."""
     share = -(-pairs // num_experts)
     return min(512, max(16, 1 << (share - 1).bit_length()))
 
 
+def _kernel_rows(pairs: int, num_experts: int, d: int,
+                 itemsize: int) -> tuple:
+    """``(cap, sub)`` of the expert kernel: a tile holds up to ``cap``
+    rows of one expert's run (eight times :func:`expert_tile`, so a run
+    of several times the even share still reads its expert's weights
+    once; at most 1,024 rows and what 40 MiB of VMEM holds of the rows'
+    three buffers and float32 sums), computed ``sub`` rows at a time
+    (the MXU's 128, or the tile where it is narrower: 16 in a decode
+    step, whose runs are a few rows)."""
+    tile = expert_tile(pairs, num_experts)
+    sub = min(128, tile)
+    fits = (40 << 20) // (d * (3 * itemsize + 4)) // sub * sub
+    return max(sub, min(1024, 8 * tile, fits)), sub
+
+
+def _h_block(d: int, h: int, itemsize: int) -> int:
+    """Hidden columns a grid step of the expert kernel streams: the
+    widest multiple of 128 that divides ``h`` with the three weight
+    blocks (``d x hb`` gate and up, ``hb x d`` down) within 8 MiB, so
+    two steps' blocks sit double-buffered in VMEM; ``h`` itself where it
+    is no multiple of 128."""
+    if h % 128:
+        return h
+    fits = [hb for hb in range(128, h + 1, 128)
+            if h % hb == 0 and 3 * d * hb * itemsize <= 8 << 20]
+    return max(fits, default=128)
+
+
 def grouped_expert_ffn(x, expert_index, gates, w_in, w_out, *,
                        act: str, w_gate=None, b_in=None, b_out=None,
-                       tile: Optional[int] = None):
+                       tile: Optional[int] = None,
+                       kernel: Optional[bool] = None,
+                       interpret: Optional[bool] = None):
     """Dropless routed FFN, token-major: ``y[t] = sum_j gates[t, j] *
     FFN_{expert_index[t, j]}(x[t])``.
 
@@ -74,16 +122,18 @@ def grouped_expert_ffn(x, expert_index, gates, w_in, w_out, *,
     GELU expert ``b_in [N, h]`` / ``b_out [N, d]``.
 
     The ``P = T k`` pairs are sorted by expert (stable, so a token's
-    pairs keep their order). Expert ``e``'s run of ``c_e`` rows is cut
-    into ``ceil(c_e / tile)`` tiles; one loop iteration gathers a tile's
-    rows of ``x``, multiplies them through expert ``e``'s weights and
-    writes the result at the run's offset. A run's last tile reaches
-    into the next expert's rows; that expert's own tile comes later in
-    the loop and overwrites them, so no mask and no padded copy of ``x``
-    is needed. Rows computed: ``sum_e ceil(c_e / tile) * tile <= P + N *
-    (tile - 1)``, whatever the routing (every token to one expert
-    included); experts with no pair cost nothing, not even a read of
-    their weights.
+    pairs keep their order), each expert's run of rows is cut into
+    tiles and goes through its expert's weights, and every token
+    gathers its ``k`` rows back. On a TPU a gated, bias-free expert runs
+    as one Pallas kernel (:func:`_expert_kernel`; ``kernel=None``
+    selects it there, ``interpret`` as the backend says); anywhere
+    else, and for the biased GELU expert, as a loop of one tile a step
+    (:func:`_expert_loop`). Either way every pair is computed, whatever
+    the routing (every token to one expert included), and an expert
+    with no pair costs nothing, not even a read of its weights.
+    ``tile`` overrides the rows of a tile (:func:`expert_tile` for the
+    loop, :func:`_kernel_rows`' cap for the kernel, where it is a
+    multiple of 16).
     """
     # Weights may arrive as host arrays (an imported checkpoint): the
     # loop indexes them with a traced expert.
@@ -94,33 +144,93 @@ def grouped_expert_ffn(x, expert_index, gates, w_in, w_out, *,
     k = expert_index.shape[1]
     n = w_in.shape[0]
     pairs = t * k
-    tile = expert_tile(pairs, n) if tile is None else int(tile)
+    if kernel is None:
+        kernel = (jax.default_backend() == "tpu" and w_gate is not None
+                  and b_in is None and b_out is None)
+    jax.monitoring.record_event(
+        KERNEL_LAYER_EVENT if kernel else LOOP_LAYER_EVENT)
+    if kernel:
+        itemsize = max(x.dtype.itemsize, w_in.dtype.itemsize)
+        cap, sub = _kernel_rows(pairs, n, d, itemsize)
+        if tile is not None:
+            cap = sub = int(tile)
+            if cap % ROW_ALIGN:
+                raise ValueError(f"the expert kernel's tile must be a "
+                                 f"multiple of {ROW_ALIGN}, got {cap}")
+    else:
+        cap = expert_tile(pairs, n) if tile is None else int(tile)
+    with jax.named_scope("moe_dispatch"):
+        order, tiles = _sort_pairs(expert_index, n, cap)
+        tok_sorted = order // k   # pair p is token p // k's
+    if kernel:
+        y_sorted = _expert_kernel(
+            x, tok_sorted, tiles, w_gate, w_in, w_out, act=act, cap=cap,
+            sub=sub, interpret=(jax.default_backend() != "tpu"
+                                if interpret is None else interpret))
+    else:
+        y_sorted = _expert_loop(x, tok_sorted, tiles, w_in, w_out, w_gate,
+                                b_in, b_out, act=act, tile=cap)
+    with jax.named_scope("moe_combine"):
+        # Where pair p landed in the sorted order: the inverse of `order`.
+        _, where = jax.lax.sort_key_val(
+            order, jnp.arange(pairs, dtype=jnp.int32))
+        where = where.reshape(t, k)
+        gates = gates.astype(jnp.float32)
+        # One choice at a time: a [T, k, d] float32 gather would be the
+        # layer's largest temporary.
+        out = sum(y_sorted[where[:, j]].astype(jnp.float32)
+                  * gates[:, j:j + 1] for j in range(k))
+        return out.astype(x.dtype)
+
+
+def _sort_pairs(expert_index, n: int, tile: int):
+    """The ``(token, choice)`` pairs sorted by expert, ``order``, and
+    each expert's run of ``c_e`` rows cut into ``ceil(c_e / tile)``
+    tiles: ``(expert, first row, rows)`` of each tile in row order and
+    the number of tiles. The tables have room for the most tiles any
+    routing makes (``c`` rows make at most ``(c + tile - 1) / tile``
+    tiles, over at most ``min(N, P)`` hit experts); their entries past
+    the last real tile repeat it."""
+    pairs = expert_index.size
+    flat = expert_index.reshape(pairs).astype(jnp.int32)
+    sorted_e, order = jax.lax.sort_key_val(
+        flat, jnp.arange(pairs, dtype=jnp.int32))
+    bounds = jnp.searchsorted(
+        sorted_e, jnp.arange(n + 1, dtype=jnp.int32),
+        method="compare_all").astype(jnp.int32)
+    starts, counts = bounds[:-1], bounds[1:] - bounds[:-1]
+    tiles_per = (counts + tile - 1) // tile
+    tile_cum = jnp.cumsum(tiles_per)
+    ntiles = tile_cum[-1].astype(jnp.int32)
+    room = (pairs + min(n, pairs) * (tile - 1)) // tile
+    ids = jnp.minimum(jnp.arange(room, dtype=jnp.int32), ntiles - 1)
+    tile_e = jnp.searchsorted(tile_cum, ids, side="right",
+                              method="compare_all").astype(jnp.int32)
+    first = (ids - (tile_cum[tile_e] - tiles_per[tile_e])) * tile
+    return order, (tile_e, (starts[tile_e] + first).astype(jnp.int32),
+                   jnp.minimum(tile, counts[tile_e] - first).astype(
+                       jnp.int32), ntiles)
+
+
+def _expert_loop(x, tok_sorted, tiles, w_in, w_out, w_gate, b_in, b_out, *,
+                 act: str, tile: int):
+    """The sorted pairs' FFN rows ``[P + tile, d]`` as a loop of one tile
+    a step: an iteration gathers a tile's rows of ``x``, multiplies them
+    through its expert's weights and writes the result at the tile's
+    first row. A run's last tile reaches into the next expert's rows;
+    that expert's own tile comes later in the loop and overwrites them,
+    so no mask and no padded copy of ``x`` is needed. Rows computed:
+    ``sum_e ceil(c_e / tile) * tile <= P + N * (tile - 1)``."""
+    tile_e, tile_row, _, ntiles = tiles
     fn = EXPERT_ACTS[act]
     with jax.named_scope("moe_dispatch"):
-        flat = expert_index.reshape(pairs).astype(jnp.int32)
-        sorted_e, order = jax.lax.sort_key_val(
-            flat, jnp.arange(pairs, dtype=jnp.int32))
-        # Pair p of token p // k; `tile` rows of padding so a run's last
-        # tile may read past the end.
+        # `tile` rows of padding so a run's last tile may read past the
+        # end.
         tok_sorted = jnp.concatenate(
-            [order // k, jnp.zeros((tile,), jnp.int32)])
-        bounds = jnp.searchsorted(
-            sorted_e, jnp.arange(n + 1, dtype=jnp.int32),
-            method="compare_all").astype(jnp.int32)
-        starts, counts = bounds[:-1], bounds[1:] - bounds[:-1]
-        tiles_per = (counts + tile - 1) // tile
-        tile_cum = jnp.cumsum(tiles_per)
-        max_tiles = -(-pairs // tile) + n
-        ids = jnp.arange(max_tiles, dtype=jnp.int32)
-        tile_e = jnp.minimum(
-            jnp.searchsorted(tile_cum, ids, side="right",
-                             method="compare_all"), n - 1
-        ).astype(jnp.int32)
-        tile_row0 = starts[tile_e] + (
-            ids - (tile_cum[tile_e] - tiles_per[tile_e])) * tile
+            [tok_sorted, jnp.zeros((tile,), jnp.int32)])
 
     def body(i, y_sorted):
-        e, r0 = tile_e[i], tile_row0[i]
+        e, r0 = tile_e[i], tile_row[i]
         xs = x[jax.lax.dynamic_slice(tok_sorted, (r0,), (tile,))]
         up = xs @ w_in[e]
         if b_in is not None:
@@ -133,19 +243,227 @@ def grouped_expert_ffn(x, expert_index, gates, w_in, w_out, *,
                                             (r0, 0))
 
     with jax.named_scope("moe_ffn"):
-        y_sorted = jax.lax.fori_loop(
-            0, tile_cum[-1], body, jnp.zeros((pairs + tile, d), x.dtype))
-    with jax.named_scope("moe_combine"):
-        # Where pair p landed in the sorted order: the inverse of `order`.
-        _, where = jax.lax.sort_key_val(
-            order, jnp.arange(pairs, dtype=jnp.int32))
-        where = where.reshape(t, k)
-        gates = gates.astype(jnp.float32)
-        # One choice at a time: a [T, k, d] float32 gather would be the
-        # layer's largest temporary.
-        out = sum(y_sorted[where[:, j]].astype(jnp.float32)
-                  * gates[:, j:j + 1] for j in range(k))
-        return out.astype(x.dtype)
+        return jax.lax.fori_loop(
+            0, ntiles, body,
+            jnp.zeros((tok_sorted.shape[0], x.shape[1]), x.dtype))
+
+
+def _expert_kernel(x, tok_sorted, tiles, w_gate, w_in, w_out, *, act: str,
+                   cap: int, sub: int, interpret: bool):
+    """The sorted pairs' FFN rows ``[P16, d]`` (``P`` rounded up to a
+    multiple of ``ROW_ALIGN``) through :func:`_expert_ffn_call`: the rows
+    of ``x`` gathered once, in sorted order, then one kernel call."""
+    d = x.shape[1]
+    n, _, h = w_in.shape
+    rows = -(-tok_sorted.shape[0] // ROW_ALIGN) * ROW_ALIGN
+    with jax.named_scope("moe_dispatch"):
+        x_rows = x[jnp.concatenate(
+            [tok_sorted,
+             jnp.zeros((rows - tok_sorted.shape[0],), jnp.int32)])]
+    tile_e, tile_row, tile_rows, ntiles = tiles
+    with jax.named_scope("moe_ffn"):
+        y = _expert_ffn_call(
+            tile_e, tile_row, tile_rows, ntiles.reshape(1),
+            x_rows.reshape(rows // ROW_ALIGN, ROW_ALIGN, d), w_gate, w_in,
+            w_out, act=act, cap=cap, sub=sub,
+            hb=_h_block(d, h, max(x.dtype.itemsize, w_in.dtype.itemsize)),
+            interpret=interpret)
+    return y.reshape(rows, d)
+
+
+def _expert_ffn_body(tile_e_ref, tile_row_ref, tile_rows_ref, ntiles_ref,
+                     x_hbm, wg_ref, wi_ref, wo_ref, y_hbm,
+                     xbuf, obuf, acc_ref, carry_ref, x_sem, y_sem,
+                     pending_ref, *, act: str, sub: int, mid):
+    """One grid step ``(i, j)``: tile ``i``'s rows through hidden block
+    ``j`` of its expert's weights (the pipeline's blocks ``wg_ref``,
+    ``wi_ref``, ``wo_ref``, the next step's in flight meanwhile).
+
+    Rows move by hand, in pieces of ``ROW_ALIGN`` (the HBM tiling): a
+    tile's rows ``[s, s + c)`` arrive as the pieces that cover them, into
+    ``xbuf[i % 2]`` while tile ``i - 1`` computes (started at its first
+    step, waited for at ``i``'s), and the rows before ``s`` in the first
+    piece, the previous tile's, are computed too and thrown away. The
+    finished pieces leave from ``obuf`` while the next tile computes
+    (waited for before ``obuf`` is written again, and at the grid's last
+    step). A piece two tiles share is written by the later one: the
+    earlier keeps its rows in ``carry_ref``, the later takes them from
+    there. ``acc_ref`` keeps the down projection's float32 sums over the
+    hidden blocks; the gate and up products are rounded to ``mid``, the
+    dtype the loop's matmuls give, and the rows once, at the last
+    block."""
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    i, j = pl.program_id(0), pl.program_id(1)
+    last_i, last_j = pl.num_programs(0) - 1, pl.num_programs(1) - 1
+    ntiles = ntiles_ref[0]
+    live = i < ntiles
+    side = i % 2
+
+    def span(tile):
+        """(first piece, pieces) of a tile's rows."""
+        s = tile_row_ref[tile]
+        first = s // ROW_ALIGN
+        return first, pl.cdiv(s + tile_rows_ref[tile], ROW_ALIGN) - first
+
+    def copies(first, pieces, make, start):
+        def one(p, carry):
+            copy = make(first + p, pl.ds(pl.multiple_of(p * ROW_ALIGN,
+                                                        ROW_ALIGN), ROW_ALIGN))
+            copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, pieces, one, None)
+
+    def rows_in(tile, to, start):
+        copies(*span(tile),
+               lambda src, dst: pltpu.make_async_copy(
+                   x_hbm.at[src], xbuf.at[to, dst], x_sem.at[to]), start)
+
+    def rows_out(first, pieces, start):
+        copies(first, pieces,
+               lambda dst, src: pltpu.make_async_copy(
+                   obuf.at[src], y_hbm.at[dst], y_sem.at[0]), start)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _first():
+        pending_ref[0] = 0
+        rows_in(0, 0, start=True)
+
+    @pl.when(jnp.logical_and(live, j == 0))
+    def _rows_arrive():
+        rows_in(i, side, start=False)
+
+        @pl.when(i + 1 < ntiles)
+        def _next():
+            rows_in(i + 1, 1 - side, start=True)
+
+    @pl.when(jnp.logical_and(live, j == last_j))
+    def _obuf_free():
+        rows_out(0, pending_ref[0], start=False)
+
+    lead = tile_row_ref[i] % ROW_ALIGN   # the previous tile's rows
+
+    @pl.when(live)
+    def _compute():
+        f32 = jnp.float32
+
+        def block(s, carry):
+            r = pl.ds(pl.multiple_of(s * sub, sub), sub)
+            xs = xbuf[side, r, :]
+            gate = jnp.dot(xs, wg_ref[...], preferred_element_type=f32)
+            up = jnp.dot(xs, wi_ref[...], preferred_element_type=f32)
+            gate = EXPERT_ACTS[act](gate.astype(mid).astype(f32))
+            hid = (gate * up.astype(mid).astype(f32)).astype(mid)
+            part = jnp.dot(hid, wo_ref[...], preferred_element_type=f32)
+
+            @pl.when(j == 0)
+            def _open():
+                acc_ref[r, :] = part
+
+            @pl.when(jnp.logical_and(j > 0, j < last_j))
+            def _add():
+                acc_ref[r, :] += part
+
+            @pl.when(j == last_j)
+            def _close():
+                total = part if last_j == 0 else acc_ref[r, :] + part
+                obuf[r, :] = total.astype(obuf.dtype)
+
+            return carry
+
+        jax.lax.fori_loop(
+            0, pl.cdiv(lead + tile_rows_ref[i], sub), block, None)
+
+    @pl.when(jnp.logical_and(live, j == last_j))
+    def _rows_leave():
+        first, pieces = span(i)
+        head = pl.ds(0, ROW_ALIGN)
+
+        @pl.when(lead > 0)
+        def _take_carry():
+            row = jax.lax.broadcasted_iota(jnp.int32, carry_ref.shape, 0)
+            obuf[head, :] = jnp.where(
+                row < lead, carry_ref[...].astype(jnp.float32),
+                obuf[head, :].astype(jnp.float32)).astype(obuf.dtype)
+
+        # The last piece, where the next tile starts inside it, waits
+        # for that tile; the grid's last tile writes it.
+        ends_inside = jnp.logical_and(
+            (tile_row_ref[i] + tile_rows_ref[i]) % ROW_ALIGN > 0,
+            i < ntiles - 1)
+        whole = pieces - ends_inside.astype(jnp.int32)
+
+        @pl.when(ends_inside)
+        def _keep_carry():
+            carry_ref[...] = obuf[pl.ds(pl.multiple_of(
+                whole * ROW_ALIGN, ROW_ALIGN), ROW_ALIGN), :]
+
+        rows_out(first, whole, start=True)
+        pending_ref[0] = whole
+
+    @pl.when(jnp.logical_and(i == last_i, j == last_j))
+    def _drain():
+        rows_out(0, pending_ref[0], start=False)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "act", "cap", "sub", "hb", "interpret"))
+def _expert_ffn_call(tile_e, tile_row, tile_rows, ntiles, x_rows, w_gate,
+                     w_in, w_out, *, act: str, cap: int, sub: int, hb: int,
+                     interpret: bool):
+    """The ``pallas_call`` of the expert kernel: grid ``(tiles, h //
+    hb)``, the weight blocks picked by the scalar-prefetched ``tile_e``
+    (a step past the last real tile maps to that tile's last block, so
+    nothing is fetched for it), ``x_rows`` and the result ``[P16 / 16,
+    16, d]`` left in HBM and moved by :func:`_expert_ffn_body`. A ``jit``
+    of its own so that jax traces and lowers the kernel once a shape, not
+    once a layer."""
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    n, d, h = w_in.shape
+    nj = h // hb
+    mid = jnp.result_type(x_rows.dtype, w_in.dtype)
+
+    def gate_up(i, j, tile_e, tile_row, tile_rows, ntiles):
+        return tile_e[i], 0, jnp.where(i < ntiles[0], j, nj - 1)
+
+    def down(i, j, tile_e, tile_row, tile_rows, ntiles):
+        return tile_e[i], jnp.where(i < ntiles[0], j, nj - 1), 0
+
+    # A tile's rows and the previous tile's in its first piece, rounded
+    # up to whole blocks of `sub`.
+    span = cap + sub
+    wsize, xsize = w_in.dtype.itemsize, x_rows.dtype.itemsize
+    vmem = (2 * 3 * d * hb * wsize + 3 * span * d * xsize + span * d * 4
+            + sub * (2 * hb + d) * 4)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(tile_e.shape[0], nj),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((None, d, hb), gate_up),
+                  pl.BlockSpec((None, d, hb), gate_up),
+                  pl.BlockSpec((None, hb, d), down)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, d), x_rows.dtype),  # rows in, two tiles
+            pltpu.VMEM((span, d), x_rows.dtype),     # rows out
+            pltpu.VMEM((span, d), jnp.float32),      # sums over blocks
+            pltpu.VMEM((ROW_ALIGN, d), x_rows.dtype),  # a shared piece
+            pltpu.SemaphoreType.DMA((2,)),           # one a rows-in buffer
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SMEM((1,), jnp.int32),             # row pieces in flight
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_expert_ffn_body, act=act, sub=sub, mid=mid),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(x_rows.shape, x_rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(vmem * 1.25) + (8 << 20)),
+        interpret=interpret,
+    )(tile_e, tile_row, tile_rows, ntiles, x_rows, w_gate, w_in, w_out)
 
 
 class SwitchFFN(nn.Module):

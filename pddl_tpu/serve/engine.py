@@ -174,6 +174,7 @@ from pddl_tpu.models.gpt import (
 from pddl_tpu.models.speculative import ngram_drafts
 from pddl_tpu.obs.ring import TelemetryRing
 from pddl_tpu.ops.attention import cache_blocks_gather, cache_blocks_scatter
+from pddl_tpu.ops import moe
 from pddl_tpu.ops.lora import adapter_pool_load, batched_lora_delta
 from pddl_tpu.obs.trace import NULL_TRACER
 from pddl_tpu.serve import drain as drain_io
@@ -265,7 +266,8 @@ _SPAN_PREFIX = "pddl.serve."
 
 class _OpenPhase(threading.local):
     """The engine phase or set-up stage open on this thread, where a
-    backend compile on it is counted (``None``: not the engine's)."""
+    backend compile on it, and a routed expert layer traced on it, is
+    counted (``None``: not the engine's)."""
 
     phase = None
 
@@ -292,6 +294,9 @@ def _on_event(event: str, **_) -> None:
         phase.metrics.record_compile_cache_hit(phase.name)
     elif event == _CACHE_MISS_EVENT:
         phase.metrics.record_compile_cache_miss(phase.name)
+    elif event in (moe.KERNEL_LAYER_EVENT, moe.LOOP_LAYER_EVENT):
+        phase.metrics.record_moe_layer(
+            phase.name, kernel=event == moe.KERNEL_LAYER_EVENT)
 
 
 def _listen_for_compiles() -> None:

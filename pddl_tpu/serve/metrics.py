@@ -278,6 +278,15 @@ class ServeMetrics:
             COMPILE_PLACES, 0)
         self._compiles_counted = 0
         self._cache_hit_pending = False
+        # Routed expert layers traced, by the path their FFN took
+        # (`ops/moe.grouped_expert_ffn`: the Pallas kernel, or the loop
+        # of one tile a step) and by the phase or stage open on the
+        # tracing thread (``COMPILE_PLACES``): counted once a layer each
+        # time a program is traced, never a step.
+        self.moe_kernel_layers: Dict[str, int] = dict.fromkeys(
+            COMPILE_PLACES, 0)
+        self.moe_loop_layers: Dict[str, int] = dict.fromkeys(
+            COMPILE_PLACES, 0)
         self.decode_ticks = 0
         self.queue_pops = 0
         self.queue_wait_s = 0.0
@@ -362,6 +371,12 @@ class ServeMetrics:
         self.compiles[where] += 1
         self.compile_s[where] += seconds
         self._compiles_counted += 1
+
+    def record_moe_layer(self, where: str, kernel: bool) -> None:
+        """A routed expert layer was traced under ``where``; its FFN
+        runs as the expert kernel (``kernel``) or as the loop."""
+        (self.moe_kernel_layers if kernel else self.moe_loop_layers)[
+            where] += 1
 
     @property
     def compiles_counted(self) -> int:
@@ -683,6 +698,10 @@ class ServeMetrics:
             "compile_s": dict(self.compile_s),
             "compile_cache_misses": dict(self.compile_cache_misses),
             "compile_cache_hits": dict(self.compile_cache_hits),
+            # Labeled series: routed layers traced by their FFN's path,
+            # per phase or stage (closed label set).
+            "moe_kernel_layers": dict(self.moe_kernel_layers),
+            "moe_loop_layers": dict(self.moe_loop_layers),
             "decode_ticks": self.decode_ticks,
             "queue_pops": self.queue_pops,
             "queue_wait_s": self.queue_wait_s,

@@ -114,25 +114,19 @@ def test_program_and_reference_route_alike(weights):
 
 
 # ------------------------------------------------------- the expert path
-def per_token_loop(x, index, gates, w1, w3, w2):
+def per_token_loop(x, index, gates, w1, w3, w2, act="reglu"):
+    gate = (lambda v: np.maximum(v, 0.0)) if act == "reglu" \
+        else (lambda v: v / (1.0 + np.exp(-v)))
     out = np.zeros_like(x)
     for t in range(x.shape[0]):
         for j in range(index.shape[1]):
             e = index[t, j]
-            hid = np.maximum(x[t] @ w1[e], 0.0) * (x[t] @ w3[e])
+            hid = gate(x[t] @ w1[e]) * (x[t] @ w3[e])
             out[t] += gates[t, j] * (hid @ w2[e])
     return out
 
 
-@pytest.mark.parametrize("routing", ["even", "all_to_one_expert", "drawn"])
-@pytest.mark.parametrize("tokens,tile", [(37, None), (37, 16), (8, None)])
-def test_grouped_expert_ffn_is_dropless(routing, tokens, tile):
-    """The token-major serving path against a loop over tokens and their
-    choices: under even routing, with EVERY token sent to one expert (a
-    capacity would drop all but a few), and drawn at random; at the
-    default tile, at a tile smaller than an expert's run (several tiles
-    an expert), and at decode size."""
-    n, k, d, h = 8, 2, 24, 16
+def expert_case(routing, tokens, n=8, k=2, d=24, h=16):
     rng = np.random.RandomState(0)
     x = rng.randn(tokens, d).astype(np.float32)
     w1, w3 = rng.randn(2, n, d, h).astype(np.float32) * 0.2
@@ -145,13 +139,51 @@ def test_grouped_expert_ffn_is_dropless(routing, tokens, tile):
     else:
         index = np.stack([rng.permutation(n)[:k] for _ in range(tokens)])
     gates = rng.rand(tokens, k).astype(np.float32)
+    return x, index, gates, w1, w3, w2
+
+
+@pytest.mark.parametrize("path", ["loop", "kernel"])
+@pytest.mark.parametrize("routing", ["even", "all_to_one_expert", "drawn"])
+@pytest.mark.parametrize("tokens,tile", [(37, None), (37, 16), (8, None)])
+def test_grouped_expert_ffn_is_dropless(routing, tokens, tile, path):
+    """The token-major serving path, as the loop and as the Pallas
+    kernel (interpret mode), against a loop over tokens and their
+    choices: under even routing, with EVERY token sent to one expert (a
+    capacity would drop all but a few; six experts get no row), and
+    drawn at random (with SwiGLU experts); at the default tile, at a
+    tile smaller than an expert's run (several tiles an expert), and at
+    decode size (two rows an expert: eight runs share one 16-row piece
+    of the kernel's copies)."""
+    x, index, gates, w1, w3, w2 = expert_case(routing, tokens)
+    act = "swiglu" if routing == "drawn" else "reglu"
     got = moe.grouped_expert_ffn(
         jnp.asarray(x), jnp.asarray(index, jnp.int32), jnp.asarray(gates),
-        jnp.asarray(w3), jnp.asarray(w2), act="reglu",
-        w_gate=jnp.asarray(w1), tile=tile)
+        jnp.asarray(w3), jnp.asarray(w2), act=act,
+        w_gate=jnp.asarray(w1), tile=tile, kernel=path == "kernel",
+        interpret=True)
     np.testing.assert_allclose(
-        np.asarray(got), per_token_loop(x, index, gates, w1, w3, w2),
+        np.asarray(got), per_token_loop(x, index, gates, w1, w3, w2, act),
         rtol=2e-5, atol=2e-5)
+
+
+def test_expert_kernel_matches_the_loop_in_bfloat16(monkeypatch):
+    """bf16 rows and weights, the hidden width streamed in three blocks
+    (the down projection's float32 sums carried across them): the
+    kernel agrees with the loop to bf16 rounding (the loop rounds the
+    SiLU and its product where the kernel keeps float32, so a row may
+    differ by an ulp or two), far under what a dropped or misplaced
+    block would move."""
+    monkeypatch.setattr(moe, "_h_block", lambda d, h, itemsize: 128)
+    x, index, gates, w1, w3, w2 = expert_case("drawn", 40, h=384)
+    args = [jnp.asarray(a, jnp.bfloat16) for a in (x, w3, w2, w1)]
+    run = lambda path: np.asarray(moe.grouped_expert_ffn(
+        args[0], jnp.asarray(index, jnp.int32), jnp.asarray(gates),
+        args[1], args[2], act="swiglu", w_gate=args[3],
+        kernel=path == "kernel", interpret=True), np.float32)
+    loop, kernel = run("loop"), run("kernel")
+    scale = np.abs(loop).max()
+    assert np.abs(kernel - loop).max() < 0.02 * scale
+    assert np.linalg.norm(kernel - loop) < 0.01 * np.linalg.norm(loop)
 
 
 def test_expert_tile_bounds_the_padding():

@@ -70,6 +70,42 @@ def _mosaic_calls(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def _kernels_by_name(text):
+    """The Mosaic kernel calls of a compiled module, by kernel: the paged
+    attention kernel's instructions are named after their scope
+    (``attn_window``, ``attn_global``, ``attn_latent``), the expert
+    kernel's after its ``jit`` (``_expert_ffn_call``). Each maps its
+    instruction name to the ``op_name`` the benchmark's scope table
+    reads (`serve_paged_moe_lm._INSTR`)."""
+    from chipbench.systems.serve_paged_moe_lm import _INSTR
+
+    found = {"attention": {}, "expert": {}, "other": {}}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name, op_name = _INSTR.match(line).groups()
+        kind = ("attention" if name.startswith("attn_") else "expert"
+                if name.startswith("_expert_ffn_call") else "other")
+        found[kind][name] = op_name
+    return found
+
+
+def _assert_expert_kernels(text, routed_layers):
+    """One expert kernel a routed layer, under the scope ``moe_ffn`` as
+    the scope table reads it, no other kernel but attention's, and no
+    loop left under ``moe_ffn``."""
+    from chipbench.systems.serve_paged_moe_lm import scope_of
+
+    kernels = _kernels_by_name(text)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    assert len(kernels["expert"]) == routed_layers, kernels["expert"]
+    assert {scope_of(module, op) for op in kernels["expert"].values()} \
+        == {"moe_ffn"}, kernels["expert"]
+    assert not kernels["other"], kernels["other"]
+    assert "/moe_ffn/while" not in text
+    return kernels
+
+
 def _makes(text, shape):
     """The instructions of a compiled module whose RESULT has ``shape``
     (``bf16[3073,20,16,128]``), parameters apart: a pool-shaped one is a
@@ -330,8 +366,11 @@ def test_smallthinker_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         text = compiled.as_text()
         header = text.split("\n", 1)[0]
         assert f"jit_{name}" in header
-        # One Mosaic call a layer in the tick (window and NoPE-global).
-        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
+        # One attention kernel call a layer in the tick (window and
+        # NoPE-global), one expert kernel call a layer in both.
+        kernels = _assert_expert_kernels(text, routed_layers=2)
+        assert len(kernels["attention"]) == (
+            2 if name == "_tick_paged" else 0)
         shape = r"\(?" + re.escape(pool_shape)
         moved = [line.strip()[:160] for line in text.splitlines()
                  if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
@@ -396,7 +435,9 @@ def test_glm_flash_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         text = compiled.as_text()
         header = text.split("\n", 1)[0]
         assert f"jit_{name}" in header
-        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
+        kernels = _assert_expert_kernels(text, routed_layers=1)
+        assert len(kernels["attention"]) == (
+            2 if name == "_tick_paged" else 0)
         shape = r"\(?" + re.escape(pool_shape)
         moved = [line.strip()[:160] for line in text.splitlines()
                  if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
@@ -480,7 +521,10 @@ def test_lfm2_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         text = compiled.as_text()
         header = text.split("\n", 1)[0]
         assert f"jit_{name}" in header
-        assert len(_mosaic_calls(text)) == (2 if name == "_tick_paged" else 0)
+        kernels = _assert_expert_kernels(
+            text, routed_layers=sum(model.moe_layout))   # 8 of 9
+        assert len(kernels["attention"]) == (
+            2 if name == "_tick_paged" else 0)
         shape = r"\(?" + re.escape(pool_shape)
         moved = [line.strip()[:160] for line in text.splitlines()
                  if re.search(rf"= {shape}\S* (copy|copy-start|copy-done)\(",
@@ -496,8 +540,14 @@ def test_lfm2_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         # probabilities its threshold search reads pass after pass.
         sampler_row = (eng_cfg["max_slots"] * cfg["vocab_size"] * 4
                        if name == "_tick_paged" else 0)
+        # With the expert kernel the chunk's peak no longer holds what
+        # the loop's schedule kept live there: four routed layers'
+        # combine gathers, [2,048 tokens, 4 choices, 2,048] bf16 each.
+        combine_gathers = (4 * eng._chunk * 4 * 2048 * 2
+                           if name == "_chunk_paged" else 0)
         assert temp_b == pytest.approx(
-            stated("temporaries")[1 - which] + sampler_row, rel=0.1)
+            stated("temporaries")[1 - which] + sampler_row
+            - combine_gathers, rel=0.1)
         assert args_b + temp_b < 16.9e9, (name, args_b, temp_b)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
                             header).group(1)
@@ -509,6 +559,42 @@ def test_lfm2_cell_programs_compile_for_v5e(v5e_chip, monkeypatch):
         assert len(held) == 9
         aliased = {int(m) for m in re.findall(r"\((\d+), \{\}, ", aliases)}
         assert held <= aliased, (name, aliases)
+
+
+@pytest.mark.parametrize("family,routed", [("smallthinker", 4), ("gpt", 0)])
+def test_engine_counts_routed_layers_by_expert_path(v5e_chip, monkeypatch,
+                                                    family, routed):
+    """`ServeMetrics.moe_kernel_layers` / `moe_loop_layers` count the
+    routed layers a program traced, by the path their FFN took, under
+    the set-up stage open: the engine's build here on the CPU takes the
+    loop, the tick and chunk programs lowered for the described chip
+    with the TPU selection forced take the kernel, one a routed layer;
+    a model without experts counts nothing."""
+    import pddl_tpu.serve.engine as engine_module
+    from pddl_tpu.models.gpt import tiny_gpt
+    from pddl_tpu.models.llama import tiny_smallthinker
+    from pddl_tpu.serve import ServeEngine
+
+    model = tiny_smallthinker() if family == "smallthinker" else tiny_gpt()
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.ones((1, 8), jnp.int32), train=False))[
+            "params"]
+    eng = ServeEngine(model, {"params": params}, max_slots=3,
+                      prefill_len=64, prefix_block_size=4, prefix_chunk=16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shapes = functools.partial(_shapes_on, v5e_chip)
+    for site, prog, args in (
+            ("warmup:tick", eng._tick_p, eng._tick_args()),
+            ("warmup:chunk_prefill", eng._chunk_p,
+             eng._chunk_args_paged(eng._chunk))):
+        with engine_module._setup_stage(site, eng.metrics):
+            prog.lower(*shapes(*args))
+    snap = eng.metrics.snapshot()
+    assert {k: v for k, v in snap["moe_kernel_layers"].items() if v} == (
+        {"warmup:tick": routed, "warmup:chunk_prefill": routed}
+        if routed else {})
+    assert {k: v for k, v in snap["moe_loop_layers"].items() if v} == (
+        {"build": routed} if routed else {})
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(12, 12), (12, 4)])
